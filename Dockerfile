@@ -20,4 +20,10 @@ COPY docker-entrypoint.sh /docker-entrypoint.sh
 RUN chmod +x /docker-entrypoint.sh
 USER fishnet
 ENV PYTHONPATH=/app
+# this image installs jax[cpu]: the device path runs on XLA:CPU, and says
+# so (--backend tpu refuses a CPU backend it was not asked for). A TPU
+# image installs jax[tpu] and drops this line.
+ENV JAX_PLATFORMS=cpu
+# /app is read-only for the fishnet user; keep compiled programs at home
+ENV JAX_COMPILATION_CACHE_DIR=/home/fishnet/.cache/fishnet-tpu/xla
 ENTRYPOINT ["/docker-entrypoint.sh"]

@@ -61,7 +61,8 @@ try:  # pragma: no cover - exercised implicitly on every import
 except Exception:  # pragma: no cover - jax builds without the module
     _serialize_executable = None
 
-MANIFEST_VERSION = 1
+# 2: blobs carry the ids of the devices the program was compiled for
+MANIFEST_VERSION = 2
 MANIFEST_NAME = "manifest.json"
 
 # Sentinel cached after a key already missed: later calls skip the disk
@@ -415,11 +416,19 @@ class Registry:
             self._quarantine(path, key, "sha256 mismatch")
             return None
         try:
-            payload, in_tree, out_tree = pickle.loads(zlib.decompress(blob))
+            payload, in_tree, out_tree, device_ids = pickle.loads(
+                zlib.decompress(blob)
+            )
+            # load onto the devices the program was compiled for: left
+            # to its default, deserialize_and_load spreads a one-device
+            # executable over every device of the backend and the first
+            # call is rejected for its shard count
+            by_id = {d.id: d for d in jax.devices()}
             with trace.span("aot.deserialize", "aot",
                             program=entry.get("entry", "?"), key=key[:12]):
                 return _serialize_executable.deserialize_and_load(
-                    payload, in_tree, out_tree
+                    payload, in_tree, out_tree,
+                    execution_devices=[by_id[i] for i in device_ids],
                 )
         except Exception as e:
             self._quarantine(path, key, repr(e))
@@ -443,8 +452,11 @@ class Registry:
             payload, in_tree, out_tree = _serialize_executable.serialize(
                 compiled
             )
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ]
             blob = zlib.compress(
-                pickle.dumps((payload, in_tree, out_tree)), 6
+                pickle.dumps((payload, in_tree, out_tree, device_ids)), 6
             )
         except Exception as e:
             # shard_map/unsupported executables may refuse serialization;

@@ -8,12 +8,14 @@ abort-on-shutdown of pending batches.
 from __future__ import annotations
 
 import asyncio
+import json
 import os
 import signal
 from dataclasses import asdict
 from pathlib import Path
 from typing import Optional, Set
 
+from ..engine.base import NoAcceleratorError
 from ..engine.pyengine import PyEngine
 from ..obs import metrics as obs_metrics
 from ..obs import perf as obs_perf
@@ -199,10 +201,6 @@ async def run(cfg: Config) -> int:
     # recorder) and the Prometheus text endpoint on loopback
     if obs_trace.RECORDER is None:
         obs_trace.install_from_settings("client")
-    try:
-        obs_perf.register_build_info()
-    except (ImportError, TypeError, ValueError):
-        pass  # build-info gauge is best-effort decoration
     metrics_server = obs_metrics.serve_from_settings()
     if metrics_server is not None:
         logger.info(
@@ -249,10 +247,26 @@ async def run(cfg: Config) -> int:
         pass  # non-unix
 
     factory = make_engine_factory(cfg, logger, stats=stats)
+
+    def mirror_supervisor() -> None:
+        """SupervisorStats → the SQLite sink and the metrics registry
+        (fishnet_supervisor_*): one interface over the scattered
+        counter piles, so quarantines/replays are visible next to
+        occupancy (tools/occupancy_report.py --stats-db)."""
+        eng = factory.peek_tpu()
+        if eng is not None and hasattr(eng, "stats"):
+            sup = asdict(eng.stats)
+            stats.record_supervisor(sup)
+            obs_metrics.REGISTRY.absorb_totals("fishnet_supervisor", sup)
+
     if cfg.backend == "tpu":
-        # pay the XLA compile cost now, before any chunk deadline ticks;
-        # a flaky device at startup is non-fatal (workers retry per chunk)
+        # pay the XLA compile cost now, before any chunk deadline ticks.
+        # Three attempts stand for faults that can pass (a flaky device
+        # at start-up); an engine that never comes up is fatal — going
+        # on would serve every chunk from the supervisor's CPU fallback
+        # under the TPU's name
         logger.info("Warming up TPU engine (compiling search program) ...")
+        boot_error: Optional[Exception] = None
         for attempt in range(3):
             try:
                 engine = factory(EngineFlavor.TPU)
@@ -289,16 +303,27 @@ async def run(cfg: Config) -> int:
                                 engine.warmup_variants, logger.info
                             )
                         )
+                boot_error = None
+                break
+            except NoAcceleratorError as e:
+                boot_error = e  # no retry cures a missing accelerator
                 break
             except Exception as e:
+                boot_error = e
                 logger.warn(f"TPU warmup attempt {attempt + 1} failed: {e}")
                 if attempt < 2:
                     await asyncio.sleep(5.0)
-        else:
-            logger.warn(
-                "Proceeding with a cold TPU engine; first chunks may miss "
-                "their deadlines while XLA compiles."
-            )
+        if boot_error is not None:
+            logger.error(f"TPU engine did not come up: {boot_error}")
+            eng = factory.peek_tpu()
+            if eng is not None:
+                await eng.close()
+            stats.close()
+            return 1
+    # after the engine is up: the device fields come from the host's
+    # ready frame (or the in-process engine), never from JAX here
+    obs_perf.register_build_info()
+    mirror_supervisor()
     tasks = [
         asyncio.ensure_future(worker(i, queue, factory, logger))
         for i in range(cfg.cores)
@@ -308,17 +333,8 @@ async def run(cfg: Config) -> int:
         while True:
             await asyncio.sleep(SUMMARY_INTERVAL_S)
             logger.info(queue.stats_summary())
-            # recovery counters ride the same cadence into the SQLite
-            # sink, so quarantines/replays are visible next to occupancy
-            # (tools/occupancy_report.py --stats-db)
-            eng = factory.peek_tpu()
-            if eng is not None and hasattr(eng, "stats"):
-                sup = asdict(eng.stats)
-                stats.record_supervisor(sup)
-                # mirror the supervisor's ad-hoc counters into the
-                # metrics registry (tentpole: one interface over the
-                # scattered counter piles)
-                obs_metrics.REGISTRY.absorb_totals("fishnet_supervisor", sup)
+            # recovery counters ride the same cadence
+            mirror_supervisor()
             # fold the registry into the sqlite time series on the same
             # cadence as the summary line
             stats.record_metrics(obs_metrics.REGISTRY.snapshot())
@@ -363,6 +379,17 @@ async def run(cfg: Config) -> int:
     updater.cancel()
     await queue.shutdown()
     await queue.drain_submissions()
+    # once more, so a run shorter than one summary interval still
+    # leaves its final counters behind
+    mirror_supervisor()
+    sup = stats.last_supervisor
+    if sup:
+        logger.info(f"Supervisor counters: {json.dumps(sup, sort_keys=True)}")
+    eng = factory.peek_tpu()
+    if eng is not None:
+        # workers close the engine they used; one that was started and
+        # never used would otherwise be left to die with the event loop
+        await eng.close()
     stats.close()
     if restart_after_drain:
         logger.headline("Restarting into the updated version ...")

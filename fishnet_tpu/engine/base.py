@@ -17,6 +17,7 @@ a chunk can serve position traffic too.
 """
 from __future__ import annotations
 
+import os
 from typing import TYPE_CHECKING, List, Protocol
 
 from ..client.ipc import Chunk, PositionResponse
@@ -28,6 +29,38 @@ if TYPE_CHECKING:  # circular at runtime: session.py builds Chunks
 class EngineError(Exception):
     """Engine died or misbehaved; the worker drops and respawns it with
     backoff (reference: src/main.rs:330-336)."""
+
+
+class NoAcceleratorError(EngineError):
+    """`--backend tpu` came up on XLA:CPU without being asked to. No
+    retry cures it, and serving from the CPU under the TPU's name would
+    hide the device — the boot fails instead."""
+
+
+# exit status of an engine host (engine/host.py) whose boot was refused
+# for this reason; the supervisor turns it back into NoAcceleratorError
+EXIT_NO_ACCELERATOR = 3
+
+
+def cpu_asked_for() -> bool:
+    """True when the environment makes the CPU JAX's default platform
+    (JAX_PLATFORMS, the standard variable, with cpu named first): tests
+    and CPU tools set it, and then a CPU backend is what was asked for,
+    not a fallback. `tpu,cpu` asks for the TPU: JAX fails at start-up
+    where it cannot have it."""
+    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+    return first.strip().lower() == "cpu"
+
+
+def require_accelerator(platform: str) -> None:
+    """Refuse an un-asked-for CPU backend (`platform` is what
+    jax.default_backend() reports in the process that owns the engine)."""
+    if platform == "cpu" and not cpu_asked_for():
+        raise NoAcceleratorError(
+            "backend 'tpu' found no accelerator: JAX's default backend is "
+            "cpu. Refusing to serve from XLA:CPU under the TPU's name; set "
+            "JAX_PLATFORMS=cpu to run on the CPU on purpose."
+        )
 
 
 class Engine(Protocol):

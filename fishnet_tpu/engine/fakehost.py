@@ -13,7 +13,9 @@ A script is a JSON object:
      "chunks": ["ok", "hang", "stall", "crash:9", "corrupt",
                 "slow:1.5", "err", "ok:333", ...]}
 
-`boot[i]` is the startup behavior of the i-th host incarnation;
+An optional `"device": {platform, kind, count}` rides the ready frame as
+the real host's device report does. `boot[i]` is the startup behavior of
+the i-th host incarnation;
 `chunks[j]` the behavior for the j-th chunk EVER dispatched (counted
 across respawns). Position-level `submit()` traffic (engine/session.py)
 reaches a fakehost child the same way chunks do: SupervisedEngine's
@@ -418,7 +420,10 @@ def main(argv=None) -> int:
         freeze()
     elif boot.startswith("slow:"):
         time.sleep(float(boot.split(":", 1)[1]))
-    send({"t": "ready", "mono": fake_mono()})
+    ready = {"t": "ready", "mono": fake_mono()}
+    if isinstance(script.get("device"), dict):
+        ready["device"] = script["device"]
+    send(ready)
 
     while True:
         try:

@@ -11,7 +11,10 @@ Protocol (all frames are JSON objects with a "t" tag):
 
   child → parent
     hb     {phase, busy_s, seq}   ticker thread, every --hb-interval
-    ready  {}                     warmup finished; chunks may be sent
+    ready  {aot, mesh, device}    warmup finished; chunks may be sent.
+                                  device = {platform, kind, count} as
+                                  JAX reports it HERE, in the process
+                                  that owns the chip
     log    {msg}                  relayed to the parent's logger
     partial {id, fp, response,    one finished position, streamed as the
              ctx?}                engine's exactly-once delivery hook
@@ -47,6 +50,7 @@ import time
 from ..client.ipc import chunk_from_wire, position_fingerprint, response_to_wire
 from ..obs import trace
 from ..utils.heartbeat import PhaseTracker
+from .base import EXIT_NO_ACCELERATOR, NoAcceleratorError
 from .frames import FrameError, PipeClosed, read_frame, write_frame
 
 
@@ -164,6 +168,9 @@ def main(argv=None) -> int:
     try:
         with trace.span("warmup", "host"):
             engine = _build_engine(args, log)
+    except NoAcceleratorError as e:
+        log(f"boot refused: {e}")
+        return EXIT_NO_ACCELERATOR
     except Exception as e:
         log(f"engine construction/warmup failed: {type(e).__name__}: {e}")
         return 1
@@ -171,14 +178,19 @@ def main(argv=None) -> int:
     # log (and the fleet surface) whether this replica booted warm, plus
     # the mesh topology (parallel/partition.py) so a pod: fleet member's
     # health surfaces how many devices/processes its one logical engine
-    # actually spans
+    # actually spans, and the device the engine runs on — the parent
+    # never asks JAX itself (a py-backend host has none to report)
     from ..aot import registry as aot_registry
     from ..parallel.partition import default_topology
 
+    device = getattr(engine, "device", None)
     send({
         "t": "ready", "mono": time.monotonic(),
         "aot": aot_registry.boot_report(),
-        "mesh": default_topology(),
+        # default_topology() asks JAX for its devices: only an engine
+        # that runs on them has a mesh to report
+        "mesh": default_topology() if device else None,
+        "device": device,
     })
     phases.enter("idle")
 

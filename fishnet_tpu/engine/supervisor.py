@@ -47,6 +47,7 @@ runs the CI acceptance ladder end-to-end).
 from __future__ import annotations
 
 import asyncio
+import json
 import os
 import sys
 import time
@@ -65,10 +66,11 @@ from ..client.ipc import (
     responses_from_wire,
 )
 from ..client.logger import Logger
+from ..obs import perf as obs_perf
 from ..obs import trace as obs_trace
 from ..utils import sanitize
 from ..utils import settings
-from .base import EngineError
+from .base import EXIT_NO_ACCELERATOR, EngineError, NoAcceleratorError
 from .frames import FrameError, PipeClosed, encode, read_frame_async
 from .session import ChunkSubmit
 
@@ -231,6 +233,11 @@ class SupervisedEngine(ChunkSubmit):
         # child boot warm from a program bundle, and what does it cover
         self.aot_report: Optional[dict] = None
         self.mesh_report: Optional[dict] = None  # host mesh topology
+        # {platform, kind, count} of the device the CHILD runs on, as
+        # its own JAX reported it — this process never asks JAX, which
+        # would take a local chip away from the child
+        self.device: Optional[dict] = None
+        self._last_log = ""  # child's last log line: names a boot failure
         self._down_noted = True  # no live child yet
         self._closing = False
         self._go_id = 0
@@ -777,14 +784,29 @@ class SupervisedEngine(ChunkSubmit):
 
     async def _read_loop(self, proc, ready_fut) -> None:
         reason = "engine host exited"
+        boot_refused = False
         try:
             while True:
                 try:
                     msg = await read_frame_async(proc.stdout)
                 except PipeClosed:
+                    if not ready_fut.done():
+                        # a boot that failed: the pipe closes a moment
+                        # before the loop reaps the child, so wait for
+                        # the status — it tells a refused boot, which
+                        # no respawn cures, from a fault that may pass
+                        try:
+                            await asyncio.wait_for(proc.wait(), timeout=2.0)
+                        except asyncio.TimeoutError:
+                            pass
                     rc = proc.returncode
                     if rc is not None and rc != 0:
                         reason = f"engine host exited with status {rc}"
+                        if not ready_fut.done():
+                            boot_refused = rc == EXIT_NO_ACCELERATOR
+                            if self._last_log:
+                                # the child's last words name the failure
+                                reason += f": {self._last_log}"
                     break
                 except FrameError as e:
                     self.stats.protocol_errors += 1
@@ -810,6 +832,14 @@ class SupervisedEngine(ChunkSubmit):
                         # pod members span devices on several processes;
                         # surface the topology next to the AOT report
                         self.mesh_report = mesh_rep
+                    dev = msg.get("device")
+                    if isinstance(dev, dict):
+                        self.device = dev
+                        obs_perf.note_device(dev)
+                        self.logger.info(
+                            "engine host: ready on device "
+                            + json.dumps(dev, sort_keys=True)
+                        )
                     rep = msg.get("aot")
                     if isinstance(rep, dict):
                         # surfaced into fleet member health and logs: a
@@ -821,7 +851,8 @@ class SupervisedEngine(ChunkSubmit):
                                 f"{rep.get('programs', 0)} programs "
                                 f"(bundle {rep.get('fingerprint', '?')}, "
                                 f"covers "
-                                f"{','.join(rep.get('covers') or []) or 'none'})"
+                                f"{','.join(rep.get('covers') or []) or 'none'}"
+                                f", {rep.get('errors', 0)} rejected)"
                             )
                     if not ready_fut.done():
                         ready_fut.set_result(True)
@@ -861,11 +892,15 @@ class SupervisedEngine(ChunkSubmit):
                             ctx=obs_trace.ctx_from_wire(msg.get("ctx")),
                         )
                 elif t == "log":
-                    self.logger.info(f"engine host: {msg.get('msg', '')}")
+                    self._last_log = str(msg.get("msg", ""))
+                    self.logger.info(f"engine host: {self._last_log}")
         except asyncio.CancelledError:
             raise
         finally:
-            err = EngineError(reason)
+            err = (
+                NoAcceleratorError(reason) if boot_refused
+                else EngineError(reason)
+            )
             if not ready_fut.done():
                 ready_fut.set_exception(err)
             if self._pending is not None and not self._pending[1].done():

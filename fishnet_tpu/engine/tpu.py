@@ -35,7 +35,7 @@ from ..ops.search import INF, MATE, search_batch_resumable
 from ..utils import sanitize
 from ..utils import settings
 from ..utils.syncstats import SegmentController, SyncStats
-from .base import EngineError
+from .base import EngineError, require_accelerator
 from .session import ChunkSubmit
 
 # static stack depth; supports search depths up to MAX_PLY-1, with the
@@ -151,6 +151,7 @@ class TpuEngine(ChunkSubmit):
         mesh_refill: Optional[bool] = None,  # refill on mesh hosts too
         logger=None,  # client Logger for operational warnings; stderr if None
     ) -> None:
+        from ..obs import perf as obs_perf
         from ..utils import enable_compile_cache
 
         enable_compile_cache()  # restarts reuse compiled search programs
@@ -165,7 +166,14 @@ class TpuEngine(ChunkSubmit):
         # BEFORE the first jax.devices() call, so the mesh below spans
         # the global device set — one logical engine across processes
         dist_mod.ensure_initialized(logger=logger)
-        n_dev = len(jax.devices())
+        # this process owns the engine, so it is the one that may ask
+        # JAX what it runs on; a supervised parent reads this off the
+        # host's ready frame (engine/host.py)
+        self.device = obs_perf.claim_device()
+        # fail before anything is built: JAX falls back to XLA:CPU in
+        # silence when it finds no chip
+        require_accelerator(self.device["platform"])
+        n_dev = self.device["count"]
         self.mesh = make_mesh() if n_dev > 1 else None
         self.n_dev = n_dev if self.mesh is not None else 1
         # one shared transposition table for every lane and every chunk —

@@ -15,7 +15,7 @@ Three pieces:
   other. The schema/insert helpers are shared with the client's
   stats.db sink (client/stats.py ensure_perf_table/record_perf) so one
   sqlite file can carry both time series. ``backfill()`` ingests the
-  checked-in ``BENCH_r01–r05.json`` + ``MULTICHIP_r*.json`` artifacts
+  checked-in ``BENCH_r*.json`` + ``MULTICHIP_r*.json`` artifacts
   (idempotently — stable run ids + INSERT OR REPLACE), so trend history
   starts populated; ``emit_bench_round()`` writes the next
   ``BENCH_rNN.json`` from the ledger instead of by hand.
@@ -34,8 +34,9 @@ Three pieces:
   cross-host comparison.
 
 Pure stdlib at module scope (same constraint as obs/metrics.py and
-obs/trace.py): jax and the settings registry are imported lazily inside
-functions and every capture degrades to a no-op when they are absent.
+obs/trace.py). Only claim_device() and what follows it touch JAX: a
+supervised client or serve parent must leave the chip to its engine
+host child, so everything else here works from recorded facts.
 tools/perf_report.py holds the direction table and the regression
 detector that reads this ledger; docs/perf.md is the contract.
 """
@@ -55,6 +56,7 @@ __all__ = [
     "backfill_rows_from_artifacts",
     "build_info",
     "default_ledger_path",
+    "claim_device",
     "ensure_perf_table",
     "env_fingerprint",
     "flatten_result",
@@ -64,6 +66,7 @@ __all__ = [
     "register_build_info",
     "split_mesh_rows",
     "live_snapshot",
+    "note_device",
 ]
 
 # One row per (run, bench row, metric). `seq` orders runs within one
@@ -92,6 +95,8 @@ _CONFIG_LINE_RE = re.compile(r"^bench config ([A-Za-z0-9_.\-]+): (\{.*)$")
 _SEARCH_NODES_RE = re.compile(r"search nodes (\d+)")
 
 _build_info_cache: Optional[Dict[str, Any]] = None
+_device_report: Optional[Dict[str, Any]] = None
+_owns_device = False  # claim_device() ran: this process may ask JAX
 
 
 # --------------------------------------------------------------- build info
@@ -122,37 +127,61 @@ def git_sha(short: int = 12) -> str:
     return out.stdout.strip() if out.returncode == 0 else ""
 
 
+def claim_device() -> Dict[str, Any]:
+    """{platform, kind, count} of the device as JAX reports it, recorded
+    for build_info(). This INITIALISES the JAX backend, so only the one
+    process that owns the engine may call it (TpuEngine does): a local
+    chip belongs to the first process that touches it, and a client
+    parent that asked would lock its own engine host child out."""
+    global _owns_device
+    import jax
+
+    devs = jax.devices()
+    _owns_device = True
+    report = {
+        "platform": str(devs[0].platform),
+        "kind": str(devs[0].device_kind),
+        "count": len(devs),
+    }
+    note_device(report)
+    return report
+
+
+def note_device(report: Dict[str, Any]) -> None:
+    """Record the device the engine runs on, for build_info(): a
+    client/serve parent passes its supervised host's `ready` frame."""
+    global _device_report, _build_info_cache
+    _device_report = dict(report)
+    _build_info_cache = None
+
+
+def _dist_version(name: str) -> str:
+    from importlib import metadata
+
+    try:
+        return metadata.version(name)
+    except metadata.PackageNotFoundError:
+        return ""
+
+
 def build_info(refresh: bool = False) -> Dict[str, Any]:
     """git sha + jax/jaxlib versions + backend + device kind/count.
-    Degrades field-by-field (empty strings / zero) with no JAX or no
-    git — callable from pure-stdlib contexts."""
+    Never imports JAX: versions come from the installed distributions'
+    metadata, and the device is what claim_device()/note_device()
+    recorded — blank until the engine, or its host's ready frame, has
+    reported one."""
     global _build_info_cache
     if _build_info_cache is not None and not refresh:
         return dict(_build_info_cache)
+    dev = _device_report or {}
     info: Dict[str, Any] = {
         "git_sha": git_sha(),
-        "jax": "",
-        "jaxlib": "",
-        "backend": "",
-        "device_kind": "",
-        "device_count": 0,
+        "jax": _dist_version("jax"),
+        "jaxlib": _dist_version("jaxlib"),
+        "backend": str(dev.get("platform", "")),
+        "device_kind": str(dev.get("kind", "")),
+        "device_count": int(dev.get("count", 0)),
     }
-    try:
-        import jax
-
-        info["jax"] = str(jax.__version__)
-        try:
-            import jaxlib
-
-            info["jaxlib"] = str(getattr(jaxlib, "__version__", ""))
-        except Exception:
-            pass
-        info["backend"] = str(jax.default_backend())
-        devs = jax.devices()
-        info["device_kind"] = devs[0].device_kind if devs else ""
-        info["device_count"] = len(devs)
-    except Exception:
-        pass
     _build_info_cache = dict(info)
     return info
 
@@ -171,14 +200,14 @@ def register_build_info(registry=None) -> Dict[str, Any]:
 def env_fingerprint() -> str:
     """The AOT store fingerprint digest (aot/keys.py) truncated to 12
     hex chars — the env compatibility envelope a ledger row was
-    measured under. Empty string when JAX is unavailable (rows without
-    a fingerprint are compared report-only, never gated)."""
-    try:
-        from ..aot import keys
-
-        return keys.fingerprint_digest(keys.store_fingerprint())[:12]
-    except Exception:
+    measured under. It asks JAX for its devices, so it is empty in a
+    process that has not claimed the device (rows without a fingerprint
+    are compared report-only, never gated)."""
+    if not _owns_device:
         return ""
+    from ..aot import keys
+
+    return keys.fingerprint_digest(keys.store_fingerprint())[:12]
 
 
 # ----------------------------------------------------------------- flatten
@@ -425,7 +454,7 @@ class PerfLedger:
     def emit_bench_round(self, run_id: str,
                          root: Optional[str] = None) -> Optional[str]:
         """Write the next BENCH_rNN.json from this ledger run: the same
-        artifact shape the bench driver recorded by hand for r01–r05
+        artifact shape the bench driver recorded by hand in rounds 1–5
         (n/rc/tail/parsed), plus build-info + env fingerprint and the
         full per-row metric table."""
         root = root or repo_root()
@@ -560,8 +589,8 @@ def _parse_bench_artifact(path: str) -> Dict[str, Dict[str, float]]:
         rows["headline"] = flatten_result(
             {k: parsed[k] for k in ("value", "vs_baseline") if k in parsed})
     if not rows and "rc" in obj:
-        # a failed/timed-out round (BENCH_r01/r02 in the checked-in
-        # history) still ingests: its exit code is the whole story
+        # a failed/timed-out round still ingests: its exit code is the
+        # whole story
         rows["artifact"] = {"rc": float(obj.get("rc") or 0)}
     return rows
 

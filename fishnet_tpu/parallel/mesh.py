@@ -27,13 +27,6 @@ from ..aot import registry as _aot_registry
 from ..utils import sanitize as _sanitize
 from . import partition as _partition
 
-try:
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except AttributeError:  # older jax ships it under experimental, as check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "dp") -> Mesh:
@@ -105,12 +98,12 @@ def _segment_callable(mesh: Mesh, axis: str, has_tt: bool,
         return state, ttab, n.reshape(1), summ[None]
 
     in_specs, out_specs = _partition.segment_specs(has_tt, axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         seg,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     # AOT-wrapped (fishnet_tpu/aot/): the shard_map closure's compile
     # flags become extra key material — all call arguments are dynamic.
@@ -180,12 +173,12 @@ def _merge_callable(mesh: Mesh, axis: str):
     from ..ops.search import _merge_lanes
 
     in_specs, out_specs = _partition.merge_specs(axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         _merge_lanes,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return _sanitize.guard_donation(
         "parallel/mesh.py::mesh_merge",

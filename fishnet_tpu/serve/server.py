@@ -285,6 +285,12 @@ class ServeApp:
                 "cache": (
                     self.cache.counters() if self.cache is not None else None
                 ),
+                # the device the engine runs on, as the process that
+                # owns it reported (supervised host's ready frame, or
+                # the in-process TpuEngine); None for host engines
+                "device": getattr(
+                    getattr(self.session, "engine", None), "device", None
+                ),
             }, {}
         if path == "/debug/requests":
             if method != "GET":
@@ -594,9 +600,14 @@ async def run_serve(cfg) -> int:
         # autoscaling cold-start signal (docs/aot.md): a replica booted
         # from an AOT bundle reached this point without compiling, so
         # it can accept traffic the moment the listener opens
-        from ..aot import registry as aot_registry
+        if cfg.supervisor:
+            # the host child's own report (its ready frame): this
+            # process leaves jax, and the AOT registry with it, alone
+            rep = getattr(engine, "aot_report", None) or {}
+        else:
+            from ..aot import registry as aot_registry
 
-        rep = getattr(engine, "aot_report", None) or aot_registry.boot_report()
+            rep = aot_registry.boot_report()
         if rep.get("enabled"):
             logger.info(
                 f"serve: AOT assets — {rep.get('programs', 0)} programs "
@@ -649,10 +660,9 @@ async def run_serve(cfg) -> int:
     # ephemeral port (FISHNET_TPU_SERVE_PORT=0)
     logger.headline(f"serve: listening on {bound_host}:{bound_port}")
 
-    try:
-        obs_perf.register_build_info()
-    except (ImportError, TypeError, ValueError):
-        pass  # build-info gauge is best-effort decoration
+    # the engine is up: the device fields come from the supervised
+    # host's ready frame (or the in-process engine), never from JAX here
+    obs_perf.register_build_info()
     metrics_server = obs_metrics.serve_from_settings()
     if metrics_server is not None:
         logger.info(

@@ -14,30 +14,37 @@ only softens the compiles that still happen — AOT misses, export runs
 cover. It stays on by default because the tiers compose: a miss that
 falls back to JIT hits this cache before it hits the compiler.
 
+Where it lives is decided outside the program: JAX reads the standard
+JAX_COMPILATION_CACHE_DIR itself, and when that is set nothing here sets
+a directory (an engine host child inherits the variable through its
+environment). Unset, the cache is at one fixed path inside the checkout
+(`.cache/xla`, git-ignored) — the path is part of JAX's cache key, so a
+directory that moves between runs never hits.
+
 Disabled with FISHNET_TPU_NO_COMPILE_CACHE=1 (e.g. read-only filesystems).
 """
 from __future__ import annotations
 
+import os
 from pathlib import Path
 from typing import Optional
 
 from . import settings
 
+# <checkout>/.cache/xla: fixed, so restarts (and a second process of
+# the same run) find what the first one compiled
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".cache" / "xla"
+
 _enabled_path: Optional[Path] = None
 _force_disabled = False
 
 
-def _drop_cache_memo() -> None:
-    # jax memoizes "is the persistent cache used" at the first compile
-    # (compilation_cache._cache_checked), so flipping the config dir
-    # mid-process is silently ignored unless that memo is reset too
-    try:
-        from jax._src import compilation_cache
+def _reset_cache_memo() -> None:
+    # jax memoizes "is the persistent cache used" at the first compile,
+    # so a config change mid-process is ignored unless that is reset too
+    from jax.experimental.compilation_cache import compilation_cache
 
-        compilation_cache.reset_cache()
-    except Exception:
-        pass  # private API moved: config-only toggling still covers
-        # processes that flip the cache before their first compile
+    compilation_cache.reset_cache()
 
 
 def disable_compile_cache() -> None:
@@ -50,22 +57,17 @@ def disable_compile_cache() -> None:
     global _enabled_path, _force_disabled
     _force_disabled = True
     _enabled_path = None
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass  # jax absent/old: nothing was cached anyway
-    _drop_cache_memo()
+    jax.config.update("jax_enable_compilation_cache", False)
+    _reset_cache_memo()
 
 
-def enable_compile_cache(path: Optional[str] = None) -> Optional[Path]:
-    """Point JAX's persistent compilation cache at a writable directory.
+def enable_compile_cache() -> Optional[Path]:
+    """Turn JAX's persistent compilation cache on for this process.
 
-    Idempotent; returns the cache dir, or None when disabled/unavailable.
-    Must be called before the first compilation to benefit it. `path` is
-    a ROOT: a /<backend> namespace dir is appended to it, so never pass
-    a previously returned cache dir back in."""
+    Idempotent; returns the cache dir, or None when disabled. Must be
+    called before the first compilation to benefit it."""
     global _enabled_path
     if _force_disabled:
         return None
@@ -73,28 +75,23 @@ def enable_compile_cache(path: Optional[str] = None) -> Optional[Path]:
         return None
     if _enabled_path is not None:
         return _enabled_path
-    try:
-        import jax
+    import jax
 
-        p = Path(
-            path
-            or settings.get_str("FISHNET_TPU_COMPILE_CACHE")
-            or Path.home() / ".cache" / "fishnet-tpu" / "xla"
-        )
-        # namespace by backend: entries written through a remote-TPU
-        # plugin target the REMOTE host's CPU features; loading them in a
-        # local CPU run fails per-program (feature mismatch) and turns
-        # every tiny eager compile into a load-fail-recompile-rewrite
-        # cycle that can stall startup for minutes
-        p = p / jax.default_backend()
-        p.mkdir(parents=True, exist_ok=True)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        # placed from outside: jax.config already holds it
+        p = Path(placed)
+    else:
+        p = DEFAULT_CACHE_DIR
+        try:
+            p.mkdir(parents=True, exist_ok=True)
+        except OSError:
+            return None  # read-only checkout: run without the cache
         jax.config.update("jax_compilation_cache_dir", str(p))
-        # default thresholds skip small programs; cache everything — even
-        # the small host-callback programs add up across restarts
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _drop_cache_memo()
-        _enabled_path = p
-        return p
-    except Exception:
-        return None  # old jax / read-only home: run without the cache
+    # default thresholds skip small programs; cache everything — even
+    # the small host-callback programs add up across restarts
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _reset_cache_memo()
+    _enabled_path = p
+    return p

@@ -536,18 +536,12 @@ SETTINGS: Tuple[Setting, ...] = (
         engine=True,
     ),
     Setting(
-        name="FISHNET_TPU_COMPILE_CACHE",
-        kind="str",
-        default="",
-        doc="Persistent XLA compile cache directory "
-            "(default ~/.cache/fishnet-tpu/xla).",
-    ),
-    Setting(
         name="FISHNET_TPU_NO_COMPILE_CACHE",
         kind="bool",
         default="0",
         doc="Disable the persistent XLA compile cache entirely "
-            "(e.g. read-only filesystems).",
+            "(e.g. read-only filesystems). Its place is the standard "
+            "JAX_COMPILATION_CACHE_DIR, or <checkout>/.cache/xla.",
     ),
     Setting(
         name="FISHNET_TPU_UPDATE_URL",

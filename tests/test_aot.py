@@ -49,11 +49,9 @@ def aot_root(tmp_path):
     compile_cache._force_disabled = prev_forced
     compile_cache._enabled_path = None
     if not prev_forced and prev_path is not None:
-        # no path argument: enable_compile_cache appends /<backend> to
-        # whatever it is given, and prev_path is already namespaced —
-        # passing it back would send the rest of the suite to a cold
-        # <cache>/cpu/cpu directory. Argless re-enable rebuilds the
-        # same path conftest built.
+        # disable_compile_cache() leaves the directory alone and flips
+        # jax's own switch; flip it back and re-enable
+        jax.config.update("jax_enable_compilation_cache", True)
         restored = compile_cache.enable_compile_cache()
         assert restored == prev_path, (restored, prev_path)
 
@@ -225,7 +223,7 @@ def test_undeserializable_artifact_quarantined(aot_root):
     blob_dir = os.path.join(store_dir, "blobs")
     (name,) = os.listdir(blob_dir)
     path = os.path.join(blob_dir, name)
-    bogus = zlib.compress(pickle.dumps((b"not-an-executable", None, None)))
+    bogus = zlib.compress(pickle.dumps((b"not-an-executable", None, None, [0])))
     with open(path, "wb") as f:
         f.write(bogus)
     man_path = os.path.join(store_dir, "manifest.json")

@@ -17,7 +17,6 @@ from fishnet_tpu.obs import metrics as obs_metrics
 from fishnet_tpu.obs import perf
 from tools import perf_report
 
-REPO_ROOT = Path(__file__).resolve().parents[1]
 
 FP = "feedc0de9abc"
 
@@ -65,16 +64,50 @@ def test_ledger_replace_is_idempotent(tmp_path):
     led.close()
 
 
-def test_backfill_ingests_checked_in_artifacts_idempotently():
+def _write_artifacts(root):
+    """The artifact shapes backfill reads, small: a driver record whose
+    tail holds a config line and the headline, a failed round with
+    nothing but its exit code, a ledger-emitted record with a `rows`
+    table, and two multichip dry-run records."""
+    headline = {"metric": "nodes/sec/chip", "value": 1725,
+                "unit": "nodes/sec", "vs_baseline": 0.0043}
+    arts = {
+        "BENCH_r01.json": {"n": 1, "cmd": "python bench.py", "rc": 124,
+                           "tail": "", "parsed": None},
+        "BENCH_r02.json": {
+            "n": 2, "cmd": "python bench.py", "rc": 0, "parsed": headline,
+            "tail": ('bench config cfg4_chess960: {"nps": 900.5, "B": 64}\n'
+                     + json.dumps(headline) + "\n"),
+        },
+        "BENCH_r03.json": {"n": 3, "cmd": "perf-ledger", "rc": 0,
+                           "rows": {"ramp_best": {"nps": 1200.0, "B": 8}}},
+        "MULTICHIP_r01.json": {"n_devices": 8, "rc": 1, "ok": False,
+                               "skipped": False, "tail": "boom"},
+        "MULTICHIP_r02.json": {
+            "n_devices": 8, "rc": 0, "ok": True, "skipped": False,
+            "tail": "dryrun_multichip ok: 8 devices, train loss 0.0958, "
+                    "search nodes 4848"},
+    }
+    for name, obj in arts.items():
+        (root / name).write_text(json.dumps(obj))
+
+
+def test_backfill_ingests_artifacts_idempotently(tmp_path):
+    _write_artifacts(tmp_path)
     led = perf.PerfLedger.open(":memory:")
-    n1 = led.backfill(str(REPO_ROOT))
-    n2 = led.backfill(str(REPO_ROOT))
+    n1 = led.backfill(str(tmp_path))
+    n2 = led.backfill(str(tmp_path))
     assert n1 > 0 and n1 == n2
     runs = {r["run_id"]: r for r in led.runs()}
-    # every checked-in round ingests, including the failed early ones
-    for i in range(1, 6):
-        assert f"backfill:BENCH_r0{i}" in runs
-        assert f"backfill:MULTICHIP_r0{i}" in runs
+    # every round ingests, including the failed early one
+    assert set(runs) == {
+        "backfill:BENCH_r01", "backfill:BENCH_r02", "backfill:BENCH_r03",
+        "backfill:MULTICHIP_r01", "backfill:MULTICHIP_r02"}
+    assert led.run_metrics("backfill:BENCH_r01") == {
+        "artifact": {"rc": 124.0}}
+    assert led.run_metrics("backfill:BENCH_r02")["headline"]["value"] == 1725
+    assert led.run_metrics("backfill:BENCH_r03") == {
+        "ramp_best": {"nps": 1200.0, "B": 8.0}}
     # backfilled history carries no env fingerprint: never gated
     assert all(r["fingerprint"] == "" for r in runs.values())
     led.close()

@@ -78,7 +78,7 @@ def test_donation_guard_forwards_attributes():
     guard = sanitize.guard_donation(
         "test::attrs", jitted, argnums=(0,), force=True)
     # AOT tooling reaches .lower through the guard
-    assert guard.lower is jitted.lower
+    assert guard.lower == jitted.lower
 
 
 def test_donation_guard_keyword_argnames():

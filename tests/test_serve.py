@@ -400,6 +400,12 @@ def test_http_rejects_and_healthz():
             assert status == 200
             assert payload["status"] == "ok"
             assert payload["inflight"] == 0
+            # no engine behind this session, so no device to report
+            assert payload["device"] is None
+            session.engine = type("Eng", (), {"device": {
+                "platform": "tpu", "kind": "TPU v5 lite", "count": 1}})()
+            _, _, payload = await _http(host, port, "GET", "/healthz")
+            assert payload["device"]["platform"] == "tpu"
 
             status, _, _ = await _http(host, port, "POST", "/nope", {})
             assert status == 404

@@ -1,0 +1,186 @@
+"""The main path's search programs compile for a TPU v5e — no chip needed.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (`jax.experimental.topologies`). Nothing runs, so
+these say nothing about results or speed; they say the production-width
+programs still get through the chip's compiler and fit its 16 GB, which
+every XLA:CPU test is blind to (tests/conftest.py shrinks the engine to
+MAX_PLY=8 for the suite — every shape here is passed explicitly so the
+toy is never what compiles).
+
+Rules these tests live by: the topology is described inside a fixture —
+never at import, never in conftest.py, never autouse — because only one
+process may load libtpu and every xdist worker imports every test file;
+the compiles run in this process; and the persistent compile cache is
+off around them (an entry written for a described chip cannot be read
+back without one, and the next run would warn about it).
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+from fishnet_tpu.assets import load_default_params
+from fishnet_tpu.chess.position import Position
+from fishnet_tpu.ops import search as S
+from fishnet_tpu.ops import tt as tt_mod
+from fishnet_tpu.ops.board import from_position, stack_boards
+from fishnet_tpu.parallel import mesh as mesh_mod
+from fishnet_tpu.parallel import partition
+
+# production width (engine/tpu.py): MAX_PLY default, the widest warmup
+# bucket, the lane ceiling, the TT the engine allocates
+MAX_PLY = 32
+BUCKET = 256
+MAX_LANES = 1024
+TT_LOG2 = 21
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def params():
+    p = load_default_params("board768")
+    assert p is not None, "shipped board768 net missing"
+    return p
+
+
+def _on(tree, sharding):
+    """Shapes of `tree`, each placed by `sharding` (one sharding, or a
+    pytree of them matching `tree`)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tree, sharding,
+        )
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _roots(lanes: int):
+    return stack_boards([from_position(Position.initial())] * lanes)
+
+
+def _init_args(lanes: int):
+    return _roots(lanes), jnp.ones(lanes, jnp.int32), jnp.full(
+        lanes, 64, jnp.int32)
+
+
+def _state_shape(params, lanes: int, variant: str = "standard"):
+    roots, depth, budget = _init_args(lanes)
+    return jax.eval_shape(
+        lambda p, r, d, b: S.init_state(p, r, d, b, MAX_PLY, variant),
+        params, roots, depth, budget,
+    )
+
+
+def _fits(compiled) -> int:
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < used < HBM_BYTES, mem
+    return used
+
+
+def _compile_segment(params, sharding, lanes: int, variant: str,
+                     deep_tt: bool, prefer_deep: bool):
+    state = _state_shape(params, lanes, variant)
+    ttab = jax.eval_shape(lambda: tt_mod.make_table(TT_LOG2))
+    fn = jax.jit(
+        S._run_segment, static_argnames=("variant", "deep_tt", "prefer_deep")
+    )
+    return fn.lower(
+        _on(params, sharding), _on(state, sharding), _on(ttab, sharding),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding),
+        variant=variant, deep_tt=deep_tt, prefer_deep=prefer_deep,
+        tt_gen=jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=sharding),
+    ).compile()
+
+
+def test_init_state_compiles_at_production_width(one_chip, params):
+    fn = jax.jit(S.init_state, static_argnames=("max_ply", "variant"))
+    roots, depth, budget = _init_args(BUCKET)
+    compiled = fn.lower(
+        _on(params, one_chip), _on(roots, one_chip), _on(depth, one_chip),
+        _on(budget, one_chip), max_ply=MAX_PLY, variant="standard",
+    ).compile()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("lanes,variant,deep_tt,prefer_deep", [
+    # what warmup compiles for analysis chunks with helper lanes on
+    (BUCKET, "standard", False, True),
+    # the single-dispatch lane ceiling (FISHNET_TPU_MAX_LANES)
+    (MAX_LANES, "standard", False, True),
+    # the variant with the widest candidate space (drops)
+    (BUCKET, "crazyhouse", False, True),
+], ids=["standard-256", "standard-1024", "crazyhouse-256"])
+def test_run_segment_compiles(one_chip, params, lanes, variant, deep_tt,
+                              prefer_deep):
+    compiled = _compile_segment(
+        params, one_chip, lanes, variant, deep_tt, prefer_deep)
+    _fits(compiled)
+
+
+def test_merge_lanes_compiles(one_chip, params):
+    state = _on(_state_shape(params, BUCKET), one_chip)
+    mask = jax.ShapeDtypeStruct((BUCKET,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(S._merge_lanes).lower(state, state, mask).compile()
+    _fits(compiled)
+
+
+def test_sharded_segment_compiles_on_four_chips(topo, params):
+    """The shard_map'd segment of parallel/mesh.py on a 4-device mesh,
+    placed by the partition-rule registry's own specs."""
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    assert mesh.devices.size == 4
+    in_specs, _ = partition.segment_specs(True, "dp")
+
+    def named(specs):
+        return jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec), specs,
+            is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
+        )
+
+    p_sh, st_sh, tt_sh, steps_sh, gen_sh = (named(s) for s in in_specs)
+    lanes = BUCKET * 4  # one production bucket per chip
+    state = _state_shape(params, lanes)
+    ttab = jax.eval_shape(
+        lambda: tt_mod.TTable(
+            data=jnp.zeros((4, 1 << TT_LOG2, 4), jnp.int32)))
+    fn = mesh_mod._segment_callable(
+        mesh, "dp", True, "standard", False, True)
+    compiled = fn.lower(
+        _on(params, p_sh), _on(state, st_sh), _on(ttab, tt_sh),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=steps_sh),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=gen_sh),
+    ).compile()
+    # per-device bytes: each chip holds its lanes and its TT shard
+    _fits(compiled)
+    assert "all-reduce" not in compiled.as_text(), (
+        "the sharded segment is meant to run with no collectives")
