@@ -1,0 +1,57 @@
+"""What one search node has to compute and move, from shapes alone.
+
+Algorithmic counts for one node of the search as the configuration defines
+it, whatever program implements it: they never read an HLO or a trace, so a
+later PR that removes an operation (the big sort, say) leaves them as they
+are and can only raise the share of the roofline it reaches.
+
+Per node:
+* NNUE accumulator update: a move changes at most 4 piece placements; each
+  adds or subtracts one L1-wide row per perspective.
+* NNUE forward: 2*L1 -> H1 -> H2 -> 1 dense layers of one output bucket
+  (2 FLOPs per multiply-add).
+* Bytes: the changed feature rows and the bucket's layer weights read, the
+  accumulator pair read and written, the board row read and the child's
+  written, the node's scalars, the move list written and read once, one
+  table probe and one table store.
+"""
+from __future__ import annotations
+
+from typing import Dict
+
+MAX_PIECE_CHANGES = 4  # mover off, mover on, captured off, rook/ep victim
+BOARD_ROW_BYTES = (64 + 1 + 1 + 4 + 1 + 12 + 2) * 4  # board, stm, ep, castling, clock, variant state, path hash
+NODE_ROW_BYTES = 16 * 4
+TT_ENTRY_BYTES = 16
+MOVE_BYTES = 4
+
+
+def per_node(shapes: Dict[str, int], max_moves: int) -> Dict[str, float]:
+    """shapes: l1, h1, h2 of the net. max_moves: the move list's width for
+    the variant (218 is the chess maximum; crazyhouse adds 5*64 drops)."""
+    l1, h1, h2 = shapes["l1"], shapes["h1"], shapes["h2"]
+    acc_flops = 2 * MAX_PIECE_CHANGES * l1  # two perspectives, one add each
+    fwd_flops = 2 * (2 * l1 * h1 + h1 * h2 + h2)
+    weight_bytes = 4 * (2 * MAX_PIECE_CHANGES * l1
+                        + 2 * l1 * h1 + h1 + h1 * h2 + h2 + h2 + 1)
+    acc_bytes = 2 * (2 * l1 * 4)  # pair read, pair written
+    board_bytes = 2 * BOARD_ROW_BYTES + NODE_ROW_BYTES
+    move_bytes = 2 * max_moves * MOVE_BYTES
+    tt_bytes = 2 * TT_ENTRY_BYTES
+    return {
+        "flops": float(acc_flops + fwd_flops),
+        "bytes": float(weight_bytes + acc_bytes + board_bytes + move_bytes
+                       + tt_bytes),
+    }
+
+
+def roofline_share(nodes: float, busy_s: float, node: Dict[str, float],
+                   peak: Dict[str, float]):
+    """→ (share in %, which bound): the least time the chip could take for
+    `nodes` nodes over the time its ops took."""
+    if not nodes or not busy_s or busy_s <= 0:
+        return None, None
+    t_flops = nodes * node["flops"] / peak["flops_per_s"]
+    t_bytes = nodes * node["bytes"] / peak["bytes_per_s"]
+    bound = "flops" if t_flops >= t_bytes else "bytes"
+    return 100.0 * max(t_flops, t_bytes) / busy_s, bound
