@@ -53,7 +53,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 
 from ..obs import trace
-from ..utils import settings
+from ..utils import settings, syncstats
 from . import keys
 
 try:  # pragma: no cover - exercised implicitly on every import
@@ -102,6 +102,14 @@ def _on_compile_duration(event: str, duration: float, **kw: Any) -> None:
         ).inc(float(duration))
     except (ImportError, TypeError, ValueError):
         pass  # metrics are best-effort; the trace mirror still runs
+    # which part of the program asked for it: the listener fires in the
+    # compiling thread, and a thread serving an engine's chunk carries
+    # that engine's counters and the phase or submit step it is in
+    totals, where = syncstats.where()
+    if totals is not None:
+        key = "compiles_" + where
+        totals[key if key in totals else "compiles_other"] += 1
+        totals["compile_ms"] += float(duration) * 1000.0
     rec = trace.RECORDER
     if rec is not None:
         dur_us = float(duration) * 1e6
@@ -113,6 +121,7 @@ def _on_compile_duration(event: str, duration: float, **kw: Any) -> None:
             args={
                 "event": event,
                 "program": getattr(_compile_current, "program", ""),
+                "where": where if totals is not None else None,
             },
         )
 

@@ -12,6 +12,7 @@ program shapes, then caches.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import sys
 import threading
 import time
@@ -34,6 +35,7 @@ from ..obs import trace as obs_trace
 from ..ops.search import INF, MATE, search_batch_resumable
 from ..utils import sanitize
 from ..utils import settings
+from ..utils import syncstats
 from ..utils.syncstats import SegmentController, SyncStats
 from .base import EngineError, require_accelerator
 from .session import ChunkSubmit
@@ -125,6 +127,17 @@ def _move_job_floor(variant: str) -> int:
     against its 7 s deadline. Crazyhouse drops push legal counts past
     64, so its bucket is 128."""
     return 128 if variant == "crazyhouse" else 64
+
+
+# the boundary phases of LaneScheduler._drive_session ("wait" and
+# "other" are SyncStats' own: blocked in fetch(), covered by no phase)
+PHASES = ("reap", "admit", "refill", "dispatch", "wait", "lanes", "pv",
+          "account", "other")
+# where a program can be built from, as `compiles_<where>` counts it
+# (aot/registry.py's compile listener reads the thread's
+# syncstats.where(); any other phase or step counts as "other")
+COMPILE_SITES = ("refill", "dispatch", "pv", "session_setup",
+                 "submit_history", "other")
 
 
 def _pad_lanes(n: int) -> int:
@@ -303,6 +316,28 @@ class TpuEngine(ChunkSubmit):
             # the host spent blocked on device results vs doing boundary
             # bookkeeping, plus the host-device transfer count
             "host_ms": 0.0, "device_ms": 0.0, "transfers": 0,
+            # the same boundary intervals by what the host was doing
+            # (SyncStats.phase): sums to host_ms + device_ms, and
+            # phase_wait_ms is device_ms
+            **{f"phase_{name}_ms": 0.0 for name in PHASES},
+            # drive sessions (wall inside _drive_session; set-up is
+            # entry to the first boundary interval, tail the last
+            # boundary to return) and the time between them: some
+            # thread inside _submit / the driver waiting for the engine
+            # lock / work queued but nobody driving (the 50 ms poll of
+            # run_chunk) / nothing submitted at all — the caller's time
+            "sessions": 0, "session_ms": 0.0, "session_setup_ms": 0.0,
+            "session_tail_ms": 0.0,
+            "gap_ms": 0.0, "gap_submit_ms": 0.0, "gap_lock_ms": 0.0,
+            "gap_handoff_ms": 0.0, "gap_starved_ms": 0.0,
+            # the submit path, per chunk and position: game-prefix
+            # replay on the host, history hash (a device call), TT warm
+            "chunks_submitted": 0, "positions_submitted": 0,
+            "submit_ms": 0.0, "submit_replay_ms": 0.0,
+            "submit_history_ms": 0.0, "submit_ttwarm_ms": 0.0,
+            # programs built or loaded while serving a chunk, by where
+            **{f"compiles_{site}": 0 for site in COMPILE_SITES},
+            "compile_ms": 0.0,
         }
         # per-delta aspiration accounting {delta: [windowed, fail_lo,
         # fail_hi, nodes]} — the measured basis for ASPIRATION_DELTAS
@@ -811,15 +846,16 @@ class TpuEngine(ChunkSubmit):
         # bit-identical to the pre-refill code by construction
         # (enforced by tests).
         work = chunk.work
-        if (
-            self.refill
-            and (self.mesh is None or self.mesh_refill)
-            and isinstance(work, AnalysisWork)
-            and work.effective_multipv() == 1
-        ):
-            return self._scheduler.run_chunk(chunk)
-        with self._lock:
-            return self._go_multiple_locked(chunk)
+        with syncstats.serving(self.occupancy_totals):
+            if (
+                self.refill
+                and (self.mesh is None or self.mesh_refill)
+                and isinstance(work, AnalysisWork)
+                and work.effective_multipv() == 1
+            ):
+                return self._scheduler.run_chunk(chunk)
+            with self._lock:
+                return self._go_multiple_locked(chunk)
 
     def _go_multiple_locked(self, chunk: Chunk) -> List[PositionResponse]:
         started = time.monotonic()
@@ -1462,6 +1498,14 @@ class LaneScheduler:
         self._pending: List[_RefillJob] = []
         self._driving = False
         self._jitter_seq = 0
+        # the time no session runs, accounted when the next one starts
+        # (all under _q_lock): when the last session ended, since when
+        # work has been queued with nobody driving, and the _submit
+        # calls open now / closed since the last session ended
+        self._session_end: Optional[float] = None
+        self._pending_since: Optional[float] = None
+        self._submits_open: dict = {}
+        self._submits_done: List[tuple] = []
         # FISHNET_TPU_SANITIZE, captured once: _deliver pays a single
         # attribute test per position, nothing per boundary
         self._sanitize = sanitize.enabled()
@@ -1558,9 +1602,80 @@ class LaneScheduler:
         except Exception as e:
             eng._warn(f"tt warm export failed: {e}")
 
+    @staticmethod
+    @contextlib.contextmanager
+    def _submit_step(rec, nest_id: str, name: str, spent: dict):
+        """One timed step of _submit for one position: adds its
+        milliseconds to spent[name], labels the thread for the compile
+        listener (`submit_<name>`), and with a recorder on leaves a
+        `submit.<name>` async pair under the chunk's `submit` pair."""
+        if rec is not None:
+            rec.nest("submit." + name, nest_id, "b", "engine")
+        try:
+            with syncstats.step("submit_" + name) as st:
+                yield
+        finally:
+            spent[name] += st.ms
+            if rec is not None:
+                rec.nest("submit." + name, nest_id, "e", "engine")
+
     def _submit(self, chunk: Chunk) -> _ChunkEntry:
+        """Replay, hash and queue one chunk's positions. Runs on the
+        caller's executor thread, several at once and mostly while no
+        session runs, so it is counted (submit_* totals, and the
+        `gap_submit_ms` part of the time between sessions) and, with a
+        recorder on, traced as nestable async pairs — never as thread
+        "X" spans, which a reader of the timeline takes for a drive
+        session's host work."""
+        t_sub = time.monotonic()
+        entry = _ChunkEntry(chunk, t_sub)
+        spent = {"replay": 0.0, "history": 0.0, "ttwarm": 0.0}
+        rec = obs_trace.RECORDER
+        nest_id = ""
+        if rec is not None and chunk.positions:
+            # the chunks of one batch share a work id: the first
+            # position's index tells their async tracks apart
+            wp0 = chunk.positions[0]
+            ctx0 = next((wp.ctx for wp in chunk.positions
+                         if wp.ctx and wp.ctx.get("trace_id")), None)
+            nest_id = (f"{ctx0['trace_id'] if ctx0 else chunk.work.id}"
+                       f":{wp0.position_index}")
+            rec.nest("submit", nest_id, "b", "engine",
+                     positions=len(chunk.positions))
+        with self._q_lock:
+            self._submits_open[id(entry)] = t_sub
+        try:
+            jobs = self._plan_jobs(entry, rec, nest_id, spent)
+            entry.n_open = len(jobs)
+            if not jobs:
+                entry.event.set()
+            with self._q_lock:
+                if jobs and not self._pending:
+                    self._pending_since = time.monotonic()
+                self._pending.extend(jobs)
+        finally:
+            t_end = time.monotonic()
+            with self._q_lock:
+                del self._submits_open[id(entry)]
+                self._submits_done.append((t_sub, t_end))
+                # several threads submit at once: their shared totals
+                # are added under the lock
+                tot = self.engine.occupancy_totals
+                tot["chunks_submitted"] += 1
+                tot["positions_submitted"] += len(chunk.positions)
+                tot["submit_ms"] += (t_end - t_sub) * 1000.0
+                for name, ms in spent.items():
+                    tot[f"submit_{name}_ms"] += ms
+            if rec is not None and nest_id:
+                rec.nest("submit", nest_id, "e", "engine")
+        return entry
+
+    def _plan_jobs(self, entry: _ChunkEntry, rec, nest_id: str,
+                   spent: dict) -> List[_RefillJob]:
+        """The chunk's positions as refill jobs; terminal positions are
+        answered here and now."""
         eng = self.engine
-        entry = _ChunkEntry(chunk, time.monotonic())
+        chunk = entry.chunk
         work = chunk.work
         assert isinstance(work, AnalysisWork)
         target_depth = min(
@@ -1572,24 +1687,27 @@ class LaneScheduler:
         deadline = chunk.deadline - 0.25  # slack to package results
         jobs = []
         for wp in chunk.positions:
-            pos = from_fen(wp.root_fen, chunk.variant)
-            game = []
-            for uci in wp.moves:
-                game.append(pos)
-                pos = pos.push(pos.parse_uci(uci))
-            if pos.outcome() is not None:
+            with self._submit_step(rec, nest_id, "replay", spent):
+                pos = from_fen(wp.root_fen, chunk.variant)
+                game = []
+                for uci in wp.moves:
+                    game.append(pos)
+                    pos = pos.push(pos.parse_uci(uci))
+                over = pos.outcome() is not None
+            if over:
                 self._deliver(
                     entry, wp, eng._terminal_response(chunk, wp, pos, 0.001)
                 )
                 continue
-            hh, hm = TpuEngine._history_arrays([game], 1, variant)
+            with self._submit_step(rec, nest_id, "history", spent):
+                hh, hm = TpuEngine._history_arrays([game], 1, variant)
             if eng.tt_warm is not None:
-                self._tt_warm_plan(entry, wp, pos, variant)
+                with self._submit_step(rec, nest_id, "ttwarm", spent):
+                    self._tt_warm_plan(entry, wp, pos, variant)
             job = _RefillJob(
                 entry, wp, pos, from_position(pos), variant, target_depth,
                 per_pos_budget, deadline, hh[0], hm[0],
             )
-            rec = obs_trace.RECORDER
             ctx = wp.ctx
             if ctx and ctx.get("trace_id"):
                 tid = ctx["trace_id"]
@@ -1606,12 +1724,7 @@ class LaneScheduler:
                     )
                     rec.flow("request", tid, "t")
             jobs.append(job)
-        entry.n_open = len(jobs)
-        if not jobs:
-            entry.event.set()
-        with self._q_lock:
-            self._pending.extend(jobs)
-        return entry
+        return jobs
 
     def _deliver(self, entry: _ChunkEntry, wp, response) -> None:
         """Exactly-once delivery point for one position's result: every
@@ -1694,25 +1807,69 @@ class LaneScheduler:
                     return
             # lock released between sessions: a blocked move job or
             # multipv chunk gets the device before the next session
+            lock_req_s = time.monotonic()
             with self.engine._lock:
-                self._drive_session(entry)
+                self._drive_session(entry, lock_req_s)
 
-    def _drive_session(self, entry: _ChunkEntry) -> None:
+    def _close_gap(self, g1: float, lock_req_s: float) -> None:
+        """Account the time since the last session ended, at the moment
+        `g1` the next one starts (caller holds _q_lock). Every instant
+        of the gap goes to the first of: some thread was inside _submit
+        (`gap_submit_ms`); the driver was waiting for the engine lock
+        (`gap_lock_ms`); work was queued but no thread had picked the
+        driving up (`gap_handoff_ms`, run_chunk's 50 ms poll); nothing
+        was submitted or queued (`gap_starved_ms`) — the caller's time,
+        not the engine's."""
+        g0 = self._session_end
+        if g0 is None:
+            return  # the engine's first session: nothing came before
+        submits = self._submits_done + [
+            (t0, g1) for t0 in self._submits_open.values()
+        ]
+        since = self._pending_since
+        marks = [lock_req_s, since] + [t for iv in submits for t in iv]
+        cuts = sorted({g0, g1} | {t for t in marks
+                                  if t is not None and g0 < t < g1})
+        parts = {"submit": 0.0, "lock": 0.0, "handoff": 0.0, "starved": 0.0}
+        for a, b in zip(cuts, cuts[1:]):
+            mid = (a + b) / 2.0
+            if any(t0 <= mid < t1 for t0, t1 in submits):
+                part = "submit"
+            elif mid >= lock_req_s:
+                part = "lock"
+            elif since is not None and mid >= since:
+                part = "handoff"
+            else:
+                part = "starved"
+            parts[part] += b - a
+        tot = self.engine.occupancy_totals
+        tot["gap_ms"] += (g1 - g0) * 1000.0
+        for part, seconds in parts.items():
+            tot[f"gap_{part}_ms"] += seconds * 1000.0
+
+    def _drive_session(self, entry: _ChunkEntry, lock_req_s: float) -> None:
         """One fixed-width drive session: admit, dispatch segments,
         process boundaries, until no lane is running. Jobs of OTHER
         device variants stay queued (each variant is a distinct static
         program); a later session picks them up."""
         eng = self.engine
-        now = time.monotonic()
+        tot = eng.occupancy_totals
+        t_enter = now = time.monotonic()
         with self._q_lock:
             if not self._pending:
                 return
+            self._close_gap(t_enter, lock_req_s)
             self._pending.sort(key=lambda j: j.deadline)
             variant = self._pending[0].variant
             n_hint = sum(1 for j in self._pending if j.variant == variant)
             filler = next(
                 j for j in self._pending if j.variant == variant
             ).board
+            # what the session's width was chosen from, and against:
+            # the row that shows the race between submitters and driver
+            session_args = {"n_hint": n_hint, "pending": len(self._pending),
+                            "variant": variant}
+        at_start = (tot["segments"], tot["steps"], tot["positions_done"])
         K = eng.helper_lanes
         B = eng._helper_width(min(max(n_hint, 1), eng.max_lanes))
         # shard-aware session: under a mesh the SAME loop drives the
@@ -1743,7 +1900,6 @@ class LaneScheduler:
             )
             seg = ctrl.steps
         pipeline = settings.get_bool("FISHNET_TPU_PIPELINE")
-        stats = SyncStats()
         prefer_deep = K > 1 and eng.tt is not None
         deltas = ASPIRATION_DELTAS + (None,)  # None = full window
 
@@ -1760,30 +1916,32 @@ class LaneScheduler:
         # _init_state_jit trace with refill_lanes' fresh states
         from ..ops.search import HIST_HM_SENTINEL, MAX_HIST
 
-        state = search_ops._init_state_jit(
-            eng.params, stack_boards([filler] * B),
-            jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
-            MAX_PLY, variant,
-            hist_hash=jnp.zeros((B, MAX_HIST, 2), jnp.uint32),
-            hist_halfmove=jnp.full(
-                (B, MAX_HIST), HIST_HM_SENTINEL, jnp.int32
-            ),
-            root_alpha=jnp.full((B,), -INF, jnp.int32),
-            root_beta=jnp.full((B,), INF, jnp.int32),
-            order_jitter=jnp.zeros((B,), jnp.int32),
-            group=jnp.zeros((B,), jnp.int32),
-        )
-        if mesh is not None:
-            from ..parallel.mesh import (
-                refill_lanes_sharded,
-                run_segment_sharded,
-                shard_batch,
+        with syncstats.step("session_setup"):
+            state = search_ops._init_state_jit(
+                eng.params, stack_boards([filler] * B),
+                jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
+                MAX_PLY, variant,
+                hist_hash=jnp.zeros((B, MAX_HIST, 2), jnp.uint32),
+                hist_halfmove=jnp.full(
+                    (B, MAX_HIST), HIST_HM_SENTINEL, jnp.int32
+                ),
+                root_alpha=jnp.full((B,), -INF, jnp.int32),
+                root_beta=jnp.full((B,), INF, jnp.int32),
+                order_jitter=jnp.zeros((B,), jnp.int32),
+                group=jnp.zeros((B,), jnp.int32),
             )
+            if mesh is not None:
+                from ..parallel.mesh import (
+                    refill_lanes_sharded,
+                    run_segment_sharded,
+                    shard_batch,
+                )
 
-            # place the base state sharded before the first dispatch:
-            # the sharded segment donates its operands, and donation
-            # only takes when the input already carries the sharding
-            state = shard_batch(mesh, state)
+                # place the base state sharded before the first
+                # dispatch: the sharded segment donates its operands,
+                # and donation only takes when the input already
+                # carries the sharding
+                state = shard_batch(mesh, state)
         tt = eng.tt
 
         # admissions accumulated between boundaries, flushed as ONE
@@ -2241,6 +2399,47 @@ class LaneScheduler:
                 adm[k].clear()
             return st, n_adm, adm_shard
 
+        # the session's boundary intervals open here: what came before
+        # is set-up, and the first admission and refill below are
+        # phases of the first segment
+        stats = SyncStats()
+        t_mark = t_enter
+
+        def credit_session(upto: float) -> float:
+            """Add the wall-clock since the last credit to session_ms:
+            at every boundary, so that counters read in the middle of a
+            session are short by one interval at most."""
+            nonlocal t_mark
+            ms = (upto - t_mark) * 1000.0
+            tot["session_ms"] += ms
+            t_mark = upto
+            return ms
+
+        setup_ms = credit_session(stats.interval_open_s)
+        tot["session_setup_ms"] += setup_ms
+        session_args["setup_ms"] = round(setup_ms, 3)
+
+        def launch(st, table, n_adm, adm_shard, speculative):
+            """Dispatch one segment of the pipelined loop on (st,
+            table), which the program donates → (its outputs, what the
+            boundary that reaps it records about it)."""
+            with stats.phase("account"):
+                meta = {
+                    "live": len(active),
+                    "helpers": sum(len(j.helpers) for j in active),
+                    "refilled": n_adm,
+                    "queue": 0 if speculative else q_len_locked(),
+                    "shard_live": shard_occup(),
+                    "shard_refilled": adm_shard,
+                    "steps": seg,
+                    "traced": traced_snapshot(),
+                }
+            meta["t0"] = time.monotonic()
+            with stats.phase("dispatch", steps=seg,
+                             speculative=speculative):
+                out = dispatch(st, table, seg)
+            return out, meta
+
         res: Optional[dict] = None
         try:
             if not pipeline:
@@ -2250,76 +2449,84 @@ class LaneScheduler:
                 # baseline, instrumented through SyncStats
                 while True:
                     now = time.monotonic()
-                    reap_jobs(
-                        now, res["nodes"] if res is not None else None
-                    )
-                    admit_new(now)
-                    state, n_adm, adm_shard = flush_adm(state)
+                    with stats.phase("reap"):
+                        reap_jobs(
+                            now, res["nodes"] if res is not None else None
+                        )
+                    with stats.phase("admit"):
+                        admit_new(now)
+                    with stats.phase("refill"):
+                        state, n_adm, adm_shard = flush_adm(state)
                     if not active:
                         break  # nothing running; next session continues
                     # ---- dispatch one segment and block on it
-                    live_n = len(active)
-                    helper_n = sum(len(j.helpers) for j in active)
-                    shard_live = shard_occup()
-                    disp_steps = seg
-                    seg_res = traced_snapshot()
+                    with stats.phase("account"):
+                        live_n = len(active)
+                        helper_n = sum(len(j.helpers) for j in active)
+                        shard_live = shard_occup()
+                        disp_steps = seg
+                        seg_res = traced_snapshot()
                     t0 = time.monotonic()
-                    with obs_trace.span("segment.dispatch", "engine",
-                                        steps=seg, live=live_n):
+                    with stats.phase("dispatch", steps=seg, live=live_n):
                         state, tt, n, _summ = dispatch(state, tt, seg)
                     n_arr = np.asarray(
                         stats.fetch(n, "steps")
                     ).reshape(-1)
                     n = int(n_arr.max())
                     wall = time.monotonic() - t0
-                    traced_residency(seg_res, t0, t0 + wall)
-                    q_len = q_len_locked()
+                    with stats.phase("account"):
+                        traced_residency(seg_res, t0, t0 + wall)
+                        q_len = q_len_locked()
                     # ---- process finished lanes at the boundary
-                    lane_done = stats.fetch(
-                        state.lane[:, search_ops.LN_MODE]
-                        == search_ops.MODE_DONE,
-                        "done",
-                    )
-                    res = {
-                        k: stats.fetch(v, k)
-                        for k, v in search_ops.extract_results(
-                            state, 0
-                        ).items()
-                        if k != "steps"
-                    }
-                    now = time.monotonic()
-                    # helper lanes that parked on their own: charge+free
-                    for lane in range(B):
-                        job = lane_owner[lane]
-                        if job is not None and lane_done[lane]:
-                            hn = int(res["nodes"][lane])
-                            job.nodes_total += hn
-                            job.remaining -= hn
-                            del job.helpers[lane]
-                            lane_owner[lane] = None
-                    # primary lanes that parked: aspiration verdict
-                    for lane in range(B):
-                        job = lane_job[lane]
-                        if job is None or not lane_done[lane]:
-                            continue
-                        on_primary_done(job, lane, res, now)
-                    snap = stats.boundary()
-                    self._record_occupancy(
-                        B, n, live_n, helper_n, n_adm, q_len, wall,
-                        snap["host_ms"], snap["device_ms"],
-                        snap["transfers"],
-                        shard=None if mesh is None else {
-                            "shard_live": shard_live,
-                            "shard_refilled":
-                                adm_shard or [0] * n_shard,
-                            "shard_steps": [int(x) for x in n_arr],
-                        },
-                    )
-                    if ctrl is not None:
-                        seg = ctrl.update(
-                            n >= disp_steps, snap["host_ms"],
-                            snap["device_ms"],
+                    with stats.phase("lanes"):
+                        lane_done = stats.fetch(
+                            state.lane[:, search_ops.LN_MODE]
+                            == search_ops.MODE_DONE,
+                            "done",
                         )
+                        res = {
+                            k: stats.fetch(v, k)
+                            for k, v in search_ops.extract_results(
+                                state, 0
+                            ).items()
+                            if k != "steps"
+                        }
+                        now = time.monotonic()
+                        # helper lanes that parked on their own:
+                        # charge+free
+                        for lane in range(B):
+                            job = lane_owner[lane]
+                            if job is not None and lane_done[lane]:
+                                hn = int(res["nodes"][lane])
+                                job.nodes_total += hn
+                                job.remaining -= hn
+                                del job.helpers[lane]
+                                lane_owner[lane] = None
+                        # primary lanes that parked: aspiration verdict
+                        for lane in range(B):
+                            job = lane_job[lane]
+                            if job is None or not lane_done[lane]:
+                                continue
+                            on_primary_done(job, lane, res, now)
+                    snap = stats.boundary()
+                    credit_session(stats.interval_open_s)
+                    with stats.phase("account"):
+                        self._record_occupancy(
+                            B, n, live_n, helper_n, n_adm, q_len, wall,
+                            snap["host_ms"], snap["device_ms"],
+                            snap["transfers"], snap["phases"],
+                            shard=None if mesh is None else {
+                                "shard_live": shard_live,
+                                "shard_refilled":
+                                    adm_shard or [0] * n_shard,
+                                "shard_steps": [int(x) for x in n_arr],
+                            },
+                        )
+                        if ctrl is not None:
+                            seg = ctrl.update(
+                                n >= disp_steps, snap["host_ms"],
+                                snap["device_ms"],
+                            )
             else:
                 # pipelined double-buffered loop: one segment always in
                 # flight; the boundary is processed from its packed
@@ -2328,23 +2535,16 @@ class LaneScheduler:
                 # dispatched speculatively before blocking, so all the
                 # host bookkeeping below overlaps device compute
                 now = time.monotonic()
-                reap_jobs(now, None)
-                admit_new(now)
-                state, n_adm, adm_shard = flush_adm(state)
+                with stats.phase("reap"):
+                    reap_jobs(now, None)
+                with stats.phase("admit"):
+                    admit_new(now)
+                with stats.phase("refill"):
+                    state, n_adm, adm_shard = flush_adm(state)
                 pend = None
                 if active:
-                    pend_meta = (
-                        len(active),
-                        sum(len(j.helpers) for j in active),
-                        n_adm, q_len_locked(),
-                        shard_occup(), adm_shard,
-                    )
-                    pend_steps = seg
-                    pend_res = traced_snapshot()
-                    pend_t0 = time.monotonic()
-                    with obs_trace.span("segment.dispatch", "engine",
-                                        steps=seg):
-                        pend = dispatch(state, tt, seg)
+                    pend, pend_meta = launch(
+                        state, tt, n_adm, adm_shard, False)
                     tt = pend[1]
                 while pend is not None:
                     p_state, p_tt, _pn, p_summ = pend
@@ -2359,99 +2559,87 @@ class LaneScheduler:
                         # synchronous loop would redispatch unchanged
                         # after this boundary, so issue segment k+1 now
                         # (donating the in-flight outputs in place)
-                        nxt_meta = (
-                            len(active),
-                            sum(len(j.helpers) for j in active), 0, 0,
-                            shard_occup(), None,
-                        )
-                        nxt_steps = seg
-                        nxt_res = traced_snapshot()
-                        nxt_t0 = time.monotonic()
-                        with obs_trace.span("segment.dispatch", "engine",
-                                            steps=seg, speculative=True):
-                            nxt = dispatch(p_state, p_tt, seg)
+                        nxt, nxt_meta = launch(p_state, p_tt, 0, None, True)
                         tt = nxt[1]
-                    summ, n, shard_steps = canon_summ(
-                        stats.fetch(p_summ, "summary")
-                    )
-                    traced_residency(pend_res, pend_t0, time.monotonic())
-                    lane_done = summ[:, search_ops.SUM_DONE].astype(bool)
-                    nodes_row = summ[:, search_ops.SUM_NODES]
-                    # lanes whose park was already handled at an earlier
-                    # speculative boundary (admission staged, splice
-                    # still pending) report DONE again — skip them
-                    staged = set(adm["lane"])
-                    now = time.monotonic()
-                    # helper lanes that parked on their own: charge+free
-                    for lane in range(B):
-                        job = lane_owner[lane]
-                        if (job is not None and lane_done[lane]
-                                and lane not in staged):
-                            hn = int(nodes_row[lane])
-                            job.nodes_total += hn
-                            job.remaining -= hn
-                            del job.helpers[lane]
-                            lane_owner[lane] = None
-                    # primary lanes that parked: aspiration verdict
-                    for lane in range(B):
-                        job = lane_job[lane]
-                        if (job is None or not lane_done[lane]
-                                or lane in staged):
-                            continue
-                        on_primary_parked(
-                            job, lane,
-                            int(summ[lane, search_ops.SUM_SCORE]),
-                            int(summ[lane, search_ops.SUM_MOVE]),
-                            int(nodes_row[lane]), nodes_row, now,
-                        )
-                    reap_jobs(now, nodes_row)
-                    admit_new(now)
+                    raw_summ = stats.fetch(p_summ, "summary")
+                    with stats.phase("account"):
+                        traced_residency(
+                            pend_meta["traced"], pend_meta["t0"],
+                            time.monotonic())
+                    with stats.phase("lanes"):
+                        summ, n, shard_steps = canon_summ(raw_summ)
+                        lane_done = summ[:, search_ops.SUM_DONE].astype(bool)
+                        nodes_row = summ[:, search_ops.SUM_NODES]
+                        # lanes whose park was already handled at an
+                        # earlier speculative boundary (admission
+                        # staged, splice still pending) report DONE
+                        # again — skip them
+                        staged = set(adm["lane"])
+                        now = time.monotonic()
+                        # helper lanes that parked on their own:
+                        # charge+free
+                        for lane in range(B):
+                            job = lane_owner[lane]
+                            if (job is not None and lane_done[lane]
+                                    and lane not in staged):
+                                hn = int(nodes_row[lane])
+                                job.nodes_total += hn
+                                job.remaining -= hn
+                                del job.helpers[lane]
+                                lane_owner[lane] = None
+                        # primary lanes that parked: aspiration verdict
+                        for lane in range(B):
+                            job = lane_job[lane]
+                            if (job is None or not lane_done[lane]
+                                    or lane in staged):
+                                continue
+                            on_primary_parked(
+                                job, lane,
+                                int(summ[lane, search_ops.SUM_SCORE]),
+                                int(summ[lane, search_ops.SUM_MOVE]),
+                                int(nodes_row[lane]), nodes_row, now,
+                            )
+                    with stats.phase("reap"):
+                        reap_jobs(now, nodes_row)
+                    with stats.phase("admit"):
+                        admit_new(now)
                     if nxt is None:
                         # PV pulls read the resolved p_state BEFORE the
                         # refill splice below resets those lanes
-                        flush_pv(p_state, now)
+                        with stats.phase("pv"):
+                            flush_pv(p_state, now)
                     snap = stats.boundary()
+                    credit_session(stats.interval_open_s)
                     last_device_s = snap["device_ms"] / 1000.0
-                    self._record_occupancy(
-                        B, n, pend_meta[0], pend_meta[1], pend_meta[2],
-                        pend_meta[3],
-                        (snap["host_ms"] + snap["device_ms"]) / 1000.0,
-                        snap["host_ms"], snap["device_ms"],
-                        snap["transfers"],
-                        shard=None if mesh is None else {
-                            "shard_live": pend_meta[4],
-                            "shard_refilled":
-                                pend_meta[5] or [0] * n_shard,
-                            "shard_steps": shard_steps,
-                        },
-                    )
-                    if ctrl is not None:
-                        seg = ctrl.update(
-                            n >= pend_steps, snap["host_ms"],
-                            snap["device_ms"],
+                    with stats.phase("account"):
+                        self._record_occupancy(
+                            B, n, pend_meta["live"], pend_meta["helpers"],
+                            pend_meta["refilled"], pend_meta["queue"],
+                            (snap["host_ms"] + snap["device_ms"]) / 1000.0,
+                            snap["host_ms"], snap["device_ms"],
+                            snap["transfers"], snap["phases"],
+                            shard=None if mesh is None else {
+                                "shard_live": pend_meta["shard_live"],
+                                "shard_refilled":
+                                    pend_meta["shard_refilled"]
+                                    or [0] * n_shard,
+                                "shard_steps": shard_steps,
+                            },
                         )
+                        if ctrl is not None:
+                            seg = ctrl.update(
+                                n >= pend_meta["steps"], snap["host_ms"],
+                                snap["device_ms"],
+                            )
                     if nxt is not None:
-                        pend = nxt
-                        pend_meta = nxt_meta
-                        pend_steps = nxt_steps
-                        pend_res = nxt_res
-                        pend_t0 = nxt_t0
+                        pend, pend_meta = nxt, nxt_meta
                         continue
-                    state, n_adm, adm_shard = flush_adm(p_state)
+                    with stats.phase("refill"):
+                        state, n_adm, adm_shard = flush_adm(p_state)
                     if not active:
                         break  # next session handles the rest
-                    pend_meta = (
-                        len(active),
-                        sum(len(j.helpers) for j in active),
-                        n_adm, q_len_locked(),
-                        shard_occup(), adm_shard,
-                    )
-                    pend_steps = seg
-                    pend_res = traced_snapshot()
-                    pend_t0 = time.monotonic()
-                    with obs_trace.span("segment.dispatch", "engine",
-                                        steps=seg):
-                        pend = dispatch(state, tt, seg)
+                    pend, pend_meta = launch(
+                        state, tt, n_adm, adm_shard, False)
                     tt = pend[1]
         except BaseException as e:
             # the driver died mid-session (device fault, OOM...): fail
@@ -2470,13 +2658,40 @@ class LaneScheduler:
             raise
         finally:
             eng.tt = tt
+            t_end = time.monotonic()
+            # last boundary → here: the final account and the refill
+            # that found nothing to admit
+            tail_ms = credit_session(t_end)
+            tot["session_tail_ms"] += tail_ms
+            tot["sessions"] += 1
+            with self._q_lock:
+                self._session_end = t_end
+                self._pending_since = t_end if self._pending else None
+                # closed before this session ended: in no later gap
+                self._submits_done.clear()
+            rec = obs_trace.RECORDER
+            if rec is not None:
+                rec.complete(
+                    "session", t_enter * 1e6, (t_end - t_enter) * 1e6,
+                    cat="engine",
+                    args=dict(
+                        session_args, width=B, tail_ms=round(tail_ms, 3),
+                        segments=tot["segments"] - at_start[0],
+                        steps=tot["steps"] - at_start[1],
+                        positions=tot["positions_done"] - at_start[2],
+                    ),
+                )
 
     def _record_occupancy(self, width, steps, live, helpers, refilled,
                           queue, wall, host_ms=0.0, device_ms=0.0,
-                          transfers=0, shard=None):
+                          transfers=0, phases=None, shard=None):
         eng = self.engine
         tot = eng.occupancy_totals
         idle = width - live - helpers
+        # with host_ms and device_ms, whichever way the row goes: the
+        # phase totals sum to the two
+        for name, ms in (phases or {}).items():
+            tot[f"phase_{name}_ms"] += ms
         if steps == 0 and refilled == 0:
             # Pipelined overrun dispatch: the prefetched segment ran zero
             # steps because every lane finished during the previous one.

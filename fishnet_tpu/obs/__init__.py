@@ -13,7 +13,7 @@ wants to read a trace dump.
   it to the supervisor over the frames protocol; ClockSync maps the
   child's monotonic clock onto the parent's so the merged file shows
   `queue.acquire` → `supervisor.dispatch` → host `search` spans with the
-  SyncStats device/host split as children of each segment.
+  scheduler's sessions, boundary phases and fetches under each segment.
 - `obs.metrics`: counter/gauge/histogram registry absorbing the ad-hoc
   counters (SupervisorStats, SyncStats totals, LaneScheduler occupancy
   totals), rendered as Prometheus text over an opt-in stdlib-http

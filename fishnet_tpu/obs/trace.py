@@ -3,7 +3,8 @@
 The recorder is a bounded deque of Chrome trace-event dicts (the JSON
 format Perfetto and chrome://tracing load natively): complete events
 ("ph": "X") for spans, instants ("i") for point markers, counter samples
-("C") for time series. Timestamps are `time.monotonic()` in microseconds
+("C") for time series, nestable async pairs ("b"/"e") for work that
+overlaps across threads. Timestamps are `time.monotonic()` in microseconds
 — never wall clock (lint rule obs-wall-clock): an NTP step must not be
 able to fold a hang timeline over itself.
 
@@ -213,6 +214,31 @@ class TraceRecorder:
             "tid": 0,
             "args": {"value": value},
         })
+        self.emitted += 1
+
+    def nest(self, name: str, nest_id: str, phase: str,
+             cat: str = "app", **args) -> None:
+        """One end of a nestable async slice ("b" begins, "e" ends):
+        events sharing `cat` and `nest_id` stack on one async track of
+        their own, whatever thread emitted them — the form for work
+        that overlaps across threads (several executor threads in
+        LaneScheduler._submit at once), where thread-track "X" spans
+        would claim a thread's timeline that a drive session also
+        uses."""
+        if phase not in ("b", "e"):
+            raise ValueError(f"nest phase must be b/e, got {phase!r}")
+        ev = {
+            "name": name,
+            "cat": cat,
+            "ph": phase,
+            "id": str(nest_id),
+            "ts": now_us(),
+            "pid": self.pid,
+            "tid": self._tid(),
+        }
+        if args:
+            ev["args"] = args
+        self._events.append(ev)
         self.emitted += 1
 
     def flow(self, name: str, flow_id: str, phase: str = "t",

@@ -167,12 +167,13 @@ def generate_moves(b: Board, variant: str = "standard",
     # order is a valid move ordering, and the host oracle calls this same
     # function, so device/oracle equality is unaffected.
     cap = max_moves_for(variant)
-    packed = jnp.where(
-        flat_valid, (flat_keys << 16) | flat_moves,
-        jnp.int32(jnp.iinfo(jnp.int32).max),
-    )
-    packed = jax.lax.sort(packed, dimension=0, is_stable=False)
-    top = jax.lax.slice_in_dim(packed, 0, cap)
+    with jax.named_scope("step.order"):
+        packed = jnp.where(
+            flat_valid, (flat_keys << 16) | flat_moves,
+            jnp.int32(jnp.iinfo(jnp.int32).max),
+        )
+        packed = jax.lax.sort(packed, dimension=0, is_stable=False)
+        top = jax.lax.slice_in_dim(packed, 0, cap)
     moves = jnp.where(
         top != jnp.iinfo(jnp.int32).max, top & 0xFFFF, jnp.int32(-1)
     )

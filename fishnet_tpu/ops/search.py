@@ -355,6 +355,11 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
     tt_hit/tt_score: a usable transposition-table cutoff for this lane's
     current ENTER node (probed outside the vmap against the shared table);
     tt_move: stored best move for ordering (-1 when none). None → no TT.
+
+    The `jax.named_scope("step.*")` blocks are names only (each op's
+    metadata; the compiled program is the same instructions): they cut
+    the step at its own seams so a profile can say what a fusion is for
+    (tools/profile_step.py prints device time by scope).
     """
     lane = s.lane
     ply0 = lane[LN_PLY]
@@ -370,54 +375,55 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
 
     # ---------------------------------------------------------- phase ENTER
     enter = mode0 == MODE_ENTER
-    b = _board_from_row(btr0)
-    us = b.stm
-    # legality of the move that led here + check state + variant-rule
-    # game end, all per the statically compiled variant (board.node_rules)
-    illegal_raw, we_are_checked, term_kind = node_rules(b, variant)
-    parent_illegal = (ply0 > 0) & illegal_raw
-    depth_left = ntr0[NT_DL]
-    # this node was reached by a null move: its window is the parent's
-    # null-window (beta-1, beta) seen from this side — and it must not
-    # null-move again (two passes in a row search the parent's position)
-    parent_null = (ply0 > 0) & (ntp0[NT_NULL] == 2)
-    over_budget = nodes >= lane[LN_BUDGET]
-    fifty = b.halfmove >= 100
+    with jax.named_scope("step.rules"):
+        b = _board_from_row(btr0)
+        us = b.stm
+        # legality of the move that led here + check state + variant-rule
+        # game end, all per the statically compiled variant (board.node_rules)
+        illegal_raw, we_are_checked, term_kind = node_rules(b, variant)
+        parent_illegal = (ply0 > 0) & illegal_raw
+        depth_left = ntr0[NT_DL]
+        # this node was reached by a null move: its window is the parent's
+        # null-window (beta-1, beta) seen from this side — and it must not
+        # null-move again (two passes in a row search the parent's position)
+        parent_null = (ply0 > 0) & (ntp0[NT_NULL] == 2)
+        over_budget = nodes >= lane[LN_BUDGET]
+        fifty = b.halfmove >= 100
 
-    # twofold repetition along the search path (reference behavior is
-    # Stockfish's draw scoring, observable through src/stockfish.rs score
-    # output): hash the position on entry, scan ancestors for an equal
-    # hash reachable through an unbroken reversible-move chain
-    # (halfmove[ply]-halfmove[k] == ply-k). Path-dependent by nature, so
-    # repetition draws are never TT-stored and never TT-overridden; the
-    # residual graph-history interaction is the same approximation every
-    # real engine ships. (_tt_mod is imported at module top: importing it
-    # lazily inside this jit-traced function once leaked its module-level
-    # Zobrist tables as tracers — see round-2 verdict.)
-    h1, h2 = _tt_mod.hash_board(
-        b.board, us, b.ep, b.castling, b.extra, variant
-    )
-    h1i = jax.lax.bitcast_convert_type(h1, jnp.int32)
-    h2i = jax.lax.bitcast_convert_type(h2, jnp.int32)
-    ks = jnp.arange(P1, dtype=jnp.int32)
-    chain_ok = (b.halfmove - s.bt[:, BT_HM]) == (ply0 - ks)
-    repet_path = jnp.any(
-        (ks < ply0)
-        & chain_ok
-        & (s.bt[:, BT_PH1] == h1i)
-        & (s.bt[:, BT_PH2] == h2i)
-    )
-    # ... and against the pre-root game history: slot k sits at virtual
-    # ply k - MAX_HIST, so the unbroken-reversible-chain condition is
-    # halfmove distance == ply distance with that offset
-    hk = jnp.arange(s.hist_halfmove.shape[0], dtype=jnp.int32)
-    hist_chain = (b.halfmove - s.hist_halfmove) == (
-        ply0 + (s.hist_halfmove.shape[0] - hk)
-    )
-    repet_hist = jnp.any(
-        hist_chain & (s.hist_hash[:, 0] == h1) & (s.hist_hash[:, 1] == h2)
-    )
-    repet = enter & (repet_path | repet_hist)
+        # twofold repetition along the search path (reference behavior is
+        # Stockfish's draw scoring, observable through src/stockfish.rs score
+        # output): hash the position on entry, scan ancestors for an equal
+        # hash reachable through an unbroken reversible-move chain
+        # (halfmove[ply]-halfmove[k] == ply-k). Path-dependent by nature, so
+        # repetition draws are never TT-stored and never TT-overridden; the
+        # residual graph-history interaction is the same approximation every
+        # real engine ships. (_tt_mod is imported at module top: importing it
+        # lazily inside this jit-traced function once leaked its module-level
+        # Zobrist tables as tracers — see round-2 verdict.)
+        h1, h2 = _tt_mod.hash_board(
+            b.board, us, b.ep, b.castling, b.extra, variant
+        )
+        h1i = jax.lax.bitcast_convert_type(h1, jnp.int32)
+        h2i = jax.lax.bitcast_convert_type(h2, jnp.int32)
+        ks = jnp.arange(P1, dtype=jnp.int32)
+        chain_ok = (b.halfmove - s.bt[:, BT_HM]) == (ply0 - ks)
+        repet_path = jnp.any(
+            (ks < ply0)
+            & chain_ok
+            & (s.bt[:, BT_PH1] == h1i)
+            & (s.bt[:, BT_PH2] == h2i)
+        )
+        # ... and against the pre-root game history: slot k sits at virtual
+        # ply k - MAX_HIST, so the unbroken-reversible-chain condition is
+        # halfmove distance == ply distance with that offset
+        hk = jnp.arange(s.hist_halfmove.shape[0], dtype=jnp.int32)
+        hist_chain = (b.halfmove - s.hist_halfmove) == (
+            ply0 + (s.hist_halfmove.shape[0] - hk)
+        )
+        repet_hist = jnp.any(
+            hist_chain & (s.hist_hash[:, 0] == h1) & (s.hist_hash[:, 1] == h2)
+        )
+        repet = enter & (repet_path | repet_hist)
     # window inherited from the parent (negamax flip); a null child runs
     # the parent's zero-width null-window (beta-1, beta) instead
     entry_alpha = jnp.where(ply0 == 0, lane[LN_RALPHA], -ntp0[NT_BETA])
@@ -432,401 +438,410 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
     in_qs = depth_left <= 0
     stack_full = ply0 >= s.moves.shape[0]  # no moves row / child slot left
 
-    # leaf value: NNUE eval (or draw for 50-move). On the board768 fast
-    # path the accumulator came down the stack incrementally and only the
-    # small layer stack runs here; the halfkav2_hm compat path pays a full
-    # refresh per step — as does atomic, whose explosions exceed the
-    # 4-slot incremental update scheme (move_piece_changes).
-    if nnue.is_board768(params) and variant != "atomic":
-        leaf_val = jnp.int32(
-            nnue.forward_from_acc(params, s.acc[ply0], us, nnue.output_bucket(b.board))
-        )
-    else:
-        leaf_val = jnp.int32(nnue.evaluate(params, b.board, us))
-    leaf_val = jnp.clip(leaf_val, -MATE + 1000, MATE - 1000)
-    static_val = leaf_val  # pre-draw-override eval (null-move eligibility)
-    leaf_val = jnp.where(fifty | repet, DRAW, leaf_val)
+    with jax.named_scope("step.eval"):
+        # leaf value: NNUE eval (or draw for 50-move). On the board768 fast
+        # path the accumulator came down the stack incrementally and only the
+        # small layer stack runs here; the halfkav2_hm compat path pays a full
+        # refresh per step — as does atomic, whose explosions exceed the
+        # 4-slot incremental update scheme (move_piece_changes).
+        if nnue.is_board768(params) and variant != "atomic":
+            leaf_val = jnp.int32(
+                nnue.forward_from_acc(params, s.acc[ply0], us, nnue.output_bucket(b.board))
+            )
+        else:
+            leaf_val = jnp.int32(nnue.evaluate(params, b.board, us))
+        leaf_val = jnp.clip(leaf_val, -MATE + 1000, MATE - 1000)
+        static_val = leaf_val  # pre-draw-override eval (null-move eligibility)
+        leaf_val = jnp.where(fifty | repet, DRAW, leaf_val)
 
-    # variant-rule game end (3 checks, exploded king, hill, goal rank,
-    # horde destroyed) ends the node at once — takes precedence over
-    # draws; mate-range (or rule-draw) values are never TT-stored
-    vterm = term_kind != TERM_NONE
-    leaf_val = jnp.where(
-        vterm,
-        jnp.where(
-            term_kind == TERM_LOSS, -(MATE - ply0),
-            jnp.where(term_kind == TERM_WIN, MATE - ply0, DRAW),
-        ),
-        leaf_val,
-    )
-
-    gen_moves, gen_count, gen_noisy = generate_moves(
-        b, variant,
-        killers=jnp.stack([ntr0[NT_K0], ntr0[NT_K1]]),
-        hist=s.hist,
-    )
-    # futility pruning: at a frontier node (depth_left 1-2, not in check,
-    # non-mate window) whose static eval sits a margin below alpha, quiet
-    # moves cannot realistically raise alpha — expand only the noisy
-    # prefix, exactly the QS mechanics with the static eval as the
-    # fail-soft floor (static < alpha, so the floor never raises alpha).
-    # The same speculative unsoundness every real engine ships: skipped
-    # quiets are treated as searched-and-failed-low.
-    if _PRUNING:
-        f_margin = jnp.where(depth_left == 1, 150, 300)
-        futile = (
-            ~in_qs
-            & (depth_left <= 2)
-            & ~we_are_checked
-            & (ply0 > 0)
-            & (static_val + f_margin <= entry_alpha)
-            & (entry_alpha > -(MATE - 1000))
-            & (entry_alpha < MATE - 1000)
-        )
-    else:
-        futile = jnp.bool_(False)
-    qs_like = in_qs | futile  # expands noisy prefix only, static floor
-    is_leaf = (
-        fifty | repet | vterm | over_budget | stack_full
-        | (qs_like & (gen_noisy == 0))
-    )
-    # stand-pat beta cutoff: in QS the static eval is already >= beta —
-    # the opponent wouldn't enter this line; fail high immediately
-    stand_pat_cut = in_qs & (leaf_val >= entry_beta)
-    is_leaf |= stand_pat_cut
-
-    # TT cutoff: treat as a leaf return with the stored score (never at
-    # the root — the root must produce a move; never on fifty-move or
-    # repetition draws — the hash excludes the halfmove counter and the
-    # path, so a stored score must not override a forced draw)
-    use_tt = (
-        (tt_hit & (ply0 > 0) & ~fifty & ~repet & ~vterm)
-        if tt_hit is not None
-        else jnp.bool_(False)
-    )
-    to_return = parent_illegal | is_leaf | use_tt
-    expand = enter & ~to_return
-    # mark fresh static-eval leaves for the runner's depth-0 TT store.
-    # Quiet positions only: a quiet static eval IS the node's QS value,
-    # while a noisy leaf (budget/stack cutoff) stored as depth-0 EXACT
-    # would later short-circuit a real QS expansion of the same position.
-    # (fifty/repetition draws excluded: they don't transpose; variant
-    # terminals excluded: their ply-relative mate-range values must
-    # never be TT-stored)
-    leaf_store = (
-        enter & is_leaf & ~parent_illegal & ~use_tt & ~fifty & ~repet
-        & ~vterm & (gen_noisy == 0)
-    )
-    store_mark = leaf_store
-    store_val = jnp.where(leaf_store, leaf_val, 0)
-
-    # order the stored TT move first (classic biggest ordering win); not
-    # in QS, where the swap could pull a quiet move into the noisy prefix
-    if tt_move is not None:
-        tm_at = jnp.argmax(gen_moves == tt_move)
-        tm_present = (tt_move >= 0) & (gen_moves[tm_at] == tt_move) & ~qs_like
-        m0 = gen_moves[0]
-        # dynamic-index swap routed through _row_set so the
-        # SELECT_UPDATES experiment covers this scatter too (the index-0
-        # write below is static — not a dynamic-update-slice)
-        gen_moves = _row_set(gen_moves, tm_at, m0, tm_present)
-        gen_moves = gen_moves.at[0].set(
-            jnp.where(tm_present, tt_move, gen_moves[0])
+        # variant-rule game end (3 checks, exploded king, hill, goal rank,
+        # horde destroyed) ends the node at once — takes precedence over
+        # draws; mate-range (or rule-draw) values are never TT-stored
+        vterm = term_kind != TERM_NONE
+        leaf_val = jnp.where(
+            vterm,
+            jnp.where(
+                term_kind == TERM_LOSS, -(MATE - ply0),
+                jnp.where(term_kind == TERM_WIN, MATE - ply0, DRAW),
+            ),
+            leaf_val,
         )
 
-    # stand-pat: in QS the node may decline every capture and keep the
-    # static eval, so it floors both best and alpha (futile nodes reuse
-    # the same floor; their static sits below alpha by construction, so
-    # only `best` actually moves — the fail-soft return value)
-    # null-move eligibility (Stockfish search.cpp nullMove conditions,
-    # minus the zugzwang verification search): interior node, depth to
-    # spare, not in check, not already inside a null subtree, static
-    # eval >= beta, non-mate window, and side to move still has a piece
-    # (pawn/king-only positions are where the null observation fails)
-    if _PRUNING and variant != "antichess":
-        # antichess excluded: captures are FORCED there, so passing is
-        # not "at least as bad as the best move" — the null observation
-        # that justifies the cutoff simply doesn't hold
-        us_base = us * 6
-        nonpawn = jnp.any(
-            (b.board >= us_base + 2) & (b.board <= us_base + 5)
+    with jax.named_scope("step.movegen"):
+        gen_moves, gen_count, gen_noisy = generate_moves(
+            b, variant,
+            killers=jnp.stack([ntr0[NT_K0], ntr0[NT_K1]]),
+            hist=s.hist,
         )
-        nmp_ok = (
-            ~in_qs
-            & (depth_left >= 3)
-            & ~we_are_checked
-            & ~parent_null
-            & (ply0 > 0)
-            & (static_val >= entry_beta)
-            & (entry_beta < MATE - 1000)
-            & (entry_beta > -(MATE - 1000))
-            & nonpawn
+    with jax.named_scope("step.enter"):
+        # futility pruning: at a frontier node (depth_left 1-2, not in check,
+        # non-mate window) whose static eval sits a margin below alpha, quiet
+        # moves cannot realistically raise alpha — expand only the noisy
+        # prefix, exactly the QS mechanics with the static eval as the
+        # fail-soft floor (static < alpha, so the floor never raises alpha).
+        # The same speculative unsoundness every real engine ships: skipped
+        # quiets are treated as searched-and-failed-low.
+        if _PRUNING:
+            f_margin = jnp.where(depth_left == 1, 150, 300)
+            futile = (
+                ~in_qs
+                & (depth_left <= 2)
+                & ~we_are_checked
+                & (ply0 > 0)
+                & (static_val + f_margin <= entry_alpha)
+                & (entry_alpha > -(MATE - 1000))
+                & (entry_alpha < MATE - 1000)
+            )
+        else:
+            futile = jnp.bool_(False)
+        qs_like = in_qs | futile  # expands noisy prefix only, static floor
+        is_leaf = (
+            fifty | repet | vterm | over_budget | stack_full
+            | (qs_like & (gen_noisy == 0))
         )
-        null_v = jnp.where(nmp_ok, 1, 0)
-    else:
-        null_v = jnp.int32(0)
+        # stand-pat beta cutoff: in QS the static eval is already >= beta —
+        # the opponent wouldn't enter this line; fail high immediately
+        stand_pat_cut = in_qs & (leaf_val >= entry_beta)
+        is_leaf |= stand_pat_cut
 
-    # the entered node's nt row, composed once: fields in _FM_EXPAND take
-    # their expansion value under `expand`, _FM_ENTER fields under
-    # `enter`, everything else keeps its old value — so one full-row
-    # write under `enter` reproduces the per-field write masks exactly
-    nv = jnp.stack([
-        jnp.where(qs_like, gen_noisy, gen_count),            # NT_COUNT
-        jnp.int32(0),                                        # NT_MIDX
-        jnp.int32(0),                                        # NT_SEARCHED
-        jnp.where(qs_like, jnp.maximum(entry_alpha, leaf_val),
-                  entry_alpha),                              # NT_ALPHA
-        entry_alpha,                                         # NT_ALPHA0
-        entry_beta,                                          # NT_BETA
-        jnp.where(qs_like, leaf_val, -INF),                  # NT_BEST
-        jnp.int32(-1),                                       # NT_BMOVE
-        null_v,                                              # NT_NULL
-        jnp.int32(0),                                        # NT_LASTRED
-        jnp.int32(0),                                        # NT_PVLEN
-        ntr0[NT_DL],                                         # NT_DL
-        we_are_checked.astype(jnp.int32),                    # NT_INCHECK
-        ntr0[NT_K0],                                         # NT_K0
-        ntr0[NT_K1],                                         # NT_K1
-        jnp.int32(0),
-    ])
-    sel = (jnp.asarray(_FM_EXPAND) & expand) | (jnp.asarray(_FM_ENTER) & enter)
-    ntE = jnp.where(sel, nv, ntr0)
-    nt_new = _row_set(s.nt, ply0, ntE, enter)
+        # TT cutoff: treat as a leaf return with the stored score (never at
+        # the root — the root must produce a move; never on fifty-move or
+        # repetition draws — the hash excludes the halfmove counter and the
+        # path, so a stored score must not override a forced draw)
+        use_tt = (
+            (tt_hit & (ply0 > 0) & ~fifty & ~repet & ~vterm)
+            if tt_hit is not None
+            else jnp.bool_(False)
+        )
+        to_return = parent_illegal | is_leaf | use_tt
+        expand = enter & ~to_return
+        # mark fresh static-eval leaves for the runner's depth-0 TT store.
+        # Quiet positions only: a quiet static eval IS the node's QS value,
+        # while a noisy leaf (budget/stack cutoff) stored as depth-0 EXACT
+        # would later short-circuit a real QS expansion of the same position.
+        # (fifty/repetition draws excluded: they don't transpose; variant
+        # terminals excluded: their ply-relative mate-range values must
+        # never be TT-stored)
+        leaf_store = (
+            enter & is_leaf & ~parent_illegal & ~use_tt & ~fifty & ~repet
+            & ~vterm & (gen_noisy == 0)
+        )
+        store_mark = leaf_store
+        store_val = jnp.where(leaf_store, leaf_val, 0)
 
-    btE = btr0.at[BT_PH1].set(h1i).at[BT_PH2].set(h2i)
-    bt_new = _row_set(s.bt, ply0, btE, enter)
-    moves_new = _row_set(
-        s.moves, jnp.minimum(ply0, s.moves.shape[0] - 1), gen_moves, expand
-    )
+        # order the stored TT move first (classic biggest ordering win); not
+        # in QS, where the swap could pull a quiet move into the noisy prefix
+        if tt_move is not None:
+            tm_at = jnp.argmax(gen_moves == tt_move)
+            tm_present = (tt_move >= 0) & (gen_moves[tm_at] == tt_move) & ~qs_like
+            m0 = gen_moves[0]
+            # dynamic-index swap routed through _row_set so the
+            # SELECT_UPDATES experiment covers this scatter too (the index-0
+            # write below is static — not a dynamic-update-slice)
+            gen_moves = _row_set(gen_moves, tm_at, m0, tm_present)
+            gen_moves = gen_moves.at[0].set(
+                jnp.where(tm_present, tt_move, gen_moves[0])
+            )
 
-    ret = jnp.where(
-        enter & to_return,
-        jnp.where(
-            parent_illegal,
-            ILLEGAL,
-            jnp.where(use_tt, tt_score, leaf_val) if tt_score is not None
-            else leaf_val,
-        ),
-        lane[LN_RET],
-    )
-    # ret_depth: 0 for static leaves, -1 for TT-sourced values (already in
-    # the table — don't re-store them)
-    ret_depth = jnp.where(
-        enter & to_return, jnp.where(use_tt, -1, 0), lane[LN_RETD]
-    )
-    nodes = nodes + jnp.where(enter & ~parent_illegal, 1, 0)
-    mode = jnp.where(
-        enter, jnp.where(to_return, MODE_RETURN, MODE_TRYMOVE), mode0
-    )
+        # stand-pat: in QS the node may decline every capture and keep the
+        # static eval, so it floors both best and alpha (futile nodes reuse
+        # the same floor; their static sits below alpha by construction, so
+        # only `best` actually moves — the fail-soft return value)
+        # null-move eligibility (Stockfish search.cpp nullMove conditions,
+        # minus the zugzwang verification search): interior node, depth to
+        # spare, not in check, not already inside a null subtree, static
+        # eval >= beta, non-mate window, and side to move still has a piece
+        # (pawn/king-only positions are where the null observation fails)
+        if _PRUNING and variant != "antichess":
+            # antichess excluded: captures are FORCED there, so passing is
+            # not "at least as bad as the best move" — the null observation
+            # that justifies the cutoff simply doesn't hold
+            us_base = us * 6
+            nonpawn = jnp.any(
+                (b.board >= us_base + 2) & (b.board <= us_base + 5)
+            )
+            nmp_ok = (
+                ~in_qs
+                & (depth_left >= 3)
+                & ~we_are_checked
+                & ~parent_null
+                & (ply0 > 0)
+                & (static_val >= entry_beta)
+                & (entry_beta < MATE - 1000)
+                & (entry_beta > -(MATE - 1000))
+                & nonpawn
+            )
+            null_v = jnp.where(nmp_ok, 1, 0)
+        else:
+            null_v = jnp.int32(0)
+
+        # the entered node's nt row, composed once: fields in _FM_EXPAND take
+        # their expansion value under `expand`, _FM_ENTER fields under
+        # `enter`, everything else keeps its old value — so one full-row
+        # write under `enter` reproduces the per-field write masks exactly
+        nv = jnp.stack([
+            jnp.where(qs_like, gen_noisy, gen_count),            # NT_COUNT
+            jnp.int32(0),                                        # NT_MIDX
+            jnp.int32(0),                                        # NT_SEARCHED
+            jnp.where(qs_like, jnp.maximum(entry_alpha, leaf_val),
+                      entry_alpha),                              # NT_ALPHA
+            entry_alpha,                                         # NT_ALPHA0
+            entry_beta,                                          # NT_BETA
+            jnp.where(qs_like, leaf_val, -INF),                  # NT_BEST
+            jnp.int32(-1),                                       # NT_BMOVE
+            null_v,                                              # NT_NULL
+            jnp.int32(0),                                        # NT_LASTRED
+            jnp.int32(0),                                        # NT_PVLEN
+            ntr0[NT_DL],                                         # NT_DL
+            we_are_checked.astype(jnp.int32),                    # NT_INCHECK
+            ntr0[NT_K0],                                         # NT_K0
+            ntr0[NT_K1],                                         # NT_K1
+            jnp.int32(0),
+        ])
+        sel = (jnp.asarray(_FM_EXPAND) & expand) | (jnp.asarray(_FM_ENTER) & enter)
+        ntE = jnp.where(sel, nv, ntr0)
+        nt_new = _row_set(s.nt, ply0, ntE, enter)
+
+        btE = btr0.at[BT_PH1].set(h1i).at[BT_PH2].set(h2i)
+        bt_new = _row_set(s.bt, ply0, btE, enter)
+        moves_new = _row_set(
+            s.moves, jnp.minimum(ply0, s.moves.shape[0] - 1), gen_moves, expand
+        )
+
+        ret = jnp.where(
+            enter & to_return,
+            jnp.where(
+                parent_illegal,
+                ILLEGAL,
+                jnp.where(use_tt, tt_score, leaf_val) if tt_score is not None
+                else leaf_val,
+            ),
+            lane[LN_RET],
+        )
+        # ret_depth: 0 for static leaves, -1 for TT-sourced values (already in
+        # the table — don't re-store them)
+        ret_depth = jnp.where(
+            enter & to_return, jnp.where(use_tt, -1, 0), lane[LN_RETD]
+        )
+        nodes = nodes + jnp.where(enter & ~parent_illegal, 1, 0)
+        mode = jnp.where(
+            enter, jnp.where(to_return, MODE_RETURN, MODE_TRYMOVE), mode0
+        )
 
     # --------------------------------------------------------- phase RETURN
     # the node at ply0 finished with value `ret` (from its stm's view);
     # it folds into parent0
-    ret_m = mode == MODE_RETURN
-    at_root = ply0 == 0
-    was_illegal = ret == ILLEGAL
-    v = -ret
-    tried = moves_p_row[jnp.maximum(ntp0[NT_MIDX] - 1, 0)]
-    # the child that just returned was the parent's null move: score it
-    # against beta only — a fail-high ends the parent (unproven-mate
-    # guard: never cut on a mate-range null score), a fail-low is simply
-    # discarded. Either way it folds into nothing: no best_move, no pv,
-    # no searched credit.
-    is_null_ret = ret_m & ~at_root & (ntp0[NT_NULL] == 2)
-    null_cut = (
-        is_null_ret & ~was_illegal & (v >= ntp0[NT_BETA]) & (v < MATE - 1000)
-    )
-    # LMR re-search: the last child was depth-reduced and its reduced
-    # score beat alpha — discard the fold and re-push it at full depth
-    need_rs = (
-        ret_m & ~at_root & ~was_illegal & ~is_null_ret
-        & (ntp0[NT_LASTRED] > 0) & (v > ntp0[NT_ALPHA])
-    )
-    better = (
-        ret_m & (~at_root) & (~was_illegal) & (v > ntp0[NT_BEST])
-        & ~is_null_ret & ~need_rs
-    )
-    fold = ret_m & ~at_root
+    with jax.named_scope("step.return"):
+        ret_m = mode == MODE_RETURN
+        at_root = ply0 == 0
+        was_illegal = ret == ILLEGAL
+        v = -ret
+        tried = moves_p_row[jnp.maximum(ntp0[NT_MIDX] - 1, 0)]
+        # the child that just returned was the parent's null move: score it
+        # against beta only — a fail-high ends the parent (unproven-mate
+        # guard: never cut on a mate-range null score), a fail-low is simply
+        # discarded. Either way it folds into nothing: no best_move, no pv,
+        # no searched credit.
+        is_null_ret = ret_m & ~at_root & (ntp0[NT_NULL] == 2)
+        null_cut = (
+            is_null_ret & ~was_illegal & (v >= ntp0[NT_BETA]) & (v < MATE - 1000)
+        )
+        # LMR re-search: the last child was depth-reduced and its reduced
+        # score beat alpha — discard the fold and re-push it at full depth
+        need_rs = (
+            ret_m & ~at_root & ~was_illegal & ~is_null_ret
+            & (ntp0[NT_LASTRED] > 0) & (v > ntp0[NT_ALPHA])
+        )
+        better = (
+            ret_m & (~at_root) & (~was_illegal) & (v > ntp0[NT_BEST])
+            & ~is_null_ret & ~need_rs
+        )
+        fold = ret_m & ~at_root
 
-    best_p = jnp.where(better | null_cut, v, ntp0[NT_BEST])
-    bmove_p = jnp.where(better, tried, ntp0[NT_BMOVE])
-    alpha_p = jnp.where(
-        fold, jnp.maximum(ntp0[NT_ALPHA], best_p), ntp0[NT_ALPHA]
-    )
-    searched_p = ntp0[NT_SEARCHED] + jnp.where(
-        fold & ~was_illegal & ~is_null_ret & ~need_rs, 1, 0
-    )
-    null_p = jnp.where(is_null_ret, 0, ntp0[NT_NULL])
-    # pv[parent] = tried + pv[ply]; pv_len[ply] is the post-ENTER value
-    # (a leaf that entered this same step zeroed it)
-    pvlen_child = ntE[NT_PVLEN]
-    pvlen_p = jnp.where(
-        better, jnp.minimum(pvlen_child + 1, s.pv.shape[-1]), ntp0[NT_PVLEN]
-    )
-    ntP = ntp0
-    for f_ix, f_val in ((NT_BEST, best_p), (NT_BMOVE, bmove_p),
-                        (NT_ALPHA, alpha_p), (NT_SEARCHED, searched_p),
-                        (NT_NULL, null_p), (NT_PVLEN, pvlen_p)):
-        ntP = ntP.at[f_ix].set(f_val)
-    nt_new = _row_set(nt_new, parent0, ntP, fold)
+        best_p = jnp.where(better | null_cut, v, ntp0[NT_BEST])
+        bmove_p = jnp.where(better, tried, ntp0[NT_BMOVE])
+        alpha_p = jnp.where(
+            fold, jnp.maximum(ntp0[NT_ALPHA], best_p), ntp0[NT_ALPHA]
+        )
+        searched_p = ntp0[NT_SEARCHED] + jnp.where(
+            fold & ~was_illegal & ~is_null_ret & ~need_rs, 1, 0
+        )
+        null_p = jnp.where(is_null_ret, 0, ntp0[NT_NULL])
+        # pv[parent] = tried + pv[ply]; pv_len[ply] is the post-ENTER value
+        # (a leaf that entered this same step zeroed it)
+        pvlen_child = ntE[NT_PVLEN]
+        pvlen_p = jnp.where(
+            better, jnp.minimum(pvlen_child + 1, s.pv.shape[-1]), ntp0[NT_PVLEN]
+        )
+        ntP = ntp0
+        for f_ix, f_val in ((NT_BEST, best_p), (NT_BMOVE, bmove_p),
+                            (NT_ALPHA, alpha_p), (NT_SEARCHED, searched_p),
+                            (NT_NULL, null_p), (NT_PVLEN, pvlen_p)):
+            ntP = ntP.at[f_ix].set(f_val)
+        nt_new = _row_set(nt_new, parent0, ntP, fold)
 
-    new_pv_row = jnp.concatenate([tried[None], s.pv[ply0][:-1]])
-    pv_new = _row_set(s.pv, parent0, new_pv_row, better)
-    research = jnp.where(ret_m, need_rs, lane[LN_RESEARCH] != 0)
-    # root: record and park (ret, not best[0] — ret carries the
-    # mate/stalemate value when the root had no legal moves)
-    root_score = jnp.where(ret_m & at_root, ret, lane[LN_RSCORE])
-    root_move = jnp.where(ret_m & at_root, ntp0[NT_BMOVE], lane[LN_RMOVE])
-    ply1 = jnp.where(fold, parent0, ply0)
-    mode = jnp.where(
-        ret_m, jnp.where(at_root, MODE_DONE, MODE_TRYMOVE), mode
-    )
+        new_pv_row = jnp.concatenate([tried[None], s.pv[ply0][:-1]])
+        pv_new = _row_set(s.pv, parent0, new_pv_row, better)
+        research = jnp.where(ret_m, need_rs, lane[LN_RESEARCH] != 0)
+        # root: record and park (ret, not best[0] — ret carries the
+        # mate/stalemate value when the root had no legal moves)
+        root_score = jnp.where(ret_m & at_root, ret, lane[LN_RSCORE])
+        root_move = jnp.where(ret_m & at_root, ntp0[NT_BMOVE], lane[LN_RMOVE])
+        ply1 = jnp.where(fold, parent0, ply0)
+        mode = jnp.where(
+            ret_m, jnp.where(at_root, MODE_DONE, MODE_TRYMOVE), mode
+        )
 
     # -------------------------------------------------------- phase TRYMOVE
     # note: the node budget is enforced in ENTER (children degrade to leaf
     # evals), not here — finishing a node early with searched==0 would
     # return -INF garbage to the parent
-    try_m = mode == MODE_TRYMOVE
-    # the row TRYMOVE acts on: the freshly-expanded node (ENTER cascade)
-    # or the freshly-folded parent (RETURN cascade) — both in registers
-    came_from_enter = enter & expand
-    nt1 = jnp.where(came_from_enter, ntE, ntP)
-    moves_row1 = jnp.where(came_from_enter, gen_moves, moves_p_row)
-    bt1 = jnp.where(came_from_enter, btE, btp0)
-    parent_b = _board_from_row(bt1)
-    exhausted = nt1[NT_MIDX] >= nt1[NT_COUNT]
-    cutoff = nt1[NT_ALPHA] >= nt1[NT_BETA]
-    # a pending null move is tried BEFORE the first real move; an LMR
-    # re-push (research, set by RETURN this same step) re-enters the
-    # previous move at full depth and overrides finish — exhausted may
-    # already be true when the reduced move was the last one
-    re_push = try_m & research
-    do_null = try_m & ~re_push & (nt1[NT_NULL] == 1) & ~cutoff
-    finish = (exhausted | cutoff) & ~do_null & ~re_push
-    advance = try_m & ~finish
-    normal_adv = advance & ~re_push & ~do_null
-    dl_node = nt1[NT_DL]
+    with jax.named_scope("step.trymove"):
+        try_m = mode == MODE_TRYMOVE
+        # the row TRYMOVE acts on: the freshly-expanded node (ENTER cascade)
+        # or the freshly-folded parent (RETURN cascade) — both in registers
+        came_from_enter = enter & expand
+        nt1 = jnp.where(came_from_enter, ntE, ntP)
+        moves_row1 = jnp.where(came_from_enter, gen_moves, moves_p_row)
+        bt1 = jnp.where(came_from_enter, btE, btp0)
+        parent_b = _board_from_row(bt1)
+        exhausted = nt1[NT_MIDX] >= nt1[NT_COUNT]
+        cutoff = nt1[NT_ALPHA] >= nt1[NT_BETA]
+        # a pending null move is tried BEFORE the first real move; an LMR
+        # re-push (research, set by RETURN this same step) re-enters the
+        # previous move at full depth and overrides finish — exhausted may
+        # already be true when the reduced move was the last one
+        re_push = try_m & research
+        do_null = try_m & ~re_push & (nt1[NT_NULL] == 1) & ~cutoff
+        finish = (exhausted | cutoff) & ~do_null & ~re_push
+        advance = try_m & ~finish
+        normal_adv = advance & ~re_push & ~do_null
+        dl_node = nt1[NT_DL]
 
-    # killer/history credit on fail-high: the quiet move that raised
-    # alpha >= beta becomes killer slot 0 for this ply and earns a
-    # depth²-weighted history bump (captures already order by MVV-LVA;
-    # en-passant reads as quiet here, which only costs ordering)
-    cause = nt1[NT_BMOVE]
-    c_quiet = (cause >= 0) & _is_quiet(cause, bt1[BT_BOARD:BT_BOARD + 64])
-    k_upd = try_m & cutoff & c_quiet
-    k_new = k_upd & (cause != nt1[NT_K0])
-    k0_v = jnp.where(k_new, cause, nt1[NT_K0])
-    k1_v = jnp.where(k_new, nt1[NT_K0], nt1[NT_K1])
-    h_idx = jnp.clip(cause, 0) & 4095
-    dl = jnp.maximum(dl_node, 0)
-    h_w = jnp.minimum(dl * dl + 1, 1024)
-    hist_new = _row_set(
-        s.hist, h_idx, jnp.minimum(s.hist[h_idx] + h_w, 1 << 20), k_upd
-    )
+        # killer/history credit on fail-high: the quiet move that raised
+        # alpha >= beta becomes killer slot 0 for this ply and earns a
+        # depth²-weighted history bump (captures already order by MVV-LVA;
+        # en-passant reads as quiet here, which only costs ordering)
+        cause = nt1[NT_BMOVE]
+        c_quiet = (cause >= 0) & _is_quiet(cause, bt1[BT_BOARD:BT_BOARD + 64])
+        k_upd = try_m & cutoff & c_quiet
+        k_new = k_upd & (cause != nt1[NT_K0])
+        k0_v = jnp.where(k_new, cause, nt1[NT_K0])
+        k1_v = jnp.where(k_new, nt1[NT_K0], nt1[NT_K1])
+        h_idx = jnp.clip(cause, 0) & 4095
+        dl = jnp.maximum(dl_node, 0)
+        h_w = jnp.minimum(dl * dl + 1, 1024)
+        hist_new = _row_set(
+            s.hist, h_idx, jnp.minimum(s.hist[h_idx] + h_w, 1 << 20), k_upd
+        )
 
-    # finished node value: best, or mate/stalemate when no legal child.
-    # QS nodes only tried captures — no legal capture is NOT mate; their
-    # stand-pat floor in `best` already covers the quiet alternatives.
-    node_in_qs = dl_node <= 0
-    # best == -INF guards the count==0 + null-cutoff corner: a null-move
-    # fail-high set best without any legal child being searched, and the
-    # node must return that score, not a phantom mate/stalemate
-    no_legal = (nt1[NT_SEARCHED] == 0) & ~node_in_qs & (nt1[NT_BEST] == -INF)
-    if variant == "antichess":
-        # losing chess: the side with no moves left (stalemated or out of
-        # pieces) WINS (host: AntichessPosition._variant_outcome)
-        mate_val = MATE - ply1
-    else:
-        mate_val = jnp.where(nt1[NT_INCHECK] != 0, -(MATE - ply1), DRAW)
-    fin_val = jnp.where(no_legal & exhausted, mate_val, nt1[NT_BEST])
+        # finished node value: best, or mate/stalemate when no legal child.
+        # QS nodes only tried captures — no legal capture is NOT mate; their
+        # stand-pat floor in `best` already covers the quiet alternatives.
+        node_in_qs = dl_node <= 0
+        # best == -INF guards the count==0 + null-cutoff corner: a null-move
+        # fail-high set best without any legal child being searched, and the
+        # node must return that score, not a phantom mate/stalemate
+        no_legal = (nt1[NT_SEARCHED] == 0) & ~node_in_qs & (nt1[NT_BEST] == -INF)
+        if variant == "antichess":
+            # losing chess: the side with no moves left (stalemated or out of
+            # pieces) WINS (host: AntichessPosition._variant_outcome)
+            mate_val = MATE - ply1
+        else:
+            mate_val = jnp.where(nt1[NT_INCHECK] != 0, -(MATE - ply1), DRAW)
+        fin_val = jnp.where(no_legal & exhausted, mate_val, nt1[NT_BEST])
 
-    m_ix = jnp.where(
-        re_push,
-        jnp.maximum(nt1[NT_MIDX] - 1, 0),
-        jnp.minimum(nt1[NT_MIDX], moves_row1.shape[0] - 1),
-    )
-    move = moves_row1[m_ix]
-    child = make_move(parent_b, jnp.maximum(move, 0), variant)
-    # late-move reduction: late, quiet, unchecked moves of a deep-enough
-    # node search 1 ply shallower (2 from move 8); RETURN re-pushes at
-    # full depth when the reduced score beats alpha
-    if _PRUNING:
-        m_quiet = _is_quiet(jnp.maximum(move, 0), bt1[BT_BOARD:BT_BOARD + 64])
-        lmr_ok = (
-            (dl_node >= 3) & (nt1[NT_MIDX] >= 3) & m_quiet
-            & (nt1[NT_INCHECK] == 0) & ~node_in_qs
+        m_ix = jnp.where(
+            re_push,
+            jnp.maximum(nt1[NT_MIDX] - 1, 0),
+            jnp.minimum(nt1[NT_MIDX], moves_row1.shape[0] - 1),
         )
-        red = jnp.where(
-            lmr_ok, jnp.where(nt1[NT_MIDX] >= 8, 2, 1), 0
-        )
-        red = jnp.where(re_push | do_null, 0, red)
-        # the null child: same position, opponent to move, no ep, and a
-        # reset halfmove clock — which deliberately breaks the reversible
-        # repetition chain across the null (Stockfish's pliesFromNull)
-        child = Board(
-            board=jnp.where(do_null, parent_b.board, child.board),
-            stm=jnp.where(do_null, 1 - parent_b.stm, child.stm),
-            ep=jnp.where(do_null, -1, child.ep),
-            castling=jnp.where(do_null, parent_b.castling, child.castling),
-            halfmove=jnp.where(do_null, 0, child.halfmove),
-            extra=jnp.where(do_null, parent_b.extra, child.extra),
-        )
-        null_r = NULL_R + jnp.where(dl_node >= 7, 1, 0)
-        child_dl = jnp.maximum(
-            jnp.where(do_null, dl_node - 1 - null_r, dl_node - 1 - red), 0
-        )
-    else:
-        red = jnp.int32(0)
-        child_dl = jnp.maximum(dl_node - 1, 0)
-    nply = jnp.minimum(ply1 + 1, P1 - 1)
-
-    # TRYMOVE's own-row write (midx/null/lastred/killers), then the
-    # child-push writes: depth_left of the pushed row (a single-field
-    # 2-D one-hot — the row's other fields belong to the OLD node there
-    # and are rewritten when the child expands), its board row, and its
-    # incremental accumulator
-    nt1w = nt1
-    for f_ix, f_val in (
-        (NT_MIDX, jnp.where(normal_adv, nt1[NT_MIDX] + 1, nt1[NT_MIDX])),
-        (NT_NULL, jnp.where(do_null, 2, nt1[NT_NULL])),
-        (NT_LASTRED, jnp.where(advance, red, nt1[NT_LASTRED])),
-        (NT_K0, k0_v), (NT_K1, k1_v),
-    ):
-        nt1w = nt1w.at[f_ix].set(f_val)
-    nt_new = _row_set(nt_new, ply1, nt1w, try_m)
-    nt_new = _field_set(nt_new, nply, NT_DL, child_dl, advance)
-    research = jnp.where(try_m, jnp.bool_(False), research)
-
-    bt_new = _row_set(bt_new, nply, _row_from_board(child), advance)
-    if nnue.is_board768(params) and variant != "atomic":
-        codes, sqs, signs = move_piece_changes(
-            parent_b, jnp.maximum(move, 0), variant
-        )
+    with jax.named_scope("step.make_move"):
+        move = moves_row1[m_ix]
+        child = make_move(parent_b, jnp.maximum(move, 0), variant)
+        # late-move reduction: late, quiet, unchecked moves of a deep-enough
+        # node search 1 ply shallower (2 from move 8); RETURN re-pushes at
+        # full depth when the reduced score beats alpha
         if _PRUNING:
-            # a null move changes no pieces: zeroed slots make the
-            # incremental update an exact no-op (code 0 → no-op)
-            codes = jnp.where(do_null, 0, codes)
-            signs = jnp.where(do_null, 0, signs)
-        child_acc = nnue.apply_acc_updates_768(params, s.acc[ply1], codes, sqs, signs)
-        acc_new = _row_set(s.acc, nply, child_acc, advance)
-    else:
-        acc_new = s.acc
+            m_quiet = _is_quiet(jnp.maximum(move, 0), bt1[BT_BOARD:BT_BOARD + 64])
+            lmr_ok = (
+                (dl_node >= 3) & (nt1[NT_MIDX] >= 3) & m_quiet
+                & (nt1[NT_INCHECK] == 0) & ~node_in_qs
+            )
+            red = jnp.where(
+                lmr_ok, jnp.where(nt1[NT_MIDX] >= 8, 2, 1), 0
+            )
+            red = jnp.where(re_push | do_null, 0, red)
+            # the null child: same position, opponent to move, no ep, and a
+            # reset halfmove clock — which deliberately breaks the reversible
+            # repetition chain across the null (Stockfish's pliesFromNull)
+            child = Board(
+                board=jnp.where(do_null, parent_b.board, child.board),
+                stm=jnp.where(do_null, 1 - parent_b.stm, child.stm),
+                ep=jnp.where(do_null, -1, child.ep),
+                castling=jnp.where(do_null, parent_b.castling, child.castling),
+                halfmove=jnp.where(do_null, 0, child.halfmove),
+                extra=jnp.where(do_null, parent_b.extra, child.extra),
+            )
+            null_r = NULL_R + jnp.where(dl_node >= 7, 1, 0)
+            child_dl = jnp.maximum(
+                jnp.where(do_null, dl_node - 1 - null_r, dl_node - 1 - red), 0
+            )
+        else:
+            red = jnp.int32(0)
+            child_dl = jnp.maximum(dl_node - 1, 0)
+        nply = jnp.minimum(ply1 + 1, P1 - 1)
 
-    ret = jnp.where(try_m & finish, fin_val, ret)
-    ret_depth = jnp.where(try_m & finish, dl_node, ret_depth)
-    mode = jnp.where(
-        try_m, jnp.where(finish, MODE_RETURN, MODE_ENTER), mode
-    )
-    ply_f = jnp.where(advance, nply, ply1)
+    with jax.named_scope("step.push"):
+        # TRYMOVE's own-row write (midx/null/lastred/killers), then the
+        # child-push writes: depth_left of the pushed row (a single-field
+        # 2-D one-hot — the row's other fields belong to the OLD node there
+        # and are rewritten when the child expands), its board row, and its
+        # incremental accumulator
+        nt1w = nt1
+        for f_ix, f_val in (
+            (NT_MIDX, jnp.where(normal_adv, nt1[NT_MIDX] + 1, nt1[NT_MIDX])),
+            (NT_NULL, jnp.where(do_null, 2, nt1[NT_NULL])),
+            (NT_LASTRED, jnp.where(advance, red, nt1[NT_LASTRED])),
+            (NT_K0, k0_v), (NT_K1, k1_v),
+        ):
+            nt1w = nt1w.at[f_ix].set(f_val)
+        nt_new = _row_set(nt_new, ply1, nt1w, try_m)
+        nt_new = _field_set(nt_new, nply, NT_DL, child_dl, advance)
+        research = jnp.where(try_m, jnp.bool_(False), research)
 
-    lane_new = jnp.stack([
-        ply_f, mode, ret, ret_depth,
-        store_mark.astype(jnp.int32), store_val,
-        nodes, lane[LN_DLIM], lane[LN_BUDGET],
-        root_score, root_move, lane[LN_RALPHA], lane[LN_RBETA],
-        research.astype(jnp.int32),
-        lane[LN_JITTER], lane[LN_GROUP],
-    ])
+        bt_new = _row_set(bt_new, nply, _row_from_board(child), advance)
+    with jax.named_scope("step.acc_update"):
+        if nnue.is_board768(params) and variant != "atomic":
+            codes, sqs, signs = move_piece_changes(
+                parent_b, jnp.maximum(move, 0), variant
+            )
+            if _PRUNING:
+                # a null move changes no pieces: zeroed slots make the
+                # incremental update an exact no-op (code 0 → no-op)
+                codes = jnp.where(do_null, 0, codes)
+                signs = jnp.where(do_null, 0, signs)
+            child_acc = nnue.apply_acc_updates_768(params, s.acc[ply1], codes, sqs, signs)
+            acc_new = _row_set(s.acc, nply, child_acc, advance)
+        else:
+            acc_new = s.acc
+
+    with jax.named_scope("step.switch"):
+        ret = jnp.where(try_m & finish, fin_val, ret)
+        ret_depth = jnp.where(try_m & finish, dl_node, ret_depth)
+        mode = jnp.where(
+            try_m, jnp.where(finish, MODE_RETURN, MODE_ENTER), mode
+        )
+        ply_f = jnp.where(advance, nply, ply1)
+
+        lane_new = jnp.stack([
+            ply_f, mode, ret, ret_depth,
+            store_mark.astype(jnp.int32), store_val,
+            nodes, lane[LN_DLIM], lane[LN_BUDGET],
+            root_score, root_move, lane[LN_RALPHA], lane[LN_RBETA],
+            research.astype(jnp.int32),
+            lane[LN_JITTER], lane[LN_GROUP],
+        ])
 
     return SearchState(
         bt=bt_new, nt=nt_new, lane=lane_new,
@@ -935,11 +950,13 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
                     _tt_mod.FLAG_UPPER, _tt_mod.FLAG_EXACT,
                 ),
             )
-            t = _tt_mod.store(
-                t, h1, h2, lane[:, LN_RET],
-                jnp.maximum(lane[:, LN_RETD], 0), flag, ntrow[:, NT_BMOVE],
-                store_mask, prefer_deep=prefer_deep, gen=gen_i,
-            )
+            with jax.named_scope("step.tt_store"):
+                t = _tt_mod.store(
+                    t, h1, h2, lane[:, LN_RET],
+                    jnp.maximum(lane[:, LN_RETD], 0), flag,
+                    ntrow[:, NT_BMOVE], store_mask,
+                    prefer_deep=prefer_deep, gen=gen_i,
+                )
 
             # ---- probe lanes about to enter a node (mode == ENTER);
             # the probe window must match the window ENTER will give the
@@ -959,10 +976,11 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
                     pnull, 1 - ntprow[:, NT_BETA], -ntprow[:, NT_ALPHA]
                 ),
             )
-            usable, score, _mv, order_mv = _tt_mod.probe(
-                t, h1, h2, ntrow[:, NT_DL], a_w, b_w,
-                deep_bounds=deep_tt,
-            )
+            with jax.named_scope("step.tt_probe"):
+                usable, score, _mv, order_mv = _tt_mod.probe(
+                    t, h1, h2, ntrow[:, NT_DL], a_w, b_w,
+                    deep_bounds=deep_tt,
+                )
             usable &= enter
             order_mv = jnp.where(enter, order_mv, -1)
             s = step(s, usable, score, order_mv)
@@ -971,12 +989,13 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
             # Their hash is the PRE-step hash: a marking lane was in ENTER
             # at this ply, exactly the position h1/h2 were computed for.
             sval = s.lane[:, LN_SVAL]
-            t = _tt_mod.store(
-                t, h1, h2, sval, jnp.zeros_like(sval),
-                jnp.full_like(sval, _tt_mod.FLAG_EXACT),
-                jnp.full_like(sval, -1), s.lane[:, LN_SMARK] != 0,
-                prefer_deep=prefer_deep, gen=gen_i,
-            )
+            with jax.named_scope("step.tt_store"):
+                t = _tt_mod.store(
+                    t, h1, h2, sval, jnp.zeros_like(sval),
+                    jnp.full_like(sval, _tt_mod.FLAG_EXACT),
+                    jnp.full_like(sval, -1), s.lane[:, LN_SMARK] != 0,
+                    prefer_deep=prefer_deep, gen=gen_i,
+                )
             return s, t, i + 1
 
     def cond(carry):
@@ -1067,7 +1086,8 @@ def _merge_lanes(state: SearchState, fresh: SearchState,
         m = mask.reshape((old.shape[0],) + (1,) * (old.ndim - 1))
         return jnp.where(m, new, old)
 
-    return jax.tree.map(pick, state, fresh)
+    with jax.named_scope("refill.merge"):
+        return jax.tree.map(pick, state, fresh)
 
 
 # both inputs are donated: the running state's tables are overwritten in
@@ -1125,20 +1145,21 @@ def _refill_fresh(params: nnue.NnueParams, state: SearchState,
             return jnp.take(x, tk, axis=0)
         return jnp.asarray(np.asarray(x))[tk]
 
-    roots_full = jax.tree.map(lambda a: jnp.asarray(a)[tk], new_roots)
-    fresh = _init_state_jit(
-        params, roots_full,
-        expand(depth, 0, np.int32), expand(node_budget, 0, np.int32),
-        max_ply, variant,
-        hist_hash=expand(hist_hash, 0, np.uint32, (MAX_HIST, 2)),
-        hist_halfmove=expand(
-            hist_halfmove, HIST_HM_SENTINEL, np.int32, (MAX_HIST,)
-        ),
-        root_alpha=expand(root_alpha, -INF, np.int32),
-        root_beta=expand(root_beta, INF, np.int32),
-        order_jitter=expand(order_jitter, 0, np.int32),
-        group=expand(group, 0, np.int32),
-    )
+    with jax.named_scope("refill.fresh"):
+        roots_full = jax.tree.map(lambda a: jnp.asarray(a)[tk], new_roots)
+        fresh = _init_state_jit(
+            params, roots_full,
+            expand(depth, 0, np.int32), expand(node_budget, 0, np.int32),
+            max_ply, variant,
+            hist_hash=expand(hist_hash, 0, np.uint32, (MAX_HIST, 2)),
+            hist_halfmove=expand(
+                hist_halfmove, HIST_HM_SENTINEL, np.int32, (MAX_HIST,)
+            ),
+            root_alpha=expand(root_alpha, -INF, np.int32),
+            root_beta=expand(root_beta, INF, np.int32),
+            order_jitter=expand(order_jitter, 0, np.int32),
+            group=expand(group, 0, np.int32),
+        )
     return fresh, mask
 
 

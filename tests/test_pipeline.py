@@ -16,6 +16,13 @@ utils/syncstats.py):
 4. The pipelined boundary is cheap: one packed-summary transfer on a
    no-finish boundary at the stream level, and >= 5x fewer transfers
    than the synchronous loop at the engine level (ISSUE acceptance).
+5. The host timeline of a session, in both loops: the phase counters
+   decompose every boundary interval (they sum to host_ms + device_ms,
+   wait is device_ms), sessions are host + device + set-up + tail, and
+   with a recorder on each `phase.*` span was emitted where its work
+   ran — inside its segment and session, overlapping no other — while
+   the submit path leaves async pairs only. Results are bit-identical
+   with the recorder on and off.
 
 conftest.py pins REFILL=0/HELPERS=1; engine tests opt in via refill=True
 exactly like tests/test_refill.py (mesh=None single-device scheduler).
@@ -29,7 +36,8 @@ import pytest
 
 from fishnet_tpu.client.ipc import Chunk, WorkPosition
 from fishnet_tpu.client.wire import AnalysisWork, EngineFlavor, NodeLimit
-from fishnet_tpu.engine.tpu import TpuEngine
+from fishnet_tpu.engine.tpu import PHASES, TpuEngine
+from fishnet_tpu.obs import trace as obs_trace
 
 START = "rnbqkbnr/pppppppp/8/8/8/8/PPPPPPPP/RNBQKBNR w KQkq - 0 1"
 GAME = ["e2e4", "c7c5", "g1f3", "d7d6", "d2d4"]
@@ -170,7 +178,9 @@ def make_refill_engine(**kw):
 @pytest.fixture(scope="module")
 def engine_pair():
     """One LaneScheduler chunk per pipeline mode at a small segment (many
-    boundaries, so the speculative path actually engages)."""
+    boundaries, so the speculative path actually engages), and the same
+    chunk again with a recorder on: out["traced", mode] holds
+    (responses, the ring's events, totals)."""
     saved = {k: os.environ.get(k)
              for k in ("FISHNET_TPU_PIPELINE", "FISHNET_TPU_SEGMENT")}
     out = {}
@@ -183,6 +193,15 @@ def engine_pair():
                 make_chunk(analysis_work(depth=3), n_positions=4)))
             out[mode] = (resp, list(eng.occupancy_log),
                          dict(eng.occupancy_totals))
+            rec = obs_trace.install(obs_trace.TraceRecorder(capacity=65536))
+            try:
+                eng = make_refill_engine()
+                resp = asyncio.run(eng.go_multiple(
+                    make_chunk(analysis_work(depth=3), n_positions=4)))
+            finally:
+                obs_trace.uninstall()
+            out["traced", mode] = (resp, rec.snapshot(),
+                                   dict(eng.occupancy_totals))
     finally:
         for k, v in saved.items():
             if v is None:
@@ -190,6 +209,11 @@ def engine_pair():
             else:
                 os.environ[k] = v
     return out
+
+
+def _flat(resps):
+    return [(r.position_index, r.best_move, r.depth, r.nodes,
+             r.scores.matrix, r.pvs.matrix) for r in resps]
 
 
 def test_engine_exactly_once_under_speculation(engine_pair):
@@ -205,14 +229,7 @@ def test_engine_exactly_once_under_speculation(engine_pair):
 def test_engine_bit_identity(engine_pair):
     """Scheduler results are identical with the pipeline on and off:
     same best moves, scores, depths, node counts and PVs."""
-    legacy = engine_pair["0"][0]
-    piped = engine_pair["1"][0]
-
-    def flat(resps):
-        return [(r.position_index, r.best_move, r.depth, r.nodes,
-                 r.scores.matrix, r.pvs.matrix) for r in resps]
-
-    assert flat(legacy) == flat(piped)
+    assert _flat(engine_pair["0"][0]) == _flat(engine_pair["1"][0])
 
 
 def test_engine_boundary_transfer_reduction(engine_pair):
@@ -238,3 +255,101 @@ def test_engine_boundary_transfer_reduction(engine_pair):
         row = engine_pair[mode][1][0]
         for key in ("transfers", "host_ms", "device_ms"):
             assert key in row
+
+
+# ------------------------------------------------- the host's timeline
+
+
+@pytest.mark.parametrize("mode", ["0", "1"])
+def test_engine_phase_counters_tie_out(engine_pair, mode):
+    """Every boundary interval is decomposed, none of it dropped: the
+    phase totals sum to host_ms + device_ms, the wait phase IS
+    device_ms, and a session is its boundaries plus set-up and tail."""
+    for totals in (engine_pair[mode][2], engine_pair["traced", mode][2]):
+        in_boundaries = totals["host_ms"] + totals["device_ms"]
+        assert in_boundaries > 0
+        assert sum(totals[f"phase_{p}_ms"] for p in PHASES) == pytest.approx(
+            in_boundaries, rel=0.01)
+        assert totals["phase_wait_ms"] == totals["device_ms"]
+        for p in ("admit", "refill", "dispatch", "lanes", "account"):
+            assert totals[f"phase_{p}_ms"] > 0, p
+        assert totals["sessions"] >= 1
+        assert totals["session_setup_ms"] > 0 and totals["session_tail_ms"] > 0
+        assert totals["session_ms"] == pytest.approx(
+            in_boundaries + totals["session_setup_ms"]
+            + totals["session_tail_ms"], rel=0.01)
+        assert totals["chunks_submitted"] == 1
+        assert totals["positions_submitted"] == 4
+        assert 0 < (totals["submit_replay_ms"] + totals["submit_history_ms"]
+                    ) <= totals["submit_ms"]
+
+
+@pytest.mark.parametrize("mode", ["0", "1"])
+def test_engine_phase_spans_are_where_the_work_ran(engine_pair, mode):
+    events = engine_pair["traced", mode][1]
+    names = {e["name"] for e in events}
+    assert "segment.device" not in names and "segment.host" not in names
+    assert "segment.dispatch" not in names  # it is phase.dispatch now
+
+    def inside(e, outer):
+        return (outer["ts"] <= e["ts"] + 1e-3 and
+                e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-3)
+
+    spans = [e for e in events if e["ph"] == "X"]
+    phases = [e for e in spans if e["name"].startswith("phase.")]
+    segments = [e for e in spans if e["name"] == "segment"]
+    sessions = [e for e in spans if e["name"] == "session"]
+    assert phases and segments and sessions
+    assert {e["name"] for e in phases} >= {
+        "phase.admit", "phase.refill", "phase.dispatch", "phase.lanes",
+        "phase.account"}
+    for s in sessions:
+        assert s["args"]["width"] >= 4 and s["args"]["pending"] == 4
+        assert s["args"]["segments"] >= 1 and s["args"]["steps"] > 0
+    assert sum(s["args"]["positions"] for s in sessions) == 4
+    for e in phases:
+        mine = [s for s in sessions if inside(e, s)]
+        assert len(mine) == 1, e
+        # after the session's last boundary (its tail) no interval is
+        # open: everything earlier lies in exactly one segment span
+        last = max(g["ts"] + g["dur"] for g in segments if inside(g, mine[0]))
+        if e["ts"] + 1e-3 < last:
+            assert sum(inside(e, g) for g in segments) == 1, e
+    # every scheduler span with a duration lies inside a session
+    for e in spans:
+        if e["name"] == "fetch" or e["name"] == "segment":
+            assert any(inside(e, s) for s in sessions), e
+    # self times: no two phase spans of one thread overlap
+    by_tid = {}
+    for e in phases:
+        by_tid.setdefault(e["tid"], []).append(e)
+    for track in by_tid.values():
+        track.sort(key=lambda e: e["ts"])
+        for a, b in zip(track, track[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-3, (a, b)
+    dispatches = [e for e in phases if e["name"] == "phase.dispatch"]
+    assert all(e["args"]["steps"] == 200 for e in dispatches)
+    if mode == "1":
+        assert any(e["args"]["speculative"] for e in dispatches)
+    # the submit path runs while no session does: async pairs, no span
+    submits = [e for e in events if e["name"].startswith("submit")]
+    assert submits and all(e["ph"] in ("b", "e") for e in submits)
+    for name in ("submit", "submit.replay", "submit.history"):
+        ends = [e["ph"] for e in submits if e["name"] == name]
+        assert ends.count("b") == ends.count("e") > 0, name
+    assert len({e["id"] for e in submits}) == 1
+    first_session = min(s["ts"] for s in sessions)
+    assert all(e["ts"] <= first_session for e in submits)
+    # and the report's cross-check holds on a real session
+    from tools import trace_report
+
+    report = trace_report.summarize(events)
+    assert trace_report.crosscheck(report, tolerance=0.01) == []
+    assert len(report["sessions"]) == len(sessions)
+
+
+@pytest.mark.parametrize("mode", ["0", "1"])
+def test_engine_bit_identity_recorder_on_off(engine_pair, mode):
+    """Tracing is bookkeeping: the new phase, session and submit sites
+    change no result."""
+    assert _flat(engine_pair[mode][0]) == _flat(engine_pair["traced", mode][0])

@@ -27,7 +27,9 @@ import pytest
 
 from fishnet_tpu.client.ipc import Chunk, WorkPosition
 from fishnet_tpu.client.wire import AnalysisWork, EngineFlavor, NodeLimit
-from fishnet_tpu.engine.tpu import TpuEngine
+from fishnet_tpu.engine.tpu import COMPILE_SITES, TpuEngine
+from fishnet_tpu.obs import trace as obs_trace
+from fishnet_tpu.utils import syncstats
 
 START = "rnbqkbnr/pppppppp/8/8/8/8/PPPPPPPP/RNBQKBNR w KQkq - 0 1"
 GAME = ["e2e4", "c7c5", "g1f3", "d7d6"]
@@ -181,6 +183,87 @@ def test_concurrent_chunks_exactly_once():
         assert [r.position_index for r in responses] == [0, 1, 2]
         assert all(r.best_move for r in responses)
     assert engine.occupancy_totals["positions_done"] == 6
+
+
+GAP_PARTS = ("gap_submit_ms", "gap_lock_ms", "gap_handoff_ms",
+             "gap_starved_ms")
+
+
+def test_gap_between_sessions_is_the_callers_when_nothing_is_submitted():
+    """Two chunks with a pause between them: the pause is nobody's work
+    (`gap_starved_ms`), the second chunk's replay and history hash are
+    the engine's (`gap_submit_ms`), the parts make up the gap, and gaps
+    plus sessions make up the time from the first session's start to
+    the last one's end."""
+    rec = obs_trace.install(obs_trace.TraceRecorder(capacity=16384))
+    try:
+        engine = make_refill_engine(max_depth=2)
+        run(engine, make_chunk(analysis_work(depth=2)))
+        assert engine.occupancy_totals["gap_ms"] == 0.0  # nothing before
+        time.sleep(0.25)
+        run(engine, make_chunk(analysis_work(depth=2)))
+    finally:
+        obs_trace.uninstall()
+    tot = engine.occupancy_totals
+    assert tot["sessions"] >= 2
+    assert tot["gap_starved_ms"] >= 250.0
+    assert tot["gap_submit_ms"] > 0.0
+    assert sum(tot[k] for k in GAP_PARTS) == pytest.approx(tot["gap_ms"])
+    sessions = [e for e in rec.snapshot() if e["name"] == "session"]
+    assert len(sessions) == tot["sessions"]
+    elapsed_ms = (max(e["ts"] + e["dur"] for e in sessions)
+                  - min(e["ts"] for e in sessions)) / 1000.0
+    assert tot["gap_ms"] + tot["session_ms"] == pytest.approx(
+        elapsed_ms, rel=0.01)
+
+
+def test_gap_counts_overlapping_submits_once():
+    """Two threads inside _submit at once after a session has ended: the
+    time either was submitting is `gap_submit_ms`, counted once — the
+    parts still make up the gap."""
+    engine = make_refill_engine(max_depth=2)
+    run(engine, make_chunk(analysis_work(depth=2)))
+    chunks = [
+        make_chunk(analysis_work(depth=2), n_positions=3, moves=GAME),
+        make_chunk(analysis_work(depth=2), n_positions=3,
+                   moves=["d2d4", "g8f6", "c2c4"]),
+    ]
+    threads = [threading.Thread(target=run, args=(engine, c)) for c in chunks]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    tot = engine.occupancy_totals
+    assert tot["positions_done"] == 9 and tot["chunks_submitted"] == 3
+    assert 0.0 < tot["gap_submit_ms"] <= tot["gap_ms"]
+    assert sum(tot[k] for k in GAP_PARTS) == pytest.approx(tot["gap_ms"])
+
+
+def test_compile_inside_submit_is_counted_there():
+    """A shape first met in _submit's history hash (one program set per
+    number of earlier positions) is counted against `submit_history`
+    on the engine the thread serves, and nowhere else."""
+    engine = make_refill_engine(max_depth=2)
+    work = analysis_work(depth=2)
+    # nine plies of history: a length no other test of this file hashes
+    moves = ["g1f3", "g8f6", "f3g1", "f6g8", "b1c3", "b8c6", "c3b1",
+             "c6b8", "e2e4"]
+    chunk = Chunk(work=work, deadline=time.monotonic() + 120,
+                  variant="standard", flavor=EngineFlavor.TPU,
+                  positions=[WorkPosition(
+                      work=work, position_index=0, url=None, skip=False,
+                      root_fen=START, moves=moves)])
+    tot = engine.occupancy_totals
+    with syncstats.serving(tot):
+        engine._scheduler._submit(chunk)
+    assert tot["compiles_submit_history"] >= 1
+    assert tot["compile_ms"] > 0.0
+    for site in COMPILE_SITES:
+        if site != "submit_history":
+            assert tot[f"compiles_{site}"] == 0, site
+    # outside the block this thread serves no engine: counted nowhere
+    assert syncstats.where() == (None, "other")
 
 
 def test_occupancy_accounting():

@@ -153,25 +153,56 @@ def test_clock_sync_takes_minimum():
 
 
 def test_syncstats_segments_crosscheck_within_1pct():
-    """The acceptance contract: per-segment device/host totals derived
-    from the trace's child spans agree with the SyncStats snapshots the
-    spans were rendered from, within trace_report's 1% tolerance."""
+    """The acceptance contract: the device/host split each `segment`
+    span carries in its args agrees, within trace_report's 1%
+    tolerance, with the spans emitted inside the interval where the
+    work ran — device_ms with the `fetch` spans, host_ms with the
+    `phase.*` self times plus what no phase covered."""
     rec = trace.install(trace.TraceRecorder(capacity=4096,
                                             process_name="t"))
     stats = SyncStats()
     import numpy as np
 
+    class OnDevice:
+        """Takes a millisecond to reach the host, like a device value
+        (a plain numpy fetch is a microsecond: all rounding)."""
+
+        def __array__(self, dtype=None, copy=None):
+            time.sleep(0.001)
+            return np.arange(100)
+
     for _ in range(5):
-        for _ in range(3):
-            stats.fetch(np.arange(100), label="test")
-        time.sleep(0.002)
+        with stats.phase("refill"):
+            time.sleep(0.002)
+        for _ in range(2):
+            stats.fetch(OnDevice(), label="test")
+        with stats.phase("pv", rows=3):
+            time.sleep(0.001)
+            stats.fetch(OnDevice(), label="pv")  # pauses the phase
+        time.sleep(0.001)  # under no phase: "other"
         snap = stats.boundary()
         assert snap["transfers"] == 3
-    report = trace_report.summarize(rec.export()["traceEvents"])
+        ph = snap["phases"]
+        assert ph["wait"] == snap["device_ms"]
+        assert ph["refill"] >= 2.0 and ph["pv"] >= 1.0 and ph["other"] >= 1.0
+        assert sum(ph.values()) == pytest.approx(
+            snap["host_ms"] + snap["device_ms"], rel=0.01)
+    events = rec.export()["traceEvents"]
+    names = {e["name"] for e in events}
+    assert "segment.device" not in names and "segment.host" not in names
+    report = trace_report.summarize(events)
     assert report["segments"]["count"] == 5
     assert trace_report.crosscheck(report, tolerance=0.01) == []
     # fetch spans are on the timeline too
     assert report["phases"]["fetch"]["count"] == 15
+    table = report["boundary_phases"]
+    assert table["refill"]["count"] == 5 and table["wait"]["count"] == 15
+    assert sum(r["share"] for r in table.values()) == pytest.approx(
+        1.0, abs=0.01)
+    # the pv span covers its fetch but claims only its own time
+    pv = [e for e in events if e["name"] == "phase.pv"]
+    assert all(e["args"]["self_ms"] * 1000.0 < e["dur"] for e in pv)
+    assert all(e["args"]["rows"] == 3 for e in pv)
     # segment windows are contiguous by construction (boundary() reuses
     # one clock reading to close a window and open the next), so any
     # gaps that survive float rounding are negligible

@@ -8,18 +8,25 @@ measurement items need without opening Perfetto:
 
 - **per-phase time shares**: total duration per span name (warmup,
   search, supervisor.dispatch, queue.acquire, segment, ...) with the
-  SyncStats-derived device/host split (`segment.device` /
-  `segment.host` child spans) called out as a share of segment time —
-  the profiling lever for the ~290 us/step fixed per-segment gap.
+  SyncStats device/host split (the `segment` spans' args) called out
+  as a share of segment time — the profiling lever for the ~290 us/step
+  fixed per-segment gap.
+- **boundary phases**: the segment time by what the host was doing —
+  the scheduler's `phase.*` spans (self time, fetches inside taken
+  out), `wait` (the `fetch` spans) and `other` (what no phase covered,
+  from the args): count, total, share of segment time.
+- **sessions**: one row per `session` span — the width the drive
+  session got, what was pending when it chose, set-up, segments.
 - **boundary-gap histogram**: the distribution of gaps between
   consecutive `segment` spans on the host timeline — the fixed
   per-boundary cost itself, bucketed.
 
 Cross-validation: every `segment` span carries its SyncStats snapshot
-in args (device_ms/host_ms), and its child spans' durations are those
-exact numbers — so `aggregate(args)` and `aggregate(child spans)` must
-agree to well under 1%; `--selftest` (and tests/test_trace.py) assert
-that.
+in args (device_ms/host_ms/phases), while the `fetch` and `phase.*`
+spans inside it were emitted where the work ran, from the same clock
+reads — so device_ms must equal the sum of the `fetch` spans and
+host_ms the sum of the `phase.*` self times plus the args' `other`,
+to well under 1%; `--selftest` (and tests/test_trace.py) assert that.
 
 Request waterfalls: `--request <trace_id>` reconstructs one request's
 causal chain from its span links — every span/instant whose args carry
@@ -91,16 +98,43 @@ def summarize(events: List[dict]) -> dict:
         row["count"] += 1
         row["total_ms"] += float(e.get("dur", 0.0)) / 1000.0
 
-    # SyncStats cross-validation: args-carried totals vs child-span sums
+    # SyncStats cross-validation: the snapshot each `segment` span
+    # carries in its args vs the spans emitted inside the interval
     seg = _spans(events, "segment")
-    args_device = sum(
-        float((e.get("args") or {}).get("device_ms", 0.0)) for e in seg
+    seg_args = [e.get("args") or {} for e in seg]
+    args_device = sum(float(a.get("device_ms", 0.0)) for a in seg_args)
+    args_host = sum(float(a.get("host_ms", 0.0)) for a in seg_args)
+    args_other = sum(
+        float((a.get("phases") or {}).get("other", 0.0)) for a in seg_args
     )
-    args_host = sum(
-        float((e.get("args") or {}).get("host_ms", 0.0)) for e in seg
+    boundary: Dict[str, dict] = defaultdict(
+        lambda: {"count": 0, "total_ms": 0.0}
     )
-    span_device = per_name.get("segment.device", {}).get("total_ms", 0.0)
-    span_host = per_name.get("segment.host", {}).get("total_ms", 0.0)
+    for e in spans:
+        name = str(e.get("name"))
+        if name.startswith("phase."):
+            # self time: a phase that fetched says so in its args
+            self_ms = (e.get("args") or {}).get(
+                "self_ms", float(e.get("dur", 0.0)) / 1000.0)
+            row = boundary[name[len("phase."):]]
+            row["count"] += 1
+            row["total_ms"] += float(self_ms)
+    span_host = sum(row["total_ms"] for row in boundary.values())
+    span_device = per_name.get("fetch", {}).get("total_ms", 0.0)
+    if span_device:
+        boundary["wait"] = {"count": per_name["fetch"]["count"],
+                            "total_ms": span_device}
+    if args_other:
+        boundary["other"] = {"count": len(seg), "total_ms": args_other}
+
+    t_first = min((float(e.get("ts", 0.0)) for e in spans), default=0.0)
+    sessions = [
+        dict(e.get("args") or {},
+             start_ms=round((float(e.get("ts", 0.0)) - t_first) / 1000.0, 3),
+             dur_ms=round(float(e.get("dur", 0.0)) / 1000.0, 3))
+        for e in sorted(_spans(events, "session"),
+                        key=lambda e: float(e.get("ts", 0.0)))
+    ]
 
     # boundary gaps: start-to-start minus duration of consecutive
     # segment spans per (pid, tid) track, i.e. time between the end of
@@ -129,7 +163,7 @@ def summarize(events: List[dict]) -> dict:
             hist[-1] += 1
 
     total_ms = sum(row["total_ms"] for row in per_name.values())
-    seg_total = span_device + span_host
+    seg_total = args_device + args_host
     return {
         "events": len(events),
         "spans": len(spans),
@@ -146,16 +180,29 @@ def summarize(events: List[dict]) -> dict:
         },
         "segments": {
             "count": len(seg),
-            "device_ms": round(span_device, 3),
-            "host_ms": round(span_host, 3),
-            "device_share": round(span_device / seg_total, 4)
+            "device_ms": round(args_device, 3),
+            "host_ms": round(args_host, 3),
+            "device_share": round(args_device / seg_total, 4)
             if seg_total > 0 else 0.0,
-            "host_share": round(span_host / seg_total, 4)
+            "host_share": round(args_host / seg_total, 4)
             if seg_total > 0 else 0.0,
-            # the args-carried SyncStats totals, for cross-validation
-            "args_device_ms": round(args_device, 3),
-            "args_host_ms": round(args_host, 3),
+            # what the spans inside the intervals add up to, for
+            # cross-validation: fetches; phase self times + other
+            "span_device_ms": round(span_device, 3),
+            "span_host_ms": round(span_host + args_other, 3),
         },
+        "boundary_phases": {
+            name: {
+                "count": row["count"],
+                "total_ms": round(row["total_ms"], 3),
+                "share": round(row["total_ms"] / seg_total, 4)
+                if seg_total > 0 else 0.0,
+            }
+            for name, row in sorted(
+                boundary.items(), key=lambda kv: -kv[1]["total_ms"]
+            )
+        },
+        "sessions": sessions,
         "boundary_gaps": {
             "count": len(gaps_ms),
             "buckets_ms": list(GAP_BUCKETS_MS),
@@ -387,18 +434,22 @@ def render_waterfall(wf: dict) -> str:
 
 
 def crosscheck(report: dict, tolerance: float = 0.01) -> List[str]:
-    """The <=1% agreement contract between SyncStats args and the child
-    spans rendered from them. Returns human-readable violations."""
+    """The <=1% agreement contract between the SyncStats snapshots in
+    the `segment` spans' args and the spans emitted inside them: the
+    `fetch` spans against device_ms, the `phase.*` self times (plus
+    the args' `other`) against host_ms. Returns human-readable
+    violations."""
     seg = report["segments"]
     out = []
-    for key in ("device", "host"):
-        spans_ms = seg[f"{key}_ms"]
-        args_ms = seg[f"args_{key}_ms"]
+    for key, what in (("device", "fetch"), ("host", "phase.* + other")):
+        spans_ms = seg[f"span_{key}_ms"]
+        args_ms = seg[f"{key}_ms"]
         ref = max(abs(args_ms), 1e-9)
         if abs(spans_ms - args_ms) / ref > tolerance:
             out.append(
-                f"segment.{key} spans sum to {spans_ms:.3f}ms but SyncStats "
-                f"args carry {args_ms:.3f}ms (>{tolerance:.0%} apart)"
+                f"{what} spans sum to {spans_ms:.3f}ms but the segments' "
+                f"SyncStats args carry {key}_ms {args_ms:.3f}ms "
+                f"(>{tolerance:.0%} apart)"
             )
     return out
 
@@ -422,6 +473,32 @@ def render_text(report: dict) -> str:
             f"({seg['device_share']:.1%})  host {seg['host_ms']:.3f}ms "
             f"({seg['host_share']:.1%})",
         ]
+    if report["boundary_phases"]:
+        lines += [
+            "",
+            f"{'boundary phase':<24} {'count':>7} {'total_ms':>12} "
+            f"{'of seg':>7}",
+        ]
+        for name, row in report["boundary_phases"].items():
+            lines.append(
+                f"{name:<24} {row['count']:>7} {row['total_ms']:>12.3f} "
+                f"{row['share']:>6.1%}"
+            )
+    if report["sessions"]:
+        lines += [
+            "",
+            f"{'session at ms':>14} {'dur_ms':>10} {'width':>6} "
+            f"{'pending':>8} {'setup_ms':>9} {'segments':>9} "
+            f"{'positions':>10}",
+        ]
+        for row in report["sessions"]:
+            lines.append(
+                f"{row['start_ms']:>14.3f} {row['dur_ms']:>10.3f} "
+                f"{row.get('width', 0):>6} {row.get('pending', 0):>8} "
+                f"{row.get('setup_ms', 0.0):>9.3f} "
+                f"{row.get('segments', 0):>9} "
+                f"{row.get('positions', 0):>10}"
+            )
     gaps = report["boundary_gaps"]
     if gaps["count"]:
         lines += ["", "boundary gaps (ms):"]
