@@ -28,7 +28,8 @@ from ..client.ipc import Chunk, Matrix, PositionResponse, WorkPosition
 from ..client.wire import AnalysisWork, MoveWork, Score
 from ..models import nnue
 from ..ops import search as search_ops
-from ..ops.board import from_position, stack_boards
+from ..ops import tt as tt_mod
+from ..ops.board import from_position, position_fields, stack_boards
 from ..obs import inflight as obs_inflight
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
@@ -92,6 +93,17 @@ DEVICE_VARIANTS = {
     "kingOfTheHill": "kingOfTheHill",
     "racingKings": "racingKings",
 }
+
+
+def _position_keys(positions, variant: str):
+    """(h1, h2) uint32 arrays, one entry a Position: `tt.hash_board`'s
+    keys computed on the host, no device put and no fetch."""
+    rows = [position_fields(p) for p in positions]
+    return tt_mod.hash_boards_host(
+        *(np.stack([getattr(b, f) for b in rows])
+          for f in ("board", "stm", "ep", "castling", "extra")),
+        variant,
+    )
 
 
 def _score_from_int(v: int, root_ply_to_mate_sign: int = 1) -> Score:
@@ -195,8 +207,6 @@ class TpuEngine(ChunkSubmit):
         # Chunks are dispatched one at a time (self._lock): concurrent
         # executor threads would otherwise interleave whole-table swaps
         # and silently discard each other's stores.
-        from ..ops import tt as tt_mod
-
         self.tt_size_log2 = tt_size_log2
         if not tt_size_log2:
             self.tt = None
@@ -331,7 +341,7 @@ class TpuEngine(ChunkSubmit):
             "gap_ms": 0.0, "gap_submit_ms": 0.0, "gap_lock_ms": 0.0,
             "gap_handoff_ms": 0.0, "gap_starved_ms": 0.0,
             # the submit path, per chunk and position: game-prefix
-            # replay on the host, history hash (a device call), TT warm
+            # replay and history hash, both on the host, TT warm
             "chunks_submitted": 0, "positions_submitted": 0,
             "submit_ms": 0.0, "submit_replay_ms": 0.0,
             "submit_history_ms": 0.0, "submit_ttwarm_ms": 0.0,
@@ -569,7 +579,6 @@ class TpuEngine(ChunkSubmit):
         touching (or locking) the live table."""
         if self.tt is None:
             return None
-        from ..ops import tt as tt_mod
         from ..parallel.mesh import make_sharded_table
 
         if self.mesh is not None:
@@ -793,26 +802,21 @@ class TpuEngine(ChunkSubmit):
         tail — a return to it inside a lane IS an in-search twofold
         repetition (distance <= ply in Stockfish's check) and must score
         as a draw on first re-visit."""
-        from ..ops import tt as tt_mod
         from ..ops.search import HIST_HM_SENTINEL, MAX_HIST
 
         hh = np.zeros((B, MAX_HIST, 2), np.uint32)
         hm = np.full((B, MAX_HIST), HIST_HM_SENTINEL, np.int32)
-        flat, slots = [], []
+        flat, lanes, ks = [], [], []
         for lane, hist in enumerate(hist_lists):
             tail = hist[-MAX_HIST:]
-            for j, p in enumerate(tail):
-                slots.append((lane, MAX_HIST - len(tail) + j))
-                flat.append(from_position(p))
+            flat.extend(tail)
+            lanes.extend([lane] * len(tail))
+            ks.extend(range(MAX_HIST - len(tail), MAX_HIST))
         if flat:
-            stacked = stack_boards(flat)
-            h1, h2 = tt_mod.hash_boards(stacked, variant)
-            h1, h2 = np.asarray(h1), np.asarray(h2)
-            hms = np.asarray(stacked.halfmove)
-            for n, (lane, k) in enumerate(slots):
-                hh[lane, k, 0] = h1[n]
-                hh[lane, k, 1] = h2[n]
-                hm[lane, k] = hms[n]
+            # on the host: the keys are `tt.hash_board`'s bit for bit,
+            # and a device call here would wait behind a running segment
+            hh[lanes, ks, 0], hh[lanes, ks, 1] = _position_keys(flat, variant)
+            hm[lanes, ks] = [p.halfmove for p in flat]
             # keep only positions occurring >=2x within their lane's tail
             # (the last keep_last slots are exempt — see docstring)
             for lane in range(B):
@@ -829,7 +833,7 @@ class TpuEngine(ChunkSubmit):
         """One history list shared by all B lanes (move jobs: every
         root-move lane has the same game prefix). Hashes the tail ONCE
         and broadcasts — the per-lane version costs B×MAX_HIST
-        from_position calls on the host, against the 7 s move-job
+        position_fields calls on the host, against the 7 s move-job
         deadline."""
         hh1, hm1 = cls._history_arrays([hist], 1, variant, keep_last)
         return (
@@ -1543,7 +1547,6 @@ class LaneScheduler:
         writes it back in its `finally`, which would clobber a
         concurrent swap); a busy engine just skips the warm start."""
         from ..cache import ttwarm as cache_ttwarm
-        from ..ops import tt as tt_mod
 
         eng = self.engine
         store = eng.tt_warm
@@ -1555,10 +1558,9 @@ class LaneScheduler:
             )
             children = [pos.push(m) for m in pos.legal_moves()]
             boards = [pos] + children[: cache_ttwarm.MAX_SLICE_ROWS - 1]
-            stacked = stack_boards([from_position(p) for p in boards])
-            h1, _h2 = tt_mod.hash_boards(stacked, variant)
+            h1, _h2 = _position_keys(boards, variant)
             mask = (1 << eng.tt_size_log2) - 1
-            slots = [int(h) & mask for h in np.asarray(h1)]
+            slots = [int(h) & mask for h in h1]
             entry.tt_warm.append((key, slots))
             rows = store.lookup(eng.tt_size_log2, key)
             if not rows:

@@ -58,8 +58,10 @@ def board_array(pos: Position) -> np.ndarray:
     return board
 
 
-def from_position(pos: Position) -> Board:
-    """Host Position → single-lane Board (numpy)."""
+def position_fields(pos: Position) -> Board:
+    """Host Position → single-lane Board of numpy fields: everything
+    `from_position` computes, before its device puts (the engine hashes
+    game histories from these on the host)."""
     board = board_array(pos)
     castling = np.full(4, -1, dtype=np.int32)
     # variants without castling (antichess, racingKings) never carry
@@ -88,13 +90,18 @@ def from_position(pos: Position) -> Board:
             word = (pos.promoted >> (32 * w)) & 0xFFFFFFFF
             extra[EXTRA_PROMOTED + w] = word - (1 << 32) if word >= 1 << 31 else word
     return Board(
-        board=jnp.asarray(board),
-        stm=jnp.asarray(np.int32(pos.turn)),
-        ep=jnp.asarray(np.int32(pos.ep_square if pos.ep_square is not None else -1)),
-        castling=jnp.asarray(castling),
-        halfmove=jnp.asarray(np.int32(pos.halfmove)),
-        extra=jnp.asarray(extra),
+        board=board,
+        stm=np.int32(pos.turn),
+        ep=np.int32(pos.ep_square if pos.ep_square is not None else -1),
+        castling=castling,
+        halfmove=np.int32(pos.halfmove),
+        extra=extra,
     )
+
+
+def from_position(pos: Position) -> Board:
+    """Host Position → single-lane Board on the device."""
+    return Board(*map(jnp.asarray, position_fields(pos)))
 
 
 def stack_boards(boards) -> Board:

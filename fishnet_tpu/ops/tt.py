@@ -66,16 +66,43 @@ _VARIANT_ID = {
     "standard": 0, "threeCheck": 1, "crazyhouse": 2, "antichess": 3,
     "atomic": 4, "horde": 5, "kingOfTheHill": 6, "racingKings": 7,
 }
-Z1 = jnp.asarray(_rng.integers(0, 2**32, _Z_SHAPE, dtype=np.uint32))
-Z2 = jnp.asarray(_rng.integers(0, 2**32, _Z_SHAPE, dtype=np.uint32))
+# the host's copies (history keys, engine/tpu.py::_history_arrays) and
+# the device constants are the same draw, in this order
+Z1_HOST = _rng.integers(0, 2**32, _Z_SHAPE, dtype=np.uint32)
+Z2_HOST = _rng.integers(0, 2**32, _Z_SHAPE, dtype=np.uint32)
+Z1 = jnp.asarray(Z1_HOST)
+Z2 = jnp.asarray(Z2_HOST)
+_SQ = np.arange(64)
 
 
-def hash_boards(boards, variant: str = "standard"):
-    """Batched `hash_board` over a stacked Board (N leading dim) —
-    used by the engine to hash game-history tails in one dispatch."""
-    return jax.vmap(
-        lambda b, s, e, c, x: hash_board(b, s, e, c, x, variant)
-    )(boards.board, boards.stm, boards.ep, boards.castling, boards.extra)
+def hash_boards_host(board, stm, ep, castling, extra,
+                     variant: str = "standard"):
+    """`hash_board` in numpy, bit for bit, for N positions: numpy int32
+    board (N,64) codes, stm (N,), ep (N,), castling (N,4), extra (N,12)
+    → (h1, h2) uint32 (N,). The plain gather form `hash_board`'s one-hot
+    selects stand for; `variant` folds the same extras in."""
+    xor = np.bitwise_xor.reduce
+    vid = _VARIANT_ID.get(variant, 0)
+
+    def fold(z):
+        # z[0:64] (code 0, an empty square) never folds in
+        h = xor(np.where(board > 0, z[board * 64 + _SQ], np.uint32(0)), axis=-1)
+        h ^= z[_EP_OFF + ep + 1]
+        h ^= xor(z[_CASTLE_OFF + np.arange(4) * 65 + castling + 1], axis=-1)
+        h ^= z[_STM_OFF + (stm != 0)]
+        if vid:
+            h ^= z[_VARIANT_OFF + vid]
+        if variant == "threeCheck":
+            checks = np.clip(extra[:, :2], 0, 3)
+            h ^= xor(z[_CHECKS_OFF + np.arange(2) * 4 + checks], axis=-1)
+        elif variant == "crazyhouse":
+            pockets = np.clip(extra[:, :10], 0, 16)
+            h ^= xor(z[_POCKET_OFF + np.arange(10) * 17 + pockets], axis=-1)
+            bits = (extra[:, 10 + _SQ // 32] >> (_SQ % 32)) & 1
+            h ^= xor(np.where(bits == 1, z[_PROMOTED_OFF + _SQ], np.uint32(0)), axis=-1)
+        return h
+
+    return fold(Z1_HOST), fold(Z2_HOST)
 
 
 class TTable(NamedTuple):

@@ -241,12 +241,18 @@ def test_gap_counts_overlapping_submits_once():
 
 
 def test_compile_inside_submit_is_counted_there():
-    """A shape first met in _submit's history hash (one program set per
-    number of earlier positions) is counted against `submit_history`
-    on the engine the thread serves, and nowhere else."""
+    """_submit builds no program (the history keys are hashed on the
+    host), and a program that is built inside one of its steps is
+    counted against that step on the engine the thread serves, and
+    nowhere else."""
+    import jax
+
+    from fishnet_tpu.chess import Position
+    from fishnet_tpu.ops.board import from_position
+
     engine = make_refill_engine(max_depth=2)
     work = analysis_work(depth=2)
-    # nine plies of history: a length no other test of this file hashes
+    # nine plies of history: a length no other test of this file submits
     moves = ["g1f3", "g8f6", "f3g1", "f6g8", "b1c3", "b8c6", "c3b1",
              "c6b8", "e2e4"]
     chunk = Chunk(work=work, deadline=time.monotonic() + 120,
@@ -254,9 +260,22 @@ def test_compile_inside_submit_is_counted_there():
                   positions=[WorkPosition(
                       work=work, position_index=0, url=None, skip=False,
                       root_fen=START, moves=moves)])
+    # the job's own from_position puts numpy scalars on the device through
+    # a one-off program per process, which an engine's warm-up has met
+    from_position(Position.from_fen(START))
     tot = engine.occupancy_totals
     with syncstats.serving(tot):
         engine._scheduler._submit(chunk)
+    for site in COMPILE_SITES:
+        assert tot[f"compiles_{site}"] == 0, site
+    assert tot["compile_ms"] == 0.0
+    assert tot["submit_history_ms"] > 0.0
+    assert tot["positions_submitted"] == 1
+    # the listener's labelling: a program first met under the step's label
+    with syncstats.serving(tot):
+        with syncstats.step("submit_history"):
+            jax.jit(lambda x: (x * 27 + len(moves)).sum())(
+                np.arange(27)).block_until_ready()
     assert tot["compiles_submit_history"] >= 1
     assert tot["compile_ms"] > 0.0
     for site in COMPILE_SITES:

@@ -145,3 +145,93 @@ def test_repetition_not_confused_by_irreversible_moves(params):
     exp = oracle_search(params, root, DEPTH, BUDGET, MAX_PLY)
     assert int(np.asarray(out["score"])[0]) == exp["score"]
     assert int(np.asarray(out["nodes"])[0]) == exp["nodes"]
+
+
+def _device_history(hist_lists, B, variant="standard", keep_last=0):
+    """`TpuEngine._history_arrays`' contract spelt out with the device's
+    own `tt.hash_board`, one position at a time: the keys the in-search
+    scan will compare against."""
+    from fishnet_tpu.ops import tt
+    from fishnet_tpu.ops.search import HIST_HM_SENTINEL, MAX_HIST
+
+    hh = np.zeros((B, MAX_HIST, 2), np.uint32)
+    hm = np.full((B, MAX_HIST), HIST_HM_SENTINEL, np.int32)
+    for lane, hist in enumerate(hist_lists):
+        tail = hist[-MAX_HIST:]
+        keys = []
+        for p in tail:
+            b = from_position(p)
+            h1, h2 = tt.hash_board(b.board, b.stm, b.ep, b.castling, b.extra, variant)
+            keys.append((int(h1), int(h2)))
+        for j, (p, key) in enumerate(zip(tail, keys)):
+            if keys.count(key) >= 2 or j >= len(tail) - keep_last:
+                k = MAX_HIST - len(tail) + j
+                hh[lane, k] = key
+                hm[lane, k] = p.halfmove
+    return hh, hm
+
+
+def _long_shuffle():
+    """Two pawn moves, then 22 plies of knight shuffling: a tail longer
+    than MAX_HIST whose halfmove clocks do not start at 0."""
+    pos = Position.initial()
+    game = []
+    for uci in ["e2e4", "e7e5"] + ["g1f3", "g8f6", "f3g1", "f6g8",
+                                   "b1c3", "b8c6", "c3b1", "c6b8"] * 3:
+        game.append(pos)
+        pos = pos.push(pos.parse_uci(uci))
+    return game[:-2]
+
+
+def _crazyhouse_game():
+    from fishnet_tpu.chess.variants import from_fen
+
+    pos = from_fen("r3k2r/8/8/8/8/8/8/R3K2Q~[PPpn] w KQkq - 0 1", "crazyhouse")
+    game = []
+    for uci in ["P@e4", "N@f6", "h1h8", "f6g8", "h8h1", "g8f6", "h1h8",
+                "f6g8", "h8h1", "p@d5"]:
+        game.append(pos)
+        pos = pos.push(pos.parse_uci(uci))
+    return game + [pos]
+
+
+@pytest.mark.parametrize("keep_last", [0, 1])
+@pytest.mark.parametrize("case", ["shuffle4", "shuffle8", "long", "crazyhouse",
+                                  "lanes", "empty"])
+def test_history_arrays_are_built_on_the_host(monkeypatch, case, keep_last):
+    """No device call in `_history_arrays` (it runs inside
+    LaneScheduler._submit, where a fetch waits behind the running
+    segment): no transfer either way, and exactly the arrays the
+    device's own hash gives."""
+    from fishnet_tpu.engine.tpu import TpuEngine
+    from fishnet_tpu.ops import tt
+    from fishnet_tpu.ops.search import MAX_HIST
+
+    variant, B = "standard", 1
+    if case == "crazyhouse":
+        hists, variant = [_crazyhouse_game()], "crazyhouse"
+    elif case == "lanes":
+        hists, B = [_shuffle_game(8)[0], [], _long_shuffle(), _shuffle_game(3)[0]], 6
+    elif case == "empty":
+        hists = [[]]
+    else:
+        hists = [{"shuffle4": _shuffle_game(4)[0], "shuffle8": _shuffle_game(8)[0],
+                  "long": _long_shuffle()}[case]]
+    if case == "long":
+        assert len(hists[0]) > MAX_HIST
+    want = _device_history(hists, B, variant, keep_last)
+
+    def no_device_hash(*_a, **_k):
+        raise AssertionError("history keys hashed on the device")
+
+    monkeypatch.setattr(tt, "hash_board", no_device_hash)
+    with jax.transfer_guard("disallow"):
+        hh, hm = TpuEngine._history_arrays(hists, B, variant, keep_last)
+        shared = TpuEngine._history_arrays_shared(hists[0], 3, variant, keep_last)
+    assert hh.dtype == np.uint32 and hm.dtype == np.int32
+    assert np.array_equal(hh, want[0]) and np.array_equal(hm, want[1])
+    for lane in range(3):
+        assert np.array_equal(shared[0][lane], want[0][0])
+        assert np.array_equal(shared[1][lane], want[1][0])
+    if case in ("shuffle8", "long", "crazyhouse", "lanes"):
+        assert (want[0] != 0).any(), "the case plants nothing"

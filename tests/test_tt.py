@@ -4,10 +4,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import random
+
 from fishnet_tpu.chess import Position
+from fishnet_tpu.chess.variants import from_fen, position_class
 from fishnet_tpu.models import nnue
 from fishnet_tpu.ops import tt
-from fishnet_tpu.ops.board import from_position, stack_boards
+from fishnet_tpu.ops.board import from_position, position_fields, stack_boards
 from fishnet_tpu.ops.search import MATE, search_batch_jit
 
 
@@ -48,6 +51,80 @@ def test_hash_ignores_halfmove():
     assert tuple(map(int, tt.hash_board(a.board, a.stm, a.ep, a.castling))) == tuple(
         map(int, tt.hash_board(b.board, b.stm, b.ep, b.castling))
     )
+
+
+# castling rights on both wings and pawns a step from promoting (the h
+# file's promotes onto bit 31 of crazyhouse's high promoted word), so a
+# short random playout loses some rights and keeps others, promotes,
+# and in crazyhouse fills pockets
+_NEAR_PROMOTION = "r3k2r/1P5P/8/8/8/8/1p5p/R3K2R w KQkq - 0 1"
+_HOST_HASH_EXTRA_FENS = {
+    "standard": [_NEAR_PROMOTION],
+    "threeCheck": [_NEAR_PROMOTION + " +1+2"],
+    "crazyhouse": [_NEAR_PROMOTION.replace(" w ", "[QRBNPqrbnp] w "),
+                   "r3k2r/8/8/8/8/8/8/R3K2Q~[PPPPPPPPPPPPPPPPPPpp] b Qkq - 0 1"],
+    "atomic": [_NEAR_PROMOTION],
+    "kingOfTheHill": [_NEAR_PROMOTION],
+}
+
+
+def _host_hash_playouts(variant, n_games=6, plies=60):
+    """Seeded legal playouts from the variant's start (double pawn
+    pushes: en-passant squares) and from its extra FENs."""
+    cls = position_class(variant)
+    rng = random.Random(0x27 + tt._VARIANT_ID[variant])
+    out = []
+    for fen in [cls.starting_fen()] * n_games + _HOST_HASH_EXTRA_FENS.get(variant, []) * 3:
+        pos = from_fen(fen, variant)
+        for _ in range(plies):
+            out.append(pos)
+            moves = list(pos.legal_moves())
+            if not moves or pos.outcome() is not None:
+                break
+            pos = pos.push(rng.choice(moves))
+    return out
+
+
+@pytest.mark.parametrize("variant", sorted(tt._VARIANT_ID))
+def test_host_hash_is_hash_board(variant):
+    """The engine hashes game histories on the host
+    (engine/tpu.py::_history_arrays); the in-search repetition scan
+    compares those keys with keys the device computes itself, so the two
+    must agree bit for bit, in every variant's extras."""
+    assert np.array_equal(tt.Z1_HOST, np.asarray(tt.Z1))
+    assert np.array_equal(tt.Z2_HOST, np.asarray(tt.Z2))
+    positions = _host_hash_playouts(variant)
+    rows = [position_fields(p) for p in positions]
+    assert all(isinstance(x, (np.ndarray, np.generic)) for b in rows for x in b)
+    f = {k: np.stack([getattr(b, k) for b in rows]) for k in rows[0]._fields}
+    # the playouts reach what the hash folds in
+    assert (f["stm"] == 0).any() and (f["stm"] == 1).any()
+    assert (f["ep"] >= 0).any() or variant == "racingKings"  # it has no pawns
+    if getattr(positions[0], "has_castling", True) and variant != "horde":
+        rights = (f["castling"] >= 0).sum(axis=1)
+        assert (rights == 4).any() and (rights == 0).any()
+        assert ((rights > 0) & (rights < 4)).any()
+    if variant == "threeCheck":
+        assert {0, 1, 2} <= set(f["extra"][:, :2].ravel().tolist())
+    if variant == "crazyhouse":
+        assert (f["extra"][:, :10] > 0).any(axis=0).all(), "a pocket slot never filled"
+        assert (f["extra"][:, :10] > 16).any(), "no pocket beyond the hashed 16"
+        assert (f["extra"][:, 10] != 0).any() and (f["extra"][:, 11] < 0).any()
+    else:
+        assert len({tuple(b.board) for b in rows}) > 100
+    h1, h2 = tt.hash_boards_host(
+        f["board"], f["stm"], f["ep"], f["castling"], f["extra"], variant)
+    assert h1.dtype == h2.dtype == np.uint32 and h1.shape == (len(rows),)
+    dev = stack_boards([from_position(p) for p in positions])
+    d1, d2 = jax.jit(jax.vmap(
+        lambda b, s, e, c, x: tt.hash_board(b, s, e, c, x, variant)
+    ))(dev.board, dev.stm, dev.ep, dev.castling, dev.extra)
+    assert np.array_equal(h1, np.asarray(d1))
+    assert np.array_equal(h2, np.asarray(d2))
+    # and unbatched, as ops/search.py calls it for a lane's root
+    b = from_position(positions[-1])
+    s1, s2 = tt.hash_board(b.board, b.stm, b.ep, b.castling, b.extra, variant)
+    assert (int(s1), int(s2)) == (int(h1[-1]), int(h2[-1]))
 
 
 def test_store_probe_roundtrip():
