@@ -147,7 +147,11 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
              make_engine: Callable, device: dict, t_start: float,
              rehearsal: Optional[dict], control: Optional[str],
              say: Callable[[str], None], trace_dir: str,
-             tracer_factory: Callable = measure.DeviceTracer) -> dict:
+             tracer_factory: Callable = measure.DeviceTracer,
+             first_run: bool = False) -> dict:
+    """first_run: no run of this cell has got as far as its window in this
+    checkout yet, so this one builds its programs and has the allowance of
+    a run that compiles."""
     traffic = dict(cell["traffic"])
     cfg = cell["config"]
     if rehearsal is not None:
@@ -158,6 +162,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         cell = dict(cell, config=cfg)
     variant = cfg["variant"]
     plies = cfg["assumed"]["plies_per_game"]
+    tcfg = cell["limits"]["trace"]
     weights = nnue_ref.load_weights(os.path.join(cell["root"], cfg["engine"]["net"]))
     compiles = measure.CompileCounter()
 
@@ -231,8 +236,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
             sampler.start()
             adapter.record_spans(True)
             shutil.rmtree(trace_dir, ignore_errors=True)
-            tcfg = cell["limits"]["trace"]
-            tracer = tracer_factory(trace_dir, sampler, tcfg["boundaries"], tcfg["max_s"])
+            tracer = tracer_factory(trace_dir, sampler, tcfg, adapter.segment_log)
             th = threading.Thread(target=tracer.take, daemon=True)
             th.start()
             state["tracer"], state["tracer_thread"] = tracer, th
@@ -298,6 +302,8 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         f"{occ.get('idle_lane_steps', 0)}")
     by_width = adapter.by_width(state["occ_open"]["segments"],
                                 state["occ_close"]["segments"])
+    blocked_s = sum(ms for _seg, _steps, ms in adapter.segment_log(
+        state["occ_open"]["segments"], state["occ_close"]["segments"])) / 1e3
     if by_width:
         say("window: by width " + "; ".join(
             f"{w}: {v['segments']} seg {v['steps']} steps "
@@ -320,30 +326,41 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         say(f"table: {fill[0]} of {fill[1]} rows written by the time the window "
             f"closed ({100.0 * fill[0] / fill[1]:.3f} %); peak {peak_bytes} bytes")
     tr = None
-    slice_ = None
     tracer = state.get("tracer")
+    trace_failed = None
     if tracer is not None:
-        state["tracer_thread"].join(timeout=300.0)
+        trace_failed = _wait_for_tracer(
+            tracer, state["tracer_thread"], tcfg, t_start, first_run)
         spans = adapter.host_spans()
         adapter.record_spans(False)
+        if trace_failed is None:
+            marks = tracer.marks() or [tracer.anchor_mark]
+            slice_widths = dict(Counter(adapter.widths(marks[0][1], marks[-1][1])))
+            marks = trace_reduce.log_marks(
+                marks, adapter.segment_log(marks[0][1], marks[-1][1]))
     sample = _sample(answered, loop_, adapter, variant, seed,
                      cell["limits"]["sample"])
     adapter.set_deliver_hook(None)
     adapter.release()
     gc.collect()
-    if tracer is not None and tracer.error is None:
+    if tracer is not None and trace_failed is None:
         t1 = time.monotonic()
         raw = trace_reduce.load_xplane(trace_dir)
         tr = trace_reduce.reduce_trace(
-            raw["ops"], raw["modules"], spans, raw["anchor_ns"], tracer.anchor_mono)
-        slice_ = _slice_steps(raw, tracer, sampler)
+            raw["ops"], raw["modules"], marks, spans,
+            raw["anchor_ns"], tracer.anchor_mono)
         say(f"trace: slice {tracer.slice_s:.2f} s, stop_trace {tracer.stop_s:.1f} s, "
-            f"read in {time.monotonic() - t1:.1f} s; {len(raw['ops'])} device ops, "
-            f"{tr['segment_programs']} segment programs, {tr['cycles']} whole cycles, "
-            f"idle {tr['idle_share']}; steps {slice_}")
+            f"read in {time.monotonic() - t1:.1f} s; {len(raw['ops'])} device ops; "
+            f"{tr['intervals']} whole boundary intervals of {tr['window_s']} s: "
+            f"{tr['segment_programs']} segment programs, {tr['segment_device_s']} s, "
+            f"{tr['steps']} steps; device busy {tr['busy_s']} s, host blocked "
+            f"{tr['wait_s']} s, busy per blocked second {tr['busy_per_wait']}")
+        say(f"trace: cut {_slice_cut(raw, tracer)}; session widths by segment "
+            f"{slice_widths}")
         shutil.rmtree(trace_dir, ignore_errors=True)
     elif tracer is not None:
-        say(f"trace: failed: {tracer.error}")
+        # the profiler may still be writing: its directory is left alone
+        say(f"trace: failed: {trace_failed}")
 
     # ------------------------------------------ the comparison, last
     t1 = time.monotonic()
@@ -358,6 +375,11 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         f"worst d1 {detail['worst_d1']}; bad {detail['bad']}")
 
     window_s = float(seconds)
+    # the slice holds a segment or two of the window's two hundred. What is
+    # read as the device's busy time has to be the window's: the slice's
+    # busy seconds per blocked second carried over the seconds the window's
+    # log shows the host blocked
+    busy_s = trace_reduce.window_busy_s(blocked_s, tr)
     e2e = {
         "positions_per_s": len(answered) / window_s,
         "setup_s": setup_s,
@@ -372,11 +394,13 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
             raise SystemExit(f"benchmark: no peaks for device kind {device['kind']!r}")
         ctx = {
             "occupancy": occ, "window_s": window_s, "trace": tr,
-            "slice": slice_, "nodes": sum(nodes), "peak_bytes": peak_bytes,
+            "busy_s": busy_s,
+            "slice": _slice_steps(tr), "nodes": sum(nodes), "peak_bytes": peak_bytes,
             "peak": peak, "notes": notes,
             "latency": {"p95_s": measure.percentile_nearest(latencies, 95),
                         "answers": len(latencies)},
             "per_node": work_count.per_node(cfg["net_shapes"], cfg["max_moves"]),
+            "config": cfg,
         }
         metrics = cells.read_per_layer(cell, ctx)
     else:
@@ -389,19 +413,16 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         "failed": failed, "metrics": metrics, "device": dev,
     }
     if trace and tr is not None and tr["busy_s"] is not None and rehearsal is None:
-        # the profiler holds about one drive session, and the slice lies
-        # inside one; between sessions the device does nothing. What the
-        # driver reads as the device's idle share has to be the window's:
-        # the slice's busy share carried over the window's in-session time
-        busy = trace_reduce.window_busy_s(occ, tr)
-        if busy is not None:
-            dev["busy_s"], dev["window_s"] = busy, window_s
-        else:  # fewer than three whole cycles: the traced span as it is
+        if busy_s is not None:
+            dev["busy_s"], dev["window_s"] = busy_s, window_s
+        else:  # no whole boundary interval: the traced span as it is
             dev["busy_s"], dev["window_s"] = tr["busy_s"], tr["window_s"]
         result["breakdown"] = {"device_ops": tr["device_ops"],
                                "idle_gaps": tr["idle_gaps"]}
-        notes["slice"] = {"busy_s": tr["busy_s"], "window_s": tr["window_s"],
-                          "whole_cycles": tr["whole_cycles"]}
+        notes["slice"] = dict({k: tr[k] for k in (
+            "busy_s", "window_s", "intervals", "wait_s", "busy_per_wait")},
+            session_widths=slice_widths)
+        notes["blocked_s"] = blocked_s
     result["window"] = {
         "seconds": window_s, "answers": len(answered),
         "nodes_per_position": (sum(nodes) / len(nodes)) if nodes else None,
@@ -460,23 +481,45 @@ def _sample(answered, loop_, adapter, variant, seed, size) -> List[dict]:
     return out
 
 
-def _slice_steps(raw, tracer, sampler) -> Optional[dict]:
-    """Device time of the segment programs inside the slice and the steps
-    those same segments ran, matched through the boundary times."""
-    if raw["anchor_ns"] is None or tracer.anchor_mono is None:
-        return None
-    segs = sorted((m for m in raw["modules"] if trace_reduce.SEGMENT_MARK in m[0]),
-                  key=lambda m: m[1])
-    if len(segs) < 2:
-        return None
+def _wait_for_tracer(tracer, thread, tcfg: dict, t_start: float,
+                     first_run: bool) -> Optional[str]:
+    """Wait for `stop_trace` as long as the run's own allowance permits:
+    the contract's seconds from process start (`compiling` for a cell's
+    first run in its checkout), less `reserve_s` for what is still to do
+    after the wait. → why there is no trace to read, or None."""
+    allowed = tcfg["run_allowance_s"]["compiling" if first_run else "warm"]
+    t_wait = time.monotonic()
+    thread.join(timeout=max(0.0, t_start + allowed - tcfg["reserve_s"] - t_wait))
+    if tracer.error is not None:
+        return tracer.error
+    if thread.is_alive():
+        return (f"the profiler had not returned {time.monotonic() - t_wait:.0f} s "
+                f"after the slice ended, {time.monotonic() - t_start:.0f} s into a "
+                f"run that is allowed {allowed} s ({tcfg['reserve_s']} s of them "
+                f"kept for reading the trace and the comparison)")
+    if tracer.slice_s is None or tracer.stop_s is None:
+        return "the tracer ended without a slice"
+    return None
 
-    def mono(ns):
-        return (ns - raw["anchor_ns"]) / 1e9 + tracer.anchor_mono
 
-    first_end = mono(segs[0][1] + segs[0][2])
-    last_end = mono(segs[-1][1] + segs[-1][2])
-    s0, s1 = sampler.steps_after(first_end), sampler.steps_after(last_end)
-    if s0 is None or s1 is None or s1 <= s0:
+def _slice_cut(raw, tracer) -> dict:
+    """How the slice was cut, and what the profiler kept around it: the
+    device ops that started before the anchor (while the profiler started)
+    and after the slice's end (while it stopped)."""
+    out = dict(getattr(tracer, "cut", None) or {})
+    if raw["anchor_ns"] is not None:
+        a0 = raw["anchor_ns"]
+        a1 = a0 + int(tracer.slice_s * 1e9)
+        starts = [o[1] for o in raw["ops"]]
+        out["ops_before_anchor"] = sum(1 for t in starts if t < a0)
+        out["ops_after_slice"] = sum(1 for t in starts if t >= a1)
+    return out
+
+
+def _slice_steps(tr: Optional[dict]) -> Optional[dict]:
+    """Device time of the segment programs inside the slice's whole
+    boundary intervals and the steps the scheduler accounted in them."""
+    if not tr or not tr["intervals"] or not tr["segment_programs"]:
         return None
-    return {"steps": s1 - s0, "device_s": sum(m[2] for m in segs[1:]) / 1e9,
-            "segments": len(segs) - 1}
+    return {"steps": tr["steps"], "device_s": tr["segment_device_s"],
+            "segments": tr["segment_programs"]}

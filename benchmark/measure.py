@@ -12,7 +12,7 @@ import contextlib
 import os
 import threading
 import time
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 import numpy as np
 
@@ -87,7 +87,7 @@ class CompileCounter:
 
 class BoundarySampler(threading.Thread):
     """Notes every change of the scheduler's segment count with the time it
-    was seen (to ~1 ms): the dispatch->boundary cycles as the host ran them."""
+    was seen (to ~1 ms): the boundaries as the host ran them."""
 
     def __init__(self, counters, period_s: float = 0.001):
         super().__init__(daemon=True)
@@ -96,13 +96,17 @@ class BoundarySampler(threading.Thread):
         self._stop = threading.Event()
         self.records: List[tuple] = []  # (t, segments, steps)
 
+    def read(self, t: Optional[float] = None) -> tuple:
+        c = self._counters()
+        return (time.monotonic() if t is None else t, c["segments"], c["steps"])
+
     def run(self):
         last = -1
         while not self._stop.is_set():
-            c = self._counters()
-            if c["segments"] != last:
-                last = c["segments"]
-                self.records.append((time.monotonic(), last, c["steps"]))
+            rec = self.read()
+            if rec[1] != last:
+                last = rec[1]
+                self.records.append(rec)
             time.sleep(self._period)
 
     def stop(self):
@@ -115,56 +119,103 @@ class BoundarySampler(threading.Thread):
             time.sleep(0.002)
         return len(self.records) - start
 
-    def steps_after(self, t: float) -> Optional[int]:
-        for rt, _seg, steps in self.records:
-            if rt > t:
-                return steps
-        return None
-
 
 class DeviceTracer:
     """One profiler slice, taken right after the window closed while the
     loop still runs, so that tracing costs the window's counters nothing.
     A drive session and the gap to the next take seconds, so a slice at a
     fixed time can fall wholly between two sessions: this one starts when
-    the scheduler has just passed a boundary (a session is running) and
-    ends after `boundaries` more, or `max_s`."""
+    the scheduler has just passed a boundary (a session is running, and as
+    a rule the device waits for the boundary's host work).
 
-    def __init__(self, trace_dir: str, sampler: BoundarySampler,
-                 boundaries: int, max_s: float):
+    What a slice costs is the device ops it holds (`stop_trace` takes one
+    to two minutes a million), and the profiler keeps next to nothing from
+    before `start_trace` returned, so the slice is cut by device work: it
+    ends at the first boundary at which it holds a whole boundary interval
+    and the scheduler has accounted `steps` steps in those it holds, or
+    after `max_s`. Where the trace began and every boundary seen inside it
+    are its `marks`: `trace_reduce` holds the device's busy time between
+    them against what the scheduler logged for the segments between them
+    (`segment_log(since, until)` → (segment, steps, blocked ms))."""
+
+    def __init__(self, trace_dir: str, sampler: BoundarySampler, cfg: dict,
+                 segment_log: Callable[[int, int], List[tuple]]):
         self.dir = trace_dir
         self.sampler = sampler
-        self.boundaries = boundaries
-        self.max_s = max_s
+        self.cfg = cfg
+        self.segment_log = segment_log
         self.captured = threading.Event()  # the slice is over; stop_trace may still run
         self.anchor_mono = None
+        self.anchor_mark = None  # the sampler's reading where the trace began
+        self.in_flight = None  # was a segment program running when it began?
         self.stop_s = None
         self.slice_s = None
+        self.cut = None  # how the slice ended
         self.error = None
 
-    def take(self):
+    def start_profiler(self) -> float:
         import jax
 
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        opts.enable_hlo_proto = False
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        with jax.profiler.TraceAnnotation("bench.anchor"):
+            return time.monotonic()
+
+    def stop_profiler(self) -> None:
+        import jax
+
+        jax.profiler.stop_trace()
+
+    def take(self):
         try:
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            opts.host_tracer_level = 1
-            opts.enable_hlo_proto = False
             self.sampler.wait_boundaries(1, 8.0)
-            jax.profiler.start_trace(self.dir, profiler_options=opts)
-            t0 = time.monotonic()
-            with jax.profiler.TraceAnnotation("bench.anchor"):
-                self.anchor_mono = time.monotonic()
-            self.sampler.wait_boundaries(self.boundaries, self.max_s)
-            self.slice_s = time.monotonic() - t0
+            self.anchor_mono = self.start_profiler()
+            self.anchor_mark = self.sampler.read(self.anchor_mono)
+            t_end = self.anchor_mono + self.cfg["max_s"]
+            while True:
+                marks = self.marks()
+                ran = marks[-1][2] - marks[0][2] if marks else 0
+                if len(marks) >= 2 and ran >= self.cfg["steps"]:
+                    ended = "work"
+                    break
+                if time.monotonic() >= t_end:
+                    ended = "max_s"
+                    break
+                time.sleep(0.002)
+            self.slice_s = time.monotonic() - self.anchor_mono
+            self.cut = {"intervals": max(len(marks) - 1, 0), "steps": ran,
+                        "ended_on": ended, "in_flight_at_anchor": self.in_flight}
             self.captured.set()
             t1 = time.monotonic()
-            jax.profiler.stop_trace()
+            self.stop_profiler()
             self.stop_s = time.monotonic() - t1
         except Exception as e:  # a failed trace fails the metrics, not the run
             self.error = repr(e)
         finally:
             self.captured.set()
+
+    def marks(self) -> List[tuple]:
+        """(t, segments, steps) where the trace began and at every boundary
+        seen inside the slice: the instants between which whole boundary
+        intervals lie. Not the first, where a segment program was in flight
+        when the trace began (a speculative one that found live lanes, or
+        admissions quicker than the profiler's start): part of it ran
+        before, and the wait logged for it is the whole one. The log shows
+        that: a wait longer than the trace had run began before it. (One
+        launched in the few milliseconds before the anchor passes for
+        inside; so much of its run is missed.)"""
+        end = float("inf") if self.slice_s is None else self.anchor_mono + self.slice_s
+        seen = [r for r in self.sampler.records if self.anchor_mono < r[0] <= end]
+        if seen and self.in_flight is None:
+            rows = self.segment_log(self.anchor_mark[1], seen[0][1])
+            if len(rows) < seen[0][1] - self.anchor_mark[1]:
+                return []  # the scheduler has counted the segment and not yet logged it
+            blocked_s = sum(ms for _seg, _steps, ms in rows) / 1e3
+            self.in_flight = blocked_s > seen[0][0] - self.anchor_mono
+        return seen if self.in_flight else [self.anchor_mark] + seen
 
 
 def program_engine_factory(cell: dict, rehearsal: Optional[dict]):
@@ -262,6 +313,15 @@ def program_engine_factory(cell: dict, rehearsal: Optional[dict]):
                 v["helper"] = 100.0 * v["helper"] / lanes
             return out
 
+        def segment_log(self, since_segment: int, until_segment: int) -> List[tuple]:
+            """(segment, steps, blocked ms) as the scheduler logged each
+            counted segment's own boundary interval: its admissions, its
+            dispatch, the wait for it. The boundary of a speculative
+            program that ran no step is in the totals and not here."""
+            return [(r["segment"], r["steps"], r["device_ms"])
+                    for r in list(self.engine.occupancy_log)
+                    if since_segment < r["segment"] <= until_segment]
+
         def warm_shapes(self, width: int, counts: int, variant: str,
                         root_fen: str) -> int:
             """Build or load the small programs whose shapes follow the
@@ -308,10 +368,6 @@ def program_engine_factory(cell: dict, rehearsal: Optional[dict]):
                 rows = jnp.asarray(np.arange(n, dtype=np.int64))
                 np.asarray(jnp.take(state.pv[:, 0], rows, axis=0))
                 np.asarray(jnp.take(state.nt[:, 0, so.NT_PVLEN], rows, axis=0))
-            # the repetition history of a position: one small program per
-            # number of earlier positions (up to the search's MAX_HIST)
-            for k in range(1, H + 1):
-                TpuEngine._history_arrays([[pos] * k], 1, dv)
             return top
 
         def queued(self) -> int:

@@ -1,20 +1,23 @@
-"""From a profiler trace to numbers: busy, idle, cycles, top ops, gaps.
+"""From a profiler trace to numbers: busy per blocked second, top ops, gaps.
 
 The reduction works on plain event lists, so it can be checked on a small
 recorded trace (tests/benchmark/data). ``load_xplane`` is the thin part
 that turns the profiler's ``.xplane.pb`` into those lists.
 
-A *cycle* is one dispatch -> boundary round of the scheduler: on the
-device it is one execution of the segment program (an event of the
-``XLA Modules`` line whose name holds ``run_segment``) and whatever
-follows it until the next one starts. The slice that idle time is taken
-over runs from the start of the first segment program that lies wholly
-inside the trace to the start of the last one, so it spans whole cycles
-and nothing else. The profiler takes a second or two to start, so the
-slice can run from the end of one drive session into the next: a gap that
-none of the scheduler's host spans lies over is time between sessions,
-and is taken out of the slice before the idle share inside sessions is
-worked out.
+The slice is read between *marks*: the instant the trace began (the
+anchor, unless a segment program was in flight then) and the boundaries
+the sampler saw inside it, each with the steps
+and the blocked seconds the scheduler logged for its counted segments up
+to there. Between two marks lie whole boundary intervals: the scheduler's
+host work with the device idle, then a segment program (an event of the
+``XLA Modules`` line whose name holds ``run_segment``) on which it blocks.
+What the slice gives is the seconds the device ran an operation for every
+second the host was blocked on it in the same intervals
+(``busy_per_wait``); the window's busy seconds are that times the blocked
+seconds logged for the window's segments. A share of idle time taken from
+the slice alone would swing with which two or three of a window's two
+hundred segments the slice met, and with how many of them it holds; this
+does neither.
 """
 from __future__ import annotations
 
@@ -26,7 +29,6 @@ Event = Tuple[str, int, int]  # name, start_ns, duration_ns
 
 SEGMENT_MARK = "run_segment"
 ANCHOR = "bench.anchor"
-MIN_CYCLES = 3
 GAP_FLOOR_NS = 20_000
 NO_SPAN = "unattributed"
 
@@ -107,34 +109,48 @@ def is_container(name: str) -> bool:
     return split_op_name(name)[0] in CONTAINERS
 
 
+Mark = Tuple[float, int, int, float]  # monotonic s; segments, steps, blocked s so far
+
+
 def reduce_trace(ops: Sequence[Event], modules: Sequence[Event],
+                 marks: Sequence[Mark] = (),
                  host_spans: Sequence[Tuple[str, float, float]] = (),
                  anchor_ns: Optional[int] = None,
                  anchor_mono_s: Optional[float] = None) -> dict:
-    """host_spans: (name, start_s, dur_s) on the monotonic clock; the
-    anchor pair maps that clock onto the trace's."""
-    segs = sorted((m for m in modules if SEGMENT_MARK in m[0]),
-                  key=lambda m: m[1])
-    out = {"cycles": max(len(segs) - 1, 0), "segment_programs": len(segs),
-           "busy_s": None, "window_s": None, "idle_share": None,
-           "between_sessions_s": 0.0,
-           "segment_device_s": sum(m[2] for m in segs) / 1e9,
-           "device_ops": [], "idle_gaps": [], "whole_cycles": False}
+    """marks: instants between which whole boundary intervals lie, each
+    with what was logged up to it (`log_marks`); host_spans:
+    (name, start_s, dur_s); both on the monotonic clock, which the anchor
+    pair maps onto the trace's."""
+    import numpy as np
+
+    out = {"intervals": 0, "segment_programs": 0, "segment_device_s": 0.0,
+           "segments": None, "steps": None, "wait_s": None, "busy_per_wait": None,
+           "busy_s": None, "window_s": None, "between_sessions_s": 0.0,
+           "device_ops": [], "idle_gaps": []}
     ops = [o for o in ops if not is_container(o[0])]
     if not ops:
         return out
-    if len(segs) >= MIN_CYCLES + 1:
-        lo, hi = segs[0][1], segs[-1][1]
-        out["whole_cycles"] = True
-    else:
-        # too few cycles: busy over the traced span is still reported
-        # for the device key, the idle share is not
-        lo = min(o[1] for o in ops)
-        hi = max(o[1] + o[2] for o in ops)
-    import numpy as np
-
     starts = np.fromiter((o[1] for o in ops), np.int64, len(ops))
     ends = starts + np.fromiter((o[2] for o in ops), np.int64, len(ops))
+    shift = None
+    if anchor_ns is not None and anchor_mono_s is not None:
+        shift = anchor_ns - int(anchor_mono_s * 1e9)
+    at = sorted((int(m[0] * 1e9) + shift,) + tuple(m[1:]) for m in marks) \
+        if shift is not None else []
+    if len(at) >= 2:
+        lo, hi = at[0][0], at[-1][0]
+        out["intervals"] = len(at) - 1
+        out["segments"] = [at[0][1], at[-1][1]]  # after the first, up to the last
+        out["steps"] = at[-1][2] - at[0][2]
+        out["wait_s"] = at[-1][3] - at[0][3]
+        segs = [m for m in modules
+                if SEGMENT_MARK in m[0] and m[1] >= lo and m[1] + m[2] <= hi]
+        out["segment_programs"] = len(segs)
+        out["segment_device_s"] = sum(m[2] for m in segs) / 1e9
+    else:
+        # no whole interval: busy over the traced span is still reported
+        # for the device key, nothing is carried over the window
+        lo, hi = int(starts.min()), int(ends.max())
     keep = (ends > lo) & (starts < hi)
     names = [o[0] for o, k in zip(ops, keep) if k]
     starts = np.clip(starts[keep], lo, hi)
@@ -150,6 +166,8 @@ def reduce_trace(ops: Sequence[Event], modules: Sequence[Event],
     busy = (hi - lo) - idle
     out["busy_s"] = busy / 1e9
     out["window_s"] = (hi - lo) / 1e9
+    if out["intervals"] and out["wait_s"] > 0:
+        out["busy_per_wait"] = out["busy_s"] / out["wait_s"]
     by_op: Dict[str, int] = {}
     for name, d in zip(names, (ends - starts).tolist()):
         by_op[name] = by_op.get(name, 0) + d
@@ -162,8 +180,7 @@ def reduce_trace(ops: Sequence[Event], modules: Sequence[Event],
         sorted(short.items(), key=lambda kv: -kv[1])[:10]
     ]
     spans_ns = []
-    if anchor_ns is not None and anchor_mono_s is not None:
-        shift = anchor_ns - int(anchor_mono_s * 1e9)
+    if shift is not None:
         spans_ns = [(n, int(s * 1e9) + shift, int((s + d) * 1e9) + shift)
                     for n, s, d in host_spans]
         spans_ns = [sp for sp in spans_ns if sp[2] > lo and sp[1] < hi]
@@ -197,8 +214,6 @@ def reduce_trace(ops: Sequence[Event], modules: Sequence[Event],
     if between:
         by_span["between_sessions"] = by_span.pop(NO_SPAN)
     out["between_sessions_s"] = between / 1e9
-    if out["whole_cycles"] and hi - lo > between:
-        out["idle_share"] = 100.0 * (idle - between) / (hi - lo - between)
     if small_total:
         by_span["between_ops_under_20us"] = small_total
     out["idle_gaps"] = [
@@ -208,17 +223,33 @@ def reduce_trace(ops: Sequence[Event], modules: Sequence[Event],
     return out
 
 
-def window_busy_s(occupancy: dict, trace: Optional[dict]) -> Optional[float]:
-    """Seconds the device was busy over the whole measured window. The
-    trace is a slice of whole cycles inside one drive session; the window
-    is sessions and the gaps between them, in which the device runs
-    nothing. So: the slice's busy share times the window's in-session
-    time, which the scheduler's counters give (host_ms + device_ms are
-    the two halves of every dispatch->boundary cycle of the window)."""
-    if not trace or trace.get("idle_share") is None:
+def log_marks(marks: Sequence[Tuple[float, int, int]],
+              segment_log: Sequence[Tuple[int, int, float]]) -> List[Mark]:
+    """The tracer's marks (t, segments, steps) with, in place of the
+    totals, the steps and blocked seconds logged for the counted segments
+    after the first mark and up to each: what a stall of the scheduler
+    while the profiler started (seen on the chip: 47 ms in a fetch of the
+    boundary before, credited after the anchor) cannot reach."""
+    out = []
+    for t, segments, _steps in marks:
+        rows = [r for r in segment_log if marks[0][1] < r[0] <= segments]
+        out.append((t, segments, sum(r[1] for r in rows),
+                    sum(r[2] for r in rows) / 1e3))
+    return out
+
+
+def window_busy_s(blocked_s: float, trace: Optional[dict]) -> Optional[float]:
+    """Seconds the device was busy over the whole measured window: the
+    seconds the scheduler was blocked in the boundary intervals of the
+    window's counted segments (its log) times the busy seconds per blocked
+    second that the slice's whole boundary intervals show. None without a
+    slice that held one."""
+    if not trace or trace.get("busy_per_wait") is None or blocked_s <= 0:
         return None
-    in_session_s = (occupancy.get("host_ms", 0.0)
-                    + occupancy.get("device_ms", 0.0)) / 1e3
-    if in_session_s <= 0:
-        return None
-    return in_session_s * (1.0 - trace["idle_share"] / 100.0)
+    return blocked_s * trace["busy_per_wait"]
+
+
+def session_s(occupancy: dict) -> float:
+    """Seconds of the window inside the boundary intervals of drive
+    sessions: the two halves of every one, as the scheduler counts them."""
+    return (occupancy.get("host_ms", 0.0) + occupancy.get("device_ms", 0.0)) / 1e3

@@ -58,6 +58,7 @@ class FakeAdapter:
         self.totals = {"segments": 0, "steps": 0, "lane_steps": 0,
                        "live_lane_steps": 0, "helper_lane_steps": 0,
                        "idle_lane_steps": 0, "host_ms": 0.0, "device_ms": 0.0}
+        self.log = []  # (segment, steps, blocked ms), as the totals count them
         self.answered = 0
 
     def new_chunk(self, work_id, variant, nodes, timeout_s, deadline,
@@ -109,6 +110,7 @@ class FakeAdapter:
             self.totals["live_lane_steps"] += 40
             self.totals["device_ms"] += 1.0
             self.totals["host_ms"] += 0.5
+            self.log.append((self.totals["segments"], 10, 1.0))
             if self.hook is not None:
                 self.hook(chunk, resp["position_index"], url, resp)
                 if hit and self.fault == "twice":
@@ -132,6 +134,9 @@ class FakeAdapter:
 
     def by_width(self, since_segment, until_segment):
         return {}
+
+    def segment_log(self, since_segment, until_segment):
+        return [r for r in self.log if since_segment < r[0] <= until_segment]
 
     def queued(self):
         return 10 ** 9

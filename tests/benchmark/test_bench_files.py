@@ -98,7 +98,7 @@ def test_readers_on_known_counters():
                       "host_ms": 300.0, "device_ms": 700.0},
         "window_s": 10.0, "nodes": 50_000, "peak_bytes": peak["hbm_bytes"] // 4,
         "peak": peak, "slice": {"steps": 2000, "device_s": 0.7},
-        "trace": {"idle_share": 50.0},
+        "busy_s": 0.5,
         "per_node": {"flops": 6000.0, "bytes": 8190.0},
     }
     got = {k: v["value"] for k, v in cells.read_per_layer(cell, ctx).items()}
@@ -107,8 +107,7 @@ def test_readers_on_known_counters():
     assert got["scheduler.session_share"] == pytest.approx(10.0)  # 1 s of 10
     assert got["segment.step_us"] == pytest.approx(350.0)
     assert got["segment.nodes_per_s"] == pytest.approx(5000.0)
-    # a session ran 1 s of the 10 s; inside it the device was busy half the
-    # time: busy 0.5 s of the window
+    # a session ran 1 s of the 10 s; the device was busy 0.5 s of the window
     assert got["device.session_idle_share"] == pytest.approx(50.0)
     assert got["device.idle_share"] == pytest.approx(95.0)
     assert got["device.hbm_peak_share"] == pytest.approx(25.0)

@@ -25,6 +25,8 @@ class NoTrace:
     error = None
     anchor_mono = None
     slice_s = stop_s = 0.0
+    # (s, segments, steps): the anchor's reading, then a recorded slice's
+    marks_at = ((0.0, 0, 0),)
 
     def __init__(self, *_a):
         import threading
@@ -33,6 +35,9 @@ class NoTrace:
 
     def take(self):
         self.captured.set()
+
+    def marks(self):
+        return list(self.marks_at)
 
 
 def drive(tmp_path, fault=None, weights=WEIGHTS, workload="standard.trickle",
@@ -162,29 +167,42 @@ def test_traced_run_reports_per_layer_names_only(tmp_path):
 
 
 def test_traced_run_carries_the_slice_over_the_whole_window(tmp_path, monkeypatch):
-    """With a recorded slice (four whole cycles) in the profiler's place:
-    what the last line gives the driver as busy_s over window_s is the
-    window's, sessions and the gaps between them, not the slice's."""
+    """With a recorded slice in the profiler's place and the stand-in's
+    log beside it (1 ms blocked a segment): what the last line gives the driver as busy_s over
+    window_s is the window's, its blocked seconds times the slice's busy
+    seconds per blocked second, not the slice's own."""
     from benchmark import trace_reduce
 
     data = json.load(open(Path(__file__).resolve().parent / "data/trace_v5e_small.json"))
     ops = [tuple(o) for o in data["ops"]]
-    period = max(s + d for _n, s, d in ops) // 4
-    mods = [(data["module_name"], i * period, period - 1000) for i in range(5)]
+    span = max(s + d for _n, s, d in ops)
+    mods = [(data["module_name"], i * (span // 4), span // 4 - 1000) for i in range(4)]
     monkeypatch.setattr(trace_reduce, "load_xplane", lambda _d: {
-        "ops": ops, "modules": mods, "anchor_ns": None, "devices": 1})
-    result, _ = drive(tmp_path, trace=True, seconds=2.0)
+        "ops": ops, "modules": mods, "anchor_ns": 0, "devices": 1})
+    monkeypatch.setattr(NoTrace, "anchor_mono", 0.0)
+    n = int(span / 1e6) + 2  # as many segments as keep the host blocked longer than the ops ran
+    monkeypatch.setattr(NoTrace, "marks_at", (
+        (-0.002, 5, 0), ((span + 1000) / 1e9, 5 + n, 0)))
+    result, lines = drive(tmp_path, trace=True, seconds=2.0)
     m, dev = result["metrics"], result["device"]
     sl = result["window"]["notes"]["slice"]
-    assert sl["whole_cycles"] and 0 < sl["busy_s"] < sl["window_s"]
-    assert dev["window_s"] == 2.0 and 0 < dev["busy_s"] < dev["window_s"]
+    assert sl["intervals"] == 1 and 0 < sl["busy_s"] < sl["window_s"]
+    assert sl["wait_s"] == pytest.approx(n / 1e3)
+    assert sl["busy_per_wait"] == pytest.approx(sl["busy_s"] / (n / 1e3))
     in_session = m["scheduler.session_share"]["value"] / 100.0 * 2.0
-    assert dev["busy_s"] == pytest.approx(in_session * sl["busy_s"] / sl["window_s"])
+    blocked = result["window"]["notes"]["blocked_s"]
+    # the stand-in logs every segment, so its log and its totals agree
+    assert blocked == pytest.approx(
+        in_session * (1 - m["scheduler.boundary_host_share"]["value"] / 100.0))
+    assert dev["window_s"] == 2.0 and 0 < dev["busy_s"] < dev["window_s"]
+    assert dev["busy_s"] == pytest.approx(blocked * sl["busy_per_wait"])
     assert m["device.session_idle_share"]["value"] == pytest.approx(
-        100.0 * (1 - sl["busy_s"] / sl["window_s"]))
+        100.0 * (1 - dev["busy_s"] / in_session))
     assert m["device.idle_share"]["value"] == pytest.approx(
         100.0 * (1 - dev["busy_s"] / dev["window_s"]))
     assert m["device.idle_share"]["value"] > m["device.session_idle_share"]["value"]
+    assert m["segment.step_us"]["value"] == pytest.approx(
+        1e6 * 4 * (span // 4 - 1000) / 1e9 / (10 * n))
     assert 0 < m["step.mfu_roofline_share"]["value"] < 100
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
 

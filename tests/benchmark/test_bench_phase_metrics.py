@@ -63,7 +63,7 @@ def test_metric_is_declared_for_the_cell_it_reads(name):
         assert entry[key] == meta[key], key
     assert entry["source"] == "program_counter"
     assert entry["layer"] == "Engine / LaneScheduler boundary"
-    assert entry["workloads"] == ["standard.trickle"]
+    assert "workloads" not in entry  # every cell reads it (PR 29)
     cell = cells.load_cell(ROOT, "standard.trickle")
     assert name in [m["name"] for m in cell["per_layer"]]
 
@@ -103,6 +103,19 @@ def _two_sessions():
     return ops, mods, events
 
 
+# the counters where the trace began (5 ms before the first program, as
+# session one's span) and at each boundary, seen 5 ms after its program:
+# 400 steps and 60 ms blocked each
+MARKS = [(-0.005, 0, 0, 0.0)] + [
+    (t / 1e3 + 0.065, k + 1, 400 * (k + 1), 0.06 * (k + 1))
+    for k, t in enumerate([0, 100, 200, 300, 1400, 1500])]
+
+
+def _reduce(ops, mods, events):
+    return tr.reduce_trace(ops, mods, MARKS, _x_spans(events),
+                           anchor_ns=0, anchor_mono_s=0.0)
+
+
 def _x_spans(events):
     """What benchmark/measure.py::host_spans takes from the ring."""
     out = []
@@ -119,10 +132,9 @@ def _x_spans(events):
 def test_async_submit_pairs_leave_the_reduction_as_it_was():
     """The submit path runs between sessions. Its events are async
     pairs and instants, which the harness does not take for host spans:
-    the time between two sessions stays out of the idle share."""
+    the time between two sessions stays time between two sessions."""
     ops, mods, events = _two_sessions()
-    plain = tr.reduce_trace(ops, mods, _x_spans(events),
-                            anchor_ns=0, anchor_mono_s=0.0)
+    plain = _reduce(ops, mods, events)
     with_submits = list(events)
     for i, (t0, t1) in enumerate([(500, 900), (700, 1350)]):  # overlapping
         for name, a, b in (("submit", t0, t1), ("submit.replay", t0, t0 + 50),
@@ -133,36 +145,39 @@ def test_async_submit_pairs_leave_the_reduction_as_it_was():
                                      "pid": 1, "tid": 2 + i})
     with_submits.append({"ph": "i", "name": "position.queued", "s": "t",
                          "ts": 880_000.0, "pid": 1, "tid": 2})
-    got = tr.reduce_trace(ops, mods, _x_spans(with_submits),
-                          anchor_ns=0, anchor_mono_s=0.0)
+    got = _reduce(ops, mods, with_submits)
     assert got["between_sessions_s"] == plain["between_sessions_s"]
-    assert got["idle_share"] == plain["idle_share"]
+    assert got["busy_per_wait"] == plain["busy_per_wait"] == pytest.approx(1.0)
     assert got["idle_gaps"] == plain["idle_gaps"]
     # between the sessions: 400 ms .. 1390 ms of the timeline
     assert plain["between_sessions_s"] == pytest.approx(0.990)
     gaps = dict(map(tuple, plain["idle_gaps"]))
     assert "segment.device" not in gaps and "segment.host" not in gaps
     # gaps inside sessions carry the names of what the host was doing:
-    # five gaps, each lanes 10 ms, refill 25, the next dispatch 4, and
-    # 1 ms of the next segment before its program starts
+    # five whole gaps, each lanes 10 ms, refill 25, the next dispatch 4,
+    # and 1 ms of the next segment before its program starts; before the
+    # first program its dispatch and that 1 ms, after the last the 5 ms of
+    # `lanes` until its boundary was seen
     assert gaps["phase.refill"] == pytest.approx(0.025 * 5)
-    assert gaps["phase.lanes"] == pytest.approx(0.010 * 5)
-    assert gaps["phase.dispatch"] == pytest.approx(0.004 * 5)
-    assert gaps["segment"] == pytest.approx(0.001 * 5)
+    assert gaps["phase.lanes"] == pytest.approx(0.010 * 5 + 0.005)
+    assert gaps["phase.dispatch"] == pytest.approx(0.004 * 6)
+    assert gaps["segment"] == pytest.approx(0.001 * 6)
     # a session's set-up and tail: under its span and no narrower one
     assert gaps["session"] == pytest.approx(0.005 + 0.005)
-    assert plain["idle_share"] == pytest.approx(100.0 * 0.210 / 0.510)
+    assert plain["intervals"] == 6 and plain["wait_s"] == pytest.approx(0.36)
+    assert plain["busy_s"] == pytest.approx(0.36)
 
 
-def test_an_x_span_over_a_submit_would_have_moved_the_idle_share():
+def test_an_x_span_over_a_submit_would_have_hidden_the_time_between_sessions():
     """Why the program may not use a thread span there: the same time
-    under an `X` event reads as idle inside a session."""
+    under an `X` event is named as the host's work inside a session in the
+    ledger's idle gaps."""
     ops, mods, events = _two_sessions()
-    plain = tr.reduce_trace(ops, mods, _x_spans(events),
-                            anchor_ns=0, anchor_mono_s=0.0)
+    plain = _reduce(ops, mods, events)
     wrong = events + [{"ph": "X", "name": "submit", "ts": 500_000.0,
                        "dur": 850_000.0, "pid": 1, "tid": 2}]
-    got = tr.reduce_trace(ops, mods, _x_spans(wrong),
-                          anchor_ns=0, anchor_mono_s=0.0)
+    got = _reduce(ops, mods, wrong)
     assert got["between_sessions_s"] < plain["between_sessions_s"]
-    assert got["idle_share"] > plain["idle_share"]
+    assert dict(map(tuple, got["idle_gaps"]))["submit"] == pytest.approx(0.850)
+    # what is carried over the window does not lean on the spans at all
+    assert got["busy_per_wait"] == plain["busy_per_wait"]
