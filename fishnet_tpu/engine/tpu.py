@@ -326,6 +326,10 @@ class TpuEngine(ChunkSubmit):
             # the host spent blocked on device results vs doing boundary
             # bookkeeping, plus the host-device transfer count
             "host_ms": 0.0, "device_ms": 0.0, "transfers": 0,
+            # counted on the device, in the segment's loop carry, and
+            # read from the boundary summary's last row: node expansions,
+            # the moves their lists hold, the drops among those
+            **{name: 0 for name in search_ops.MOVEGEN_COUNTERS},
             # the same boundary intervals by what the host was doing
             # (SyncStats.phase): sums to host_ms + device_ms, and
             # phase_wait_ms is device_ms
@@ -2180,6 +2184,12 @@ class LaneScheduler:
             ]
             return lanes, max(shard_steps), shard_steps
 
+        def count_movegen(raw):
+            """The summary's last rows hold what the segment's loop
+            counted (summed over shards): into the interval's snapshot."""
+            stats.count(search_ops.movegen_counts(
+                raw[B] if mesh is None else raw[:, local]))
+
         def shard_occup():
             """Busy (primary or helper) lane count per shard, or None
             off-mesh — the per-shard occupancy column of the log."""
@@ -2470,11 +2480,11 @@ class LaneScheduler:
                         seg_res = traced_snapshot()
                     t0 = time.monotonic()
                     with stats.phase("dispatch", steps=seg, live=live_n):
-                        state, tt, n, _summ = dispatch(state, tt, seg)
-                    n_arr = np.asarray(
-                        stats.fetch(n, "steps")
-                    ).reshape(-1)
-                    n = int(n_arr.max())
+                        state, tt, _n, summ = dispatch(state, tt, seg)
+                    # steps and movegen counters: the summary's last rows
+                    raw = stats.fetch(summ, "steps")
+                    count_movegen(raw)
+                    _lanes, n, shard_steps = canon_summ(raw)
                     wall = time.monotonic() - t0
                     with stats.phase("account"):
                         traced_residency(seg_res, t0, t0 + wall)
@@ -2517,11 +2527,12 @@ class LaneScheduler:
                             B, n, live_n, helper_n, n_adm, q_len, wall,
                             snap["host_ms"], snap["device_ms"],
                             snap["transfers"], snap["phases"],
+                            counts=snap["counts"],
                             shard=None if mesh is None else {
                                 "shard_live": shard_live,
                                 "shard_refilled":
                                     adm_shard or [0] * n_shard,
-                                "shard_steps": [int(x) for x in n_arr],
+                                "shard_steps": shard_steps,
                             },
                         )
                         if ctrl is not None:
@@ -2570,6 +2581,7 @@ class LaneScheduler:
                             time.monotonic())
                     with stats.phase("lanes"):
                         summ, n, shard_steps = canon_summ(raw_summ)
+                        count_movegen(raw_summ)
                         lane_done = summ[:, search_ops.SUM_DONE].astype(bool)
                         nodes_row = summ[:, search_ops.SUM_NODES]
                         # lanes whose park was already handled at an
@@ -2620,6 +2632,7 @@ class LaneScheduler:
                             (snap["host_ms"] + snap["device_ms"]) / 1000.0,
                             snap["host_ms"], snap["device_ms"],
                             snap["transfers"], snap["phases"],
+                            counts=snap["counts"],
                             shard=None if mesh is None else {
                                 "shard_live": pend_meta["shard_live"],
                                 "shard_refilled":
@@ -2686,7 +2699,8 @@ class LaneScheduler:
 
     def _record_occupancy(self, width, steps, live, helpers, refilled,
                           queue, wall, host_ms=0.0, device_ms=0.0,
-                          transfers=0, phases=None, shard=None):
+                          transfers=0, phases=None, shard=None,
+                          counts=None):
         eng = self.engine
         tot = eng.occupancy_totals
         idle = width - live - helpers
@@ -2694,6 +2708,8 @@ class LaneScheduler:
         # phase totals sum to the two
         for name, ms in (phases or {}).items():
             tot[f"phase_{name}_ms"] += ms
+        for name, n in (counts or {}).items():
+            tot[name] += n
         if steps == 0 and refilled == 0:
             # Pipelined overrun dispatch: the prefetched segment ran zero
             # steps because every lane finished during the previous one.

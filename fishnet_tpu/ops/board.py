@@ -474,41 +474,60 @@ def make_move(b: Board, move: jnp.ndarray, variant: str = "standard") -> Board:
             jnp.where(gave_check, 1, 0)
         )
     elif variant == "crazyhouse":
-        # promoted-piece bit transport: bit(sq) lives in extra[10 + sq//32]
-        def get_bit(e, sq):
-            return (e[EXTRA_PROMOTED + sq // 32] >> (sq % 32)) & 1
+        with jax.named_scope("step.pocket"):
+            # Every index below (pocket slot, promoted-bit word) is a
+            # traced value, batched under the lane vmap: `extra[i]` and
+            # `extra.at[i]` there are gathers and scatters the TPU runs
+            # lane by lane. So the ten pocket counters take one-hot adds
+            # and the promoted bitboard rides as its two words, selected
+            # by `sq >= 32`; bit(sq) lives in extra[10 + sq // 32].
+            lo0 = extra[EXTRA_PROMOTED]
+            hi0 = extra[EXTRA_PROMOTED + 1]
 
-        def with_bit(e, sq, val):
-            w = EXTRA_PROMOTED + sq // 32
-            bit = jnp.int32(1) << (sq % 32)
-            return e.at[w].set(
-                jnp.where(val == 1, e[w] | bit, e[w] & ~bit)
+            def get_bit(lo, hi, sq):
+                return (jnp.where(sq >= 32, hi, lo) >> (sq & 31)) & 1
+
+            def with_bit(lo, hi, sq, val):
+                bit = jnp.int32(1) << (sq & 31)
+                upper = sq >= 32
+
+                def put(w):
+                    return jnp.where(val == 1, w | bit, w & ~bit)
+
+                return (jnp.where(upper, lo, put(lo)),
+                        jnp.where(upper, put(hi), hi))
+
+            was_promoted_mover = get_bit(lo0, hi0, frm) & jnp.where(is_drop, 0, 1)
+            cap_sq = jnp.where(is_ep, ep_victim_c, to)
+            victim_code = jnp.where(is_ep, board[ep_victim_c], target)
+            real_capture = capture & ~is_castle & ~is_drop
+            cap_promoted = get_bit(lo0, hi0, cap_sq) & jnp.where(real_capture, 1, 0)
+            # pocket gains the captured piece, demoted to pawn if promoted,
+            # and pays for a drop
+            cap_type = jnp.where(
+                cap_promoted == 1, 0, jnp.maximum(piece_type(victim_code), 0)
             )
-
-        was_promoted_mover = get_bit(extra, frm) & jnp.where(is_drop, 0, 1)
-        cap_sq = jnp.where(is_ep, ep_victim_c, to)
-        victim_code = jnp.where(is_ep, board[ep_victim_c], target)
-        real_capture = capture & ~is_castle & ~is_drop
-        cap_promoted = get_bit(extra, cap_sq) & jnp.where(real_capture, 1, 0)
-        # pocket gains the captured piece, demoted to pawn if promoted
-        cap_type = jnp.where(
-            cap_promoted == 1, 0, jnp.maximum(piece_type(victim_code), 0)
-        )
-        pocket_slot = EXTRA_POCKET + us * 5 + jnp.clip(cap_type, 0, 4)
-        extra = extra.at[pocket_slot].add(jnp.where(real_capture, 1, 0))
-        # pocket pays for a drop
-        drop_slot = EXTRA_POCKET + us * 5 + jnp.clip(promo, 0, 4)
-        extra = extra.at[drop_slot].add(jnp.where(is_drop, -1, 0))
-        # bits: clear mover origin + capture square, then set destination
-        # when the arriving piece is promoted (fresh promotion or transport)
-        extra = with_bit(extra, frm, jnp.int32(0))
-        extra = with_bit(
-            extra, cap_sq, jnp.where(real_capture, 0, get_bit(extra, cap_sq))
-        )
-        dest_promoted = jnp.where(
-            is_drop, 0, jnp.where(promo > 0, 1, was_promoted_mover)
-        )
-        extra = with_bit(extra, to, dest_promoted)
+            slots = jnp.arange(10, dtype=jnp.int32)
+            pocket_slot = us * 5 + jnp.clip(cap_type, 0, 4)
+            drop_slot = us * 5 + jnp.clip(promo, 0, 4)
+            pockets = (
+                extra[EXTRA_POCKET:EXTRA_POCKET + 10]
+                + (real_capture & (slots == pocket_slot)).astype(jnp.int32)
+                - (is_drop & (slots == drop_slot)).astype(jnp.int32)
+            )
+            # bits: clear mover origin + capture square, then set
+            # destination when the arriving piece is promoted (fresh
+            # promotion or transport)
+            lo, hi = with_bit(lo0, hi0, frm, jnp.int32(0))
+            lo, hi = with_bit(
+                lo, hi, cap_sq,
+                jnp.where(real_capture, 0, get_bit(lo, hi, cap_sq)),
+            )
+            dest_promoted = jnp.where(
+                is_drop, 0, jnp.where(promo > 0, 1, was_promoted_mover)
+            )
+            lo, hi = with_bit(lo, hi, to, dest_promoted)
+            extra = jnp.concatenate([pockets, jnp.stack([lo, hi])])
 
     return Board(
         board=out_board,

@@ -396,19 +396,26 @@ def _candidate_space(b: Board, variant: str = "standard"):
 
     # ------------------------------------------------------ crazyhouse drops
     if variant == "crazyhouse":
-        pocket = jax.lax.dynamic_slice(
-            b.extra, (us * 5,), (5,)
-        )  # (5,) our P N B R Q counts
-        empty = board == 0  # (64,)
-        pt = jnp.arange(5, dtype=jnp.int32)
-        ranks8 = sq_idx >> 3
-        pawn_ok_sq = (ranks8 != 0) & (ranks8 != 7)
-        valid = (
-            (pocket > 0)[:, None]
-            & empty[None, :]
-            & jnp.where(pt[:, None] == 0, pawn_ok_sq[None, :], True)
-        )  # (5, 64)
-        cands = DROP_FLAG | (pt[:, None] << 12) | (sq_idx[None, :] << 6) | sq_idx[None, :]
+        with jax.named_scope("step.drops"):
+            # the mover's P N B R Q counts: two static slices and a select
+            # on `us`. A dynamic_slice at us * 5 has a batched start index
+            # under the lane vmap and becomes a gather the TPU runs lane
+            # by lane inside every step
+            pocket = jnp.where(
+                us == 0,
+                b.extra[EXTRA_POCKET:EXTRA_POCKET + 5],
+                b.extra[EXTRA_POCKET + 5:EXTRA_POCKET + 10],
+            )  # (5,)
+            empty = board == 0  # (64,)
+            pt = jnp.arange(5, dtype=jnp.int32)
+            ranks8 = sq_idx >> 3
+            pawn_ok_sq = (ranks8 != 0) & (ranks8 != 7)
+            valid = (
+                (pocket > 0)[:, None]
+                & empty[None, :]
+                & jnp.where(pt[:, None] == 0, pawn_ok_sq[None, :], True)
+            )  # (5, 64)
+            cands = DROP_FLAG | (pt[:, None] << 12) | (sq_idx[None, :] << 6) | sq_idx[None, :]
         all_moves.append(cands)
         all_valid.append(valid)
         # drops search after ordinary quiet moves

@@ -88,6 +88,9 @@ class _Oracle:
         self.variant = variant
         self.nodes = 0
         self.rep_hits = 0  # repetition-draw leaves seen (test instrumentation)
+        # node expansions, the moves their lists hold, the drops among
+        # them: what the device counts as ops/search.py MOVEGEN_COUNTERS
+        self.movegen = [0, 0, 0]
         self.b768 = nnue.is_board768(params)
         self.ops = _jitted(self.b768, variant)
         # [(h1, h2, halfmove, virtual_ply)]: pre-root game history at
@@ -173,6 +176,10 @@ class _Oracle:
 
         n = noisy if qs_like else count
         moves = np.asarray(moves)
+        listed = moves[:count]
+        self.movegen[0] += 1
+        self.movegen[1] += count
+        self.movegen[2] += int(np.sum((listed >> 15) & 1))
         if qs_like:
             best = leaf_val  # stand-pat floors best and alpha
             alpha = max(alpha, leaf_val)
@@ -290,7 +297,8 @@ class _Oracle:
 def oracle_search(params, root: Board, depth: int, node_budget: int,
                   max_ply: int, variant: str = "standard",
                   history=None) -> dict:
-    """Search one root exactly like one device lane; → {score, nodes}.
+    """Search one root exactly like one device lane; → {score, nodes,
+    rep_hits, movegen}.
 
     root: single-lane Board. Matches ops.search.search_batch semantics for
     the same (depth, node_budget, max_ply, variant); scores must agree
@@ -305,4 +313,5 @@ def oracle_search(params, root: Board, depth: int, node_budget: int,
     else:
         acc = jnp.zeros((2, params.ft_w.shape[1]), params.ft_w.dtype)
     score = o.search(root, acc, 0, -INF, INF)
-    return {"score": score, "nodes": o.nodes, "rep_hits": o.rep_hits}
+    return {"score": score, "nodes": o.nodes, "rep_hits": o.rep_hits,
+            "movegen": tuple(o.movegen)}

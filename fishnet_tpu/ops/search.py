@@ -67,7 +67,7 @@ from .board import (
     move_piece_changes,
     node_rules,
 )
-from .movegen import MAX_MOVES, generate_moves, max_moves_for
+from .movegen import DROP_FLAG, MAX_MOVES, generate_moves, max_moves_for
 from . import tt as _tt_mod
 
 INF = 32500
@@ -83,10 +83,24 @@ MODE_DONE = 3
 # packed boundary summary (int32, shape (B+1, 4)): everything the host
 # needs to decide a segment boundary — done bitmap plus per-lane
 # nodes/score/best-move — in ONE small transfer instead of the full
-# extract_results set; row B broadcasts the segment's step count. PV
-# rows are pulled separately, and only for lanes that actually finished.
+# extract_results set; row B holds the segment's step count and, in
+# columns SUM_MOVEGEN, its movegen counters (node expansions, the moves
+# their lists hold, the drops among those; `movegen_counts` reads them).
+# PV rows are pulled separately, and only for lanes that actually finished.
 SUM_DONE, SUM_NODES, SUM_SCORE, SUM_MOVE = range(4)
 SUM_W = 4
+SUM_MOVEGEN = slice(1, 4)
+MOVEGEN_COUNTERS = ("movegen_nodes", "movegen_moves", "movegen_drops")
+
+
+def movegen_counts(last_rows: np.ndarray) -> dict:
+    """The movegen counters of one segment from the summary's last row
+    ((SUM_W,), or (n_shard, SUM_W) under a mesh: summed over shards).
+    The device counts in int32 and may wrap past 2^31 on the longest
+    segments at full width; read as uint32 a count holds to 2^32."""
+    rows = np.asarray(last_rows, np.int32).reshape(-1, SUM_W)
+    sums = rows[:, SUM_MOVEGEN].view(np.uint32).astype(np.int64).sum(axis=0)
+    return dict(zip(MOVEGEN_COUNTERS, map(int, sums)))
 
 # game-history repetition seeding: hashes of up to MAX_HIST reversible
 # game positions before each lane's root (the reference feeds Stockfish
@@ -342,8 +356,9 @@ def init_state(params: nnue.NnueParams, roots: Board, depth: jnp.ndarray,
 
 def _step_lane(params: nnue.NnueParams, s: SearchState,
                tt_hit=None, tt_score=None, tt_move=None,
-               variant: str = "standard") -> SearchState:
-    """One state-machine step for a single lane (vmapped over B).
+               variant: str = "standard"):
+    """One state-machine step for a single lane (vmapped over B):
+    → (the lane's next SearchState, its (3,) MOVEGEN_COUNTERS of the step).
 
     The three phases keep their row state in registers: ENTER composes
     the entered node's nt/bt rows, RETURN composes the parent's, and
@@ -515,6 +530,17 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
         )
         to_return = parent_illegal | is_leaf | use_tt
         expand = enter & ~to_return
+        # what _run_segment sums over lanes and steps (MOVEGEN_COUNTERS):
+        # this step expanded a node, the moves its list holds, and the
+        # drops among them (only the crazyhouse program has the flag)
+        if variant == "crazyhouse":
+            # a listed move fits 16 bits and an empty slot is -1, so the
+            # drop flag (bit 15) is set exactly where the slot >= DROP_FLAG
+            gen_drops = jnp.sum(gen_moves >= DROP_FLAG).astype(jnp.int32)
+        else:
+            gen_drops = jnp.int32(0)
+        movegen = expand.astype(jnp.int32) * jnp.stack(
+            [jnp.int32(1), gen_count, gen_drops])
         # mark fresh static-eval leaves for the runner's depth-0 TT store.
         # Quiet positions only: a quiet static eval IS the node's QS value,
         # while a noisy leaf (budget/stack cutoff) stored as depth-0 EXACT
@@ -847,7 +873,7 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
         bt=bt_new, nt=nt_new, lane=lane_new,
         hist_hash=s.hist_hash, hist_halfmove=s.hist_halfmove,
         moves=moves_new, hist=hist_new, pv=pv_new, acc=acc_new,
-    )
+    ), movegen
 
 
 def make_search_step(params: nnue.NnueParams, variant: str = "standard"):
@@ -910,13 +936,14 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
         step = make_search_step(params, variant)
 
         def body(carry):
-            s, t, i = carry
-            return step(s), t, i + 1
+            s, t, i, mg = carry
+            s, movegen = step(s)
+            return s, t, i + 1, mg + jnp.sum(movegen, axis=0)
     else:
         step = make_search_step_tt(params, variant)
 
         def body(carry):
-            s, t, i = carry
+            s, t, i, mg = carry
             lane = s.lane
             ply = lane[:, LN_PLY]
             btrow = _gather_ply(s.bt, ply)  # one row gather serves all
@@ -983,7 +1010,7 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
                 )
             usable &= enter
             order_mv = jnp.where(enter, order_mv, -1)
-            s = step(s, usable, score, order_mv)
+            s, movegen = step(s, usable, score, order_mv)
 
             # ---- store leaves the step just evaluated (depth-0 EXACT).
             # Their hash is the PRE-step hash: a marking lane was in ENTER
@@ -996,14 +1023,14 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
                     jnp.full_like(sval, -1), s.lane[:, LN_SMARK] != 0,
                     prefer_deep=prefer_deep, gen=gen_i,
                 )
-            return s, t, i + 1
+            return s, t, i + 1, mg + jnp.sum(movegen, axis=0)
 
     def cond(carry):
-        s, t, i = carry
+        s, t, i, mg = carry
         return (i < segment_steps) & jnp.any(s.lane[:, LN_MODE] != MODE_DONE)
 
-    state, ttab, n = jax.lax.while_loop(
-        cond, body, (state, ttab, jnp.int32(0))
+    state, ttab, n, movegen = jax.lax.while_loop(
+        cond, body, (state, ttab, jnp.int32(0), jnp.zeros(3, jnp.int32))
     )
     lane = state.lane
     summary = jnp.concatenate([
@@ -1013,7 +1040,7 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
             lane[:, LN_RSCORE],
             lane[:, LN_RMOVE],
         ], axis=1),
-        jnp.full((1, SUM_W), n, jnp.int32),
+        jnp.concatenate([n[None], movegen])[None],
     ], axis=0)
     return state, ttab, n, summary
 

@@ -160,6 +160,7 @@ class SyncStats:
         self._seg_elements = 0
         self._seg_blocked_ms = 0.0
         self._seg_phases: dict = {}
+        self._seg_counts: dict = {}
         self._phase: Optional[_Phase] = None
         self._seg_start = time.monotonic()
 
@@ -177,6 +178,13 @@ class SyncStats:
         with a recorder on it also emits one `phase.<name>` span (args
         as given) from the same two clock reads. Do not nest."""
         return _Phase(self, name, args)
+
+    def count(self, counts: dict) -> None:
+        """Add what the device counted for this interval (the boundary
+        summary's movegen counters) to the interval's `counts`."""
+        table = self._seg_counts
+        for name, n in counts.items():
+            table[name] = table.get(name, 0) + n
 
     # ------------------------------------------------------------ fetch
 
@@ -213,11 +221,11 @@ class SyncStats:
         """Close the current segment's accounting window.
 
         Returns {"transfers", "elements", "device_ms", "host_ms",
-        "phases"} for the interval since the previous boundary() (or
-        construction): device_ms is the blocked-in-fetch time, host_ms
-        the remainder of the interval's wall-clock, phases the same
-        wall-clock by phase name ("wait" is device_ms, "other" what no
-        phase covered).
+        "phases", "counts"} for the interval since the previous
+        boundary() (or construction): device_ms is the blocked-in-fetch
+        time, host_ms the remainder of the interval's wall-clock, phases
+        the same wall-clock by phase name ("wait" is device_ms, "other"
+        what no phase covered), counts what count() was given.
         """
         now = time.monotonic()
         wall_ms = (now - self._seg_start) * 1000.0
@@ -233,6 +241,7 @@ class SyncStats:
             "device_ms": device_ms,
             "host_ms": host_ms,
             "phases": phases,
+            "counts": self._seg_counts,
         }
         rec = _trace.RECORDER
         if rec is not None:
@@ -248,6 +257,7 @@ class SyncStats:
         self._seg_elements = 0
         self._seg_blocked_ms = 0.0
         self._seg_phases = {}
+        self._seg_counts = {}
         self._seg_start = now
         return snap
 

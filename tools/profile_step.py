@@ -11,9 +11,11 @@ built it.
 
 Usage:
   python tools/profile_step.py [B] [depth] [max_ply] [--trace] [--tt]
+                               [--variant crazyhouse]
 """
 from __future__ import annotations
 
+import argparse
 import glob
 import os
 import re
@@ -25,12 +27,20 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> None:
-    args = [a for a in sys.argv[1:] if not a.startswith("--")]
-    B = int(args[0]) if len(args) > 0 else 64
-    depth = int(args[1]) if len(args) > 1 else 3
-    max_ply = int(args[2]) if len(args) > 2 else depth + 1
-    do_trace = "--trace" in sys.argv
-    use_tt = "--tt" in sys.argv  # shared 2^21-slot table (production config)
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("B", nargs="?", type=int, default=64)
+    ap.add_argument("depth", nargs="?", type=int, default=3)
+    ap.add_argument("max_ply", nargs="?", type=int, default=None)
+    ap.add_argument("--trace", action="store_true")
+    ap.add_argument("--tt", action="store_true",
+                    help="shared 2^21-slot table (production config)")
+    ap.add_argument("--variant", default="standard",
+                    help="the statically compiled program to profile "
+                         "(engine/tpu.py DEVICE_VARIANTS' values)")
+    opts = ap.parse_args()
+    B, depth, variant = opts.B, opts.depth, opts.variant
+    max_ply = opts.max_ply if opts.max_ply is not None else depth + 1
+    do_trace, use_tt = opts.trace, opts.tt
     steps = int(os.environ.get("PROFILE_STEPS", "200"))
 
     import jax
@@ -49,9 +59,10 @@ def main() -> None:
     from fishnet_tpu.models import nnue
     from fishnet_tpu.ops import search as S
 
-    from bench import _roots_for
+    from bench import FENS_VARIANT, _roots_for
 
-    roots = _roots_for(B, "standard", "standard")
+    roots = _roots_for(
+        B, variant, "variant" if variant in FENS_VARIANT else "standard")
     params = nnue.init_params(jax.random.PRNGKey(0), l1=64, feature_set="board768")
     depth_arr = jnp.full((B,), depth, jnp.int32)
     budget_arr = jnp.full((B,), 10_000_000, jnp.int32)
@@ -65,7 +76,7 @@ def main() -> None:
         # so every dispatch needs its own copies — rebuilding also keeps
         # the step counts comparable across the timed runs
         st = S._init_state_jit(params, roots, depth_arr, budget_arr,
-                               max_ply, "standard")
+                               max_ply, variant)
         t = tt_mod.make_table(21) if use_tt else None
         jax.block_until_ready(st.bt)
         return st, t
@@ -73,8 +84,9 @@ def main() -> None:
     state, tt0 = fresh_inputs()
     t0 = time.perf_counter()
     compiled = S._run_segment_jit.lower(params, state, tt0, steps,
-                                        "standard", False).compile()
-    print(f"compile run_segment({steps}): {time.perf_counter() - t0:.1f}s",
+                                        variant, False).compile()
+    print(f"compile run_segment({steps}) for {variant}: "
+          f"{time.perf_counter() - t0:.1f}s",
           file=sys.stderr)
 
     # warmup + timed: same fresh state each time so step counts match
@@ -82,7 +94,7 @@ def main() -> None:
         state, tt0 = fresh_inputs()
         t0 = time.perf_counter()
         out, _, n, _summ = S._run_segment_jit(params, state, tt0, steps,
-                                              "standard", False)
+                                              variant, False)
         jax.block_until_ready(out.lane)
         dt = time.perf_counter() - t0
         n = int(n)
@@ -97,7 +109,7 @@ def main() -> None:
     trace_dir = os.environ.get("PROFILE_TRACE_DIR", "/tmp/fishnet-trace")
     with jax.profiler.trace(trace_dir):
         out, _, n, _summ = S._run_segment_jit(params, state, tt0, steps,
-                                              "standard", False)
+                                              variant, False)
         jax.block_until_ready(out.lane)
     print(f"trace written to {trace_dir}", file=sys.stderr)
     report(trace_dir, scope_of_instruction(compiled.as_text()), steps)
