@@ -29,7 +29,12 @@ from ..client.wire import AnalysisWork, MoveWork, Score
 from ..models import nnue
 from ..ops import search as search_ops
 from ..ops import tt as tt_mod
-from ..ops.board import from_position, position_fields, stack_boards
+from ..ops.board import (
+    from_position,
+    position_fields,
+    stack_boards,
+    stack_fields,
+)
 from ..obs import inflight as obs_inflight
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
@@ -98,12 +103,9 @@ DEVICE_VARIANTS = {
 def _position_keys(positions, variant: str):
     """(h1, h2) uint32 arrays, one entry a Position: `tt.hash_board`'s
     keys computed on the host, no device put and no fetch."""
-    rows = [position_fields(p) for p in positions]
+    b = stack_fields([position_fields(p) for p in positions])
     return tt_mod.hash_boards_host(
-        *(np.stack([getattr(b, f) for b in rows])
-          for f in ("board", "stm", "ep", "castling", "extra")),
-        variant,
-    )
+        b.board, b.stm, b.ep, b.castling, b.extra, variant)
 
 
 def _score_from_int(v: int, root_ply_to_mate_sign: int = 1) -> Score:
@@ -321,7 +323,9 @@ class TpuEngine(ChunkSubmit):
         self.occupancy_totals = {
             "segments": 0, "steps": 0, "lane_steps": 0,
             "live_lane_steps": 0, "helper_lane_steps": 0,
-            "idle_lane_steps": 0, "refills": 0, "positions_done": 0,
+            "idle_lane_steps": 0, "positions_done": 0,
+            # lanes spliced, and the boundaries that spliced any
+            "refills": 0, "refill_splices": 0,
             # segment-boundary cost split (utils/syncstats.py): wall-clock
             # the host spent blocked on device results vs doing boundary
             # bookkeeping, plus the host-device transfer count
@@ -1430,7 +1434,7 @@ class _RefillJob:
         self.entry = entry
         self.wp = wp
         self.pos = pos
-        self.board = board
+        self.board = board  # position_fields(pos): numpy, never a device array
         self.variant = variant
         self.target_depth = target_depth
         self.remaining = budget  # node budget left (host int)
@@ -1711,7 +1715,7 @@ class LaneScheduler:
                 with self._submit_step(rec, nest_id, "ttwarm", spent):
                     self._tt_warm_plan(entry, wp, pos, variant)
             job = _RefillJob(
-                entry, wp, pos, from_position(pos), variant, target_depth,
+                entry, wp, pos, position_fields(pos), variant, target_depth,
                 per_pos_budget, deadline, hh[0], hm[0],
             )
             ctx = wp.ctx
@@ -1917,24 +1921,24 @@ class LaneScheduler:
         gen = np.zeros(B, np.int32)
         active: List[_RefillJob] = []
 
-        # idle base state: budget-0 lanes park in DONE within two steps;
-        # passing every optional init arg as a full array shares ONE
-        # _init_state_jit trace with refill_lanes' fresh states
+        # idle base state: budget-0 lanes park in DONE within two steps.
+        # Built from host rows, like every refill after it: one call
+        # whose operands are numpy at the session's width
         from ..ops.search import HIST_HM_SENTINEL, MAX_HIST
 
         with syncstats.step("session_setup"):
             state = search_ops._init_state_jit(
-                eng.params, stack_boards([filler] * B),
-                jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
+                eng.params, stack_fields([filler] * B),
+                np.zeros(B, np.int32), np.zeros(B, np.int32),
                 MAX_PLY, variant,
-                hist_hash=jnp.zeros((B, MAX_HIST, 2), jnp.uint32),
-                hist_halfmove=jnp.full(
-                    (B, MAX_HIST), HIST_HM_SENTINEL, jnp.int32
+                hist_hash=np.zeros((B, MAX_HIST, 2), np.uint32),
+                hist_halfmove=np.full(
+                    (B, MAX_HIST), HIST_HM_SENTINEL, np.int32
                 ),
-                root_alpha=jnp.full((B,), -INF, jnp.int32),
-                root_beta=jnp.full((B,), INF, jnp.int32),
-                order_jitter=jnp.zeros((B,), jnp.int32),
-                group=jnp.zeros((B,), jnp.int32),
+                root_alpha=np.full((B,), -INF, np.int32),
+                root_beta=np.full((B,), INF, np.int32),
+                order_jitter=np.zeros((B,), np.int32),
+                group=np.zeros((B,), np.int32),
             )
             if mesh is not None:
                 from ..parallel.mesh import (
@@ -1950,8 +1954,8 @@ class LaneScheduler:
                 state = shard_batch(mesh, state)
         tt = eng.tt
 
-        # admissions accumulated between boundaries, flushed as ONE
-        # refill_lanes call before each dispatch
+        # admissions accumulated between boundaries as host rows, flushed
+        # as ONE refill_lanes call before each dispatch
         adm: dict = {k: [] for k in (
             "lane", "board", "depth", "budget", "alpha", "beta",
             "jitter", "group", "hh", "hm",
@@ -2256,26 +2260,22 @@ class LaneScheduler:
                   0, lane, job.hh, job.hm)
 
         def flush_pv(st, now: float):
-            """Materialize deferred PV rows with two small device-side
-            gathers from a resolved state, then finalize the jobs whose
-            response waited only on the PV. Must run BEFORE flush_adm:
-            a refill splice resets the spliced lanes' PV tables."""
+            """Materialize deferred PV rows from a resolved state, then
+            finalize the jobs whose response waited only on the PV. The
+            whole (B, max_ply) root-PV block and its (B,) lengths come
+            home — a few kB whose shapes are the session's, not the
+            count's — and the rows owed are picked on the host. Must run
+            BEFORE flush_adm: a refill splice resets the spliced lanes'
+            PV tables."""
             if not pv_pending:
                 return
-            rows = jnp.asarray(
-                np.asarray([e[1] for e in pv_pending], np.int64)
-            )
-            pv_rows = stats.fetch(
-                jnp.take(st.pv[:, 0], rows, axis=0), "pv"
-            )
+            pv_rows = stats.fetch(st.pv[:, 0], "pv")
             pv_lens = stats.fetch(
-                jnp.take(st.nt[:, 0, search_ops.NT_PVLEN], rows, axis=0),
-                "pv_len",
-            )
-            for i, (job, _lane, depth, final) in enumerate(pv_pending):
+                st.nt[:, 0, search_ops.NT_PVLEN], "pv_len")
+            for job, lane, depth, final in pv_pending:
                 pv = [
                     _decode_uci(int(m))
-                    for m in pv_rows[i][: int(pv_lens[i])]
+                    for m in pv_rows[lane][: int(pv_lens[lane])]
                     if m >= 0
                 ]
                 job.pvs.set(1, depth, pv)
@@ -2375,10 +2375,11 @@ class LaneScheduler:
 
         def flush_adm(st):
             # ---- flush staged admissions in ONE refill splice (donates
-            # st — rebind to the return value); under a mesh the splice
-            # runs through the shard_map'd masked merge, each device
-            # rewriting only its own lanes. Returns (state, count,
-            # per-shard admission counts or None).
+            # st — rebind to the return value): the staged host rows go
+            # to the one splice program of this width, whatever their
+            # number; under a mesh it runs through shard_map, each
+            # device rewriting only its own lanes. Returns (state,
+            # count, per-shard admission counts or None).
             n_adm = len(adm["lane"])
             if not n_adm:
                 return st, 0, None
@@ -2389,7 +2390,7 @@ class LaneScheduler:
                 ).astype(int).tolist()
             )
             splice_args = (
-                eng.params, st, stack_boards(adm["board"]),
+                eng.params, st, stack_fields(adm["board"]),
                 adm["lane"],
                 np.asarray(adm["depth"], np.int32),
                 np.asarray(adm["budget"], np.int32),
@@ -2467,7 +2468,7 @@ class LaneScheduler:
                         )
                     with stats.phase("admit"):
                         admit_new(now)
-                    with stats.phase("refill"):
+                    with stats.phase("refill", lanes=len(adm["lane"])):
                         state, n_adm, adm_shard = flush_adm(state)
                     if not active:
                         break  # nothing running; next session continues
@@ -2552,7 +2553,7 @@ class LaneScheduler:
                     reap_jobs(now, None)
                 with stats.phase("admit"):
                     admit_new(now)
-                with stats.phase("refill"):
+                with stats.phase("refill", lanes=len(adm["lane"])):
                     state, n_adm, adm_shard = flush_adm(state)
                 pend = None
                 if active:
@@ -2649,7 +2650,7 @@ class LaneScheduler:
                     if nxt is not None:
                         pend, pend_meta = nxt, nxt_meta
                         continue
-                    with stats.phase("refill"):
+                    with stats.phase("refill", lanes=len(adm["lane"])):
                         state, n_adm, adm_shard = flush_adm(p_state)
                     if not active:
                         break  # next session handles the rest
@@ -2728,6 +2729,7 @@ class LaneScheduler:
         tot["helper_lane_steps"] += steps * helpers
         tot["idle_lane_steps"] += steps * idle
         tot["refills"] += refilled
+        tot["refill_splices"] += int(refilled > 0)
         tot["host_ms"] += host_ms
         tot["device_ms"] += device_ms
         tot["transfers"] += transfers

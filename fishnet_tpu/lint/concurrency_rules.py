@@ -56,7 +56,7 @@ deliberately not flagged — the rules target *broad* swallowing.
 The host-sync rule runs on the scheduler-loop modules (engine/tpu.py's
 LaneScheduler and ops/search.py's stream/batch loops): values that
 flow from the segment dispatch jits (`_run_segment_jit`,
-`_init_state_jit`, `_merge_lanes_jit`, `refill_lanes`,
+`_init_state_jit`, `_splice_lanes_jit`, `refill_lanes`,
 `extract_results`, the shard_map'd mesh callables
 `run_segment_sharded`/`refill_lanes_sharded`, or a local
 `dispatch`/`flush_adm` wrapper) are device-resident, and the only
@@ -173,7 +173,7 @@ _MUT_METHODS = ("update", "pop", "clear", "setdefault", "popitem",
 # as do the shard_map'd mesh callables (parallel/mesh.py) the sharded
 # scheduler drives
 _DEVICE_PRODUCERS = ("_run_segment_jit", "_init_state_jit",
-                     "_merge_lanes_jit", "refill_lanes", "extract_results",
+                     "_splice_lanes_jit", "refill_lanes", "extract_results",
                      "dispatch", "flush_adm",
                      "run_segment_sharded", "refill_lanes_sharded")
 

@@ -54,7 +54,7 @@ from .core import Finding, Project, SourceFile, dotted, register_family
 # apply everywhere in scope — the names are unambiguous.
 DONATING_CALLS: Dict[str, Tuple[Tuple[int, ...], Tuple[str, ...]]] = {
     "_run_segment_jit": ((1, 2), ()),      # state, tt
-    "_merge_lanes_jit": ((0, 1), ()),      # state, fresh
+    "_splice_lanes_jit": ((1,), ()),       # state (after params)
     "_init_state_jit": ((), ("hist_hash", "hist_halfmove")),
     "run_segment_sharded": ((2, 3), ()),   # state, ttab (after mesh, params)
     "refill_lanes_sharded": ((2,), ()),    # state

@@ -109,6 +109,12 @@ def stack_boards(boards) -> Board:
     return Board(*[jnp.stack([getattr(b, f) for b in boards]) for f in Board._fields])
 
 
+def stack_fields(rows) -> Board:
+    """List of `position_fields` rows → batched Board of numpy fields:
+    `stack_boards` for boards that stay on the host, no device call."""
+    return Board(*[np.stack([getattr(b, f) for b in rows]) for f in Board._fields])
+
+
 def piece_color(code: jnp.ndarray) -> jnp.ndarray:
     """0 white, 1 black, -1 empty."""
     return jnp.where(code == 0, -1, jnp.where(code <= 6, 0, 1))

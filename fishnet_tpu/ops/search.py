@@ -1062,7 +1062,8 @@ _run_segment_jit = _aot_registry.wrap(
     static_names=("variant", "deep_tt", "prefer_deep"),
 )
 # the big tables are OUTPUTS of init_state; its only device-state-shaped
-# inputs are the history rows, donated so refill splices don't copy them
+# inputs are the history rows, donated so a caller's device rows are not
+# copied (a refill does not come through here: _splice_lanes_jit below)
 _init_state_jit = _aot_registry.wrap(
     "init_state",
     jax.jit(
@@ -1102,7 +1103,18 @@ def extract_results(state: SearchState, steps) -> dict:
 # the caller passes a (B,) tt_gen array into _run_segment_jit, which
 # ops/tt.py broadcasts elementwise, so a refilled lane's stores carry
 # its own fresh generation without any tt.py change.
-
+#
+# The splice is ONE program per state width and variant
+# (_splice_lanes_jit): init_state at the state's own width, then the
+# masked per-lane select of _merge_lanes, the running state donated.
+# Its per-lane operands — the six board fields, depth, budget, history
+# rows, window, jitter, group — and the (B,) mask are always B rows,
+# however many lanes a boundary refills: _refill_inputs widens the n
+# admitted rows on the host (numpy; row i of a lane that is not
+# refilled holds the first admitted row, which the mask discards), so
+# no shape anywhere in a refill follows n and a session builds the
+# program once. Operands that already live on the device (a caller's
+# carried rows) are widened there by a gather instead.
 
 def _merge_lanes(state: SearchState, fresh: SearchState,
                  mask: jnp.ndarray) -> SearchState:
@@ -1117,13 +1129,33 @@ def _merge_lanes(state: SearchState, fresh: SearchState,
         return jax.tree.map(pick, state, fresh)
 
 
-# both inputs are donated: the running state's tables are overwritten in
-# place where the mask selects, and the fresh (refill-sized) state is
-# consumed by the splice — a refill boundary allocates nothing big
-_merge_lanes_jit = _aot_registry.wrap(
-    "merge_lanes",
-    jax.jit(_merge_lanes, donate_argnums=(0, 1)),
-    _merge_lanes,
+def _splice_lanes(params: nnue.NnueParams, state: SearchState,
+                  roots: Board, depth, node_budget, hist_hash,
+                  hist_halfmove, root_alpha, root_beta, order_jitter,
+                  group, mask, variant: str = "standard") -> SearchState:
+    """The refill splice: a fresh init_state of `roots` at the width
+    and stack depth of `state`, taken where mask (B,) is True; every
+    other lane keeps `state` bit for bit. All per-lane operands have
+    B rows (see _refill_inputs)."""
+    with jax.named_scope("refill.fresh"):
+        fresh = init_state(
+            params, roots, depth, node_budget, state.bt.shape[1] - 1,
+            variant, hist_hash=hist_hash, hist_halfmove=hist_halfmove,
+            root_alpha=root_alpha, root_beta=root_beta,
+            order_jitter=order_jitter, group=group,
+        )
+    return _merge_lanes(state, fresh, mask)
+
+
+# the running state is donated: its tables are overwritten in place
+# where the mask selects, and the fresh state never leaves the program —
+# a refill boundary allocates nothing big
+_splice_lanes_jit = _aot_registry.wrap(
+    "splice_lanes",
+    jax.jit(_splice_lanes, static_argnames=("variant",),
+            donate_argnums=(1,)),
+    _splice_lanes,
+    static_names=("variant",),
 )
 
 # FISHNET_TPU_SANITIZE: poison donated inputs after dispatch so a
@@ -1135,59 +1167,58 @@ _run_segment_jit = _sanitize.guard_donation(
 _init_state_jit = _sanitize.guard_donation(
     "ops/search.py::_init_state_jit", _init_state_jit,
     argnames=("hist_hash", "hist_halfmove"))
-_merge_lanes_jit = _sanitize.guard_donation(
-    "ops/search.py::_merge_lanes_jit", _merge_lanes_jit, argnums=(0, 1))
+_splice_lanes_jit = _sanitize.guard_donation(
+    "ops/search.py::_splice_lanes_jit", _splice_lanes_jit, argnums=(1,))
 
 
-def _refill_fresh(params: nnue.NnueParams, state: SearchState,
-                  new_roots: Board, lane_idx, depth, node_budget, *,
-                  variant: str = "standard", hist_hash=None,
-                  hist_halfmove=None, root_alpha=None, root_beta=None,
-                  order_jitter=None, group=None):
-    """Build the full-width fresh state and (B,) splice mask for a refill.
+def _refill_inputs(state: SearchState, new_roots: Board, lane_idx, depth,
+                   node_budget, *, hist_hash=None, hist_halfmove=None,
+                   root_alpha=None, root_beta=None, order_jitter=None,
+                   group=None):
+    """The per-lane operands of _splice_lanes for n admitted rows, each
+    widened to the state's B lanes, and the (B,) splice mask last:
+    (roots, depth, node_budget, hist_hash, hist_halfmove, root_alpha,
+    root_beta, order_jitter, group, mask), or None when lane_idx is
+    empty.
 
     Shared by the single-device `refill_lanes` and the sharded
-    parallel.mesh.refill_lanes_sharded — the fresh state and mask are
-    mesh-agnostic (the merge is what differs: plain jit vs shard_map).
-    Returns (fresh, mask), or (None, None) when lane_idx is empty."""
+    parallel.mesh.refill_lanes_sharded. A host operand (numpy, a
+    sequence) is widened on the host and stays numpy, so the splice
+    program receives it as it is; one that is a jax.Array is gathered
+    on the device — np.asarray there would block the host and
+    round-trip the rows through it. Lane lane_idx[i] gets row i; a lane
+    that is not refilled gets row 0, which the mask discards. None
+    takes the init_state default, so every call shares one trace."""
     B = state.lane.shape[0]
-    max_ply = state.bt.shape[1] - 1
     lane_idx = np.asarray(lane_idx, np.int64).reshape(-1)
     n = int(lane_idx.shape[0])
     if n == 0:
-        return None, None
+        return None
     take = np.zeros(B, np.int64)
     take[lane_idx] = np.arange(n)
     mask = np.zeros(B, bool)
     mask[lane_idx] = True
-    tk = jnp.asarray(take)
+    tk = None  # `take` on the device, put there by the first operand to need it
 
-    def expand(x, fill, dtype, tail=()):
+    def widen(x, dtype, fill=0, tail=()):
+        nonlocal tk
         if x is None:
-            x = np.full((n,) + tail, fill, dtype)
-        elif isinstance(x, jax.Array):
-            # already device-resident (e.g. carried from a previous
-            # segment's outputs): gather on device — np.asarray here
-            # would block the host and round-trip the rows through it
+            return np.full((B,) + tail, fill, dtype)
+        if isinstance(x, jax.Array):
+            if tk is None:
+                tk = jnp.asarray(take)
             return jnp.take(x, tk, axis=0)
-        return jnp.asarray(np.asarray(x))[tk]
+        return np.asarray(x, dtype)[take]
 
-    with jax.named_scope("refill.fresh"):
-        roots_full = jax.tree.map(lambda a: jnp.asarray(a)[tk], new_roots)
-        fresh = _init_state_jit(
-            params, roots_full,
-            expand(depth, 0, np.int32), expand(node_budget, 0, np.int32),
-            max_ply, variant,
-            hist_hash=expand(hist_hash, 0, np.uint32, (MAX_HIST, 2)),
-            hist_halfmove=expand(
-                hist_halfmove, HIST_HM_SENTINEL, np.int32, (MAX_HIST,)
-            ),
-            root_alpha=expand(root_alpha, -INF, np.int32),
-            root_beta=expand(root_beta, INF, np.int32),
-            order_jitter=expand(order_jitter, 0, np.int32),
-            group=expand(group, 0, np.int32),
-        )
-    return fresh, mask
+    return (
+        jax.tree.map(lambda a: widen(a, np.int32), new_roots),
+        widen(depth, np.int32), widen(node_budget, np.int32),
+        widen(hist_hash, np.uint32, 0, (MAX_HIST, 2)),
+        widen(hist_halfmove, np.int32, HIST_HM_SENTINEL, (MAX_HIST,)),
+        widen(root_alpha, np.int32, -INF), widen(root_beta, np.int32, INF),
+        widen(order_jitter, np.int32), widen(group, np.int32),
+        mask,
+    )
 
 
 def refill_lanes(params: nnue.NnueParams, state: SearchState, new_roots: Board,
@@ -1199,26 +1230,26 @@ def refill_lanes(params: nnue.NnueParams, state: SearchState, new_roots: Board,
 
     new_roots: batched Board with n rows; lane_idx: host sequence of n
     distinct lane indices to reinitialize; depth/node_budget (n,) and the
-    optional per-lane arrays follow init_state semantics (None defaults
-    are expanded to the init_state defaults so every call shares ONE
-    _init_state_jit trace with the initial fill).
+    optional per-lane arrays follow init_state semantics. One program
+    per state width and variant runs whatever n is (_splice_lanes_jit);
+    `state` is donated — rebind to the return value.
 
     Lanes not in lane_idx keep their exact pre-call state — including
     mid-segment stack contents, accumulators and history — so live
     searches are unaffected. The caller is responsible for only
     refilling DONE lanes and for bumping those lanes' TT generation
     tags before the next _run_segment_jit dispatch. For a mesh-sharded
-    state use parallel.mesh.refill_lanes_sharded (same contract, merge
-    routed through the shard_map'd splice)."""
-    fresh, mask = _refill_fresh(
-        params, state, new_roots, lane_idx, depth, node_budget,
-        variant=variant, hist_hash=hist_hash, hist_halfmove=hist_halfmove,
+    state use parallel.mesh.refill_lanes_sharded (same contract, the
+    splice routed through shard_map)."""
+    operands = _refill_inputs(
+        state, new_roots, lane_idx, depth, node_budget,
+        hist_hash=hist_hash, hist_halfmove=hist_halfmove,
         root_alpha=root_alpha, root_beta=root_beta,
         order_jitter=order_jitter, group=group,
     )
-    if fresh is None:
+    if operands is None:
         return state
-    return _merge_lanes_jit(state, fresh, jnp.asarray(mask))
+    return _splice_lanes_jit(params, state, *operands, variant=variant)
 
 
 def search_stream(

@@ -164,32 +164,43 @@ def run_segment_sharded(mesh: Mesh, params, state, ttab, segment_steps: int,
 
 
 @functools.lru_cache(maxsize=None)
-def _merge_callable(mesh: Mesh, axis: str):
-    """shard_map'd masked lane merge (ops/search._merge_lanes): the splice
-    is elementwise along the lane dim, so each shard merges its own slice
-    of the fresh state — values change, shapes and shardings never, and
-    the segment program keeps running with zero recompiles. Both inputs
-    are donated (the merge rebinds, never copies)."""
-    from ..ops.search import _merge_lanes
+def _splice_callable(mesh: Mesh, axis: str, variant: str):
+    """shard_map'd refill splice (ops/search._splice_lanes): init_state
+    and the masked select are per lane, so each shard rebuilds and
+    merges its own slice of the lanes from its slice of the width-B
+    operands — values change, shapes and shardings never, and the
+    segment program keeps running with zero recompiles. The running
+    state is donated (the splice rebinds, never copies)."""
+    from ..ops.search import _splice_lanes
 
-    in_specs, out_specs = _partition.merge_specs(axis)
+    # parameters spelled out: the AOT wrapper keys a call by binding it
+    # to this signature, and leaves a *args program plain JIT for good
+    def splice(params, state, roots, depth, node_budget, hist_hash,
+               hist_halfmove, root_alpha, root_beta, order_jitter, group,
+               mask):
+        return _splice_lanes(
+            params, state, roots, depth, node_budget, hist_hash,
+            hist_halfmove, root_alpha, root_beta, order_jitter, group,
+            mask, variant)
+
+    in_specs, out_specs = _partition.splice_specs(axis)
     fn = jax.shard_map(
-        _merge_lanes,
+        splice,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
         check_vma=False,
     )
     return _sanitize.guard_donation(
-        "parallel/mesh.py::mesh_merge",
+        "parallel/mesh.py::mesh_splice",
         _aot_registry.wrap(
-            "mesh_merge", jax.jit(fn, donate_argnums=(0, 1)), _merge_lanes,
+            "mesh_splice", jax.jit(fn, donate_argnums=(1,)), splice,
             extra_static={
                 "mesh": "x".join(str(d) for d in mesh.devices.shape),
-                "axis": axis,
+                "axis": axis, "variant": variant,
             },
         ),
-        argnums=(0, 1),
+        argnums=(1,),
     )
 
 
@@ -200,26 +211,24 @@ def refill_lanes_sharded(mesh: Mesh, params, state, new_roots, lane_idx,
                          order_jitter=None, group=None):
     """Splice replacement positions into DONE lanes of a SHARDED state.
 
-    Same contract as ops/search.refill_lanes, with the merge routed
-    through the shard_map'd masked splice: each device rewrites only its
-    own lanes, locally. `state` is donated (rebind to the return value).
-    lane_idx is global lane numbering — the host assigns lanes, the
-    shard split falls out of the sharding."""
-    from ..ops.search import _refill_fresh
+    Same contract as ops/search.refill_lanes, with the splice routed
+    through shard_map: the width-B operands go to the devices sharded
+    by lane, and each device rewrites only its own lanes, locally.
+    `state` is donated (rebind to the return value). lane_idx is global
+    lane numbering — the host assigns lanes, the shard split falls out
+    of the sharding."""
+    from ..ops.search import _refill_inputs
 
-    fresh, mask = _refill_fresh(
-        params, state, new_roots, lane_idx, depth, node_budget,
-        variant=variant, hist_hash=hist_hash, hist_halfmove=hist_halfmove,
+    operands = _refill_inputs(
+        state, new_roots, lane_idx, depth, node_budget,
+        hist_hash=hist_hash, hist_halfmove=hist_halfmove,
         root_alpha=root_alpha, root_beta=root_beta,
         order_jitter=order_jitter, group=group,
     )
-    if fresh is None:
+    if operands is None:
         return state
-    import jax.numpy as jnp
-
-    fresh = shard_batch(mesh, fresh, axis)
-    mask_dev = shard_batch(mesh, jnp.asarray(mask), axis)
-    return _merge_callable(mesh, axis)(state, fresh, mask_dev)
+    return _splice_callable(mesh, axis, variant)(
+        params, state, *shard_batch(mesh, operands, axis))
 
 
 def make_sharded_table(mesh: Mesh, size_log2: int):

@@ -335,11 +335,15 @@ def segment_specs(has_tt: bool, axis: str = "dp"):
     return in_specs, out_specs
 
 
-def merge_specs(axis: str = "dp"):
-    """(in_specs, out_specs) of the shard_map'd masked lane merge:
-    (state, fresh, mask) → state, everything lane-sharded."""
+def splice_specs(axis: str = "dp"):
+    """(in_specs, out_specs) of the shard_map'd refill splice:
+    (params, state, roots, depth, node_budget, hist_hash, hist_halfmove,
+    root_alpha, root_beta, order_jitter, group, mask) → state. The
+    weights are replicated; the state and every per-lane operand (the
+    root Board as one subtree) shard their leading, lane dim."""
     st = state_specs(axis)
-    return (st, st, spec_for("mask", axis)), st
+    lanes = spec_for("mask", axis)
+    return (param_specs(), st) + (lanes,) * 10, st
 
 
 def batch_spec(ndim: int, axis: str = "dp") -> P:

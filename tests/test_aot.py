@@ -342,7 +342,7 @@ def test_engine_pack_then_warm_boot_bit_identity(aot_root):
                 int(np.asarray(out["nodes"]).sum()))
 
     progs = (search_ops._run_segment_jit, search_ops._init_state_jit,
-             search_ops._merge_lanes_jit)
+             search_ops._splice_lanes_jit)
     registry.uninstall()
     ref = run_search(TpuEngine())
 

@@ -114,12 +114,15 @@ def test_segment_specs_equal_old_hand_built_literals(axis, has_tt):
 
 
 @pytest.mark.parametrize("axis", ["dp", "x"])
-def test_merge_specs_equal_old_hand_built_literals(axis):
-    in_specs, out_specs = PT.merge_specs(axis)
-    st, fresh, mask = in_specs
+def test_splice_specs_shard_every_per_lane_operand(axis):
+    """(params, state, roots, depth, node_budget, hist_hash,
+    hist_halfmove, root_alpha, root_beta, order_jitter, group, mask):
+    weights replicated, everything with a lane dim sharded over it."""
+    in_specs, out_specs = PT.splice_specs(axis)
+    p_params, st, *lanes = in_specs
+    assert all(s == P() for s in _leaves(p_params))
     assert all(s == P(axis) for s in _leaves(st))
-    assert all(s == P(axis) for s in _leaves(fresh))
-    assert mask == P(axis)
+    assert lanes == [P(axis)] * 10
     assert all(s == P(axis) for s in _leaves(out_specs))
 
 

@@ -382,3 +382,354 @@ def test_refill_never_corrupts_live_lanes():
             np.asarray(solo["score"])[0]), f"position {i} score diverged"
         assert int(np.asarray(stream["nodes"])[i]) == int(
             np.asarray(solo["nodes"])[0]), f"position {i} nodes diverged"
+
+
+# ------------------------------------------ the one-shape splice (PR 31)
+#
+# A boundary's admissions are staged on the host and spliced by one
+# program per state width and variant (ops/search.py _splice_lanes_jit),
+# whatever their number. The reference below is the splice as the
+# parent commit ran it: rows gathered to the width on the device, a
+# fresh _init_state_jit, a masked merge.
+
+SPLICE_PLY = 5
+# promotions at hand and both pockets full from the first ply on
+ZH_FENS = [
+    "6k1/PPPP4/8/8/8/8/pppp4/6K1[QRBNPqrbnp] w - - 0 1",
+    "r3k2r/1PP3P1/8/8/8/8/1pp3p1/R3K2R[QRqr] w KQkq - 0 1",
+]
+
+
+@pytest.fixture(scope="module")
+def splice_params():
+    import jax
+
+    from fishnet_tpu.models import nnue
+
+    return nnue.init_params(jax.random.PRNGKey(31), l1=64,
+                            feature_set="board768")
+
+
+def _splice_positions(variant, n):
+    """n positions of seeded playouts that like drops and promotions."""
+    import random
+
+    from fishnet_tpu.chess.variants import from_fen, position_class
+
+    rng = random.Random(31)
+    starts = (ZH_FENS if variant == "crazyhouse"
+              else [position_class(variant).starting_fen()])
+    out = []
+    while len(out) < n:
+        pos = from_fen(starts[len(out) % len(starts)], variant)
+        for _ in range(24):
+            legal = pos.legal_moves()
+            if not legal or pos.outcome() is not None:
+                break
+            loud = [m for m in legal
+                    if m.drop is not None or m.promotion is not None]
+            pos = pos.push(
+                rng.choice(loud if loud and rng.random() < 0.5 else legal))
+            out.append(pos)
+    return out[:n]
+
+
+def _running_state(params, width, variant, filler, seed):
+    """A width-lane state with every field random, as host arrays: a
+    lane that is not refilled has to come through bit for bit, and a
+    refilled one owes nothing to what was there."""
+    from fishnet_tpu.ops import search as S
+    from fishnet_tpu.ops.board import stack_fields
+
+    base = S._init_state_jit(
+        params, stack_fields([filler] * width), np.zeros(width, np.int32),
+        np.zeros(width, np.int32), SPLICE_PLY, variant)
+    rng = np.random.default_rng(seed)
+    return type(base)(*[
+        rng.integers(-2**20, 2**20, a.shape).astype(a.dtype) for a in base])
+
+
+def _admissions(rng, n, hist):
+    """Per-lane operands of n admissions, helpers among them (jitter
+    and a window on some rows), with or without history rows."""
+    from fishnet_tpu.ops import search as S
+
+    kw = dict(
+        root_alpha=rng.integers(-900, 0, n).astype(np.int32),
+        root_beta=rng.integers(1, 900, n).astype(np.int32),
+        order_jitter=(rng.integers(0, 4, n)
+                      * rng.integers(1, 60000, n)).astype(np.int32),
+        group=rng.integers(0, 64, n).astype(np.int32),
+    )
+    if hist:
+        kw["hist_hash"] = rng.integers(
+            0, 2**32, (n, S.MAX_HIST, 2), dtype=np.uint32)
+        kw["hist_halfmove"] = rng.integers(
+            0, 60, (n, S.MAX_HIST)).astype(np.int32)
+    return (rng.integers(1, 5, n).astype(np.int32),
+            rng.integers(1, 10**6, n).astype(np.int32), kw)
+
+
+def _parent_refill(params, state, roots, lane_idx, depth, budget, variant,
+                   hist_hash=None, hist_halfmove=None, root_alpha=None,
+                   root_beta=None, order_jitter=None, group=None):
+    """`refill_lanes` of the parent commit (`_refill_fresh` +
+    `_merge_lanes`), on host copies of `state`; → fields as numpy."""
+    import jax
+    import jax.numpy as jnp
+
+    from fishnet_tpu.ops import search as S
+
+    B, n = state.lane.shape[0], len(lane_idx)
+    take = np.zeros(B, np.int64)
+    take[lane_idx] = np.arange(n)
+    mask = np.zeros(B, bool)
+    mask[lane_idx] = True
+    tk = jnp.asarray(take)
+
+    def expand(x, fill, dtype, tail=()):
+        if x is None:
+            x = np.full((n,) + tail, fill, dtype)
+        return jnp.asarray(np.asarray(x))[tk]
+
+    fresh = S._init_state_jit(
+        params, jax.tree.map(lambda a: jnp.asarray(a)[tk], roots),
+        expand(depth, 0, np.int32), expand(budget, 0, np.int32),
+        state.bt.shape[1] - 1, variant,
+        hist_hash=expand(hist_hash, 0, np.uint32, (S.MAX_HIST, 2)),
+        hist_halfmove=expand(
+            hist_halfmove, S.HIST_HM_SENTINEL, np.int32, (S.MAX_HIST,)),
+        root_alpha=expand(root_alpha, -S.INF, np.int32),
+        root_beta=expand(root_beta, S.INF, np.int32),
+        order_jitter=expand(order_jitter, 0, np.int32),
+        group=expand(group, 0, np.int32),
+    )
+    return type(state)(*[
+        np.where(mask.reshape((B,) + (1,) * (old.ndim - 1)),
+                 np.asarray(new), old)
+        for old, new in zip(state, fresh)])
+
+
+def _assert_states_equal(got, want):
+    for name, g, w in zip(type(want)._fields, got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("hist", [False, True], ids=["nohist", "hist"])
+@pytest.mark.parametrize("variant", ["standard", "crazyhouse"])
+@pytest.mark.parametrize("width", [16, 64])
+@pytest.mark.parametrize("count", [1, 7, "B"])
+def test_splice_equals_parent_refill(splice_params, count, width, variant,
+                                     hist):
+    """The host-padded splice gives, field by field of SearchState, what
+    the parent's gather + init + merge gave: scattered lanes, helpers'
+    jitter and windows, pockets and promoted bits, history or none."""
+    import jax
+    import jax.numpy as jnp
+
+    from fishnet_tpu.ops import search as S
+    from fishnet_tpu.ops.board import (from_position, position_fields,
+                                       stack_boards, stack_fields)
+
+    n = width if count == "B" else count
+    rng = np.random.default_rng(width * 131 + n)
+    positions = _splice_positions(variant, n)
+    rows = [position_fields(p) for p in positions]
+    if variant == "crazyhouse" and n > 1:
+        extra = np.stack([r.extra for r in rows])
+        assert (extra[:, :10] > 0).any() and (extra[:, 10:] != 0).any()
+    lane_idx = rng.permutation(width)[:n]
+    depth, budget, kw = _admissions(rng, n, hist)
+    host_state = _running_state(splice_params, width, variant, rows[0], n)
+    want = _parent_refill(
+        splice_params, host_state,
+        stack_boards([from_position(p) for p in positions]), lane_idx,
+        depth, budget, variant, **kw)
+    got = S.refill_lanes(
+        splice_params, jax.tree.map(jnp.asarray, host_state),
+        stack_fields(rows), lane_idx, depth, budget, variant=variant, **kw)
+    _assert_states_equal(got, want)
+    # lanes that were not refilled: the running state's own rows
+    keep = np.setdiff1d(np.arange(width), lane_idx)
+    for g, old in zip(got, host_state):
+        np.testing.assert_array_equal(np.asarray(g)[keep], old[keep])
+
+
+@pytest.mark.parametrize("variant", ["standard", "crazyhouse"])
+def test_device_rows_take_the_same_splice(splice_params, variant,
+                                            monkeypatch):
+    """Operands that already live on the device (search_stream's roots,
+    the benchmark's warm-up board) are widened there and meet the same
+    program with the same result as host rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from fishnet_tpu.ops import search as S
+    from fishnet_tpu.ops.board import (from_position, position_fields,
+                                       stack_boards, stack_fields)
+
+    width, n = 16, 5
+    rng = np.random.default_rng(5)
+    positions = _splice_positions(variant, n)
+    rows = [position_fields(p) for p in positions]
+    lane_idx = rng.permutation(width)[:n]
+    depth, budget, kw = _admissions(rng, n, True)
+    host_state = _running_state(splice_params, width, variant, rows[0], 5)
+    from_host = S.refill_lanes(
+        splice_params, jax.tree.map(jnp.asarray, host_state),
+        stack_fields(rows), lane_idx, depth, budget, variant=variant, **kw)
+    # the program's body runs when it is traced, and only then
+    traced = []
+    merge = S._merge_lanes
+    monkeypatch.setattr(
+        S, "_merge_lanes", lambda *a: traced.append(1) or merge(*a))
+    from_device = S.refill_lanes(
+        splice_params, jax.tree.map(jnp.asarray, host_state),
+        stack_boards([from_position(p) for p in positions]), lane_idx,
+        jnp.asarray(depth), budget, variant=variant,
+        **{k: jnp.asarray(v) for k, v in kw.items()})
+    assert not traced
+    _assert_states_equal(from_device, from_host)
+
+
+def test_one_splice_program_whatever_the_count(splice_params):
+    """After one splice at a width, splices of five other counts build
+    or load no program: `compiles_refill` stays 0 on the engine the
+    thread serves, and the jitted entry holds one executable per width
+    and variant."""
+    import jax
+    import jax.numpy as jnp
+
+    from fishnet_tpu.ops import search as S
+    from fishnet_tpu.ops.board import position_fields, stack_fields
+
+    make_refill_engine(max_depth=2)  # installs the compile listener
+    entry = S._splice_lanes_jit.jit
+    tot = {f"compiles_{site}": 0 for site in COMPILE_SITES}
+    tot["compile_ms"] = 0.0
+    built = []
+    # widths no other test of this file splices at
+    for width, variant in ((24, "standard"), (24, "crazyhouse"),
+                           (40, "standard")):
+        rows = [position_fields(p)
+                for p in _splice_positions(variant, width)]
+        rng = np.random.default_rng(width)
+        state = jax.tree.map(jnp.asarray, _running_state(
+            splice_params, width, variant, rows[0], width))
+
+        def splice(state, n):
+            depth, budget, kw = _admissions(rng, n, n % 2 == 0)
+            return S.refill_lanes(
+                splice_params, state, stack_fields(rows[:n]),
+                rng.permutation(width)[:n], depth, budget,
+                variant=variant, **kw)
+
+        size = entry._cache_size()
+        state = splice(state, 3)
+        built.append(entry._cache_size() - size)
+        with syncstats.serving(tot), syncstats.step("refill"):
+            for n in (1, 2, 7, 11, width):
+                state = splice(state, n)
+            jax.block_until_ready(state)
+        assert entry._cache_size() == size + 1, (width, variant)
+    assert built == [1, 1, 1]
+    for site in COMPILE_SITES:
+        assert tot[f"compiles_{site}"] == 0, site
+    assert tot["compile_ms"] == 0.0
+
+
+def test_submit_stages_host_rows_and_makes_no_device_call():
+    """A job's root is host arrays (`position_fields`), so `_submit`
+    puts nothing on the device and runs nothing there: it passes under
+    a guard that refuses every host-to-device transfer, explicit ones
+    too, and builds no program."""
+    import jax
+
+    engine = make_refill_engine(max_depth=2)
+    tot = engine.occupancy_totals
+    chunk = make_chunk(analysis_work(depth=2), n_positions=4)
+    with syncstats.serving(tot):
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            entry = engine._scheduler._submit(chunk)
+    jobs = list(engine._scheduler._pending)
+    assert len(jobs) == entry.n_open == 4
+    for job in jobs:
+        assert type(job.board).__name__ == "Board"
+        for x in tuple(job.board) + (job.hh, job.hm):
+            assert isinstance(x, (np.ndarray, np.generic)), type(x)
+            assert not isinstance(x, jax.Array)
+    for site in COMPILE_SITES:
+        assert tot[f"compiles_{site}"] == 0, site
+    # the guard itself bites: the device form of the same root raises
+    from fishnet_tpu.ops.board import from_position
+
+    with pytest.raises(Exception, match="[Dd]isallowed"):
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            from_position(jobs[0].pos)
+    # and the queued jobs still run to their answers
+    engine._scheduler._pending.clear()
+    responses = run(engine, make_chunk(analysis_work(depth=2), 4))
+    assert [r.position_index for r in responses] == [0, 1, 2, 3]
+
+
+def test_refill_splices_tie_out():
+    """`refill_splices` counts the boundaries that spliced and `refills`
+    the lanes they spliced: both tie out with the log's `refilled`, and
+    with the `lanes` argument of the `phase.refill` spans."""
+    rec = obs_trace.install(obs_trace.TraceRecorder(capacity=16384))
+    try:
+        engine = make_refill_engine(max_depth=3)
+        run(engine, make_chunk(analysis_work(depth=3), n_positions=4))
+        run(engine, make_chunk(analysis_work(depth=2), n_positions=2))
+    finally:
+        obs_trace.uninstall()
+    tot, log = engine.occupancy_totals, engine.occupancy_log
+    assert tot["refill_splices"] == sum(1 for r in log if r["refilled"])
+    assert tot["refills"] == sum(r["refilled"] for r in log)
+    assert 0 < tot["refill_splices"] < tot["refills"]
+    lanes = [e["args"]["lanes"] for e in rec.snapshot()
+             if e["name"] == "phase.refill"]
+    assert sum(lanes) == tot["refills"]
+    assert sum(1 for n in lanes if n) == tot["refill_splices"]
+    # tools/trace_report.py prints the phase by the same three numbers
+    from tools import trace_report
+
+    refill = trace_report.summarize(rec.snapshot())["refill"]
+    assert refill["splices"] == tot["refill_splices"]
+    assert refill["lanes"] == tot["refills"]
+    assert refill["ms_per_splice"] * refill["splices"] == pytest.approx(
+        tot["phase_refill_ms"], rel=0.01)
+    assert tot["compiles_refill"] <= 1  # the first splice's own program
+
+
+@pytest.mark.parametrize("variant", ["standard", "crazyhouse"])
+def test_sharded_splice_matches_single_device(splice_params, variant):
+    """The shard_map'd splice on the 8-device CPU mesh (each device
+    rebuilds and merges its own two lanes) is bit-identical to the
+    single-device one, and its result stays sharded by lane."""
+    import jax
+    import jax.numpy as jnp
+
+    from fishnet_tpu.ops import search as S
+    from fishnet_tpu.ops.board import position_fields, stack_fields
+    from fishnet_tpu.parallel.mesh import (make_mesh, refill_lanes_sharded,
+                                           shard_batch)
+
+    mesh = make_mesh()
+    assert mesh.devices.size == 8  # conftest's virtual devices
+    width, n = 16, 7
+    rng = np.random.default_rng(8)
+    rows = [position_fields(p) for p in _splice_positions(variant, n)]
+    lane_idx = rng.permutation(width)[:n]
+    assert len(set(lane_idx // 2)) > 3  # several shards take part
+    depth, budget, kw = _admissions(rng, n, True)
+    host_state = _running_state(splice_params, width, variant, rows[0], 8)
+    single = S.refill_lanes(
+        splice_params, jax.tree.map(jnp.asarray, host_state),
+        stack_fields(rows), lane_idx, depth, budget, variant=variant, **kw)
+    sharded = refill_lanes_sharded(
+        mesh, splice_params, shard_batch(mesh, host_state),
+        stack_fields(rows), lane_idx, depth, budget, variant=variant, **kw)
+    _assert_states_equal(sharded, single)
+    assert len(sharded.lane.sharding.device_set) == 8

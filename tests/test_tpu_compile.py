@@ -154,6 +154,26 @@ def test_merge_lanes_compiles(one_chip, params):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("variant", ["standard", "crazyhouse"])
+def test_splice_lanes_compiles(one_chip, params, variant):
+    """The refill splice (init_state at the state's width + the masked
+    merge, one program, the running state donated) at the width of the
+    `.backlog` sessions, for the chip."""
+    state = _state_shape(params, BUCKET, variant)
+    roots, depth, budget = _init_args(BUCKET)
+    hist = jnp.zeros((BUCKET, S.MAX_HIST, 2), jnp.uint32)
+    lanes = jnp.zeros(BUCKET, jnp.int32)
+    fn = jax.jit(S._splice_lanes, static_argnames=("variant",),
+                 donate_argnums=(1,))
+    compiled = fn.lower(
+        _on(params, one_chip), _on(state, one_chip),
+        *_on((roots, depth, budget, hist, hist[:, :, 0].astype(jnp.int32),
+              lanes, lanes, lanes, lanes, lanes != 0), one_chip),
+        variant=variant,
+    ).compile()
+    _fits(compiled)
+
+
 def test_sharded_segment_compiles_on_four_chips(topo, params):
     """The shard_map'd segment of parallel/mesh.py on a 4-device mesh,
     placed by the partition-rule registry's own specs."""

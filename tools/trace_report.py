@@ -14,7 +14,11 @@ measurement items need without opening Perfetto:
 - **boundary phases**: the segment time by what the host was doing —
   the scheduler's `phase.*` spans (self time, fetches inside taken
   out), `wait` (the `fetch` spans) and `other` (what no phase covered,
-  from the args): count, total, share of segment time.
+  from the args): count, total, share of segment time; under it the
+  refill phase by what it did — splices (boundaries that spliced),
+  lanes a splice and ms a splice, from the `lanes` argument of the
+  `phase.refill` spans (the engine's `refill_splices`, `refills` and
+  `phase_refill_ms` are the same three over a window).
 - **sessions**: one row per `session` span — the width the drive
   session got, what was pending when it chose, set-up, segments.
 - **boundary-gap histogram**: the distribution of gaps between
@@ -110,15 +114,23 @@ def summarize(events: List[dict]) -> dict:
     boundary: Dict[str, dict] = defaultdict(
         lambda: {"count": 0, "total_ms": 0.0}
     )
+    # the refill phase by what it spliced: `phase.refill` carries the
+    # lanes staged at its boundary (0: nothing to splice), which sum to
+    # the engine's `refills` over its `refill_splices`
+    splices = lanes_spliced = 0
     for e in spans:
         name = str(e.get("name"))
         if name.startswith("phase."):
             # self time: a phase that fetched says so in its args
-            self_ms = (e.get("args") or {}).get(
+            args = e.get("args") or {}
+            self_ms = args.get(
                 "self_ms", float(e.get("dur", 0.0)) / 1000.0)
             row = boundary[name[len("phase."):]]
             row["count"] += 1
             row["total_ms"] += float(self_ms)
+            if name == "phase.refill" and args.get("lanes"):
+                splices += 1
+                lanes_spliced += int(args["lanes"])
     span_host = sum(row["total_ms"] for row in boundary.values())
     span_device = per_name.get("fetch", {}).get("total_ms", 0.0)
     if span_device:
@@ -201,6 +213,15 @@ def summarize(events: List[dict]) -> dict:
             for name, row in sorted(
                 boundary.items(), key=lambda kv: -kv[1]["total_ms"]
             )
+        },
+        "refill": {
+            "splices": splices,
+            "lanes": lanes_spliced,
+            "lanes_per_splice": round(lanes_spliced / splices, 2)
+            if splices else 0.0,
+            "ms_per_splice": round(
+                boundary["refill"]["total_ms"] / splices, 3)
+            if splices else 0.0,
         },
         "sessions": sessions,
         "boundary_gaps": {
@@ -484,6 +505,12 @@ def render_text(report: dict) -> str:
                 f"{name:<24} {row['count']:>7} {row['total_ms']:>12.3f} "
                 f"{row['share']:>6.1%}"
             )
+    refill = report["refill"]
+    if refill["splices"]:
+        lines.append(
+            f"refill: {refill['splices']} splices of "
+            f"{refill['lanes_per_splice']:.1f} lanes, "
+            f"{refill['ms_per_splice']:.3f}ms a splice")
     if report["sessions"]:
         lines += [
             "",
