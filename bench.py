@@ -166,7 +166,6 @@ def _bench_refill(t0: float, params, B: int, depth: int, budget: int,
     import numpy as np
 
     from fishnet_tpu.ops import search as S
-    from fishnet_tpu.utils import settings
 
     seg = int(os.environ.get("BENCH_SEG", "1024"))
     roots, N = _all_boards_for(B, variant, fen_set)
@@ -217,15 +216,14 @@ def _bench_refill(t0: float, params, B: int, depth: int, budget: int,
             "segments": len(occ),
             "refills": out["refills"],
             "mean_live_frac": round(live_steps / lane_steps, 4),
-            # segment-pipeline A/B columns (round 8): the host/device
-            # wall-clock split of every boundary interval and the
-            # transfer count (utils/syncstats.py via search_stream)
+            # the host/device wall-clock split of every boundary
+            # interval and the transfer count (utils/syncstats.py via
+            # search_stream)
             "host_ms": round(host_ms, 1),
             "device_ms": round(device_ms, 1),
             "boundary_share": round(
                 host_ms / max(host_ms + device_ms, 1e-9), 4),
             "transfers": sum(o["transfers"] for o in occ),
-            "pipeline": int(settings.get_bool("FISHNET_TPU_PIPELINE")),
         }
         if mesh is not None:
             # per-shard mean live fraction (shard_live columns from
@@ -1520,8 +1518,7 @@ def mesh_scaling_child(ndev: int) -> None:
     params = nnue.init_params(
         jax.random.PRNGKey(3), l1=32, feature_set="board768")
     mesh = make_mesh(ndev)
-    kw = dict(max_ply=6, width=width, segment_steps=30, mesh=mesh,
-              pipeline=True)
+    kw = dict(max_ply=6, width=width, segment_steps=30, mesh=mesh)
 
     # warmup: the SAME shapes (compilation is shape-keyed) at a budget
     # low enough to drain in seconds — still deep enough to fire refill
@@ -1941,26 +1938,13 @@ def main() -> None:
             ("production_d6_mp32_serial", 192, 6, "standard", "multipv",
              {"BENCH_MAX_PLY": "32", "BENCH_NET": "default",
               "BENCH_TT_LOG2": "21", "BENCH_REFILL": "0"}),
-            # FISHNET_TPU_PIPELINE pinned OFF: this row stays the
-            # round-7 synchronous-boundary baseline for the pipelined
-            # row below (same workload, same width, same refill path)
+            # the stream row: packed boundary summaries, donated
+            # segment buffers and speculative next-segment dispatch
+            # (ops/search.py search_stream); host_ms / device_ms /
+            # transfers in its occupancy summary
             ("production_d6_mp32_refill", 192, 6, "standard", "multipv",
              {"BENCH_MAX_PLY": "32", "BENCH_NET": "default",
-              "BENCH_TT_LOG2": "21", "BENCH_REFILL": "1",
-              "FISHNET_TPU_PIPELINE": "0"}),
-            # asynchronous segment pipeline A/B (round 8): identical
-            # stream workload with double-buffered dispatch — packed
-            # boundary summaries, donated segment buffers and
-            # speculative next-segment dispatch (ops/search.py
-            # search_stream pipeline=True). Compare host_ms /
-            # device_ms / transfers in the occupancy summary against
-            # the _refill row; acceptance is >=1.2x positions_done_per_s
-            # at the identical node total on the toy CPU shape
-            ("production_d6_mp32_pipelined", 192, 6, "standard",
-             "multipv",
-             {"BENCH_MAX_PLY": "32", "BENCH_NET": "default",
-              "BENCH_TT_LOG2": "21", "BENCH_REFILL": "1",
-              "FISHNET_TPU_PIPELINE": "1"}),
+              "BENCH_TT_LOG2": "21", "BENCH_REFILL": "1"}),
             # mesh parity A/B (round 10): the production refill workload
             # sharded over 8 devices (XLA_FLAGS forces 8 virtual CPU
             # devices when no real mesh is present; on a TPU pod slice

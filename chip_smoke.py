@@ -61,8 +61,9 @@ GAME_B = (
 ).split()
 SKIP_PLIES = (3,)  # one skipped position, as lichess sends them
 
-# what tests/conftest.py sets so the suite survives XLA:CPU; none of
-# these may be in force on the chip
+# what tests/conftest.py sets so the suite survives XLA:CPU (CPU_SHRINK)
+# and the other switches that narrow the engine; none of these may be in
+# force on the chip
 SHRINKERS = (
     "FISHNET_TPU_MAX_PLY", "FISHNET_TPU_WARMUP_BUCKETS",
     "FISHNET_TPU_HELPERS", "FISHNET_TPU_REFILL", "FISHNET_TPU_MAX_LANES",
@@ -70,7 +71,7 @@ SHRINKERS = (
 )
 CPU_SHRINK = {
     "FISHNET_TPU_MAX_PLY": "8", "FISHNET_TPU_WARMUP_BUCKETS": "16",
-    "FISHNET_TPU_HELPERS": "1", "FISHNET_TPU_REFILL": "0",
+    "FISHNET_TPU_HELPERS": "1",
 }
 PRODUCTION = {"max_ply": 32, "helpers": 4, "refill": True, "max_lanes": 1024}
 PRODUCTION_BUCKETS = [16, 64, 128, 256]

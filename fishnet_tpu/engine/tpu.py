@@ -42,7 +42,7 @@ from ..ops.search import INF, MATE, search_batch_resumable
 from ..utils import sanitize
 from ..utils import settings
 from ..utils import syncstats
-from ..utils.syncstats import SegmentController, SyncStats
+from ..utils.syncstats import SyncStats
 from .base import EngineError, require_accelerator
 from .session import ChunkSubmit
 
@@ -1859,12 +1859,12 @@ class LaneScheduler:
 
     def _drive_session(self, entry: _ChunkEntry, lock_req_s: float) -> None:
         """One fixed-width drive session: admit, dispatch segments,
-        process boundaries, until no lane is running. Jobs of OTHER
-        device variants stay queued (each variant is a distinct static
-        program); a later session picks them up."""
+        process boundaries, until no lane is running (`_Session.run`).
+        Jobs of OTHER device variants stay queued (each variant is a
+        distinct static program); a later session picks them up."""
         eng = self.engine
         tot = eng.occupancy_totals
-        t_enter = now = time.monotonic()
+        t_enter = time.monotonic()
         with self._q_lock:
             if not self._pending:
                 return
@@ -1880,804 +1880,32 @@ class LaneScheduler:
             session_args = {"n_hint": n_hint, "pending": len(self._pending),
                             "variant": variant}
         at_start = (tot["segments"], tot["steps"], tot["positions_done"])
-        K = eng.helper_lanes
         B = eng._helper_width(min(max(n_hint, 1), eng.max_lanes))
-        # shard-aware session: under a mesh the SAME loop drives the
-        # shard_map'd segment/refill callables (parallel/mesh.py) — B is
-        # padded to a multiple of n_dev by _helper_width, each device
-        # owns `local` consecutive lanes, and every boundary is one
-        # stacked-summary fetch
-        mesh = eng.mesh
-        n_shard = eng.n_dev if mesh is not None else 1
-        local = B // n_shard
-        # mesh-topology-aware admission: free lists index GLOBAL shards
-        # (lane numbering spans the whole pod) but new work is admitted
-        # only into shards whose device this process can address — on a
-        # single-host mesh that is every shard, so the historical
-        # assignment is unchanged bit-for-bit
-        if mesh is not None:
-            from ..parallel import distributed as _dist
-
-            fillable_shards = set(_dist.addressable_shards(mesh))
-        else:
-            fillable_shards = {0}
-        seg = settings.get_segment()
-        ctrl = None
-        if seg is None:  # FISHNET_TPU_SEGMENT=auto
-            ctrl = SegmentController(
-                settings.get_int("FISHNET_TPU_SEGMENT_MIN"),
-                settings.get_int("FISHNET_TPU_SEGMENT_MAX"),
-            )
-            seg = ctrl.steps
-        pipeline = settings.get_bool("FISHNET_TPU_PIPELINE")
-        prefer_deep = K > 1 and eng.tt is not None
-        deltas = ASPIRATION_DELTAS + (None,)  # None = full window
-
-        # host-side lane tables
-        lane_job: List[Optional[_RefillJob]] = [None] * B  # primary owner
-        lane_owner: List[Optional[_RefillJob]] = [None] * B  # helper owner
-        lane_alpha = np.full(B, -INF, np.int64)
-        lane_beta = np.full(B, INF, np.int64)
-        gen = np.zeros(B, np.int32)
-        active: List[_RefillJob] = []
-
-        # idle base state: budget-0 lanes park in DONE within two steps.
-        # Built from host rows, like every refill after it: one call
-        # whose operands are numpy at the session's width
-        from ..ops.search import HIST_HM_SENTINEL, MAX_HIST
-
-        with syncstats.step("session_setup"):
-            state = search_ops._init_state_jit(
-                eng.params, stack_fields([filler] * B),
-                np.zeros(B, np.int32), np.zeros(B, np.int32),
-                MAX_PLY, variant,
-                hist_hash=np.zeros((B, MAX_HIST, 2), np.uint32),
-                hist_halfmove=np.full(
-                    (B, MAX_HIST), HIST_HM_SENTINEL, np.int32
-                ),
-                root_alpha=np.full((B,), -INF, np.int32),
-                root_beta=np.full((B,), INF, np.int32),
-                order_jitter=np.zeros((B,), np.int32),
-                group=np.zeros((B,), np.int32),
-            )
-            if mesh is not None:
-                from ..parallel.mesh import (
-                    refill_lanes_sharded,
-                    run_segment_sharded,
-                    shard_batch,
-                )
-
-                # place the base state sharded before the first
-                # dispatch: the sharded segment donates its operands,
-                # and donation only takes when the input already
-                # carries the sharding
-                state = shard_batch(mesh, state)
-        tt = eng.tt
-
-        # admissions accumulated between boundaries as host rows, flushed
-        # as ONE refill_lanes call before each dispatch
-        adm: dict = {k: [] for k in (
-            "lane", "board", "depth", "budget", "alpha", "beta",
-            "jitter", "group", "hh", "hm",
-        )}
-
-        def window_for(job: _RefillJob, scale: int):
-            """Per-lane mirror of _search_windowed's window: narrow
-            around the previous depth's score, widening per failed
-            attempt, full-width first at depth 1 / after a mate score."""
-            use_win = (
-                job.have_prev
-                and abs(job.prev_score) < MATE - 1000
-                and job.depth >= 2
-            )
-            delta = deltas[min(job.delta_idx, len(deltas) - 1)]
-            if not use_win or delta is None:
-                return -INF, INF, None
-            return (
-                max(job.prev_score - delta * scale, -INF),
-                min(job.prev_score + delta * scale, INF),
-                delta,
-            )
-
-        def admit(lane, board, depth, budget, alpha, beta, jit, grp,
-                  hh, hm):
-            adm["lane"].append(lane)
-            adm["board"].append(board)
-            adm["depth"].append(depth)
-            adm["budget"].append(int(np.clip(budget, 1, 2**31 - 1)))
-            adm["alpha"].append(alpha)
-            adm["beta"].append(beta)
-            adm["jitter"].append(jit)
-            adm["group"].append(grp)
-            adm["hh"].append(hh)
-            adm["hm"].append(hm)
-            lane_alpha[lane] = alpha
-            lane_beta[lane] = beta
-            # fresh TT generation per admission: depth-preferred
-            # replacement must never protect the lane's previous
-            # occupant's entries (ops/tt.py store)
-            eng._tt_gen = (eng._tt_gen + 1) & 0x3FFFFFFF
-            gen[lane] = eng._tt_gen
-
-        def admit_primary(job: _RefillJob, lane: int):
-            job.lane = lane
-            lane_job[lane] = job
-            wp = job.wp
-            if wp.ctx:
-                obs_inflight.REGISTRY.position(
-                    wp.ctx.get("trace_id"), wp.position_index or 0,
-                    "lane", lane=lane,
-                )
-            if job.traced:
-                job.t_spliced = time.monotonic()
-                rec = obs_trace.RECORDER
-                if rec is not None:
-                    rec.instant(
-                        "position.spliced", "request",
-                        **obs_trace.ctx_args(
-                            wp.ctx, position_index=wp.position_index,
-                            lane=lane,
-                        ),
-                    )
-                    rec.flow("request", wp.ctx["trace_id"], "t")
-            a, b, _delta = window_for(job, 1)
-            admit(lane, job.board, job.depth, job.remaining, a, b,
-                  0, lane, job.hh, job.hm)
-
-        def admit_helper(job: _RefillJob, lane: int, h: int):
-            # same layout as _analyse_single: odd h at the primary's
-            # depth (exact-depth TT entries consumable THIS iteration),
-            # even h one ply deeper; staggered window scale; nonzero
-            # unique jitter; group = primary lane
-            job.helpers[lane] = h
-            lane_owner[lane] = job
-            self._jitter_seq = (self._jitter_seq & 0xFFFF) + 1
-            a, b, _delta = window_for(job, 1 << min(h, 4))
-            d = min(job.depth + (1 - (h & 1)), job.target_depth)
-            admit(lane, job.board, d, job.remaining, a, b,
-                  self._jitter_seq, job.lane, job.hh, job.hm)
-
-        def release(job: _RefillJob, nodes_row):
-            """Free the job's primary + helper lanes; mid-flight helper
-            work is charged at its last-boundary node count (nodes_row:
-            the latest boundary's (B,) per-lane node counts — the work
-            actually spent against the position's budget, same honesty
-            rule as _analyse_single's helper charging)."""
-            if job.lane >= 0:
-                lane_job[job.lane] = None
-                job.lane = -1
-            for hl in list(job.helpers):
-                if nodes_row is not None:
-                    hn = int(nodes_row[hl])
-                    job.nodes_total += hn
-                    job.remaining -= hn
-                lane_owner[hl] = None
-            job.helpers.clear()
-
-        def on_primary_done(job: _RefillJob, lane: int, res: dict,
-                            now: float):
-            """One primary lane parked in DONE: fail-low/high re-search,
-            next depth, or finalize — the per-lane equivalent of one
-            `_search_windowed` attempt boundary. The fail checks and the
-            widening schedule mirror that method exactly, so with no TT
-            a refilled lane's score chain is bit-identical to the
-            serial path's."""
-            score = int(res["score"][lane])
-            nodes = int(res["nodes"][lane])
-            job.nodes_depth += nodes
-            a_w = int(lane_alpha[lane])
-            b_w = int(lane_beta[lane])
-            fail_lo = score <= a_w and a_w > -INF
-            fail_hi = score >= b_w and b_w < INF
-            delta = deltas[min(job.delta_idx, len(deltas) - 1)]
-            if a_w > -INF or b_w < INF:
-                # same per-delta accounting as _search_windowed
-                st = eng.aspiration_stats.setdefault(delta, [0, 0, 0, 0])
-                st[0] += 1
-                st[1] += int(fail_lo)
-                st[2] += int(fail_hi)
-                st[3] += nodes
-            if (fail_lo or fail_hi) and delta is not None:
-                # re-search the same depth with the next wider window;
-                # the lane stays this job's — only its window changes
-                job.delta_idx += 1
-                a, b, _d = window_for(job, 1)
-                admit(lane, job.board, job.depth, job.remaining, a, b,
-                      0, lane, job.hh, job.hm)
-                return
-            # depth complete: record, charge the depth's nodes, advance
-            job.prev_score = score
-            job.have_prev = True
-            job.hardness = max(nodes, 1)
-            job.nodes_total += job.nodes_depth
-            job.remaining -= job.nodes_depth
-            job.nodes_depth = 0
-            job.delta_idx = 0
-            job.scores.set(1, job.depth, _score_from_int(score))
-            pv = [
-                _decode_uci(int(m))
-                for m in res["pv"][lane][: int(res["pv_len"][lane])]
-                if m >= 0
-            ]
-            job.pvs.set(1, job.depth, pv)
-            job.depth_reached = job.depth
-            mv = int(res["move"][lane])
-            job.best_move = _decode_uci(mv) if mv >= 0 else None
-            if (
-                job.depth >= job.target_depth
-                or job.remaining <= 0
-                or now >= job.deadline
-            ):
-                release(job, res["nodes"])
-                active.remove(job)
-                self._finalize(job, now)
-                return
-            job.depth += 1
-            a, b, _d = window_for(job, 1)
-            admit(lane, job.board, job.depth, job.remaining, a, b,
-                  0, lane, job.hh, job.hm)
-
-        # pipelined boundary state: PV pulls deferred past speculative
-        # boundaries as (job, lane, depth, final) — the PV row is the
-        # one per-lane result NOT in the packed summary
-        pv_pending: List[tuple] = []
-        last_device_s = 0.0
-
-        def q_len_locked() -> int:
-            with self._q_lock:
-                return len(self._pending)
-
-        def traced_snapshot():
-            """(ctx, lane, position_index) for every sampled job resident
-            in this segment — captured at dispatch, because by the time
-            the boundary is processed jobs may have parked/finalized."""
-            if obs_trace.RECORDER is None:
-                return ()
-            return [
-                (j.wp.ctx, j.lane, j.wp.position_index)
-                for j in active if j.traced
-            ]
-
-        def traced_residency(snapshot, t0_s: float, t1_s: float):
-            """Retroactive per-position residency spans for one segment:
-            which lanes a request's positions occupied while the device
-            ran — the finest grain of the request waterfall."""
-            rec = obs_trace.RECORDER
-            if rec is None:
-                return
-            for ctx, lane, idx in snapshot:
-                rec.complete(
-                    "segment.residency", t0_s * 1e6,
-                    (t1_s - t0_s) * 1e6, cat="request",
-                    args=obs_trace.ctx_args(
-                        ctx, lane=lane, position_index=idx
-                    ),
-                )
-
-        if mesh is not None:
-            def dispatch(st, table, n_steps):
-                # donates st and table (parallel/mesh.py): both handles
-                # are dead after this call — always rebind to the
-                # outputs. Each device advances its shard locally; the
-                # summary arrives stacked (n_shard, local+1, 4).
-                return run_segment_sharded(
-                    mesh, eng.params, st, table, n_steps,
-                    variant=variant, prefer_deep=prefer_deep,
-                    tt_gen=jnp.asarray(gen),
-                )
-        else:
-            def dispatch(st, table, n_steps):
-                # donates st and table (ops/search.py): both handles are
-                # dead after this call — always rebind to the outputs
-                return search_ops._run_segment_jit(
-                    eng.params, st, table, n_steps, variant, False,
-                    prefer_deep, jnp.asarray(gen),
-                )
-
-        def canon_summ(raw):
-            """Boundary summary → ((B, 4) lane rows, step count,
-            per-shard step list). Single-device summaries are (B+1, 4);
-            sharded ones come back stacked (n_shard, local+1, 4) and
-            the step count is the max over shards (devices park
-            independently)."""
-            if mesh is None:
-                return raw[:B], int(raw[B, search_ops.SUM_DONE]), None
-            lanes = raw[:, :local, :].reshape(B, search_ops.SUM_W)
-            shard_steps = [
-                int(x) for x in raw[:, local, search_ops.SUM_DONE]
-            ]
-            return lanes, max(shard_steps), shard_steps
-
-        def count_movegen(raw):
-            """The summary's last rows hold what the segment's loop
-            counted (summed over shards): into the interval's snapshot."""
-            stats.count(search_ops.movegen_counts(
-                raw[B] if mesh is None else raw[:, local]))
-
-        def shard_occup():
-            """Busy (primary or helper) lane count per shard, or None
-            off-mesh — the per-shard occupancy column of the log."""
-            if mesh is None:
-                return None
-            return [
-                sum(
-                    1 for i in range(s * local, (s + 1) * local)
-                    if lane_job[i] is not None or lane_owner[i] is not None
-                )
-                for s in range(n_shard)
-            ]
-
-        def on_primary_parked(job: _RefillJob, lane: int, score: int,
-                              move: int, nodes: int, nodes_row,
-                              now: float):
-            """Summary-only twin of on_primary_done for the pipelined
-            loop: the aspiration verdict and all bookkeeping come from
-            the packed boundary summary; the PV row is deferred to
-            flush_pv, which reads it from the next RESOLVED state —
-            legal because a DONE lane is frozen until the refill splice
-            that flush_pv always precedes."""
-            job.nodes_depth += nodes
-            a_w = int(lane_alpha[lane])
-            b_w = int(lane_beta[lane])
-            fail_lo = score <= a_w and a_w > -INF
-            fail_hi = score >= b_w and b_w < INF
-            delta = deltas[min(job.delta_idx, len(deltas) - 1)]
-            if a_w > -INF or b_w < INF:
-                st = eng.aspiration_stats.setdefault(delta, [0, 0, 0, 0])
-                st[0] += 1
-                st[1] += int(fail_lo)
-                st[2] += int(fail_hi)
-                st[3] += nodes
-            if (fail_lo or fail_hi) and delta is not None:
-                job.delta_idx += 1
-                a, b, _d = window_for(job, 1)
-                admit(lane, job.board, job.depth, job.remaining, a, b,
-                      0, lane, job.hh, job.hm)
-                return
-            job.prev_score = score
-            job.have_prev = True
-            job.hardness = max(nodes, 1)
-            job.nodes_total += job.nodes_depth
-            job.remaining -= job.nodes_depth
-            job.nodes_depth = 0
-            job.delta_idx = 0
-            job.scores.set(1, job.depth, _score_from_int(score))
-            job.depth_reached = job.depth
-            job.best_move = _decode_uci(move) if move >= 0 else None
-            final = (
-                job.depth >= job.target_depth
-                or job.remaining <= 0
-                or now >= job.deadline
-            )
-            pv_pending.append((job, lane, job.depth, final))
-            if final:
-                release(job, nodes_row)
-                active.remove(job)
-                return  # _finalize waits in flush_pv for the PV row
-            job.depth += 1
-            a, b, _d = window_for(job, 1)
-            admit(lane, job.board, job.depth, job.remaining, a, b,
-                  0, lane, job.hh, job.hm)
-
-        def flush_pv(st, now: float):
-            """Materialize deferred PV rows from a resolved state, then
-            finalize the jobs whose response waited only on the PV. The
-            whole (B, max_ply) root-PV block and its (B,) lengths come
-            home — a few kB whose shapes are the session's, not the
-            count's — and the rows owed are picked on the host. Must run
-            BEFORE flush_adm: a refill splice resets the spliced lanes'
-            PV tables."""
-            if not pv_pending:
-                return
-            pv_rows = stats.fetch(st.pv[:, 0], "pv")
-            pv_lens = stats.fetch(
-                st.nt[:, 0, search_ops.NT_PVLEN], "pv_len")
-            for job, lane, depth, final in pv_pending:
-                pv = [
-                    _decode_uci(int(m))
-                    for m in pv_rows[lane][: int(pv_lens[lane])]
-                    if m >= 0
-                ]
-                job.pvs.set(1, depth, pv)
-                if final:
-                    self._finalize(job, now)
-            pv_pending.clear()
-
-        def reap_jobs(now: float, nodes_row):
-            # ---- reap jobs past their chunk deadline
-            for job in list(active):
-                if now >= job.deadline:
-                    release(job, nodes_row)
-                    active.remove(job)
-                    if pv_pending:
-                        # the response built below holds job.pvs BY
-                        # REFERENCE: a deferred pull landing after it
-                        # would mutate an already-sent response
-                        pv_pending[:] = [
-                            e for e in pv_pending if e[0] is not job
-                        ]
-                    if job.depth_reached == 0:
-                        # no usable result: fail the chunk so the
-                        # server reassigns it (same contract as the
-                        # serial path)
-                        self._finalize(
-                            job, now,
-                            error="chunk deadline expired before "
-                                  "depth 1 completed",
-                        )
-                    else:
-                        self._finalize(job, now)
-
-        def admit_new(now: float):
-            # ---- admit pending positions, earliest deadline first.
-            # Free lanes are tracked per shard and every admission lands
-            # on the shard with the most free lanes (ties → lowest
-            # shard), hardest-deadline-first within the boundary, so
-            # queued positions spread across devices instead of piling
-            # onto shard 0's early lanes. With one shard this is exactly
-            # the historical ascending-lane assignment (one list, front
-            # pops) — the single-device bit-identity contract holds.
-            free_by_shard: List[List[int]] = [[] for _ in range(n_shard)]
-            for i in range(B):
-                if lane_job[i] is None and lane_owner[i] is None:
-                    if (i // local) in fillable_shards:
-                        free_by_shard[i // local].append(i)
-            n_free = sum(len(f) for f in free_by_shard)
-
-            def take_lane() -> int:
-                s = max(
-                    range(n_shard), key=lambda i: len(free_by_shard[i])
-                )
-                return free_by_shard[s].pop(0)
-
-            if not entry.event.is_set():
-                with self._q_lock:
-                    self._pending.sort(key=lambda j: j.deadline)
-                    take: List[_RefillJob] = []
-                    for j in list(self._pending):
-                        if len(take) >= n_free:
-                            break
-                        if j.variant != variant:
-                            continue
-                        self._pending.remove(j)
-                        take.append(j)
-                for job in take:
-                    if now >= job.deadline:
-                        self._finalize(
-                            job, now,
-                            error="chunk deadline expired before "
-                                  "depth 1 completed",
-                        )
-                        continue
-                    admit_primary(job, take_lane())
-                    n_free -= 1
-                    active.append(job)
-            # ---- spend leftover free lanes on Lazy-SMP helpers
-            if K > 1 and tt is not None and n_free and active:
-                n_act = len(active)
-                cur = sum(len(j.helpers) for j in active)
-                hardness = [
-                    j.hardness if j.remaining > 0 else 0
-                    for j in active
-                ]
-                plan = TpuEngine._plan_helpers(
-                    n_act, n_act + cur + n_free, K, hardness
-                )
-                want: dict = {}
-                for r, _h in plan:
-                    want[r] = want.get(r, 0) + 1
-                for r, job in enumerate(active):
-                    while n_free and len(job.helpers) < want.get(r, 0):
-                        admit_helper(
-                            job, take_lane(), len(job.helpers) + 1
-                        )
-                        n_free -= 1
-
-        def flush_adm(st):
-            # ---- flush staged admissions in ONE refill splice (donates
-            # st — rebind to the return value): the staged host rows go
-            # to the one splice program of this width, whatever their
-            # number; under a mesh it runs through shard_map, each
-            # device rewriting only its own lanes. Returns (state,
-            # count, per-shard admission counts or None).
-            n_adm = len(adm["lane"])
-            if not n_adm:
-                return st, 0, None
-            adm_shard = (
-                None if mesh is None else np.bincount(
-                    np.asarray(adm["lane"], np.int64) // local,
-                    minlength=n_shard,
-                ).astype(int).tolist()
-            )
-            splice_args = (
-                eng.params, st, stack_fields(adm["board"]),
-                adm["lane"],
-                np.asarray(adm["depth"], np.int32),
-                np.asarray(adm["budget"], np.int32),
-            )
-            splice_kw = dict(
-                variant=variant,
-                hist_hash=np.stack(adm["hh"]),
-                hist_halfmove=np.stack(adm["hm"]),
-                root_alpha=np.asarray(adm["alpha"], np.int32),
-                root_beta=np.asarray(adm["beta"], np.int32),
-                order_jitter=np.asarray(adm["jitter"], np.int32),
-                group=np.asarray(adm["group"], np.int32),
-            )
-            if mesh is not None:
-                st = refill_lanes_sharded(mesh, *splice_args, **splice_kw)
-            else:
-                st = search_ops.refill_lanes(*splice_args, **splice_kw)
-            for k in adm:
-                adm[k].clear()
-            return st, n_adm, adm_shard
-
-        # the session's boundary intervals open here: what came before
-        # is set-up, and the first admission and refill below are
-        # phases of the first segment
-        stats = SyncStats()
-        t_mark = t_enter
-
-        def credit_session(upto: float) -> float:
-            """Add the wall-clock since the last credit to session_ms:
-            at every boundary, so that counters read in the middle of a
-            session are short by one interval at most."""
-            nonlocal t_mark
-            ms = (upto - t_mark) * 1000.0
-            tot["session_ms"] += ms
-            t_mark = upto
-            return ms
-
-        setup_ms = credit_session(stats.interval_open_s)
-        tot["session_setup_ms"] += setup_ms
-        session_args["setup_ms"] = round(setup_ms, 3)
-
-        def launch(st, table, n_adm, adm_shard, speculative):
-            """Dispatch one segment of the pipelined loop on (st,
-            table), which the program donates → (its outputs, what the
-            boundary that reaps it records about it)."""
-            with stats.phase("account"):
-                meta = {
-                    "live": len(active),
-                    "helpers": sum(len(j.helpers) for j in active),
-                    "refilled": n_adm,
-                    "queue": 0 if speculative else q_len_locked(),
-                    "shard_live": shard_occup(),
-                    "shard_refilled": adm_shard,
-                    "steps": seg,
-                    "traced": traced_snapshot(),
-                }
-            meta["t0"] = time.monotonic()
-            with stats.phase("dispatch", steps=seg,
-                             speculative=speculative):
-                out = dispatch(st, table, seg)
-            return out, meta
-
-        res: Optional[dict] = None
+        session = _Session(self, entry, variant, filler, B, t_enter)
+        session_args["setup_ms"] = round(session.setup_ms, 3)
         try:
-            if not pipeline:
-                # round-7 synchronous loop (FISHNET_TPU_PIPELINE=0):
-                # block on the segment, materialize the full result
-                # set, refill, repeat — kept bit-for-bit as the A/B
-                # baseline, instrumented through SyncStats
-                while True:
-                    now = time.monotonic()
-                    with stats.phase("reap"):
-                        reap_jobs(
-                            now, res["nodes"] if res is not None else None
-                        )
-                    with stats.phase("admit"):
-                        admit_new(now)
-                    with stats.phase("refill", lanes=len(adm["lane"])):
-                        state, n_adm, adm_shard = flush_adm(state)
-                    if not active:
-                        break  # nothing running; next session continues
-                    # ---- dispatch one segment and block on it
-                    with stats.phase("account"):
-                        live_n = len(active)
-                        helper_n = sum(len(j.helpers) for j in active)
-                        shard_live = shard_occup()
-                        disp_steps = seg
-                        seg_res = traced_snapshot()
-                    t0 = time.monotonic()
-                    with stats.phase("dispatch", steps=seg, live=live_n):
-                        state, tt, _n, summ = dispatch(state, tt, seg)
-                    # steps and movegen counters: the summary's last rows
-                    raw = stats.fetch(summ, "steps")
-                    count_movegen(raw)
-                    _lanes, n, shard_steps = canon_summ(raw)
-                    wall = time.monotonic() - t0
-                    with stats.phase("account"):
-                        traced_residency(seg_res, t0, t0 + wall)
-                        q_len = q_len_locked()
-                    # ---- process finished lanes at the boundary
-                    with stats.phase("lanes"):
-                        lane_done = stats.fetch(
-                            state.lane[:, search_ops.LN_MODE]
-                            == search_ops.MODE_DONE,
-                            "done",
-                        )
-                        res = {
-                            k: stats.fetch(v, k)
-                            for k, v in search_ops.extract_results(
-                                state, 0
-                            ).items()
-                            if k != "steps"
-                        }
-                        now = time.monotonic()
-                        # helper lanes that parked on their own:
-                        # charge+free
-                        for lane in range(B):
-                            job = lane_owner[lane]
-                            if job is not None and lane_done[lane]:
-                                hn = int(res["nodes"][lane])
-                                job.nodes_total += hn
-                                job.remaining -= hn
-                                del job.helpers[lane]
-                                lane_owner[lane] = None
-                        # primary lanes that parked: aspiration verdict
-                        for lane in range(B):
-                            job = lane_job[lane]
-                            if job is None or not lane_done[lane]:
-                                continue
-                            on_primary_done(job, lane, res, now)
-                    snap = stats.boundary()
-                    credit_session(stats.interval_open_s)
-                    with stats.phase("account"):
-                        self._record_occupancy(
-                            B, n, live_n, helper_n, n_adm, q_len, wall,
-                            snap["host_ms"], snap["device_ms"],
-                            snap["transfers"], snap["phases"],
-                            counts=snap["counts"],
-                            shard=None if mesh is None else {
-                                "shard_live": shard_live,
-                                "shard_refilled":
-                                    adm_shard or [0] * n_shard,
-                                "shard_steps": shard_steps,
-                            },
-                        )
-                        if ctrl is not None:
-                            seg = ctrl.update(
-                                n >= disp_steps, snap["host_ms"],
-                                snap["device_ms"],
-                            )
-            else:
-                # pipelined double-buffered loop: one segment always in
-                # flight; the boundary is processed from its packed
-                # summary (one small transfer), and when every boundary
-                # decision is already settled the NEXT segment is
-                # dispatched speculatively before blocking, so all the
-                # host bookkeeping below overlaps device compute
-                now = time.monotonic()
-                with stats.phase("reap"):
-                    reap_jobs(now, None)
-                with stats.phase("admit"):
-                    admit_new(now)
-                with stats.phase("refill", lanes=len(adm["lane"])):
-                    state, n_adm, adm_shard = flush_adm(state)
-                pend = None
-                if active:
-                    pend, pend_meta = launch(
-                        state, tt, n_adm, adm_shard, False)
-                    tt = pend[1]
-                while pend is not None:
-                    p_state, p_tt, _pn, p_summ = pend
-                    nxt = None
-                    now = time.monotonic()
-                    margin = now + 2.0 * last_device_s
-                    if (not adm["lane"] and not pv_pending
-                            and q_len_locked() == 0
-                            and all(margin < j.deadline for j in active)):
-                        # no admissions staged, no PV owed, nothing
-                        # queued, no deadline within ~2 segments: the
-                        # synchronous loop would redispatch unchanged
-                        # after this boundary, so issue segment k+1 now
-                        # (donating the in-flight outputs in place)
-                        nxt, nxt_meta = launch(p_state, p_tt, 0, None, True)
-                        tt = nxt[1]
-                    raw_summ = stats.fetch(p_summ, "summary")
-                    with stats.phase("account"):
-                        traced_residency(
-                            pend_meta["traced"], pend_meta["t0"],
-                            time.monotonic())
-                    with stats.phase("lanes"):
-                        summ, n, shard_steps = canon_summ(raw_summ)
-                        count_movegen(raw_summ)
-                        lane_done = summ[:, search_ops.SUM_DONE].astype(bool)
-                        nodes_row = summ[:, search_ops.SUM_NODES]
-                        # lanes whose park was already handled at an
-                        # earlier speculative boundary (admission
-                        # staged, splice still pending) report DONE
-                        # again — skip them
-                        staged = set(adm["lane"])
-                        now = time.monotonic()
-                        # helper lanes that parked on their own:
-                        # charge+free
-                        for lane in range(B):
-                            job = lane_owner[lane]
-                            if (job is not None and lane_done[lane]
-                                    and lane not in staged):
-                                hn = int(nodes_row[lane])
-                                job.nodes_total += hn
-                                job.remaining -= hn
-                                del job.helpers[lane]
-                                lane_owner[lane] = None
-                        # primary lanes that parked: aspiration verdict
-                        for lane in range(B):
-                            job = lane_job[lane]
-                            if (job is None or not lane_done[lane]
-                                    or lane in staged):
-                                continue
-                            on_primary_parked(
-                                job, lane,
-                                int(summ[lane, search_ops.SUM_SCORE]),
-                                int(summ[lane, search_ops.SUM_MOVE]),
-                                int(nodes_row[lane]), nodes_row, now,
-                            )
-                    with stats.phase("reap"):
-                        reap_jobs(now, nodes_row)
-                    with stats.phase("admit"):
-                        admit_new(now)
-                    if nxt is None:
-                        # PV pulls read the resolved p_state BEFORE the
-                        # refill splice below resets those lanes
-                        with stats.phase("pv"):
-                            flush_pv(p_state, now)
-                    snap = stats.boundary()
-                    credit_session(stats.interval_open_s)
-                    last_device_s = snap["device_ms"] / 1000.0
-                    with stats.phase("account"):
-                        self._record_occupancy(
-                            B, n, pend_meta["live"], pend_meta["helpers"],
-                            pend_meta["refilled"], pend_meta["queue"],
-                            (snap["host_ms"] + snap["device_ms"]) / 1000.0,
-                            snap["host_ms"], snap["device_ms"],
-                            snap["transfers"], snap["phases"],
-                            counts=snap["counts"],
-                            shard=None if mesh is None else {
-                                "shard_live": pend_meta["shard_live"],
-                                "shard_refilled":
-                                    pend_meta["shard_refilled"]
-                                    or [0] * n_shard,
-                                "shard_steps": shard_steps,
-                            },
-                        )
-                        if ctrl is not None:
-                            seg = ctrl.update(
-                                n >= pend_meta["steps"], snap["host_ms"],
-                                snap["device_ms"],
-                            )
-                    if nxt is not None:
-                        pend, pend_meta = nxt, nxt_meta
-                        continue
-                    with stats.phase("refill", lanes=len(adm["lane"])):
-                        state, n_adm, adm_shard = flush_adm(p_state)
-                    if not active:
-                        break  # next session handles the rest
-                    pend, pend_meta = launch(
-                        state, tt, n_adm, adm_shard, False)
-                    tt = pend[1]
+            session.run()
         except BaseException as e:
             # the driver died mid-session (device fault, OOM...): fail
             # every admitted job so no submitting thread waits forever
             now = time.monotonic()
-            for job in active:
-                release(job, None)
+            for job in session.active:
+                session.release(job, None)
                 self._finalize(job, now, error=f"tpu engine failed: {e}")
             # jobs released at a park boundary whose _finalize was still
             # deferred behind a PV pull: complete them with what the
             # summary recorded, or their submitters wait forever
-            for job, _lane, _depth, final in pv_pending:
+            for job, _lane, _depth, final in session.pv_pending:
                 if final:
                     self._finalize(job, now)
-            pv_pending.clear()
+            session.pv_pending.clear()
             raise
         finally:
-            eng.tt = tt
+            eng.tt = session.tt
             t_end = time.monotonic()
             # last boundary → here: the final account and the refill
             # that found nothing to admit
-            tail_ms = credit_session(t_end)
+            tail_ms = session.credit(t_end)
             tot["session_tail_ms"] += tail_ms
             tot["sessions"] += 1
             with self._q_lock:
@@ -2772,3 +2000,663 @@ class LaneScheduler:
                 f"host={host_ms:.1f}ms dev={device_ms:.1f}ms "
                 f"xfers={transfers}"
             )
+
+
+class _Session:
+    """One fixed-width drive session of the LaneScheduler: the host-side
+    lane tables, the device state and table handles, and the boundary
+    loop (`run`). One segment is always in flight; a boundary is
+    processed from its packed summary (one small transfer), and when
+    every boundary decision is already settled the NEXT segment is
+    dispatched speculatively before blocking, so the host bookkeeping
+    overlaps device compute.
+
+    The methods named as the spans — `reap`, `admit`, `refill`,
+    `dispatch`, `lanes`, `pv`, `account` — each open their own
+    `stats.phase(...)`; `run` is the order they are called in.
+
+    `state` and `tt` are the ONLY handles to the device state and
+    table: the segment and splice programs donate their operands, and
+    every call rebinds both to the outputs.
+
+    Shard-aware: under a mesh the SAME loop drives the shard_map'd
+    segment/refill callables (parallel/mesh.py) — B is padded to a
+    multiple of n_dev by _helper_width, each device owns `local`
+    consecutive lanes, and every boundary is one stacked-summary
+    fetch."""
+
+    deltas = ASPIRATION_DELTAS + (None,)  # None = full window
+
+    def __init__(self, sched: LaneScheduler, entry: _ChunkEntry,
+                 variant: str, filler, B: int, t_enter: float):
+        from ..ops.search import HIST_HM_SENTINEL, MAX_HIST
+
+        self.sched = sched
+        self.eng = eng = sched.engine
+        self.tot = eng.occupancy_totals
+        self.entry = entry
+        self.variant = variant
+        self.B = B
+        self.K = eng.helper_lanes
+        self.mesh = mesh = eng.mesh
+        self.n_shard = eng.n_dev if mesh is not None else 1
+        self.local = B // self.n_shard
+        # mesh-topology-aware admission: free lists index GLOBAL shards
+        # (lane numbering spans the whole pod) but new work is admitted
+        # only into shards whose device this process can address — on a
+        # single-host mesh that is every shard, so the historical
+        # assignment is unchanged bit-for-bit
+        if mesh is not None:
+            from ..parallel import distributed as _dist
+
+            self.fillable_shards = set(_dist.addressable_shards(mesh))
+        else:
+            self.fillable_shards = {0}
+        self.seg = settings.get_int("FISHNET_TPU_SEGMENT")
+        self.prefer_deep = self.K > 1 and eng.tt is not None
+
+        # host-side lane tables
+        self.lane_job: List[Optional[_RefillJob]] = [None] * B  # primary owner
+        self.lane_owner: List[Optional[_RefillJob]] = [None] * B  # helper owner
+        self.lane_alpha = np.full(B, -INF, np.int64)
+        self.lane_beta = np.full(B, INF, np.int64)
+        self.gen = np.zeros(B, np.int32)
+        self.active: List[_RefillJob] = []
+
+        # idle base state: budget-0 lanes park in DONE within two steps.
+        # Built from host rows, like every refill after it: one call
+        # whose operands are numpy at the session's width
+        with syncstats.step("session_setup"):
+            self.state = search_ops._init_state_jit(
+                eng.params, stack_fields([filler] * B),
+                np.zeros(B, np.int32), np.zeros(B, np.int32),
+                MAX_PLY, variant,
+                hist_hash=np.zeros((B, MAX_HIST, 2), np.uint32),
+                hist_halfmove=np.full(
+                    (B, MAX_HIST), HIST_HM_SENTINEL, np.int32
+                ),
+                root_alpha=np.full((B,), -INF, np.int32),
+                root_beta=np.full((B,), INF, np.int32),
+                order_jitter=np.zeros((B,), np.int32),
+                group=np.zeros((B,), np.int32),
+            )
+            if mesh is not None:
+                from ..parallel.mesh import shard_batch
+
+                # place the base state sharded before the first
+                # dispatch: the sharded segment donates its operands,
+                # and donation only takes when the input already
+                # carries the sharding
+                self.state = shard_batch(mesh, self.state)
+        self.tt = eng.tt
+
+        # admissions accumulated between boundaries as host rows, flushed
+        # as ONE refill_lanes call before each dispatch
+        self.adm: dict = {k: [] for k in (
+            "lane", "board", "depth", "budget", "alpha", "beta",
+            "jitter", "group", "hh", "hm",
+        )}
+        # PV pulls deferred past speculative boundaries as (job, lane,
+        # depth, final) — the PV row is the one per-lane result NOT in
+        # the packed summary
+        self.pv_pending: List[tuple] = []
+        self.last_device_s = 0.0
+
+        # the session's boundary intervals open here: what came before
+        # is set-up, and the first admission and refill are phases of
+        # the first segment
+        self.stats = SyncStats()
+        self.t_mark = t_enter
+        self.setup_ms = self.credit(self.stats.interval_open_s)
+        self.tot["session_setup_ms"] += self.setup_ms
+
+    def credit(self, upto: float) -> float:
+        """Add the wall-clock since the last credit to session_ms:
+        at every boundary, so that counters read in the middle of a
+        session are short by one interval at most."""
+        ms = (upto - self.t_mark) * 1000.0
+        self.tot["session_ms"] += ms
+        self.t_mark = upto
+        return ms
+
+    # ------------------------------------------------------ the loop
+
+    def run(self) -> None:
+        now = time.monotonic()
+        self.reap(now, None)
+        self.admit(now)
+        n_adm, adm_shard = self.refill()
+        pend = None
+        if self.active:
+            pend = self.dispatch(n_adm, adm_shard, False)
+        while pend is not None:
+            summ, meta = pend
+            nxt = None
+            now = time.monotonic()
+            if self.settled(now):
+                nxt = self.dispatch(0, None, True)
+            raw_summ = self.stats.fetch(summ, "summary")
+            self.residency(meta)
+            n, shard_steps, nodes_row, now = self.lanes(raw_summ)
+            self.reap(now, nodes_row)
+            self.admit(now)
+            if nxt is None:
+                # PV pulls read the resolved state BEFORE the refill
+                # splice below resets those lanes
+                self.pv(now)
+            self.account(meta, n, shard_steps)
+            if nxt is not None:
+                pend = nxt
+                continue
+            n_adm, adm_shard = self.refill()
+            if not self.active:
+                break  # next session handles the rest
+            pend = self.dispatch(n_adm, adm_shard, False)
+
+    def settled(self, now: float) -> bool:
+        """No admissions staged, no PV owed, nothing queued, no deadline
+        within ~2 segments: this boundary will redispatch the state
+        unchanged, so segment k+1 can be issued now (donating the
+        in-flight outputs in place)."""
+        margin = now + 2.0 * self.last_device_s
+        return (not self.adm["lane"] and not self.pv_pending
+                and self.q_len_locked() == 0
+                and all(margin < j.deadline for j in self.active))
+
+    # ---------------------------------------------------- the phases
+
+    def reap(self, now: float, nodes_row) -> None:
+        """Fail or finalize jobs past their chunk deadline."""
+        with self.stats.phase("reap"):
+            for job in list(self.active):
+                if now >= job.deadline:
+                    self.release(job, nodes_row)
+                    self.active.remove(job)
+                    if self.pv_pending:
+                        # the response built below holds job.pvs BY
+                        # REFERENCE: a deferred pull landing after it
+                        # would mutate an already-sent response
+                        self.pv_pending[:] = [
+                            e for e in self.pv_pending if e[0] is not job
+                        ]
+                    if job.depth_reached == 0:
+                        # no usable result: fail the chunk so the
+                        # server reassigns it (same contract as the
+                        # serial path)
+                        self.sched._finalize(
+                            job, now,
+                            error="chunk deadline expired before "
+                                  "depth 1 completed",
+                        )
+                    else:
+                        self.sched._finalize(job, now)
+
+    def admit(self, now: float) -> None:
+        """Admit pending positions, earliest deadline first, then spend
+        leftover free lanes on Lazy-SMP helpers.
+
+        Free lanes are tracked per shard and every admission lands on
+        the shard with the most free lanes (ties → lowest shard),
+        hardest-deadline-first within the boundary, so queued positions
+        spread across devices instead of piling onto shard 0's early
+        lanes. With one shard this is exactly the historical
+        ascending-lane assignment (one list, front pops) — the
+        single-device bit-identity contract holds."""
+        sched = self.sched
+        active = self.active
+        lane_job, lane_owner, local = self.lane_job, self.lane_owner, self.local
+        with self.stats.phase("admit"):
+            free_by_shard: List[List[int]] = [
+                [] for _ in range(self.n_shard)]
+            for i in range(self.B):
+                if lane_job[i] is None and lane_owner[i] is None:
+                    if (i // local) in self.fillable_shards:
+                        free_by_shard[i // local].append(i)
+            n_free = sum(len(f) for f in free_by_shard)
+
+            def take_lane() -> int:
+                s = max(
+                    range(self.n_shard),
+                    key=lambda i: len(free_by_shard[i])
+                )
+                return free_by_shard[s].pop(0)
+
+            if not self.entry.event.is_set():
+                with sched._q_lock:
+                    sched._pending.sort(key=lambda j: j.deadline)
+                    take: List[_RefillJob] = []
+                    for j in list(sched._pending):
+                        if len(take) >= n_free:
+                            break
+                        if j.variant != self.variant:
+                            continue
+                        sched._pending.remove(j)
+                        take.append(j)
+                for job in take:
+                    if now >= job.deadline:
+                        sched._finalize(
+                            job, now,
+                            error="chunk deadline expired before "
+                                  "depth 1 completed",
+                        )
+                        continue
+                    self.admit_primary(job, take_lane())
+                    n_free -= 1
+                    active.append(job)
+            # ---- spend leftover free lanes on Lazy-SMP helpers
+            if self.K > 1 and self.tt is not None and n_free and active:
+                n_act = len(active)
+                cur = sum(len(j.helpers) for j in active)
+                hardness = [
+                    j.hardness if j.remaining > 0 else 0
+                    for j in active
+                ]
+                plan = TpuEngine._plan_helpers(
+                    n_act, n_act + cur + n_free, self.K, hardness
+                )
+                want: dict = {}
+                for r, _h in plan:
+                    want[r] = want.get(r, 0) + 1
+                for r, job in enumerate(active):
+                    while n_free and len(job.helpers) < want.get(r, 0):
+                        self.admit_helper(
+                            job, take_lane(), len(job.helpers) + 1
+                        )
+                        n_free -= 1
+
+    def refill(self):
+        """Flush the staged admissions in ONE refill splice: the staged
+        host rows go to the one splice program of this width, whatever
+        their number; under a mesh it runs through shard_map, each
+        device rewriting only its own lanes. → (count, per-shard
+        admission counts or None)."""
+        adm = self.adm
+        with self.stats.phase("refill", lanes=len(adm["lane"])):
+            n_adm = len(adm["lane"])
+            if not n_adm:
+                return 0, None
+            adm_shard = (
+                None if self.mesh is None else np.bincount(
+                    np.asarray(adm["lane"], np.int64) // self.local,
+                    minlength=self.n_shard,
+                ).astype(int).tolist()
+            )
+            splice_args = (
+                self.eng.params, self.state, stack_fields(adm["board"]),
+                adm["lane"],
+                np.asarray(adm["depth"], np.int32),
+                np.asarray(adm["budget"], np.int32),
+            )
+            splice_kw = dict(
+                variant=self.variant,
+                hist_hash=np.stack(adm["hh"]),
+                hist_halfmove=np.stack(adm["hm"]),
+                root_alpha=np.asarray(adm["alpha"], np.int32),
+                root_beta=np.asarray(adm["beta"], np.int32),
+                order_jitter=np.asarray(adm["jitter"], np.int32),
+                group=np.asarray(adm["group"], np.int32),
+            )
+            if self.mesh is not None:
+                from ..parallel.mesh import refill_lanes_sharded
+
+                self.state = refill_lanes_sharded(
+                    self.mesh, *splice_args, **splice_kw)
+            else:
+                self.state = search_ops.refill_lanes(
+                    *splice_args, **splice_kw)
+            for k in adm:
+                adm[k].clear()
+            return n_adm, adm_shard
+
+    def dispatch(self, n_adm, adm_shard, speculative):
+        """Dispatch one segment on (state, tt) → (its boundary summary,
+        what the boundary that reaps it records about it)."""
+        active = self.active
+        with self.stats.phase("account"):
+            meta = {
+                "live": len(active),
+                "helpers": sum(len(j.helpers) for j in active),
+                "refilled": n_adm,
+                "queue": 0 if speculative else self.q_len_locked(),
+                "shard_live": self.shard_occup(),
+                "shard_refilled": adm_shard,
+                "traced": self.traced_snapshot(),
+            }
+        meta["t0"] = time.monotonic()
+        with self.stats.phase("dispatch", steps=self.seg,
+                              speculative=speculative):
+            if self.mesh is not None:
+                from ..parallel.mesh import run_segment_sharded
+
+                # each device advances its shard locally; the summary
+                # arrives stacked (n_shard, local+1, 4)
+                self.state, self.tt, _n, summ = run_segment_sharded(
+                    self.mesh, self.eng.params, self.state, self.tt,
+                    self.seg, variant=self.variant,
+                    prefer_deep=self.prefer_deep,
+                    tt_gen=jnp.asarray(self.gen),
+                )
+            else:
+                self.state, self.tt, _n, summ = search_ops._run_segment_jit(
+                    self.eng.params, self.state, self.tt, self.seg,
+                    self.variant, False, self.prefer_deep,
+                    jnp.asarray(self.gen),
+                )
+        return summ, meta
+
+    def lanes(self, raw_summ):
+        """The parks of one boundary, from its packed summary: helper
+        lanes that parked on their own are charged and freed, then each
+        parked primary lane gets its verdict (`on_parked`). → (steps,
+        per-shard steps, the (B,) node counts, now)."""
+        with self.stats.phase("lanes"):
+            summ, n, shard_steps = self.canon_summ(raw_summ)
+            self.count_movegen(raw_summ)
+            lane_done = summ[:, search_ops.SUM_DONE].astype(bool)
+            nodes_row = summ[:, search_ops.SUM_NODES]
+            # lanes whose park was already handled at an earlier
+            # speculative boundary (admission staged, splice still
+            # pending) report DONE again — skip them
+            staged = set(self.adm["lane"])
+            now = time.monotonic()
+            # helper lanes that parked on their own: charge+free
+            lane_owner, lane_job = self.lane_owner, self.lane_job
+            for lane in range(self.B):
+                job = lane_owner[lane]
+                if (job is not None and lane_done[lane]
+                        and lane not in staged):
+                    hn = int(nodes_row[lane])
+                    job.nodes_total += hn
+                    job.remaining -= hn
+                    del job.helpers[lane]
+                    lane_owner[lane] = None
+            # primary lanes that parked: aspiration verdict
+            for lane in range(self.B):
+                job = lane_job[lane]
+                if (job is None or not lane_done[lane]
+                        or lane in staged):
+                    continue
+                self.on_parked(
+                    job, lane,
+                    int(summ[lane, search_ops.SUM_SCORE]),
+                    int(summ[lane, search_ops.SUM_MOVE]),
+                    int(nodes_row[lane]), nodes_row, now,
+                )
+        return n, shard_steps, nodes_row, now
+
+    def pv(self, now: float) -> None:
+        """Materialize deferred PV rows from the resolved state, then
+        finalize the jobs whose response waited only on the PV. The
+        whole (B, max_ply) root-PV block and its (B,) lengths come
+        home — a few kB whose shapes are the session's, not the
+        count's — and the rows owed are picked on the host. Must run
+        BEFORE refill: a refill splice resets the spliced lanes' PV
+        tables."""
+        with self.stats.phase("pv"):
+            if not self.pv_pending:
+                return
+            st = self.state
+            pv_rows = self.stats.fetch(st.pv[:, 0], "pv")
+            pv_lens = self.stats.fetch(
+                st.nt[:, 0, search_ops.NT_PVLEN], "pv_len")
+            for job, lane, depth, final in self.pv_pending:
+                pv = [
+                    _decode_uci(int(m))
+                    for m in pv_rows[lane][: int(pv_lens[lane])]
+                    if m >= 0
+                ]
+                job.pvs.set(1, depth, pv)
+                if final:
+                    self.sched._finalize(job, now)
+            self.pv_pending.clear()
+
+    def account(self, meta: dict, n: int, shard_steps) -> None:
+        """Close the boundary interval and record the segment it
+        reaped."""
+        snap = self.stats.boundary()
+        self.credit(self.stats.interval_open_s)
+        self.last_device_s = snap["device_ms"] / 1000.0
+        with self.stats.phase("account"):
+            self.sched._record_occupancy(
+                self.B, n, meta["live"], meta["helpers"],
+                meta["refilled"], meta["queue"],
+                (snap["host_ms"] + snap["device_ms"]) / 1000.0,
+                snap["host_ms"], snap["device_ms"],
+                snap["transfers"], snap["phases"],
+                counts=snap["counts"],
+                shard=None if self.mesh is None else {
+                    "shard_live": meta["shard_live"],
+                    "shard_refilled":
+                        meta["shard_refilled"]
+                        or [0] * self.n_shard,
+                    "shard_steps": shard_steps,
+                },
+            )
+
+    # ------------------------------------------- admission and policy
+
+    def window_for(self, job: _RefillJob, scale: int):
+        """Per-lane mirror of _search_windowed's window: narrow
+        around the previous depth's score, widening per failed
+        attempt, full-width first at depth 1 / after a mate score."""
+        use_win = (
+            job.have_prev
+            and abs(job.prev_score) < MATE - 1000
+            and job.depth >= 2
+        )
+        delta = self.deltas[min(job.delta_idx, len(self.deltas) - 1)]
+        if not use_win or delta is None:
+            return -INF, INF, None
+        return (
+            max(job.prev_score - delta * scale, -INF),
+            min(job.prev_score + delta * scale, INF),
+            delta,
+        )
+
+    def stage(self, lane, board, depth, budget, alpha, beta, jit, grp,
+              hh, hm) -> None:
+        """Stage one lane's admission for the next refill splice."""
+        adm = self.adm
+        adm["lane"].append(lane)
+        adm["board"].append(board)
+        adm["depth"].append(depth)
+        adm["budget"].append(int(np.clip(budget, 1, 2**31 - 1)))
+        adm["alpha"].append(alpha)
+        adm["beta"].append(beta)
+        adm["jitter"].append(jit)
+        adm["group"].append(grp)
+        adm["hh"].append(hh)
+        adm["hm"].append(hm)
+        self.lane_alpha[lane] = alpha
+        self.lane_beta[lane] = beta
+        # fresh TT generation per admission: depth-preferred
+        # replacement must never protect the lane's previous
+        # occupant's entries (ops/tt.py store)
+        eng = self.eng
+        eng._tt_gen = (eng._tt_gen + 1) & 0x3FFFFFFF
+        self.gen[lane] = eng._tt_gen
+
+    def admit_primary(self, job: _RefillJob, lane: int) -> None:
+        job.lane = lane
+        self.lane_job[lane] = job
+        wp = job.wp
+        if wp.ctx:
+            obs_inflight.REGISTRY.position(
+                wp.ctx.get("trace_id"), wp.position_index or 0,
+                "lane", lane=lane,
+            )
+        if job.traced:
+            job.t_spliced = time.monotonic()
+            rec = obs_trace.RECORDER
+            if rec is not None:
+                rec.instant(
+                    "position.spliced", "request",
+                    **obs_trace.ctx_args(
+                        wp.ctx, position_index=wp.position_index,
+                        lane=lane,
+                    ),
+                )
+                rec.flow("request", wp.ctx["trace_id"], "t")
+        a, b, _delta = self.window_for(job, 1)
+        self.stage(lane, job.board, job.depth, job.remaining, a, b,
+                   0, lane, job.hh, job.hm)
+
+    def admit_helper(self, job: _RefillJob, lane: int, h: int) -> None:
+        # same layout as _analyse_single: odd h at the primary's
+        # depth (exact-depth TT entries consumable THIS iteration),
+        # even h one ply deeper; staggered window scale; nonzero
+        # unique jitter; group = primary lane
+        sched = self.sched
+        job.helpers[lane] = h
+        self.lane_owner[lane] = job
+        sched._jitter_seq = (sched._jitter_seq & 0xFFFF) + 1
+        a, b, _delta = self.window_for(job, 1 << min(h, 4))
+        d = min(job.depth + (1 - (h & 1)), job.target_depth)
+        self.stage(lane, job.board, d, job.remaining, a, b,
+                   sched._jitter_seq, job.lane, job.hh, job.hm)
+
+    def release(self, job: _RefillJob, nodes_row) -> None:
+        """Free the job's primary + helper lanes; mid-flight helper
+        work is charged at its last-boundary node count (nodes_row:
+        the latest boundary's (B,) per-lane node counts — the work
+        actually spent against the position's budget, same honesty
+        rule as _analyse_single's helper charging)."""
+        if job.lane >= 0:
+            self.lane_job[job.lane] = None
+            job.lane = -1
+        for hl in list(job.helpers):
+            if nodes_row is not None:
+                hn = int(nodes_row[hl])
+                job.nodes_total += hn
+                job.remaining -= hn
+            self.lane_owner[hl] = None
+        job.helpers.clear()
+
+    def on_parked(self, job: _RefillJob, lane: int, score: int,
+                  move: int, nodes: int, nodes_row, now: float) -> None:
+        """One primary lane parked in DONE: fail-low/high re-search,
+        next depth, or finalize — the per-lane equivalent of one
+        `_search_windowed` attempt boundary. The fail checks and the
+        widening schedule mirror that method exactly, so with no TT a
+        refilled lane's score chain is bit-identical to the serial
+        path's. The verdict and all bookkeeping come from the packed
+        boundary summary; the PV row is deferred to `pv`, which reads
+        it from the next RESOLVED state — legal because a DONE lane is
+        frozen until the refill splice that `pv` always precedes."""
+        job.nodes_depth += nodes
+        a_w = int(self.lane_alpha[lane])
+        b_w = int(self.lane_beta[lane])
+        fail_lo = score <= a_w and a_w > -INF
+        fail_hi = score >= b_w and b_w < INF
+        delta = self.deltas[min(job.delta_idx, len(self.deltas) - 1)]
+        if a_w > -INF or b_w < INF:
+            # same per-delta accounting as _search_windowed
+            st = self.eng.aspiration_stats.setdefault(delta, [0, 0, 0, 0])
+            st[0] += 1
+            st[1] += int(fail_lo)
+            st[2] += int(fail_hi)
+            st[3] += nodes
+        if (fail_lo or fail_hi) and delta is not None:
+            # re-search the same depth with the next wider window;
+            # the lane stays this job's — only its window changes
+            job.delta_idx += 1
+            a, b, _d = self.window_for(job, 1)
+            self.stage(lane, job.board, job.depth, job.remaining, a, b,
+                       0, lane, job.hh, job.hm)
+            return
+        # depth complete: record, charge the depth's nodes, advance
+        job.prev_score = score
+        job.have_prev = True
+        job.hardness = max(nodes, 1)
+        job.nodes_total += job.nodes_depth
+        job.remaining -= job.nodes_depth
+        job.nodes_depth = 0
+        job.delta_idx = 0
+        job.scores.set(1, job.depth, _score_from_int(score))
+        job.depth_reached = job.depth
+        job.best_move = _decode_uci(move) if move >= 0 else None
+        final = (
+            job.depth >= job.target_depth
+            or job.remaining <= 0
+            or now >= job.deadline
+        )
+        self.pv_pending.append((job, lane, job.depth, final))
+        if final:
+            self.release(job, nodes_row)
+            self.active.remove(job)
+            return  # _finalize waits in pv for the PV row
+        job.depth += 1
+        a, b, _d = self.window_for(job, 1)
+        self.stage(lane, job.board, job.depth, job.remaining, a, b,
+                   0, lane, job.hh, job.hm)
+
+    # --------------------------------------- what a boundary records
+
+    def q_len_locked(self) -> int:
+        with self.sched._q_lock:
+            return len(self.sched._pending)
+
+    def traced_snapshot(self):
+        """(ctx, lane, position_index) for every sampled job resident
+        in this segment — captured at dispatch, because by the time
+        the boundary is processed jobs may have parked/finalized."""
+        if obs_trace.RECORDER is None:
+            return ()
+        return [
+            (j.wp.ctx, j.lane, j.wp.position_index)
+            for j in self.active if j.traced
+        ]
+
+    def residency(self, meta: dict) -> None:
+        """Retroactive per-position residency spans for the segment
+        just reaped: which lanes a request's positions occupied while
+        the device ran — the finest grain of the request waterfall."""
+        with self.stats.phase("account"):
+            t0_s, t1_s = meta["t0"], time.monotonic()
+            rec = obs_trace.RECORDER
+            if rec is None:
+                return
+            for ctx, lane, idx in meta["traced"]:
+                rec.complete(
+                    "segment.residency", t0_s * 1e6,
+                    (t1_s - t0_s) * 1e6, cat="request",
+                    args=obs_trace.ctx_args(
+                        ctx, lane=lane, position_index=idx
+                    ),
+                )
+
+    def canon_summ(self, raw):
+        """Boundary summary → ((B, 4) lane rows, step count,
+        per-shard step list). Single-device summaries are (B+1, 4);
+        sharded ones come back stacked (n_shard, local+1, 4) and
+        the step count is the max over shards (devices park
+        independently)."""
+        B, local = self.B, self.local
+        if self.mesh is None:
+            return raw[:B], int(raw[B, search_ops.SUM_DONE]), None
+        lanes = raw[:, :local, :].reshape(B, search_ops.SUM_W)
+        shard_steps = [
+            int(x) for x in raw[:, local, search_ops.SUM_DONE]
+        ]
+        return lanes, max(shard_steps), shard_steps
+
+    def count_movegen(self, raw) -> None:
+        """The summary's last rows hold what the segment's loop
+        counted (summed over shards): into the interval's snapshot."""
+        self.stats.count(search_ops.movegen_counts(
+            raw[self.B] if self.mesh is None else raw[:, self.local]))
+
+    def shard_occup(self):
+        """Busy (primary or helper) lane count per shard, or None
+        off-mesh — the per-shard occupancy column of the log."""
+        if self.mesh is None:
+            return None
+        local = self.local
+        return [
+            sum(
+                1 for i in range(s * local, (s + 1) * local)
+                if self.lane_job[i] is not None
+                or self.lane_owner[i] is not None
+            )
+            for s in range(self.n_shard)
+        ]

@@ -59,7 +59,7 @@ flow from the segment dispatch jits (`_run_segment_jit`,
 `_init_state_jit`, `_splice_lanes_jit`, `refill_lanes`,
 `extract_results`, the shard_map'd mesh callables
 `run_segment_sharded`/`refill_lanes_sharded`, or a local
-`dispatch`/`flush_adm` wrapper) are device-resident, and the only
+`dispatch` wrapper) are device-resident, and the only
 sanctioned way to materialize one on the host inside a `while` loop is
 `SyncStats.fetch`, which counts the transfer and measures the blocked
 time (utils/syncstats.py).
@@ -169,12 +169,11 @@ _MUT_METHODS = ("update", "pop", "clear", "setdefault", "popitem",
                 "add", "discard", "remove")
 
 # calls whose results are device arrays (or tuples of them); a local
-# `dispatch`/`flush_adm` closure wrapping the segment jit counts too,
-# as do the shard_map'd mesh callables (parallel/mesh.py) the sharded
-# scheduler drives
+# `dispatch` wrapping the segment jit counts too, as do the shard_map'd
+# mesh callables (parallel/mesh.py) the sharded scheduler drives
 _DEVICE_PRODUCERS = ("_run_segment_jit", "_init_state_jit",
                      "_splice_lanes_jit", "refill_lanes", "extract_results",
-                     "dispatch", "flush_adm",
+                     "dispatch",
                      "run_segment_sharded", "refill_lanes_sharded")
 
 # attribute calls that block the caller until a peer acts

@@ -61,12 +61,13 @@ DONATING_CALLS: Dict[str, Tuple[Tuple[int, ...], Tuple[str, ...]]] = {
     "refill_lanes": ((1,), ()),            # state
 }
 
-# Local closure wrappers over the donating jits inside the two scheduler
-# modules. The names are generic, so they only register there.
-WRAPPER_SCOPE = ("fishnet_tpu/engine/tpu.py", "fishnet_tpu/ops/search.py")
+# Local closure wrappers over the donating jits inside search_stream.
+# The names are generic, so they only register there. (The engine's
+# _Session holds its state and table as attributes, each rebound in the
+# statement that donates it: nothing for a flow over names to follow.)
+WRAPPER_SCOPE = ("fishnet_tpu/ops/search.py",)
 WRAPPER_DONATING_CALLS: Dict[str, Tuple[Tuple[int, ...], Tuple[str, ...]]] = {
     "dispatch": ((0, 1), ()),              # st, table
-    "flush_adm": ((0,), ()),               # st
     "do_refill": ((0,), ()),               # st
 }
 

@@ -1046,9 +1046,9 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
 
 
 # segment_steps is TRACED (an int32 operand of the while cond), not
-# static: the FISHNET_TPU_SEGMENT=auto controller retunes the length
-# between segments with zero recompiles. state and ttab are DONATED —
-# chained segments alias the multi-MB tables in place instead of
+# static: callers pass different lengths (FISHNET_TPU_SEGMENT, a test's
+# own, search_batch's max_steps) to one program. state and ttab are
+# DONATED — chained segments alias the multi-MB tables in place instead of
 # copying them, so a caller must treat the arguments it passed as
 # consumed and continue from the returned state/ttab only.
 _run_segment_jit = _aot_registry.wrap(
@@ -1268,7 +1268,6 @@ def search_stream(
     hist=None,
     prefer_deep_store: bool = False,
     tt_gen_start: int = 1,
-    pipeline: bool | None = None,
     sync_stats=None,
 ):
     """Stream N root positions through a fixed `width`-lane program.
@@ -1291,18 +1290,13 @@ def search_stream(
     row gains shard_live / shard_refilled / shard_steps lists (one entry
     per shard).
 
-    pipeline (default FISHNET_TPU_PIPELINE): asynchronous segment
-    boundaries — the host fetches ONE packed summary per boundary
-    instead of the full result set, pulls PV rows only for lanes that
-    actually finished, and, when the refill queue is empty (no boundary
-    decision pending), dispatches the next segment speculatively before
-    blocking on the current one, so host bookkeeping overlaps device
-    compute. False restores the round-7 synchronous loop; results are
-    bit-identical in both modes. sync_stats: optional
-    utils.syncstats.SyncStats to account transfers into.
-
-    segment_steps None reads FISHNET_TPU_SEGMENT; "auto" runs the
-    measured-feedback SegmentController within the registry bounds.
+    Segment boundaries are asynchronous: the host fetches ONE packed
+    summary per boundary, pulls PV rows only for lanes that actually
+    finished, and, when the refill queue is empty (no boundary decision
+    pending), dispatches the next segment speculatively before blocking
+    on the current one, so host bookkeeping overlaps device compute.
+    sync_stats: optional utils.syncstats.SyncStats to account transfers
+    into. segment_steps None reads FISHNET_TPU_SEGMENT.
 
     Returns per-position (N,) results keyed as extract_results, plus:
       occupancy: list of per-segment dicts {segment, steps, live, idle,
@@ -1317,20 +1311,11 @@ def search_stream(
     """
     import time as _time
 
-    from ..utils.syncstats import SegmentController, SyncStats
+    from ..utils.syncstats import SyncStats
 
-    if pipeline is None:
-        pipeline = settings.get_bool("FISHNET_TPU_PIPELINE")
     stats = sync_stats if sync_stats is not None else SyncStats()
-    ctrl = None
     if segment_steps is None:
-        segment_steps = settings.get_segment()
-        if segment_steps is None:  # FISHNET_TPU_SEGMENT=auto
-            ctrl = SegmentController(
-                settings.get_int("FISHNET_TPU_SEGMENT_MIN"),
-                settings.get_int("FISHNET_TPU_SEGMENT_MAX"),
-            )
-            segment_steps = ctrl.steps
+        segment_steps = settings.get_int("FISHNET_TPU_SEGMENT")
     N = int(roots.stm.shape[0])
     P = max_ply
     depth = np.broadcast_to(np.asarray(depth, np.int32), (N,)).copy()
@@ -1398,14 +1383,8 @@ def search_stream(
         multiproc = _dist.spans_processes(mesh)
         if multiproc:
             # multi-host stream: every participating process drives this
-            # same loop with identical inputs (SPMD discipline); only
-            # the pipelined loop's host fetches are addressable-shard
-            # aware (parallel/distributed.py), the synchronous loop
-            # materializes full sharded arrays and cannot be
-            if not pipeline:
-                raise ValueError(
-                    "a multi-host mesh requires the pipelined stream "
-                    "loop (FISHNET_TPU_PIPELINE=1)")
+            # same loop with identical inputs (SPMD discipline); its host
+            # fetches are addressable-shard aware (parallel/distributed.py)
             params = _dist.replicate_tree(mesh, params)
         # place the fresh state sharded BEFORE the first dispatch: the
         # sharded segment donates its operands, and donation only takes
@@ -1529,8 +1508,8 @@ def search_stream(
             return _dist.fetch_summary(mesh, p_summ, stats, "summary")
         return stats.fetch(p_summ, "summary")
 
-    def record(n, live, n_ref, pend_steps, shard=None):
-        nonlocal seg_i, segment_steps
+    def record(n, live, n_ref, shard=None):
+        nonlocal seg_i
         seg_i += 1
         snap = stats.boundary()
         row = {
@@ -1542,125 +1521,80 @@ def search_stream(
         if shard is not None:
             row.update(shard)
         occupancy.append(row)
-        if ctrl is not None:
-            segment_steps = ctrl.update(
-                int(n) >= pend_steps, snap["host_ms"], snap["device_ms"])
 
-    final_state, final_tt = state, tt
-    if not pipeline:
-        # round-7 synchronous loop: block on the segment, materialize
-        # the full result set, refill, repeat (kept bit-for-bit for
-        # FISHNET_TPU_PIPELINE=0 and as the A/B baseline)
-        while total < max_steps:
-            if deadline is not None and _time.monotonic() >= deadline:
-                break
-            state, tt, n, _summ = dispatch(state, tt, segment_steps)
-            pend_steps = segment_steps
-            n_arr = np.asarray(stats.fetch(n, "steps")).reshape(-1)
-            shard_steps = (
-                [int(x) for x in n_arr] if mesh is not None else None
-            )
-            n = int(n_arr.max())
-            total += n
-            lane_done = stats.fetch(
-                state.lane[:, LN_MODE] == MODE_DONE, "done")
-            res = extract_results(state, jnp.int32(total))
-            fin = np.nonzero(lane_done & (lane_pos >= 0))[0]
-            if fin.size:
-                for key in out:
-                    out[key][lane_pos[fin]] = stats.fetch(res[key], key)[fin]
-                done_out[lane_pos[fin]] = True
-                lane_pos[fin] = -1
-            live = int((lane_pos >= 0).sum())
-            free = np.nonzero(lane_pos < 0)[0]
-            n_ref = min(len(free), len(queue))
-            if n_ref and (deadline is None or _time.monotonic() < deadline):
-                state = do_refill(state, free, n_ref)
+    final_tt = tt
+    # one in-flight segment at all times; while it runs, the host
+    # processes the PREVIOUS boundary from its packed summary, and when
+    # no refill decision is pending the NEXT segment is dispatched
+    # speculatively (chained on the in-flight segment's output futures)
+    # before blocking on the summary
+    pend = None
+    prev_live = k > 0
+    pv_pending: list[tuple[int, int]] = []  # deferred (lane, pos)
+    if total < max_steps and (
+            deadline is None or _time.monotonic() < deadline):
+        pend = dispatch(state, tt, segment_steps)
+    while pend is not None:
+        p_state, p_tt, _p_n, p_summ = pend
+        nxt = None
+        if (prev_live and not queue
+                and total + segment_steps < max_steps
+                and (deadline is None or _time.monotonic() < deadline)):
+            # the queue is empty, so this exact segment would be
+            # dispatched after the boundary anyway; issuing it now
+            # donates p_state/p_tt in place and keeps the device busy
+            # across the host's boundary work
+            nxt = dispatch(p_state, p_tt, segment_steps)
+        summ, n, shard_steps = canon_summ(pull_summ(p_summ))
+        total += n
+        lane_done = summ[:, SUM_DONE].astype(bool)
+        fin = np.nonzero(lane_done & (lane_pos >= 0))[0]
+        if fin.size:
+            pos = lane_pos[fin]
+            out["score"][pos] = summ[fin, SUM_SCORE]
+            out["move"][pos] = summ[fin, SUM_MOVE]
+            out["nodes"][pos] = summ[fin, SUM_NODES]
+            done_out[pos] = True
+            if nxt is None:
+                pull_pv(p_state, fin, pos)
             else:
-                n_ref = 0
-            record(n, live, n_ref, pend_steps,
-                   shard_row(free, n_ref, shard_steps))
-            if live == 0 and n_ref == 0 and not queue:
-                break
-        final_state, final_tt = state, tt
-    else:
-        # pipelined loop: one in-flight segment at all times; while it
-        # runs, the host processes the PREVIOUS boundary from its packed
-        # summary, and when no refill decision is pending the NEXT
-        # segment is dispatched speculatively (chained on the in-flight
-        # segment's output futures) before blocking on the summary
-        pend = None
-        pend_steps = segment_steps
-        prev_live = k > 0
-        pv_pending: list[tuple[int, int]] = []  # deferred (lane, pos)
-        if total < max_steps and (
-                deadline is None or _time.monotonic() < deadline):
-            pend = dispatch(state, tt, segment_steps)
-        while pend is not None:
-            p_state, p_tt, _p_n, p_summ = pend
-            nxt = None
-            nxt_steps = segment_steps
-            if (prev_live and not queue
-                    and total + pend_steps < max_steps
-                    and (deadline is None or _time.monotonic() < deadline)):
-                # the queue is empty, so the synchronous loop would
-                # dispatch this exact segment after the boundary anyway;
-                # issuing it now donates p_state/p_tt in place and keeps
-                # the device busy across the host's boundary work
-                nxt = dispatch(p_state, p_tt, nxt_steps)
-            summ, n, shard_steps = canon_summ(pull_summ(p_summ))
-            total += n
-            lane_done = summ[:, SUM_DONE].astype(bool)
-            fin = np.nonzero(lane_done & (lane_pos >= 0))[0]
-            if fin.size:
-                pos = lane_pos[fin]
-                out["score"][pos] = summ[fin, SUM_SCORE]
-                out["move"][pos] = summ[fin, SUM_MOVE]
-                out["nodes"][pos] = summ[fin, SUM_NODES]
-                done_out[pos] = True
-                if nxt is None:
-                    pull_pv(p_state, fin, pos)
-                else:
-                    # p_state was donated into the speculative dispatch;
-                    # DONE lanes stay frozen (and the empty queue means
-                    # they are never respliced), so their PV rows are
-                    # pulled from a later resolved state
-                    pv_pending.extend(zip(fin.tolist(), pos.tolist()))
-                lane_pos[fin] = -1
-            if pv_pending and nxt is None:
-                lanes = np.asarray([ln for ln, _ in pv_pending], np.int64)
-                pos = np.asarray([p for _, p in pv_pending], np.int64)
-                pull_pv(p_state, lanes, pos)
-                pv_pending.clear()
-            live = int((lane_pos >= 0).sum())
-            free = np.nonzero(lane_pos < 0)[0]
-            n_ref = min(len(free), len(queue))
-            cur_state = p_state
-            if (n_ref and nxt is None
-                    and (deadline is None or _time.monotonic() < deadline)):
-                cur_state = do_refill(cur_state, free, n_ref)
-            else:
-                n_ref = 0
-            record(n, live, n_ref, pend_steps,
-                   shard_row(free, n_ref, shard_steps))
-            if nxt is not None:
-                pend = nxt
-                pend_steps = nxt_steps
-                prev_live = live > 0
-                continue
-            stop = (
-                (live == 0 and n_ref == 0 and not queue)
-                or total >= max_steps
-                or (deadline is not None
-                    and _time.monotonic() >= deadline)
-            )
-            if stop:
-                final_state, final_tt = cur_state, p_tt
-                pend = None
-            else:
-                pend = dispatch(cur_state, p_tt, segment_steps)
-                pend_steps = segment_steps
-                prev_live = live > 0 or n_ref > 0
+                # p_state was donated into the speculative dispatch;
+                # DONE lanes stay frozen (and the empty queue means
+                # they are never respliced), so their PV rows are
+                # pulled from a later resolved state
+                pv_pending.extend(zip(fin.tolist(), pos.tolist()))
+            lane_pos[fin] = -1
+        if pv_pending and nxt is None:
+            lanes = np.asarray([ln for ln, _ in pv_pending], np.int64)
+            pos = np.asarray([p for _, p in pv_pending], np.int64)
+            pull_pv(p_state, lanes, pos)
+            pv_pending.clear()
+        live = int((lane_pos >= 0).sum())
+        free = np.nonzero(lane_pos < 0)[0]
+        n_ref = min(len(free), len(queue))
+        cur_state = p_state
+        if (n_ref and nxt is None
+                and (deadline is None or _time.monotonic() < deadline)):
+            cur_state = do_refill(cur_state, free, n_ref)
+        else:
+            n_ref = 0
+        record(n, live, n_ref, shard_row(free, n_ref, shard_steps))
+        if nxt is not None:
+            pend = nxt
+            prev_live = live > 0
+            continue
+        stop = (
+            (live == 0 and n_ref == 0 and not queue)
+            or total >= max_steps
+            or (deadline is not None
+                and _time.monotonic() >= deadline)
+        )
+        if stop:
+            final_tt = p_tt
+            pend = None
+        else:
+            pend = dispatch(cur_state, p_tt, segment_steps)
+            prev_live = live > 0 or n_ref > 0
 
     return {
         "score": jnp.asarray(out["score"]),
@@ -1750,13 +1684,8 @@ def search_batch_resumable(
     # segment length and narrowing floor are registry-backed so deployments
     # can trade host-check latency against dispatch overhead without code
     # edits; the defaults reproduce the historical hardcoded values exactly.
-    # FISHNET_TPU_SEGMENT=auto has no feedback loop on this path (the
-    # controller lives in the streaming loops) — it falls back to the
-    # registry's upper bound
     if segment_steps is None:
-        segment_steps = settings.get_segment()
-        if segment_steps is None:
-            segment_steps = settings.get_int("FISHNET_TPU_SEGMENT_MAX")
+        segment_steps = settings.get_int("FISHNET_TPU_SEGMENT")
     narrow_floor = settings.get_int("FISHNET_TPU_NARROW_FLOOR")
 
     B = roots.stm.shape[0]
@@ -1886,12 +1815,9 @@ def search_batch(params: nnue.NnueParams, roots: Board, depth, node_budget,
     tests and production share the same `_run_segment_jit` programs; a
     second whole-search jit used to double every suite's compile cost).
     """
-    seg = settings.get_segment()
-    if seg is None:
-        seg = settings.get_int("FISHNET_TPU_SEGMENT_MAX")
     return search_batch_resumable(
         params, roots, depth, node_budget, max_ply=max_ply,
-        segment_steps=min(max_steps, seg),
+        segment_steps=min(max_steps, settings.get_int("FISHNET_TPU_SEGMENT")),
         max_steps=max_steps, tt=tt, variant=variant, hist=hist,
     )
 

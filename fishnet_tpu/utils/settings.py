@@ -149,41 +149,10 @@ SETTINGS: Tuple[Setting, ...] = (
     ),
     Setting(
         name="FISHNET_TPU_SEGMENT",
-        kind="str",
+        kind="int",
         default="20000",
         doc="Device steps per resumable segment between host checks "
-            "(deadline / narrowing / refill boundaries): an integer, or "
-            "\"auto\" for the measured-feedback controller that tunes "
-            "segment length from the boundary-cost/compute ratio within "
-            "[FISHNET_TPU_SEGMENT_MIN, FISHNET_TPU_SEGMENT_MAX].",
-        engine=True,
-    ),
-    Setting(
-        name="FISHNET_TPU_SEGMENT_MIN",
-        kind="int",
-        default="2048",
-        doc="Lower bound for FISHNET_TPU_SEGMENT=auto (and its starting "
-            "value): the controller never shrinks segments below this.",
-        engine=True,
-    ),
-    Setting(
-        name="FISHNET_TPU_SEGMENT_MAX",
-        kind="int",
-        default="65536",
-        doc="Upper bound for FISHNET_TPU_SEGMENT=auto: the controller "
-            "never grows segments beyond this (bounds deadline/refill "
-            "latency at a boundary check every MAX steps).",
-        engine=True,
-    ),
-    Setting(
-        name="FISHNET_TPU_PIPELINE",
-        kind="bool",
-        default="1",
-        doc="Asynchronous segment pipeline: the host stages the next "
-            "segment's admissions while the device runs the current one "
-            "and fetches one packed boundary summary instead of the full "
-            "result set (ops/search.py, engine/tpu.py LaneScheduler); 0 "
-            "restores the round-7 synchronous boundary loop bit-for-bit.",
+            "(deadline / narrowing / refill boundaries).",
         engine=True,
     ),
     Setting(
@@ -730,19 +699,6 @@ def get_str(name: str) -> Optional[str]:
     if s.kind != "str":
         raise TypeError(f"{name} is registered as {s.kind}, not str")
     return raw(name)
-
-
-def get_segment() -> Optional[int]:
-    """FISHNET_TPU_SEGMENT: fixed device-step count per segment, or None
-    when set to "auto" — callers run the measured-feedback
-    SegmentController (utils/syncstats.py) within the registry bounds
-    FISHNET_TPU_SEGMENT_MIN/_MAX instead of a fixed length."""
-    value = raw("FISHNET_TPU_SEGMENT")
-    assert value is not None, "FISHNET_TPU_SEGMENT has a registry default"
-    value = value.strip().lower()
-    if value == "auto":
-        return None
-    return int(value)
 
 
 def get_csv_int(name: str) -> Optional[Tuple[int, ...]]:

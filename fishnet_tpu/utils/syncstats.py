@@ -261,46 +261,3 @@ class SyncStats:
         self._seg_start = now
         return snap
 
-
-class SegmentController:
-    """Measured-feedback segment-length tuner (FISHNET_TPU_SEGMENT=auto).
-
-    Holds the boundary-cost share — host_ms / (host_ms + device_ms) per
-    segment — inside a hysteresis band by doubling the segment length
-    when boundaries dominate and halving it when the host is already
-    negligible (shorter segments mean lower deadline/refill latency, so
-    the controller never pays for responsiveness it doesn't need).
-    Bounds come from the settings registry (FISHNET_TPU_SEGMENT_MIN /
-    _MAX); adjustments are power-of-two so the step count revisits the
-    same few values instead of drifting. segment_steps is a *traced*
-    argument of _run_segment_jit, so retuning never recompiles.
-    """
-
-    def __init__(self, lo: int, hi: int, start: Optional[int] = None,
-                 low_share: float = 0.02, high_share: float = 0.10) -> None:
-        if lo < 1:
-            raise ValueError(f"segment lower bound must be >= 1, got {lo}")
-        if hi < lo:
-            raise ValueError(f"segment bounds inverted: [{lo}, {hi}]")
-        self.lo = lo
-        self.hi = hi
-        self.low_share = low_share
-        self.high_share = high_share
-        self.steps = min(max(start if start is not None else lo, lo), hi)
-
-    def update(self, ran_full: bool, host_ms: float,
-               device_ms: float) -> int:
-        """Feed one boundary's measurement; returns the step count for
-        the next segment. Segments that ended early (every lane DONE)
-        carry no length signal and leave the setting untouched."""
-        if not ran_full:
-            return self.steps
-        total = host_ms + device_ms
-        if total <= 0.0:
-            return self.steps
-        share = host_ms / total
-        if share > self.high_share:
-            self.steps = min(self.steps * 2, self.hi)
-        elif share < self.low_share:
-            self.steps = max(self.steps // 2, self.lo)
-        return self.steps

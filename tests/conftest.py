@@ -23,12 +23,9 @@ os.environ.setdefault("FISHNET_TPU_WARMUP_BUCKETS", "16")
 # behavior is covered explicitly in tests/test_helper_lanes.py, which
 # constructs TpuEngine(helper_lanes=...) itself.
 os.environ.setdefault("FISHNET_TPU_HELPERS", "1")
-# Continuous lane refill off by default under pytest for the same reason:
-# the LaneScheduler is a second dispatch path through the engine, and the
-# dozens of existing engine tests assert against the chunk-serial path's
-# exact behavior. Refill behavior is covered explicitly in
-# tests/test_refill.py, which constructs TpuEngine(refill=True) itself.
-os.environ.setdefault("FISHNET_TPU_REFILL", "0")
+# FISHNET_TPU_REFILL is NOT pinned: single-pv analysis chunks run through
+# the LaneScheduler, as in every deployment path. A test that means the
+# chunk-serial path says so: TpuEngine(refill=False).
 
 # make the package importable regardless of how pytest was invoked; the
 # settings registry (pure stdlib, safe before jax) is the single source

@@ -269,10 +269,13 @@ def test_decode_uci_handles_king_promotion():
     assert _decode_uci(m) == "e7e8k"
 
 
-def test_variant_chunk_through_engine(variant):
+@pytest.mark.parametrize("refill", [False, True])
+def test_variant_chunk_through_engine(variant, refill):
+    """Every variant program through both dispatch paths: chunk-serial
+    (`_analyse_single`) and the LaneScheduler."""
     from fishnet_tpu.engine.tpu import TpuEngine
 
-    engine = TpuEngine(max_depth=2)
+    engine = TpuEngine(max_depth=2, refill=refill)
     work = AnalysisWork(
         id="varjob01",
         nodes=NodeLimit(sf16=500_000, classical=500_000),

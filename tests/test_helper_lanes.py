@@ -122,11 +122,13 @@ def test_plan_helpers_hardest_first_round_robin():
 
 
 def _host_engine(helper_lanes):
-    """Engine with the device program stubbed out: records every _search
-    dispatch so the host-side helper layout is testable without XLA."""
+    """Chunk-serial engine with the device program stubbed out: records
+    every _search dispatch so the host-side helper layout of
+    _analyse_single is testable without XLA."""
     from fishnet_tpu.engine.tpu import TpuEngine
 
-    engine = TpuEngine(max_depth=2, max_lanes=16, helper_lanes=helper_lanes)
+    engine = TpuEngine(max_depth=2, max_lanes=16, helper_lanes=helper_lanes,
+                       refill=False)
     calls = []
 
     def fake_search(roots, depth_arr, budget_arr, deadline=None, **kw):

@@ -9,9 +9,8 @@ a single result. Contracts pinned here:
 1. Shard-local refill is bit-identical to chunk-serial dispatch and to
    the single-device stream: resplicing lanes per shard is pure
    scheduling, never search behavior.
-2. Pipeline ON under a mesh is bit-identical to the synchronous mesh
-   loop, and a no-finish boundary still costs exactly one host transfer
-   (the stacked per-shard summary is one fetch).
+2. Under a mesh a no-finish boundary still costs exactly one host
+   transfer (the stacked per-shard summary is one fetch).
 3. The sharded segment donates its operands like the single-device jit:
    inputs are dead after the call, callers must rebind to outputs.
 4. Every position answers exactly once even when lanes finish on
@@ -22,8 +21,8 @@ a single result. Contracts pinned here:
    strictly above the chunk-serial mesh path (the point of the change).
 
 conftest.py forces 8 virtual CPU devices (the `mesh` marker documents
-the requirement) and pins FISHNET_TPU_REFILL=0; engines here opt in with
-refill=True and keep the mesh conftest provides.
+the requirement); engines here say refill=True or False and keep the mesh
+conftest provides.
 """
 import asyncio
 import threading
@@ -76,7 +75,7 @@ def _inputs():
 @pytest.fixture(scope="module")
 def mesh_streams():
     """One set of search_stream runs over the same staggered workload:
-    single-device baseline, mesh sync, mesh pipelined, and the
+    single-device baseline, the sharded stream, and the
     chunk-serial mesh baseline (same width, each chunk fits, so no
     refill ever fires). Several tests assert against the set — the
     XLA:CPU runs are the slow part, the asserts are free."""
@@ -90,12 +89,9 @@ def mesh_streams():
     mesh = make_mesh()
     kw = dict(max_ply=6, width=WIDTH, segment_steps=150)
     out = {
-        "base": S.search_stream(params, roots, DEPTHS, budget,
-                                pipeline=False, **kw),
-        "mesh_sync": S.search_stream(params, roots, DEPTHS, budget,
-                                     mesh=mesh, pipeline=False, **kw),
+        "base": S.search_stream(params, roots, DEPTHS, budget, **kw),
         "mesh_piped": S.search_stream(params, roots, DEPTHS, budget,
-                                      mesh=mesh, pipeline=True, **kw),
+                                      mesh=mesh, **kw),
     }
     serial = {"occupancy": [], "score": [], "move": [], "nodes": [],
               "pv_len": [], "pv": []}
@@ -103,7 +99,7 @@ def mesh_streams():
         hi = min(lo + WIDTH, N_POS)
         sub = jax.tree.map(lambda a: a[lo:hi], roots)
         r = S.search_stream(params, sub, DEPTHS[lo:hi], budget[lo:hi],
-                            mesh=mesh, pipeline=False, **kw)
+                            mesh=mesh, **kw)
         assert r["refills"] == 0, "chunk-serial baseline must never refill"
         serial["occupancy"].extend(r["occupancy"])
         for key in ("score", "move", "nodes", "pv_len", "pv"):
@@ -117,7 +113,7 @@ def mesh_streams():
 def test_stream_mesh_matches_single_device(mesh_streams):
     """Sharded dispatch is bit-identical to the single-device stream:
     same scores, moves, PVs and node counts position by position."""
-    base, sharded = mesh_streams["base"], mesh_streams["mesh_sync"]
+    base, sharded = mesh_streams["base"], mesh_streams["mesh_piped"]
     assert bool(np.asarray(base["done"]).all())
     assert bool(np.asarray(sharded["done"]).all())
     for key in ("score", "move", "nodes", "pv_len", "pv", "done"):
@@ -128,50 +124,36 @@ def test_stream_mesh_matches_single_device(mesh_streams):
 def test_stream_mesh_refill_matches_chunk_serial(mesh_streams):
     """ISSUE acceptance: shard-local refill reproduces the chunk-serial
     mesh path exactly — refill is scheduling, not search."""
-    refill, serial = mesh_streams["mesh_sync"], mesh_streams["serial"]
+    refill, serial = mesh_streams["mesh_piped"], mesh_streams["serial"]
     assert refill["refills"] >= N_POS - WIDTH
     for key in ("score", "move", "nodes", "pv_len", "pv"):
         np.testing.assert_array_equal(
             np.asarray(refill[key]), serial[key], err_msg=key)
 
 
-def test_stream_mesh_pipeline_parity(mesh_streams):
-    """Pipeline on/off parity holds under a mesh: speculation over the
-    stacked per-shard summary never changes a result."""
-    sync, piped = mesh_streams["mesh_sync"], mesh_streams["mesh_piped"]
-    for key in ("score", "move", "nodes", "pv_len", "pv", "done"):
-        np.testing.assert_array_equal(
-            np.asarray(sync[key]), np.asarray(piped[key]), err_msg=key)
-
-
 def test_stream_mesh_occupancy_shard_columns(mesh_streams):
     """Mesh occupancy rows carry per-shard live/refilled/steps lists (one
     entry per device) consistent with the scalar columns."""
-    for mode in ("mesh_sync", "mesh_piped"):
-        occ = mesh_streams[mode]["occupancy"]
-        assert occ, f"{mode}: no boundaries recorded"
-        for row in occ:
-            for key in ("shard_live", "shard_refilled", "shard_steps"):
-                assert len(row[key]) == 8, (mode, key)
-            assert sum(row["shard_live"]) == row["live"]
-            assert sum(row["shard_refilled"]) == row["refilled"]
-            assert max(row["shard_steps"]) == row["steps"]
+    occ = mesh_streams["mesh_piped"]["occupancy"]
+    assert occ, "no boundaries recorded"
+    for row in occ:
+        for key in ("shard_live", "shard_refilled", "shard_steps"):
+            assert len(row[key]) == 8, key
+        assert sum(row["shard_live"]) == row["live"]
+        assert sum(row["shard_refilled"]) == row["refilled"]
+        assert max(row["shard_steps"]) == row["steps"]
     # the single-device run must NOT grow shard columns
     assert "shard_live" not in mesh_streams["base"]["occupancy"][0]
 
 
 def test_stream_mesh_pipelined_boundary_is_one_transfer(mesh_streams):
-    """ISSUE acceptance: a no-finish boundary under the pipelined mesh
-    loop is ONE host transfer — the stacked (ndev, local+1, 4) summary
+    """ISSUE acceptance: a no-finish boundary under a mesh is ONE host
+    transfer — the stacked (ndev, local+1, 4) summary
     comes back as a single fetch, not one per shard."""
     occ = mesh_streams["mesh_piped"]["occupancy"]
     nofin = [o for o in occ[:-1] if o["refilled"] == 0]
     assert nofin, "shape produced no quiet boundaries; shrink the segment"
     assert all(o["transfers"] == 1 for o in nofin)
-    # and the synchronous mesh loop pays more at the same boundaries
-    sync_nofin = [o for o in mesh_streams["mesh_sync"]["occupancy"][:-1]
-                  if o["refilled"] == 0]
-    assert min(o["transfers"] for o in sync_nofin) >= 2
 
 
 def _mean_live_occupancy(rows):
@@ -186,7 +168,7 @@ def test_stream_mesh_refill_occupancy_beats_serial(mesh_streams):
     occupancy with shard-local refill is strictly higher than the
     chunk-serial mesh path at the same width — idle lanes get respliced
     instead of spinning until the deepest lane in the chunk finishes."""
-    refill = _mean_live_occupancy(mesh_streams["mesh_sync"]["occupancy"])
+    refill = _mean_live_occupancy(mesh_streams["mesh_piped"]["occupancy"])
     serial = _mean_live_occupancy(mesh_streams["serial"]["occupancy"])
     assert refill > serial, (refill, serial)
 

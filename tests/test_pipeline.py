@@ -1,34 +1,32 @@
-"""Asynchronous segment pipeline (round 8) tests.
+"""Asynchronous segment boundaries: the one loop of search_stream and
+of the engine's LaneScheduler (ops/search.py packed boundary summary +
+buffer donation, engine/tpu.py _Session, utils/syncstats.py).
 
-Four contracts from the pipeline change (ops/search.py packed boundary
-summary + buffer donation, engine/tpu.py double-buffered LaneScheduler,
-utils/syncstats.py):
-
-1. Pipeline ON is bit-identical to the round-7 synchronous loop at both
-   the ops level (search_stream) and the engine level (LaneScheduler):
-   overlap and speculation must never change a result, only its timing.
-2. Every submitted position gets exactly one PositionResponse even when
+1. Every submitted position gets exactly one PositionResponse even when
    boundaries are processed one segment behind the device (speculative
-   dispatch) — no drops, no duplicates.
-3. Buffer donation is real: the state handed to _run_segment_jit is dead
+   dispatch) — no drops, no duplicates. That the results are the right
+   ones is held against independent paths elsewhere: the chunk-serial
+   engine (tests/test_refill.py::test_refill_on_matches_refill_off),
+   search_batch (test_search_stream_matches_batch), the benchmark's
+   plain reference (tests/benchmark/).
+2. Buffer donation is real: the state handed to _run_segment_jit is dead
    after the call, and the jits always rebind to outputs (a use of the
    donated input is a bug this suite must catch before XLA does).
-4. The pipelined boundary is cheap: one packed-summary transfer on a
-   no-finish boundary at the stream level, and >= 5x fewer transfers
-   than the synchronous loop at the engine level (ISSUE acceptance).
-5. The host timeline of a session, in both loops: the phase counters
-   decompose every boundary interval (they sum to host_ms + device_ms,
-   wait is device_ms), sessions are host + device + set-up + tail, and
-   with a recorder on each `phase.*` span was emitted where its work
-   ran — inside its segment and session, overlapping no other — while
-   the submit path leaves async pairs only. Results are bit-identical
-   with the recorder on and off.
+3. The boundary is cheap: one packed-summary transfer on a quiet
+   boundary, at the stream level and in the scheduler, whose dearest
+   boundary adds the PV block and its lengths and nothing else.
+4. The host timeline of a session: the phase counters decompose every
+   boundary interval (they sum to host_ms + device_ms, wait is
+   device_ms), sessions are host + device + set-up + tail, and with a
+   recorder on each `phase.*` span was emitted where its work ran —
+   inside its segment and session, overlapping no other — while the
+   submit path leaves async pairs only. Results are bit-identical with
+   the recorder on and off.
 
-conftest.py pins REFILL=0/HELPERS=1; engine tests opt in via refill=True
-exactly like tests/test_refill.py (mesh=None single-device scheduler).
+Engine tests build the single-device scheduler (mesh=None) with no
+helper coupling, exactly like tests/test_refill.py.
 """
 import asyncio
-import os
 import time
 
 import numpy as np
@@ -67,59 +65,21 @@ def _stream_inputs(n=6, depth=2):
     return params, roots, depth_arr, budget
 
 
-@pytest.fixture(scope="module")
-def stream_pair():
-    """One search_stream run per mode over the same inputs; several
-    tests assert against the pair (XLA:CPU runs are the slow part)."""
+def test_stream_pipelined_boundary_is_one_transfer():
+    """A no-finish boundary fetches exactly the packed summary — one
+    transfer (the final boundary additionally drains results; refill
+    boundaries pull the finished lanes' rows)."""
     from fishnet_tpu.ops import search as S
 
     params, roots, depth_arr, budget = _stream_inputs()
-    out = {}
-    for pipeline in (False, True):
-        out[pipeline] = S.search_stream(
-            params, roots, depth_arr, budget, max_ply=6, width=4,
-            segment_steps=200, pipeline=pipeline)
-    return out
-
-
-def test_stream_bit_identity(stream_pair):
-    """Same scores, moves, PVs and node counts with the pipeline on and
-    off: speculation and summary-only boundaries are pure scheduling."""
-    legacy, piped = stream_pair[False], stream_pair[True]
-    assert bool(np.asarray(legacy["done"]).all())
-    assert bool(np.asarray(piped["done"]).all())
-    for key in ("score", "move", "nodes", "pv_len", "pv", "done"):
-        np.testing.assert_array_equal(
-            np.asarray(legacy[key]), np.asarray(piped[key]), err_msg=key)
-
-
-def test_stream_pipelined_boundary_is_one_transfer(stream_pair):
-    """A no-finish boundary in pipelined mode fetches exactly the packed
-    summary — one transfer (the final boundary additionally drains
-    results; refill boundaries pull the finished lanes' rows)."""
-    occ = stream_pair[True]["occupancy"]
+    out = S.search_stream(params, roots, depth_arr, budget, max_ply=6,
+                          width=4, segment_steps=200)
+    assert bool(np.asarray(out["done"]).all())
+    occ = out["occupancy"]
     assert occ, "no boundaries recorded"
     nofin = [o for o in occ[:-1] if o["refilled"] == 0]
     assert nofin, "shape produced no quiet boundaries; shrink the segment"
     assert all(o["transfers"] == 1 for o in nofin)
-    # and the synchronous loop pays more at the same boundaries
-    legacy_nofin = [o for o in stream_pair[False]["occupancy"][:-1]
-                    if o["refilled"] == 0]
-    assert min(o["transfers"] for o in legacy_nofin) >= 2
-
-
-def test_stream_segment_auto_controller(monkeypatch):
-    """segment_steps=None + FISHNET_TPU_SEGMENT=auto engages the
-    measured-feedback controller and still finishes every position."""
-    from fishnet_tpu.ops import search as S
-
-    monkeypatch.setenv("FISHNET_TPU_SEGMENT", "auto")
-    monkeypatch.setenv("FISHNET_TPU_SEGMENT_MIN", "64")
-    monkeypatch.setenv("FISHNET_TPU_SEGMENT_MAX", "1024")
-    params, roots, depth_arr, budget = _stream_inputs(n=4)
-    out = S.search_stream(params, roots, depth_arr, budget, max_ply=6,
-                          width=4, segment_steps=None, pipeline=True)
-    assert bool(np.asarray(out["done"]).all())
 
 
 def test_no_use_after_donate():
@@ -177,37 +137,27 @@ def make_refill_engine(**kw):
 
 @pytest.fixture(scope="module")
 def engine_pair():
-    """One LaneScheduler chunk per pipeline mode at a small segment (many
-    boundaries, so the speculative path actually engages), and the same
-    chunk again with a recorder on: out["traced", mode] holds
-    (responses, the ring's events, totals)."""
-    saved = {k: os.environ.get(k)
-             for k in ("FISHNET_TPU_PIPELINE", "FISHNET_TPU_SEGMENT")}
+    """One LaneScheduler chunk at a small segment (many boundaries, so
+    the speculative path actually engages): out["plain"] holds
+    (responses, occupancy log, totals), and the same chunk again with a
+    recorder on: out["traced"] holds (responses, the ring's events,
+    totals)."""
     out = {}
-    try:
-        os.environ["FISHNET_TPU_SEGMENT"] = "200"
-        for mode in ("0", "1"):
-            os.environ["FISHNET_TPU_PIPELINE"] = mode
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("FISHNET_TPU_SEGMENT", "200")
+        eng = make_refill_engine()
+        resp = asyncio.run(eng.go_multiple(
+            make_chunk(analysis_work(depth=3), n_positions=4)))
+        out["plain"] = (resp, list(eng.occupancy_log),
+                        dict(eng.occupancy_totals))
+        rec = obs_trace.install(obs_trace.TraceRecorder(capacity=65536))
+        try:
             eng = make_refill_engine()
             resp = asyncio.run(eng.go_multiple(
                 make_chunk(analysis_work(depth=3), n_positions=4)))
-            out[mode] = (resp, list(eng.occupancy_log),
-                         dict(eng.occupancy_totals))
-            rec = obs_trace.install(obs_trace.TraceRecorder(capacity=65536))
-            try:
-                eng = make_refill_engine()
-                resp = asyncio.run(eng.go_multiple(
-                    make_chunk(analysis_work(depth=3), n_positions=4)))
-            finally:
-                obs_trace.uninstall()
-            out["traced", mode] = (resp, rec.snapshot(),
-                                   dict(eng.occupancy_totals))
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        finally:
+            obs_trace.uninstall()
+        out["traced"] = (resp, rec.snapshot(), dict(eng.occupancy_totals))
     return out
 
 
@@ -219,53 +169,40 @@ def _flat(resps):
 def test_engine_exactly_once_under_speculation(engine_pair):
     """Every position answers exactly once even when the host stages
     admissions one segment behind the speculatively-dispatched device."""
-    for mode in ("0", "1"):
-        resp, _log, totals = engine_pair[mode]
-        assert sorted(r.position_index for r in resp) == [0, 1, 2, 3]
-        assert all(r.best_move for r in resp)
-        assert totals["positions_done"] == 4
-
-
-def test_engine_bit_identity(engine_pair):
-    """Scheduler results are identical with the pipeline on and off:
-    same best moves, scores, depths, node counts and PVs."""
-    assert _flat(engine_pair["0"][0]) == _flat(engine_pair["1"][0])
+    resp, _log, totals = engine_pair["plain"]
+    assert sorted(r.position_index for r in resp) == [0, 1, 2, 3]
+    assert all(r.best_move for r in resp)
+    assert totals["positions_done"] == 4
 
 
 def test_engine_boundary_transfer_reduction(engine_pair):
-    """ISSUE acceptance: >= 5x fewer host transfers per no-finish
-    boundary. The synchronous loop fetches the step count, the DONE mask
-    and the six extract_results arrays every boundary; the pipelined
-    loop fetches one packed summary."""
-    quiet = {}
-    for mode in ("0", "1"):
-        log = engine_pair[mode][1]
-        nofin = [r["transfers"] for r in log if r["refilled"] == 0]
-        assert nofin, f"mode {mode}: no quiet boundaries recorded"
-        # rows where a lane parked for re-admission also count
-        # refilled == 0 (the admission lands in the NEXT row) but pay a
-        # PV pull; the steady-state no-finish cost is the row minimum
-        quiet[mode] = min(nofin)
-    assert quiet["0"] >= 5 * quiet["1"], quiet
-    # even the engine's most expensive pipelined boundary (summary + PV
-    # pull) undercuts the synchronous loop's cheapest one
-    assert max(r["transfers"] for r in engine_pair["1"][1]) < quiet["0"]
-    # occupancy rows carry the host/device split for both modes
-    for mode in ("0", "1"):
-        row = engine_pair[mode][1][0]
-        for key in ("transfers", "host_ms", "device_ms"):
-            assert key in row
+    """A quiet boundary of the scheduler fetches exactly one array, the
+    packed summary; its dearest boundary adds the PV block and its
+    lengths (flush_pv) and nothing else: three. A loop that brought the
+    step count, the DONE mask and the six result arrays home paid eight
+    at its cheapest."""
+    log = engine_pair["plain"][1]
+    nofin = [r["transfers"] for r in log if r["refilled"] == 0]
+    assert nofin, "no quiet boundaries recorded"
+    # rows where a lane parked for re-admission also count refilled == 0
+    # (the admission lands in the NEXT row) but pay a PV pull; the
+    # steady-state no-finish cost is the row minimum
+    assert min(nofin) == 1
+    assert max(r["transfers"] for r in log) == 3
+    assert {r["transfers"] for r in log} == {1, 3}
+    # occupancy rows carry the host/device split
+    for key in ("transfers", "host_ms", "device_ms"):
+        assert key in log[0]
 
 
 # ------------------------------------------------- the host's timeline
 
 
-@pytest.mark.parametrize("mode", ["0", "1"])
-def test_engine_phase_counters_tie_out(engine_pair, mode):
+def test_engine_phase_counters_tie_out(engine_pair):
     """Every boundary interval is decomposed, none of it dropped: the
     phase totals sum to host_ms + device_ms, the wait phase IS
     device_ms, and a session is its boundaries plus set-up and tail."""
-    for totals in (engine_pair[mode][2], engine_pair["traced", mode][2]):
+    for totals in (engine_pair["plain"][2], engine_pair["traced"][2]):
         in_boundaries = totals["host_ms"] + totals["device_ms"]
         assert in_boundaries > 0
         assert sum(totals[f"phase_{p}_ms"] for p in PHASES) == pytest.approx(
@@ -284,9 +221,8 @@ def test_engine_phase_counters_tie_out(engine_pair, mode):
                     ) <= totals["submit_ms"]
 
 
-@pytest.mark.parametrize("mode", ["0", "1"])
-def test_engine_phase_spans_are_where_the_work_ran(engine_pair, mode):
-    events = engine_pair["traced", mode][1]
+def test_engine_phase_spans_are_where_the_work_ran(engine_pair):
+    events = engine_pair["traced"][1]
     names = {e["name"] for e in events}
     assert "segment.device" not in names and "segment.host" not in names
     assert "segment.dispatch" not in names  # it is phase.dispatch now
@@ -329,8 +265,7 @@ def test_engine_phase_spans_are_where_the_work_ran(engine_pair, mode):
             assert a["ts"] + a["dur"] <= b["ts"] + 1e-3, (a, b)
     dispatches = [e for e in phases if e["name"] == "phase.dispatch"]
     assert all(e["args"]["steps"] == 200 for e in dispatches)
-    if mode == "1":
-        assert any(e["args"]["speculative"] for e in dispatches)
+    assert any(e["args"]["speculative"] for e in dispatches)
     # the submit path runs while no session does: async pairs, no span
     submits = [e for e in events if e["name"].startswith("submit")]
     assert submits and all(e["ph"] in ("b", "e") for e in submits)
@@ -348,8 +283,7 @@ def test_engine_phase_spans_are_where_the_work_ran(engine_pair, mode):
     assert len(report["sessions"]) == len(sessions)
 
 
-@pytest.mark.parametrize("mode", ["0", "1"])
-def test_engine_bit_identity_recorder_on_off(engine_pair, mode):
+def test_engine_bit_identity_recorder_on_off(engine_pair):
     """Tracing is bookkeeping: the new phase, session and submit sites
     change no result."""
-    assert _flat(engine_pair[mode][0]) == _flat(engine_pair["traced", mode][0])
+    assert _flat(engine_pair["plain"][0]) == _flat(engine_pair["traced"][0])

@@ -11,8 +11,7 @@ ops/search.py refill_lanes/search_stream):
 3. Every submitted position gets exactly one response, even when several
    chunks share the engine concurrently through the combining driver.
 
-conftest.py sets FISHNET_TPU_REFILL=0, so engines here opt in explicitly
-with refill=True. This file pins the SINGLE-DEVICE scheduler semantics:
+Engines here say refill=True or refill=False explicitly. This file pins the SINGLE-DEVICE scheduler semantics:
 refill engines force engine.mesh = None, which is exactly what a
 single-device production host looks like (conftest's 8 virtual CPU
 devices would otherwise give every engine a mesh — the sharded
@@ -73,10 +72,14 @@ def make_refill_engine(**kw):
 
 
 def test_refill_defaults_to_registry():
-    """refill=None defers to FISHNET_TPU_REFILL, which conftest pins to 0;
-    an explicit constructor argument wins over the registry."""
-    assert TpuEngine(max_depth=2, tt_size_log2=0).refill is False
-    assert TpuEngine(max_depth=2, tt_size_log2=0, refill=True).refill is True
+    """refill=None defers to FISHNET_TPU_REFILL (the registry default is
+    on, and the suite does not pin it); an explicit constructor argument
+    wins over the registry."""
+    from fishnet_tpu.utils import settings
+
+    assert settings.lookup("FISHNET_TPU_REFILL").default == "1"
+    assert TpuEngine(max_depth=2, tt_size_log2=0).refill is True
+    assert TpuEngine(max_depth=2, tt_size_log2=0, refill=False).refill is False
 
 
 def _stub_search(engine):

@@ -24,6 +24,15 @@ def engine():
     return TpuEngine(max_depth=3)
 
 
+@pytest.fixture(scope="module")
+def engines(engine):
+    """refill → engine. The module's engine sends single-pv analysis
+    through the LaneScheduler (the registry default, as deployed); the
+    other one takes the chunk-serial path (`_analyse_single`)."""
+    assert engine.refill is True
+    return {True: engine, False: TpuEngine(max_depth=3, refill=False)}
+
+
 def make_chunk(work, n_positions=3, moves=GAME, variant="standard"):
     positions = [
         WorkPosition(
@@ -52,7 +61,9 @@ def run(engine, chunk):
     return asyncio.run(engine.go_multiple(chunk))
 
 
-def test_analysis_chunk(engine):
+@pytest.mark.parametrize("refill", [False, True])
+def test_analysis_chunk(engines, refill):
+    engine = engines[refill]
     responses = run(engine, make_chunk(analysis_work(depth=3)))
     assert len(responses) == 3
     for i, res in enumerate(responses):
@@ -143,7 +154,9 @@ def test_multipv_chunk(engine):
         assert val(0) >= val(1) >= val(2)
 
 
-def test_terminal_position(engine):
+@pytest.mark.parametrize("refill", [False, True])
+def test_terminal_position(engines, refill):
+    engine = engines[refill]
     # fool's mate final position: mate 0 at depth 0
     moves = ["f2f3", "e7e5", "g2g4", "d8h4"]
     work = analysis_work(depth=3)
@@ -159,7 +172,9 @@ def test_terminal_position(engine):
     assert res.best_move is None
 
 
-def test_mate_in_one_found(engine):
+@pytest.mark.parametrize("refill", [False, True])
+def test_mate_in_one_found(engines, refill):
+    engine = engines[refill]
     work = analysis_work(depth=2)
     positions = [
         WorkPosition(work=work, position_index=0, url=None, skip=False,

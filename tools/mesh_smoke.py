@@ -49,10 +49,8 @@ SMOKE_ENV = {
     "JAX_PLATFORMS": "cpu",
     "FISHNET_TPU_MAX_PLY": "8",
     "FISHNET_TPU_HELPERS": "1",
-    # the SegmentController adapts on wall-clock; bit-identity needs a
-    # pinned boundary cadence
+    # short segments: lanes park at different boundaries
     "FISHNET_TPU_SEGMENT": "150",
-    "FISHNET_TPU_PIPELINE": "1",
 }
 CHILD_TIMEOUT_S = 540.0
 
@@ -128,7 +126,7 @@ def run_child(role: str, out_path: str) -> int:
         np.full(len(DEPTHS), BUDGET, np.int32),
         max_ply=MAX_PLY, width=WIDTH,
         tt=make_sharded_table(mesh, TT_LOG2),
-        mesh=mesh, pipeline=True, sync_stats=stats,
+        mesh=mesh, sync_stats=stats,
     )
     report = {
         "role": role,

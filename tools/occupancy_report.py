@@ -23,10 +23,7 @@ transfer count and host/device wall-clock split (utils/syncstats.py via
 search_stream), and the summary line reports the aggregate boundary
 share host_ms/(host_ms+device_ms). --host-share-threshold warns (a
 ::warning annotation under --format=github) when that share exceeds the
-bound — the pipeline exists precisely to keep it small. --pipeline-ab
-runs the stage twice (FISHNET_TPU_PIPELINE off, then on) and FAILS on
-any per-position result divergence: the pipelined loop must be
-bit-identical to the round-7 synchronous loop.
+bound — the asynchronous boundary exists precisely to keep it small.
 
 Round 10 (mesh parity): --mesh-ab runs the stage single-device and then
 sharded over every local device (search_stream(mesh=make_mesh())) and
@@ -140,9 +137,6 @@ def main() -> int:
     ap.add_argument("--host-share-threshold", type=float, default=0.25,
                     help="annotate when the boundary host share "
                          "host_ms/(host_ms+device_ms) exceeds this")
-    ap.add_argument("--pipeline-ab", action="store_true",
-                    help="run the stage with the segment pipeline off "
-                         "then on; FAIL on any result divergence")
     ap.add_argument("--mesh-ab", action="store_true",
                     help="run the stage single-device then sharded over "
                          "all local devices (TT disabled for both); FAIL "
@@ -218,7 +212,7 @@ def main() -> int:
             print("mesh A/B: TT disabled for both passes "
                   "(sharded vs flat tables hash differently)")
 
-    def run(pipeline=None, on_mesh=None):
+    def run(on_mesh=None):
         # the table (and the running state) are DONATED into the segment
         # jits, so every pass gets its own fresh table
         tt = None
@@ -230,18 +224,13 @@ def main() -> int:
         out = S.search_stream(
             params, roots, depth, budget, max_ply=args.max_ply,
             width=args.lanes, segment_steps=args.segment, tt=tt,
-            mesh=on_mesh, pipeline=pipeline,
+            mesh=on_mesh,
         )
         jax.block_until_ready(out["nodes"])
         return out, time.perf_counter() - t0
 
-    legacy = None
-    if args.pipeline_ab:
-        legacy = run(pipeline=False, on_mesh=mesh)
-        out, wall = run(pipeline=True, on_mesh=mesh)
-    else:
-        out, wall = run(on_mesh=mesh)
-    flat_base = run(pipeline=False) if args.mesh_ab else None
+    out, wall = run(on_mesh=mesh)
+    flat_base = run() if args.mesh_ab else None
 
     # ops-level rows: {segment, steps, live, refilled, idle, queue} plus
     # the round-8 syncstats columns {transfers, host_ms, device_ms}
@@ -299,27 +288,6 @@ def main() -> int:
             ]
         print("OCCUPANCY " + json.dumps(summary))
 
-    if legacy is not None:
-        lout, lwall = legacy
-        diverged = []
-        for key in ("score", "move", "nodes", "pv_len", "pv", "done"):
-            if not np.array_equal(np.asarray(lout[key]),
-                                  np.asarray(out[key])):
-                diverged.append(key)
-        lx = sum(o["transfers"] for o in lout["occupancy"])
-        print(f"pipeline A/B: legacy {lwall:.2f}s / pipelined {wall:.2f}s "
-              f"({lwall / max(wall, 1e-9):.2f}x), transfers {lx} -> "
-              f"{transfers}")
-        if diverged:
-            msg = (f"pipelined results diverge from the synchronous loop "
-                   f"on: {', '.join(diverged)} — the segment pipeline "
-                   "must be bit-identical")
-            if args.format == "github":
-                print(f"::error title=pipeline-ab divergence::{msg}")
-            else:
-                print(f"ERROR: {msg}")
-            return 1
-
     if flat_base is not None:
         fout, fwall = flat_base
         diverged = []
@@ -359,7 +327,7 @@ def main() -> int:
         msg = (f"boundary host share {boundary_share:.3f} exceeds "
                f"{args.host_share_threshold} — the host is stalling the "
                "device at segment boundaries; shrink the boundary work "
-               "or raise FISHNET_TPU_SEGMENT (=auto retunes it)")
+               "or raise FISHNET_TPU_SEGMENT")
         if args.format == "github":
             print(f"::warning title=occupancy-report host-share::{msg}")
         else:
