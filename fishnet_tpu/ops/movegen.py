@@ -6,7 +6,14 @@ slots, (64×4) pawn slots, (8×3×4) promotion slots (promotions only
 originate from the 8 pre-promotion-rank squares), 2 castling slots — as
 masks, then compact valid candidates into a fixed (MAX_MOVES,) ORDERED move
 list with one single-array sort of packed (ordering_key << 16 | move)
-values (see generate_moves for the packing invariants). Legality is *not*
+values (see generate_moves for the packing invariants). The space that is
+sorted is not the space that is enumerated: about half the slots are table
+padding (a ray past the edge, a knight target off the board), False on
+every board, and a constant per-variant index table (_live_slots) takes
+only the slots that can hold a move — 2,550 of 4,962; 2,854 of 5,282 with
+crazyhouse's drops — to the ordering refinements, the pack and the sort.
+The sort's cost on the TPU steps at powers of two of its width (PERF.md
+§5), so the table is what takes it below 4,096. Legality is *not*
 fully resolved here: the search uses king-capture pruning (an illegal mover
 is refuted one ply later when its king is captured), so only castling does
 attack checks. This keeps the kernel free of pin/evasion logic; the host
@@ -17,6 +24,7 @@ Single-lane function; `vmap` over lanes gives the batch.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -70,25 +78,27 @@ def max_moves_for(variant: str) -> int:
     return MAX_MOVES_ZH if variant == "crazyhouse" else MAX_MOVES
 
 
-@functools.lru_cache(maxsize=None)
-def _hist_idx_tables(variant: str):
-    """Per-color (n_candidates,) tables of `cand & 4095` (the from|to
-    history index) for every candidate slot, as numpy constants.
+def _promo_list(variant: str) -> list:
+    """Promotion pieces, in slot order (antichess also promotes to king)."""
+    promos = [T.PROMO_N, T.PROMO_B, T.PROMO_R, T.PROMO_Q]
+    return promos + [T.PROMO_K] if variant == "antichess" else promos
 
-    Candidate VALUES are static per side to move — every section below
-    mirrors `generate_moves`' candidate assembly (same tables, same
-    order) — except the two castling slots, which hold 0 here; castling
-    keys are 900, and the history bonus only applies at keys 1000/1100,
-    so those slots never read their (meaningless) history value. Constant
-    index tables let the per-step history lookup compile to a vectorized
-    static gather instead of the serialized dynamic-gather fusion the
-    round-5 device profile flagged (tests/test_device_board.py
-    test_hist_index_tables_match_candidates pins the mirror)."""
+
+@functools.lru_cache(maxsize=None)
+def _static_moves(variant: str):
+    """Per-color (n_candidates,) tables of the candidate move VALUES of
+    every slot of `_candidate_space`, as numpy constants.
+
+    Candidate values are static per side to move — every section below
+    mirrors `_candidate_space`'s candidate assembly (same tables, same
+    order) — except the two castling slots, which hold 0 here
+    (tests/test_device_board.py test_hist_index_tables_match_candidates pins
+    the mirror)."""
     rsq = np.clip(np.asarray(T.RAYS), 0, None)
     sl = (_SQ[:, None, None] | (rsq << 6)).reshape(-1)
     kn = (_SQ[:, None] | (np.clip(np.asarray(T.KNIGHT_TARGETS), 0, None) << 6)).reshape(-1)
     kg = (_SQ[:, None] | (np.clip(np.asarray(T.KING_TARGETS), 0, None) << 6)).reshape(-1)
-    n_promo = 5 if variant == "antichess" else 4
+    promos = np.asarray(_promo_list(variant), np.int32)
     out = []
     for c in (0, 1):
         pawn_tos = np.stack(
@@ -99,16 +109,85 @@ def _hist_idx_tables(variant: str):
         promo_tos = np.stack(
             [_TO1[c][pf], _CSQ[c][pf, 0], _CSQ[c][pf, 1]], axis=1
         )
-        pr = np.broadcast_to(
-            (pf[:, None] | (promo_tos << 6))[:, :, None], (8, 3, n_promo)
+        pr = (
+            (pf[:, None] | (promo_tos << 6))[:, :, None]
+            | (promos[None, None, :] << 12)
         ).reshape(-1)
         secs = [sl, kn, kg, pw, pr, np.zeros(2, np.int32)]
         if variant == "crazyhouse":
+            pt = np.arange(5, dtype=np.int32)
             secs.append(
-                np.broadcast_to(((_SQ << 6) | _SQ)[None, :], (5, 64)).reshape(-1)
+                (DROP_FLAG | (pt[:, None] << 12) | ((_SQ << 6) | _SQ)[None, :])
+                .reshape(-1)
             )
-        out.append((np.concatenate(secs) & 4095).astype(np.int32))
+        out.append(np.concatenate(secs).astype(np.int32))
     return out[0], out[1]
+
+
+@functools.lru_cache(maxsize=None)
+def _hist_idx_tables(variant: str):
+    """Per-color (n_candidates,) tables of `cand & 4095` (the from|to
+    history index) for every candidate slot, as numpy constants.
+
+    The two castling slots hold 0; castling keys are 900, and the history
+    bonus only applies at keys 1000/1100, so those slots never read their
+    (meaningless) history value. Constant index tables let the per-step
+    history lookup compile to a vectorized static gather instead of the
+    serialized dynamic-gather fusion the round-5 device profile flagged."""
+    mw, mb = _static_moves(variant)
+    return mw & 4095, mb & 4095
+
+
+@functools.lru_cache(maxsize=None)
+def _live_slots(variant: str) -> np.ndarray:
+    """The flat indices, in section order, of the candidate slots whose
+    STATIC factor of `valid` is not identically False: the slots that can
+    hold a move on some board. Built from the very tables the sections of
+    `_candidate_space` AND into `valid` (`rvalid`, `tvalid`, `cvalid`, the
+    pawn-drop ranks), so a slot left out is False on every board of this
+    variant. Push columns, promos and castling stay whole (horde has white
+    pawns on rank 0; a section with no static factor has nothing to drop).
+    2,550 of 4,962 slots (2,574 of 4,986 in antichess, 2,854 of 5,282 in
+    crazyhouse): what `generate_moves` packs and sorts."""
+    caps_on = (_CAPS[0] >= 0) | (_CAPS[1] >= 0)  # (64, 2): either color's
+    secs = [
+        np.asarray(T.RAYS) >= 0,
+        np.asarray(T.KNIGHT_TARGETS) >= 0,
+        np.asarray(T.KING_TARGETS) >= 0,
+        np.concatenate([np.ones((64, 2), bool), caps_on], axis=1),
+        np.ones(8 * 3 * len(_promo_list(variant)), bool),
+        np.ones(2, bool),
+    ]
+    if variant == "crazyhouse":
+        ranks = _SQ >> 3
+        drops = np.ones((5, 64), bool)
+        drops[0] = (ranks != 0) & (ranks != 7)  # pawn_ok_sq
+        secs.append(drops)
+    live = np.flatnonzero(np.concatenate([x.reshape(-1) for x in secs]))
+    return live.astype(np.int32)
+
+
+class _LiveTables(NamedTuple):
+    """What `generate_moves` needs of `_live_slots`, per variant."""
+    slots: np.ndarray  # _live_slots(variant)
+    castle_lo: int  # the first castling slot in the candidate space ...
+    castle_at: int  # ... and among the live slots
+    moves: tuple  # per color: _static_moves(variant)[c][slots]
+    hist: tuple  # per color: _hist_idx_tables(variant)[c][slots]
+
+
+@functools.lru_cache(maxsize=None)
+def _live_tables(variant: str) -> _LiveTables:
+    slots = _live_slots(variant)
+    moves = _static_moves(variant)
+    castle_lo = moves[0].shape[0] - 2 - (5 * 64 if variant == "crazyhouse" else 0)
+    castle_at = int(np.searchsorted(slots, castle_lo))
+    assert slots[castle_at] == castle_lo and slots[castle_at + 1] == castle_lo + 1
+    return _LiveTables(
+        slots, castle_lo, castle_at,
+        tuple(m[slots] for m in moves),
+        tuple(h[slots] for h in _hist_idx_tables(variant)),
+    )
 
 
 def _capture_key(victim_type: jnp.ndarray, attacker_type: jnp.ndarray,
@@ -139,37 +218,55 @@ def generate_moves(b: Board, variant: str = "standard",
     """
     white, flat_moves, flat_valid, flat_keys = _candidate_space(b, variant)
 
-    # quiet-move ordering refinements on the FULL candidate space:
-    # history first (quiets 1000 → 911..1010, drops 1100 → 1011..1110 by
-    # counter magnitude), then killers jump the whole quiet tail to 901
+    # from here on only the slots that can hold a move on some board
+    # (_live_slots: about half the space is table padding, False on every
+    # board): one constant-index gather takes valid and key through the
+    # table together (keys are >= 10, so -1 marks an invalid slot), and the
+    # move values of the live slots are constants per side to move, the
+    # two castling slots excepted. The history lookup, the killer compare,
+    # the pack and the sort below all run at the live width.
+    live = _live_tables(variant)
+    keys = jnp.where(flat_valid, flat_keys, -1)[live.slots]
+    valid = keys >= 0
+    cands = jax.lax.dynamic_update_slice_in_dim(
+        jnp.where(white, live.moves[0], live.moves[1]),
+        jax.lax.slice_in_dim(flat_moves, live.castle_lo, live.castle_lo + 2),
+        live.castle_at, axis=0,
+    )
+
+    # quiet-move ordering refinements: history first (quiets 1000 →
+    # 911..1010, drops 1100 → 1011..1110 by counter magnitude), then
+    # killers jump the whole quiet tail to 901
     if hist is not None:
         # candidate from|to indices are static per color (castling slots
         # excepted — their key is 900, never history-adjusted), so the
         # lookup is a constant-index gather per color + a stm select
-        hw, hb = _hist_idx_tables(variant)
-        hval = jnp.where(white, hist[hw], hist[hb])
+        hval = jnp.where(white, hist[live.hist[0]], hist[live.hist[1]])
         hbonus = jnp.clip(hval >> 5, 0, 99)
-        flat_keys = jnp.where(flat_keys == 1000, 1010 - hbonus, flat_keys)
-        flat_keys = jnp.where(flat_keys == 1100, 1110 - hbonus, flat_keys)
+        keys = jnp.where(keys == 1000, 1010 - hbonus, keys)
+        keys = jnp.where(keys == 1100, 1110 - hbonus, keys)
     if killers is not None:
         # candidates are never -1, so an empty killer slot (-1) matches
         # nothing; invalid candidates are masked out at the pack below
-        is_k = (flat_moves == killers[0]) | (flat_moves == killers[1])
-        flat_keys = jnp.where(is_k & (flat_keys >= 900), 901, flat_keys)
+        is_k = (cands == killers[0]) | (cands == killers[1])
+        keys = jnp.where(is_k & (keys >= 900), 901, keys)
 
     # compaction + ordering in ONE single-array sort: pack (key << 16) |
     # move — key < 2048 and move <= 0xFFFF, so valid packs stay positive
     # and below the invalid sentinel — sort ascending, keep the first cap
     # entries. Replaces round 4's 3-array compaction sort + stable
     # ordering sort (the round-5 device profile: 350 us + the argsort
-    # gather). Ties within a key break by move encoding (the previous
+    # gather). Valid packs are distinct (moves are), so the first cap
+    # entries are decided by the SET of valid packs alone: sorting the
+    # live slots gives, bit for bit, what sorting the whole space gave.
+    # Ties within a key break by move encoding (the previous
     # two-stage form broke them by candidate position): any deterministic
     # order is a valid move ordering, and the host oracle calls this same
     # function, so device/oracle equality is unaffected.
     cap = max_moves_for(variant)
     with jax.named_scope("step.order"):
         packed = jnp.where(
-            flat_valid, (flat_keys << 16) | flat_moves,
+            valid, (keys << 16) | cands,
             jnp.int32(jnp.iinfo(jnp.int32).max),
         )
         packed = jax.lax.sort(packed, dimension=0, is_stable=False)
@@ -177,10 +274,10 @@ def generate_moves(b: Board, variant: str = "standard",
     moves = jnp.where(
         top != jnp.iinfo(jnp.int32).max, top & 0xFFFF, jnp.int32(-1)
     )
-    count = jnp.minimum(jnp.sum(flat_valid), cap).astype(jnp.int32)
+    count = jnp.minimum(jnp.sum(valid), cap).astype(jnp.int32)
     # captures 100..739, queen promos down to 10; castling 900, quiets 1000
     noisy = jnp.minimum(
-        jnp.sum(flat_valid & (flat_keys < 900)), cap
+        jnp.sum(valid & (keys < 900)), cap
     ).astype(jnp.int32)
     return moves, count, noisy
 
@@ -329,9 +426,7 @@ def _candidate_space(b: Board, variant: str = "standard"):
     promo_ok_base = jnp.stack(
         [to1_ok_8, cap_ok_8[:, 0], cap_ok_8[:, 1]], axis=1
     )
-    promo_list = [T.PROMO_N, T.PROMO_B, T.PROMO_R, T.PROMO_Q]
-    if variant == "antichess":
-        promo_list.append(T.PROMO_K)
+    promo_list = _promo_list(variant)
     promos = jnp.asarray(promo_list, dtype=jnp.int32)
     cands = (
         promo_from[:, None, None]

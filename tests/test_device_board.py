@@ -5,6 +5,7 @@ The device generator is pseudo-legal with legality-checked castling — which
 is exactly what the host's generate_pseudo_legal + _castling_moves produce,
 so the move *sets* must match square-for-square.
 """
+import functools
 import random
 
 import jax
@@ -156,13 +157,19 @@ def test_history_ordering_uses_correct_slot_both_colors():
 
 
 def test_hist_index_tables_match_candidates():
-    """Exhaustive pin of the _hist_idx_tables mirror: for every variant
-    table shape and both colors, the static from|to index table must
-    equal `cand & 4095` for EVERY candidate slot the traced assembly
-    produces (castling slots excepted — they hold 0 in the table and are
-    never history-adjusted because their ordering key is 900)."""
+    """Exhaustive pin of the _static_moves / _hist_idx_tables mirror: for
+    every variant table shape and both colors, the static move table must
+    equal the candidate VALUE, and the from|to index table `cand & 4095`,
+    for EVERY candidate slot the traced assembly produces (castling slots
+    excepted — they hold 0 in the tables; generate_moves takes their
+    values from the board, and they are never history-adjusted because
+    their ordering key is 900)."""
     from fishnet_tpu.chess.variants import from_fen as v_from_fen
-    from fishnet_tpu.ops.movegen import _candidate_space, _hist_idx_tables
+    from fishnet_tpu.ops.movegen import (
+        _candidate_space,
+        _hist_idx_tables,
+        _static_moves,
+    )
 
     fens = {
         0: "rnbqkbnr/pppppppp/8/8/8/8/PPPPPPPP/RNBQKBNR w KQkq - 0 1",
@@ -171,7 +178,6 @@ def test_hist_index_tables_match_candidates():
     # the three distinct table shapes: standard (4 promos), antichess
     # (5 promos incl. king), crazyhouse (+ drop section)
     for variant in ("standard", "antichess", "crazyhouse"):
-        tables = _hist_idx_tables(variant)
         space = jax.jit(lambda b: _candidate_space(b, variant))
         for color in (0, 1):
             pos = (
@@ -179,8 +185,8 @@ def test_hist_index_tables_match_candidates():
                 else v_from_fen(fens[color], variant)
             )
             _, flat_moves, _, _ = space(from_position(pos))
-            cands = np.asarray(flat_moves) & 4095
-            table = np.asarray(tables[color])
+            cands = np.asarray(flat_moves)
+            table = np.asarray(_static_moves(variant)[color])
             assert cands.shape == table.shape, variant
             # locate the 2 castling slots: fixed offset before the drops
             n = cands.shape[0]
@@ -191,6 +197,8 @@ def test_hist_index_tables_match_candidates():
             assert set(mism.tolist()) <= allowed, (
                 variant, color, mism[:10], cands[mism[:10]], table[mism[:10]]
             )
+            assert np.array_equal(
+                _hist_idx_tables(variant)[color], table & 4095)
 
 
 def test_history_ordering_crazyhouse_drop_slot():
@@ -218,3 +226,230 @@ def test_history_ordering_crazyhouse_drop_slot():
     # lands at 1011..1110 - 99 → ahead of every un-bumped drop
     drops = [m for m in moves if m & DROP_FLAG]
     assert drops[0] == drop_mv
+
+
+# ------------------------------------------------------------------------
+# The ordering sort sorts only the slots that can hold a move (PR 33):
+# `_live_slots(variant)` drops the candidate slots that are False on every
+# board, and `generate_moves` packs and sorts what is left. Proofs: (a) the
+# table holds every slot whose static factor is true, (b) no board reaches
+# a slot outside it, (c) `generate_moves` is the parent's, bit for bit,
+# (d) the pruned history tables are the full ones taken through the table.
+
+# every statically compiled program (engine/tpu.py DEVICE_VARIANTS' values)
+PROGRAMS = [
+    "standard", "threeCheck", "kingOfTheHill", "racingKings", "atomic",
+    "horde", "antichess", "crazyhouse",
+]
+LIVE_WIDTH = dict.fromkeys(PROGRAMS, 2550) | {
+    "antichess": 2574, "crazyhouse": 2854}
+_LANE = Board(0, 0, 0, 0, 0, 0)
+# what seeded playouts from the start rarely reach
+_EXTRA_FENS = {
+    "standard": [  # chess960 castling (the standard program serves it)
+        ("bqnb1rkr/pp3ppp/3ppn2/2p5/5P2/P2P4/NPP1P1PP/BQ1BNRKR w HFhf - 2 9",
+         "chess960"),
+        ("b1q1rrkb/pppppppp/3nn3/8/P7/1PPP4/4PPPP/BQNNRKRB b GE - 1 9",
+         "chess960"),
+        ("r3k2r/Pppp1ppp/1b3nbN/nP6/BBP1P3/q4N2/Pp1P2PP/R2Q1RK1 w kq - 0 1",
+         "standard"),
+    ],
+    "horde": [  # white pawns on rank 0 (they double-push from there)
+        ("rnbqkbnr/pppppppp/8/8/8/8/8/PPPPPPPP w kq - 0 1", "horde"),
+        ("rnbqkbnr/pppppppp/8/1PP2PP1/PPPPPPPP/PPPPPPPP/PPPPPPPP/PPPPPPPP"
+         " b kq - 0 1", "horde"),
+    ],
+    "antichess": [  # promoted kings, promotions to king at hand
+        ("8/1PK3P1/8/2K5/5k2/8/1pk3p1/8 w - - 0 1", "antichess"),
+        ("8/1PK3P1/8/2K5/5k2/8/1pk3p1/8 b - - 0 1", "antichess"),
+    ],
+    "crazyhouse": [  # every piece type in both pockets, promotions at once
+        ("6k1/PPPP4/8/8/8/8/pppp4/6K1[QRBNPqrbnp] w - - 0 1", "crazyhouse"),
+        ("k7/4PPPP/8/8/8/8/4pppp/K7[NNPPnnpp] b - - 0 1", "crazyhouse"),
+        ("r3k2r/1PP3P1/8/8/8/8/1pp3p1/R3K2R[QRqr] w KQkq - 0 1",
+         "crazyhouse"),
+        ("4k3/8/8/8/8/8/8/4K3[QRBNPqrbnp] w - - 0 1", "crazyhouse"),
+        ("4k3/8/8/8/8/8/8/4K3[QRBNPqrbnp] b - - 0 1", "crazyhouse"),
+    ],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _playout_boards(variant: str) -> Board:
+    """Batched numpy Board of every position of 8 seeded 60-ply playouts
+    of `variant` (both colors to move) and of 24-ply playouts from
+    _EXTRA_FENS."""
+    from fishnet_tpu.chess.variants import from_fen as v_from_fen
+    from fishnet_tpu.chess.variants import position_class
+    from fishnet_tpu.ops.board import position_fields, stack_fields
+
+    rng = random.Random(33)
+    cls = position_class(variant)
+    starts = [(cls.from_fen(cls.starting_fen()), 60)] * 8
+    for fen, rules in _EXTRA_FENS.get(variant, []):
+        pos = (Chess960Position.from_fen(fen) if rules == "chess960"
+               else v_from_fen(fen, rules))
+        starts += [(pos, 24)] * 3
+    rows = []
+    for pos, plies in starts:
+        for _ in range(plies):
+            rows.append(position_fields(pos))
+            legal = pos.legal_moves()
+            if not legal or pos.outcome() is not None:
+                break
+            pos = pos.push(rng.choice(legal))
+    return stack_fields(rows)
+
+
+def _static_factor(variant: str) -> np.ndarray:
+    """The static factor of `valid`, slot by slot, written out from
+    `_candidate_space`'s sections: what `_live_slots` may not drop."""
+    from fishnet_tpu.ops.movegen import _CAPS
+
+    ranks = np.arange(64) >> 3
+    secs = [
+        np.asarray(T.RAYS) >= 0,  # rvalid
+        np.asarray(T.KNIGHT_TARGETS) >= 0,  # tvalid
+        np.asarray(T.KING_TARGETS) >= 0,
+        np.stack([np.ones(64, bool), np.ones(64, bool),  # pushes: none
+                  (_CAPS[0][:, 0] >= 0) | (_CAPS[1][:, 0] >= 0),  # cvalid
+                  (_CAPS[0][:, 1] >= 0) | (_CAPS[1][:, 1] >= 0)], axis=1),
+        np.ones((8, 3, 5 if variant == "antichess" else 4), bool),
+        np.ones(2, bool),
+    ]
+    if variant == "crazyhouse":
+        secs.append(np.stack(
+            [(ranks != 0) & (ranks != 7)] + [np.ones(64, bool)] * 4))
+    return np.concatenate([x.reshape(-1) for x in secs])
+
+
+def parent_generate_moves(b, variant="standard", killers=None, hist=None):
+    """`generate_moves` as the parent commit (0cff71d) had it: refinements,
+    pack and sort over the WHOLE candidate space."""
+    import jax.numpy as jnp
+
+    from fishnet_tpu.ops.movegen import (
+        _candidate_space,
+        _hist_idx_tables,
+        max_moves_for,
+    )
+
+    white, flat_moves, flat_valid, flat_keys = _candidate_space(b, variant)
+    if hist is not None:
+        hw, hb = _hist_idx_tables(variant)
+        hval = jnp.where(white, hist[hw], hist[hb])
+        hbonus = jnp.clip(hval >> 5, 0, 99)
+        flat_keys = jnp.where(flat_keys == 1000, 1010 - hbonus, flat_keys)
+        flat_keys = jnp.where(flat_keys == 1100, 1110 - hbonus, flat_keys)
+    if killers is not None:
+        is_k = (flat_moves == killers[0]) | (flat_moves == killers[1])
+        flat_keys = jnp.where(is_k & (flat_keys >= 900), 901, flat_keys)
+    cap = max_moves_for(variant)
+    packed = jnp.where(
+        flat_valid, (flat_keys << 16) | flat_moves,
+        jnp.int32(jnp.iinfo(jnp.int32).max),
+    )
+    packed = jax.lax.sort(packed, dimension=0, is_stable=False)
+    top = jax.lax.slice_in_dim(packed, 0, cap)
+    moves = jnp.where(
+        top != jnp.iinfo(jnp.int32).max, top & 0xFFFF, jnp.int32(-1)
+    )
+    count = jnp.minimum(jnp.sum(flat_valid), cap).astype(jnp.int32)
+    noisy = jnp.minimum(
+        jnp.sum(flat_valid & (flat_keys < 900)), cap
+    ).astype(jnp.int32)
+    return moves, count, noisy
+
+
+@pytest.mark.parametrize("variant", PROGRAMS)
+def test_live_slots_by_construction(variant):
+    from fishnet_tpu.ops.movegen import _live_slots, _static_moves
+
+    live = _live_slots(variant)
+    factor = _static_factor(variant)
+    assert factor.shape == _static_moves(variant)[0].shape
+    assert live.dtype == np.int32
+    assert np.all(np.diff(live) > 0), "sorted and unique"
+    assert 0 <= live[0] and live[-1] < factor.shape[0]
+    assert set(np.flatnonzero(factor).tolist()) <= set(live.tolist())
+    assert live.shape == (LIVE_WIDTH[variant],)
+
+
+@pytest.mark.parametrize("variant", PROGRAMS)
+def test_no_board_reaches_a_slot_outside_live_slots(variant):
+    from fishnet_tpu.ops.movegen import _candidate_space, _live_slots
+
+    boards = _playout_boards(variant)
+    space = jax.jit(jax.vmap(
+        lambda b: _candidate_space(b, variant)[2], in_axes=(_LANE,)))
+    seen = np.asarray(space(boards)).any(axis=0)
+    assert boards.board.shape[0] >= 300
+    assert {0, 1} == set(np.asarray(boards.stm).tolist())
+    dead = np.ones(seen.shape[0], bool)
+    dead[_live_slots(variant)] = False
+    assert not np.any(seen & dead), np.flatnonzero(seen & dead)[:10]
+    # the playouts do exercise the space: most live slots held a move
+    assert seen.sum() > 0.3 * _live_slots(variant).shape[0]
+
+
+@pytest.mark.parametrize("ordering", ["plain", "killers_hist"])
+@pytest.mark.parametrize("variant", PROGRAMS)
+def test_generate_moves_is_the_parents(variant, ordering):
+    """Bit for bit on moves (the whole list, padding included), count and
+    noisy, both colors, with and without the quiet-ordering state."""
+    import jax.numpy as jnp
+
+    boards = _playout_boards(variant)
+    n = boards.board.shape[0]
+    if ordering == "plain":
+        args = ()
+        new = jax.vmap(lambda b: generate_moves(b, variant), in_axes=(_LANE,))
+        old = jax.vmap(
+            lambda b: parent_generate_moves(b, variant), in_axes=(_LANE,))
+    else:
+        # killers taken from each board's own list (a capture and late
+        # quiets among them, -1 where the list is short), dense history
+        plain = jax.jit(jax.vmap(
+            lambda b: parent_generate_moves(b, variant), in_axes=(_LANE,)))
+        listed = np.asarray(plain(boards)[0])
+        nrng = np.random.default_rng(33)
+        cols = nrng.integers(0, 40, (n, 2))
+        killers = jnp.asarray(np.take_along_axis(listed, cols, axis=1))
+        hist = jnp.asarray(
+            nrng.integers(0, 1 << 13, (n, 4096)) * (nrng.random((n, 4096)) < 0.5),
+            jnp.int32)
+        args = (killers, hist)
+        new = jax.vmap(
+            lambda b, k, h: generate_moves(b, variant, killers=k, hist=h),
+            in_axes=(_LANE, 0, 0))
+        old = jax.vmap(
+            lambda b, k, h: parent_generate_moves(
+                b, variant, killers=k, hist=h),
+            in_axes=(_LANE, 0, 0))
+    got = jax.jit(new)(boards, *args)
+    want = jax.jit(old)(boards, *args)
+    for name, g, w in zip(("moves", "count", "noisy"), got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), (variant, name)
+    assert int(np.asarray(want[1]).max()) > 30
+
+
+@pytest.mark.parametrize("variant", PROGRAMS)
+def test_live_tables_are_the_full_tables_through_live_slots(variant):
+    from fishnet_tpu.ops.movegen import (
+        _hist_idx_tables,
+        _live_slots,
+        _live_tables,
+        _static_moves,
+    )
+
+    live = _live_tables(variant)
+    assert np.array_equal(live.slots, _live_slots(variant))
+    for c in (0, 1):
+        assert np.array_equal(
+            live.hist[c], _hist_idx_tables(variant)[c][live.slots])
+        assert np.array_equal(
+            live.moves[c], _static_moves(variant)[c][live.slots])
+    # the two castling slots, whose move values come from the board
+    assert live.slots[live.castle_at] == live.castle_lo
+    assert live.slots[live.castle_at + 1] == live.castle_lo + 1
+    assert _static_moves(variant)[0][live.castle_lo] == 0

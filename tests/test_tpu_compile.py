@@ -15,6 +15,10 @@ the compiles run in this process; and the persistent compile cache is
 off around them (an entry written for a described chip cannot be read
 back without one, and the next run would warn about it).
 """
+import hashlib
+import re
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,9 +27,10 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from fishnet_tpu.assets import load_default_params
 from fishnet_tpu.chess.position import Position
+from fishnet_tpu.ops import movegen
 from fishnet_tpu.ops import search as S
 from fishnet_tpu.ops import tt as tt_mod
-from fishnet_tpu.ops.board import from_position, stack_boards
+from fishnet_tpu.ops.board import Board, from_position, stack_boards
 from fishnet_tpu.parallel import mesh as mesh_mod
 from fishnet_tpu.parallel import partition
 
@@ -147,6 +152,25 @@ def test_run_segment_compiles(one_chip, params, lanes, variant, deep_tt,
     _fits(compiled)
 
 
+@pytest.mark.parametrize("variant,widest", [
+    ("standard", 2600), ("crazyhouse", 2900),
+], ids=["standard", "crazyhouse"])
+def test_segment_sorts_only_live_slots(one_chip, params, variant, widest):
+    """The shape as the counter (PR 33): the 64-lane segment the trickle
+    cells run holds exactly ONE sort, and what it sorts is the live slots
+    of the candidate space (ops/movegen.py _live_slots: 2,550 / 2,854),
+    not the whole space (4,962 / 5,282). The op's name in a trace,
+    `sort s32[64,<width>]`, is what the ledger's `breakdown.device_ops`
+    shows."""
+    text = _compile_segment(params, one_chip, 64, variant, False, True).as_text()
+    sorts = re.findall(r"= (\w+)\[([\d,]+)\]\S* sort\(.*?dimensions=\{(\d+)\}", text)
+    assert len(sorts) == 1, sorts
+    dtype, dims, axis = sorts[0]
+    dims = [int(d) for d in dims.split(",")]
+    assert dtype == "s32" and sorted(dims)[0] == 64, sorts
+    assert dims[int(axis)] == len(movegen._live_slots(variant)) <= widest
+
+
 def test_merge_lanes_compiles(one_chip, params):
     state = _on(_state_shape(params, BUCKET), one_chip)
     mask = jax.ShapeDtypeStruct((BUCKET,), jnp.bool_, sharding=one_chip)
@@ -204,3 +228,86 @@ def test_sharded_segment_compiles_on_four_chips(topo, params):
     _fits(compiled)
     assert "all-reduce" not in compiled.as_text(), (
         "the sharded segment is meant to run with no collectives")
+
+
+# ---------------------------------------------------------------------------
+# Not a compile for the chip: the CPU bit-identity case of PR 33. The sort
+# lost its never-valid slots; the tree searched must be the parent's, node
+# for node, in every variant program.
+
+# sha256[:16] over done, move, nodes, pv, pv_len, score, steps of the search
+# below, recorded on the parent commit (0cff71d) on this sandbox's CPU
+PARENT_TREE = {
+    "antichess": "3b11793f950ca883", "atomic": "9f679f7a55bd9a83",
+    "crazyhouse": "1b627dd443080a3c", "horde": "8df043a18c4b088a",
+    "kingOfTheHill": "0b1fb6a83203f0de", "racingKings": "a0118e1b40c422cd",
+    "standard": "c25290edc4211971", "threeCheck": "4a999f0a82919548",
+}
+
+NODES = 400  # a root: the widest roots stop on it, the rest on depth 2
+
+
+def _seeded_roots(variant: str, n: int = 16) -> Board:
+    """`n` boards spread over test_device_board's seeded playouts (both
+    colors, the special positions at the end among them)."""
+    from test_device_board import _playout_boards
+
+    boards = _playout_boards(variant)
+    pick = np.linspace(3, boards.board.shape[0] - 1, n).astype(int)
+    return Board(*[field[pick] for field in boards])
+
+
+def _tree(variant: str) -> dict:
+    from fishnet_tpu.models import nnue
+
+    net = nnue.init_params(
+        jax.random.PRNGKey(0), l1=32, h1=8, h2=8, feature_set="board768")
+    out = S.search_batch(
+        net, _seeded_roots(variant), 2, NODES, max_ply=4,
+        tt=tt_mod.make_table(12), variant=variant)
+    return {k: np.asarray(v) for k, v in sorted(out.items()) if k != "tt"}
+
+
+def _digest(tree: dict) -> str:
+    h = hashlib.sha256()
+    for k, v in tree.items():
+        h.update(k.encode())
+        h.update(np.ascontiguousarray(v).tobytes())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("variant", sorted(PARENT_TREE))
+def test_search_tree_is_the_parents(variant, monkeypatch):
+    """A depth-2 search of 16 seeded roots through `search_batch` (table,
+    killers and history on) gives the parent's scores, PVs, best moves,
+    node and step counts: against the parent's `generate_moves` run here
+    (exact, whatever this CPU's float arithmetic), and against the digest
+    recorded on the parent commit where this CPU reproduces it."""
+    from test_device_board import parent_generate_moves
+
+    tree = _tree(variant)
+    assert int(tree["nodes"].sum()) > 500
+    def parent_run_segment(params, state, ttab, segment_steps,
+                           variant="standard", deep_tt=False,
+                           prefer_deep=False, tt_gen=0):
+        # a function of its own, so that jit traces it anew and does not
+        # hand back the program it holds for S._run_segment
+        return S._run_segment(params, state, ttab, segment_steps, variant,
+                              deep_tt, prefer_deep, tt_gen)
+
+    with monkeypatch.context() as m:
+        m.setattr(S, "generate_moves", parent_generate_moves)
+        m.setattr(S, "_run_segment_jit", jax.jit(
+            parent_run_segment,
+            static_argnames=("variant", "deep_tt", "prefer_deep"),
+            donate_argnums=(1, 2)))
+        parent = _tree(variant)
+    for k in parent:
+        assert np.array_equal(tree[k], parent[k]), (variant, k)
+    if _digest(parent) != PARENT_TREE[variant]:
+        warnings.warn(
+            f"{variant}: the parent's form gives {_digest(parent)} here, "
+            f"{PARENT_TREE[variant]} where it was recorded: another CPU's "
+            "float arithmetic; the comparison above still held")
+    else:
+        assert _digest(tree) == PARENT_TREE[variant]
