@@ -1,16 +1,18 @@
 """Finding a cell's files by the names in BENCHMARK.json.
 
 A cell is a pair of names. ``configs/<config>.json`` (or the ``file`` the
-entry gives), ``traffic/<traffic>.json`` and ``metrics/<metric>.json`` with
-its reader are looked up by name, so a later PR adds a cell, a
-configuration, a traffic mix or a per-layer metric with new files and new
-entries and edits nothing that is here.
+entry gives), ``traffic/<traffic>.json``, ``metrics/<metric>.json`` with
+its reader and ``evaluators/<evaluator>.py`` (the name the configuration
+gives under ``engine``) are looked up by name, so a later PR adds a cell, a
+configuration, a traffic mix, a per-layer metric or an evaluator with new
+files and new entries and edits nothing that is here.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict
 
 
@@ -24,6 +26,17 @@ def load_json(path: Path) -> dict:
 
 
 HERE = Path(__file__).resolve().parent
+
+# what an evaluator's file provides (load_evaluator says what each is)
+EVALUATOR_FUNCTIONS = ("load_weights", "evaluate", "program_params", "net_work")
+
+
+def _load_module(name: str, path: Path) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def load_cell(root: Path, workload: str, bench_dir: Path = HERE) -> dict:
@@ -43,6 +56,12 @@ def load_cell(root: Path, workload: str, bench_dir: Path = HERE) -> dict:
     if not traffic_path.exists():
         raise CellError(f"no traffic file {traffic_path}")
     traffic = load_json(traffic_path)
+    if not isinstance(traffic.get("pool_seed"), int):
+        # games made from --seed itself let the seed set the rate (PERF.md 2)
+        raise CellError(f"{traffic_path} states no pool_seed")
+    evaluator = config.get("engine", {}).get("evaluator")
+    if evaluator is None:
+        raise CellError(f"{cfg_entry['file']} names no evaluator under engine")
 
     def applies(metric: dict) -> bool:
         return "workloads" not in metric or workload in metric["workloads"]
@@ -52,6 +71,7 @@ def load_cell(root: Path, workload: str, bench_dir: Path = HERE) -> dict:
         "chips": entry["chips"],
         "config": config,
         "traffic": traffic,
+        "evaluator": load_evaluator(evaluator, here),
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
         "limits": load_json(here / "limits.json"),
@@ -70,11 +90,31 @@ def load_reader(name: str, bench_dir: Path = HERE) -> Callable[[dict], object]:
         raise CellError(f"per-layer metric {name!r} has no {spec_path.name}")
     meta = load_json(spec_path)
     path = mdir / meta.get("reader", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "benchmark_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return _load_module("benchmark_metric_" + name, path).read
+
+
+def load_evaluator(name: str, bench_dir: Path = HERE) -> ModuleType:
+    """The evaluator a configuration names, ``evaluators/<name>.py``:
+
+    * ``load_weights(engine_cfg, root)`` → {name: numpy array}, from the
+      file ``engine.net`` names or made from a seed the configuration
+      states (numpy's PCG64, so every machine gets the same bytes);
+    * ``evaluate(weights, pos)`` → int: the plain reference eval of a
+      ``rules.Pos`` in centipawns, numpy float32, nothing of the program,
+      truncated and clamped as the search clamps;
+    * ``program_params(weights)``: what ``TpuEngine(params=...)`` takes; the
+      file's only import of the program;
+    * ``net_work(net_shapes)`` → {"flops", "bytes"}: the net's share of one
+      node's work by ``work_count``'s rules, from shape keys of its own."""
+    path = Path(bench_dir) / "evaluators" / f"{name}.py"
+    if not path.exists():
+        raise CellError(f"no evaluator file {path}")
+    module = _load_module("benchmark_evaluator_" + name, path)
+    missing = [f for f in EVALUATOR_FUNCTIONS
+               if not callable(getattr(module, f, None))]
+    if missing:
+        raise CellError(f"evaluator {name!r} lacks {', '.join(missing)}")
+    return module
 
 
 def read_per_layer(cell: dict, ctx: dict) -> Dict[str, dict]:

@@ -1,11 +1,16 @@
 """Games from the seed, and the planner's chunk tiling (the benchmark's copy).
 
 A game is a legal playout from the variant's start position under a seeded
-policy: every legal move is valued by the reference NNUE one ply deep and
+policy: every legal move is valued by the configuration's reference eval
+(`evaluators/<name>.py::evaluate`) one ply deep and
 one is drawn from a softmax over those values. No position repeats inside a
 game (so no repetition history reaches the search), and no game ends before
-its last ply. The seed decides the games; it does not decide how much work
-a position is, because analysis is node-budgeted.
+its last ply. Analysis is node-budgeted, but a node budget does not fix the
+lockstep steps an answer takes: over seeds the games moved that by 252-286
+(PERF.md section 2, PR 34), and the rate with it. So a traffic file states
+a `pool_seed`: the games are the same for every `--seed`, and the seed
+deals them out in another order (`deal`) and draws the sample that is held
+against the reference.
 """
 from __future__ import annotations
 
@@ -13,13 +18,13 @@ import math
 import random
 from typing import List, Optional, Tuple
 
-from . import nnue_ref, rules
+from . import rules
 
 MAX_CHUNK_POSITIONS = 6  # upstream src/ipc.rs:23
 
 
-def play_game(weights, variant: str, plies: int, rng: random.Random,
-              temperature_cp: float = 80.0) -> List[str]:
+def play_game(weights, evaluator, variant: str, plies: int,
+              rng: random.Random, temperature_cp: float = 80.0) -> List[str]:
     """UCI moves of one playout of exactly `plies` plies."""
     while True:
         p = rules.start(variant)
@@ -31,7 +36,7 @@ def play_game(weights, variant: str, plies: int, rng: random.Random,
                 child = rules.make(p, mv)
                 if child.key() in seen:
                     continue
-                cands.append((mv, child, -nnue_ref.evaluate(weights, child.board, child.stm)))
+                cands.append((mv, child, -evaluator.evaluate(weights, child)))
             if not cands:
                 break
             top = max(v for _m, _c, v in cands)
@@ -55,13 +60,20 @@ def play_game(weights, variant: str, plies: int, rng: random.Random,
         # the playout ran into a dead end: draw another (rare)
 
 
-def make_games(weights, variant: str, n_games: int, plies: int,
+def make_games(weights, evaluator, variant: str, n_games: int, plies: int,
                seed: int) -> List[List[str]]:
     return [
-        play_game(weights, variant, plies,
+        play_game(weights, evaluator, variant, plies,
                   random.Random(f"{seed}:{variant}:{g}"))
         for g in range(n_games)
     ]
+
+
+def deal(n_games: int, seed: int) -> List[int]:
+    """The order in which a run hands out a fixed pool's games."""
+    order = list(range(n_games))
+    random.Random(f"{seed}:deal").shuffle(order)
+    return order
 
 
 def tile(n_moves: int) -> List[List[Tuple[Optional[int], int]]]:

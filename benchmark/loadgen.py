@@ -2,7 +2,8 @@
 
 A traffic file describes a closed loop: so many workers, each holding one
 chunk of an analysis job and taking the next the moment its chunk is
-answered, from a queue of whole games made from the seed. The window is
+answered, from a queue of whole games (the traffic file's pool, dealt out
+in an order drawn from the seed). The window is
 exactly ``seconds`` long by the host clock, opens once the loop is in steady
 state and closes on the clock; what is in flight at the close is not
 counted.
@@ -12,7 +13,6 @@ from __future__ import annotations
 import asyncio
 import concurrent.futures
 import gc
-import os
 import random
 import shutil
 import statistics
@@ -21,7 +21,7 @@ import time
 from collections import Counter, deque
 from typing import Callable, Dict, List, Optional
 
-from . import cells, games, measure, nnue_ref, reference, rules, trace_reduce, work_count
+from . import cells, games, measure, reference, rules, trace_reduce, work_count
 
 DRAIN_S = 3.0  # in flight at the close is not counted: nothing is owed
 
@@ -163,16 +163,22 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     variant = cfg["variant"]
     plies = cfg["assumed"]["plies_per_game"]
     tcfg = cell["limits"]["trace"]
-    weights = nnue_ref.load_weights(os.path.join(cell["root"], cfg["engine"]["net"]))
+    evaluator = cell["evaluator"]
+    weights = evaluator.load_weights(cfg["engine"], cell["root"])
     compiles = measure.CompileCounter()
 
     t0 = time.monotonic()
-    game_list = games.make_games(weights, variant, traffic["games"], plies, seed)
+    # every --seed gets the traffic file's games, dealt out in another
+    # order: the same work in every run
+    pool_seed = traffic["pool_seed"]
+    pool = games.make_games(weights, evaluator, variant, traffic["games"],
+                            plies, pool_seed)
+    game_list = [pool[g] for g in games.deal(len(pool), seed)]
     sessions = traffic.get("warm_sessions", [])
     need = max([s["positions"] for s in sessions] + [0])
     warm_games = games.make_games(
-        weights, variant, -(-need // (plies + 1)) if need else 0, plies,
-        seed ^ 0x5BD1E995)
+        weights, evaluator, variant, -(-need // (plies + 1)) if need else 0,
+        plies, pool_seed ^ 0x5BD1E995)
     t_games = time.monotonic() - t0
     t0 = time.monotonic()
     adapter = make_engine()
@@ -365,7 +371,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     # ------------------------------------------ the comparison, last
     t1 = time.monotonic()
     correct, checks, detail = reference.compare(
-        weights, sample,
+        reference.Reference(weights, evaluator), sample,
         {"delivery": delivery_faults, "programs_inside": built_inside},
         cell["limits"]["checks"])
     say(f"check: {detail['answers']} answers against the reference in "
@@ -399,7 +405,8 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
             "peak": peak, "notes": notes,
             "latency": {"p95_s": measure.percentile_nearest(latencies, 95),
                         "answers": len(latencies)},
-            "per_node": work_count.per_node(cfg["net_shapes"], cfg["max_moves"]),
+            "per_node": work_count.per_node(
+                evaluator.net_work(cfg["net_shapes"]), cfg["max_moves"]),
             "config": cfg,
         }
         metrics = cells.read_per_layer(cell, ctx)

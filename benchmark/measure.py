@@ -228,24 +228,21 @@ def program_engine_factory(cell: dict, rehearsal: Optional[dict]):
     from fishnet_tpu.client.ipc import Chunk, WorkPosition
     from fishnet_tpu.client.wire import AnalysisWork, EngineFlavor, NodeLimit
     from fishnet_tpu.engine.tpu import TpuEngine
-    from fishnet_tpu.models import nnue
     from fishnet_tpu.obs import trace as obs_trace
-
-    from . import nnue_ref
 
     cfg = dict(cell["config"]["engine"])
     if rehearsal is not None:
         cfg.update(rehearsal.get("engine", {}))
-    weights = nnue_ref.load_weights(os.path.join(cell["root"], cfg["net"]))
+    evaluator = cell["evaluator"]
+    weights = evaluator.load_weights(cfg, cell["root"])
 
     class ProgramAdapter:
         name = "TpuEngine"
 
         def __init__(self):
-            params = nnue.NnueParams(
-                **{f: jnp.asarray(weights[f]) for f in nnue_ref.FIELDS})
             self.engine = TpuEngine(
-                params=params, max_depth=cfg["max_depth"],
+                params=evaluator.program_params(weights),
+                max_depth=cfg["max_depth"],
                 tt_size_log2=cfg["tt_size_log2"], max_lanes=cfg["max_lanes"],
                 helper_lanes=cfg["helper_lanes"], refill=cfg["refill"],
             )

@@ -12,7 +12,8 @@ dicts made at the delivery point) is held against:
 * ``d1_gap_cp``    widest gap between the served depth-1 score and the
                    reference's depth-1 value: one full-width ply plus the
                    capture-only quiescence with stand-pat that the search
-                   defines, evaluated by the numpy float32 NNUE. Depth 1 has
+                   defines, evaluated by the configuration's evaluator
+                   (`evaluators/<name>.py`: plain numpy float32). Depth 1 has
                    no window, no reduction and no pruning, so the value does
                    not depend on move order or on what the shared table
                    holds, and any eval precision below float32 moves it;
@@ -40,7 +41,7 @@ from __future__ import annotations
 import statistics
 from typing import Dict, List, Optional, Tuple
 
-from . import nnue_ref, rules
+from . import rules
 
 INF = 32500
 STACK_PLIES = 32  # the search's static stack: deeper nodes are leaves
@@ -51,13 +52,16 @@ class Budget(Exception):
 
 
 class Reference:
-    def __init__(self, weights, node_cap: int = 20000):
+    def __init__(self, weights, evaluator, node_cap: int = 20000):
+        """evaluator: the configuration's (`cells.load_evaluator`), whose
+        `evaluate(weights, pos)` is the leaf eval."""
         self.w = weights
+        self.evaluator = evaluator
         self.node_cap = node_cap
         self.nodes = 0
 
     def eval(self, p: rules.Pos) -> int:
-        return nnue_ref.evaluate(self.w, p.board, p.stm)
+        return self.evaluator.evaluate(self.w, p)
 
     def qs(self, p: rules.Pos, alpha: int, beta: int, ply: int) -> int:
         """Capture-only quiescence with a stand-pat floor; `p` is known to
@@ -170,11 +174,10 @@ def check_answer(ref: Reference, ans: dict) -> dict:
     return out
 
 
-def compare(weights, answers: List[dict], counted: Dict[str, int],
+def compare(ref: Reference, answers: List[dict], counted: Dict[str, int],
             limits: Dict[str, float]) -> Tuple[bool, Dict[str, dict], dict]:
     """→ (correct, {name: {"value", "limit"}}, detail). `counted`: the
     numbers the harness counted itself (delivery, programs_inside)."""
-    ref = Reference(weights)
     bad: List[str] = []
     d1, d1m, leaf = [], [], []
     worst = None

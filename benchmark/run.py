@@ -3,9 +3,10 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The process owns the chip and calls ``TpuEngine.go_multiple`` in-process.
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is a file found by the name in BENCHMARK.json; this file
-holds only what is common to all cells.
+Everything that belongs to one configuration, one traffic mix, one
+per-layer metric or one evaluator is a file found by name (the first three
+by the names in BENCHMARK.json, the evaluator by the name the configuration
+gives); this file holds only what is common to all cells.
 """
 from __future__ import annotations
 

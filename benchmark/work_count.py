@@ -6,42 +6,37 @@ later PR that removes an operation (the big sort, say) leaves them as they
 are and can only raise the share of the roofline it reaches.
 
 Per node:
-* NNUE accumulator update: a move changes at most 4 piece placements; each
-  adds or subtracts one L1-wide row per perspective.
-* NNUE forward: 2*L1 -> H1 -> H2 -> 1 dense layers of one output bucket
-  (2 FLOPs per multiply-add).
-* Bytes: the changed feature rows and the bucket's layer weights read, the
-  accumulator pair read and written, the board row read and the child's
-  written, the node's scalars, the move list written and read once, one
-  table probe and one table store.
+* The net's share, which the configuration's evaluator counts from its own
+  ``net_shapes`` (``evaluators/<name>.py::net_work``) by these rules. A
+  move changes at most 4 piece placements; each adds or subtracts one
+  accumulator-wide feature row per perspective. The forward pass is the
+  dense layers of the one output bucket or stack used (2 FLOPs per
+  multiply-add). Bytes: the changed feature rows and that bucket's layer
+  weights read, the accumulators read and written.
+* What every evaluator shares, counted here. Bytes: the board row read and
+  the child's written, the node's scalars, the move list written and read
+  once, one table probe and one table store.
 """
 from __future__ import annotations
 
 from typing import Dict
 
-MAX_PIECE_CHANGES = 4  # mover off, mover on, captured off, rook/ep victim
 BOARD_ROW_BYTES = (64 + 1 + 1 + 4 + 1 + 12 + 2) * 4  # board, stm, ep, castling, clock, variant state, path hash
 NODE_ROW_BYTES = 16 * 4
 TT_ENTRY_BYTES = 16
 MOVE_BYTES = 4
 
 
-def per_node(shapes: Dict[str, int], max_moves: int) -> Dict[str, float]:
-    """shapes: l1, h1, h2 of the net. max_moves: the move list's width for
-    the variant (218 is the chess maximum; crazyhouse adds 5*64 drops)."""
-    l1, h1, h2 = shapes["l1"], shapes["h1"], shapes["h2"]
-    acc_flops = 2 * MAX_PIECE_CHANGES * l1  # two perspectives, one add each
-    fwd_flops = 2 * (2 * l1 * h1 + h1 * h2 + h2)
-    weight_bytes = 4 * (2 * MAX_PIECE_CHANGES * l1
-                        + 2 * l1 * h1 + h1 + h1 * h2 + h2 + h2 + 1)
-    acc_bytes = 2 * (2 * l1 * 4)  # pair read, pair written
+def per_node(net: Dict[str, float], max_moves: int) -> Dict[str, float]:
+    """net: the evaluator's ``net_work`` of the configuration's shapes.
+    max_moves: the move list's width for the variant (218 is the chess
+    maximum; crazyhouse adds 5*64 drops)."""
     board_bytes = 2 * BOARD_ROW_BYTES + NODE_ROW_BYTES
     move_bytes = 2 * max_moves * MOVE_BYTES
     tt_bytes = 2 * TT_ENTRY_BYTES
     return {
-        "flops": float(acc_flops + fwd_flops),
-        "bytes": float(weight_bytes + acc_bytes + board_bytes + move_bytes
-                       + tt_bytes),
+        "flops": float(net["flops"]),
+        "bytes": float(net["bytes"] + board_bytes + move_bytes + tt_bytes),
     }
 
 

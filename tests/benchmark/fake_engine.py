@@ -6,12 +6,22 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import json
+import shutil
 
 import numpy as np
 
-from benchmark import reference, rules
+from benchmark import cells, reference, rules
 
 INF = reference.INF
+
+
+def cell_weights(root, workload="standard.trickle"):
+    """→ (weights, evaluator) of the cell's configuration, as the harness
+    loads them."""
+    cell = cells.load_cell(root, workload)
+    evaluator = cell["evaluator"]
+    return evaluator.load_weights(cell["config"]["engine"], root), evaluator
 
 
 def qs_line(ref, p, alpha, beta, ply):
@@ -50,8 +60,8 @@ def bf16_weights(w):
 class FakeAdapter:
     name = "fake"
 
-    def __init__(self, weights, fault=None, latency_s=0.0):
-        self.ref = reference.Reference(weights)
+    def __init__(self, weights, evaluator, fault=None, latency_s=0.0):
+        self.ref = reference.Reference(weights, evaluator)
         self.fault = fault
         self.latency_s = latency_s
         self.hook = None
@@ -166,10 +176,37 @@ class FakeAdapter:
         pass
 
 
-def toy_cell(root, workload="standard.trickle", variant=None, **traffic):
-    from benchmark import cells
+def tree_with_new_evaluator(tmp_path, root, name, source, engine, net_shapes,
+                            **config):
+    """A later PR's addition, made in a copy of the tree by new files and
+    new entries alone: evaluator `name` (the text of its file is `source`),
+    a configuration `name` that names it (standard.json's with `engine`,
+    `net_shapes` and `config` laid over it, and no `net` file) and the cell
+    `<name>.trickle`. → (the copy's root, the bytes of every file that was
+    there before)."""
+    new_root = tmp_path / "repo"
+    bdir = new_root / "benchmark"
+    shutil.copytree(root / "benchmark", bdir,
+                    ignore=shutil.ignore_patterns("__pycache__", "weights"))
+    before = {p: p.read_bytes() for p in bdir.rglob("*") if p.is_file()}
+    (bdir / "evaluators" / f"{name}.py").write_text(source)
+    cfg = json.load(open(bdir / "configs/standard.json"))
+    kept = {k: v for k, v in cfg["engine"].items() if k != "net"}
+    cfg.update(config, name=name, net_shapes=net_shapes,
+               engine=dict(kept, evaluator=name, **engine))
+    (bdir / f"configs/{name}.json").write_text(json.dumps(cfg))
+    bench = json.load(open(root / "BENCHMARK.json"))
+    bench["configs"].append({"name": name, "source": "x", "reduced": [],
+                             "file": f"benchmark/configs/{name}.json", "why": "y"})
+    bench["workloads"].append({"name": f"{name}.trickle", "config": name,
+                               "traffic": "trickle", "chips": 1, "why": "z"})
+    (new_root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return new_root, before
 
-    cell = cells.load_cell(root, workload)
+
+def toy_cell(root, workload="standard.trickle", variant=None, bench_dir=cells.HERE,
+             **traffic):
+    cell = cells.load_cell(root, workload, bench_dir=bench_dir)
     cell["traffic"] = dict(cell["traffic"], games=2, preroll_min_s=0.2,
                            preroll_quiet_s=0.0, preroll_max_s=0.5,
                            warm_sessions=[], **traffic)

@@ -1,15 +1,20 @@
 """`crazyhouse.trickle`: the drop program through the real `TpuEngine`
 against the benchmark's plain reference at the rehearsal's sizes, and the
-two movegen metrics' readers."""
+two movegen metrics' readers; and, by the same road, a second params type
+that comes as an evaluator file."""
 import json
+import sys
 import time
 from pathlib import Path
 
 import pytest
 
-from benchmark import cells, loadgen, measure, reference
-
 ROOT = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(Path(__file__).resolve().parent))
+
+from benchmark import cells, loadgen, measure, reference  # noqa: E402
+import fake_engine  # noqa: E402
+
 CELL = "crazyhouse.trickle"
 
 
@@ -53,11 +58,11 @@ def test_readers(occupancy, config, want):
     assert {k: v for k, v in got.items() if v is not None} == pytest.approx(want)
 
 
-def test_program_against_the_reference_at_rehearsal_size(tmp_path, monkeypatch):
-    """What `run.py --rehearse-cpu` runs: the real engine on XLA:CPU,
-    seeded weights, the comparison that decides `correct`."""
+def rehearse(cell, tmp_path, monkeypatch, seed):
+    """What `run.py --rehearse-cpu` runs: the real engine on XLA:CPU at the
+    rehearsal's sizes, the comparison that decides `correct`.
+    → (result, the lines said, the answers that were compared)."""
     rehearsal = cells.load_json(ROOT / "benchmark/rehearsal.json")
-    cell = cells.load_cell(ROOT, CELL)
     # what measure.prepare_environment sets for a rehearsal, for this test only
     for key, value in {**measure._REHEARSAL_ENV, **rehearsal["env"]}.items():
         monkeypatch.setenv(key, value)
@@ -65,9 +70,9 @@ def test_program_against_the_reference_at_rehearsal_size(tmp_path, monkeypatch):
     sampled = []
     compare = reference.compare
 
-    def keep(weights, answers, counted, limits):
+    def keep(ref, answers, counted, limits):
         sampled.extend(answers)
-        return compare(weights, answers, counted, limits)
+        return compare(ref, answers, counted, limits)
 
     monkeypatch.setattr(reference, "compare", keep)
     make_adapter = measure.program_engine_factory(cell, rehearsal)
@@ -83,13 +88,20 @@ def test_program_against_the_reference_at_rehearsal_size(tmp_path, monkeypatch):
         return adapter
 
     lines = []
-    # a seed whose eight-ply games see a capture: pockets at the roots
     result = loadgen.run_cell(
-        cell, seed=2147483659, seconds=6.0, trace=False,
+        cell, seed=seed, seconds=6.0, trace=False,
         make_engine=one_chip,
         device={"platform": "cpu", "kind": "cpu", "count": 1},
         t_start=time.monotonic(), rehearsal=rehearsal, control=None,
         say=lines.append, trace_dir=str(tmp_path / "trace"))
+    return result, lines, sampled
+
+
+def test_program_against_the_reference_at_rehearsal_size(tmp_path, monkeypatch):
+    """The drop program, seeded games, the committed net."""
+    # a seed whose eight-ply games see a capture: pockets at the roots
+    result, lines, sampled = rehearse(
+        cells.load_cell(ROOT, CELL), tmp_path, monkeypatch, seed=2147483659)
     assert result["correct"] is True, json.dumps([result["checks"], lines[-8:]])
     assert result["failed"] == 0 and result["window"]["answers"] > 0
     checks = {k: v["value"] for k, v in result["checks"].items()}
@@ -104,3 +116,47 @@ def test_program_against_the_reference_at_rehearsal_size(tmp_path, monkeypatch):
     for a, p in zip(sampled, roots):
         for line in a["pvs"].values():
             assert reference.walk_line(p, line) is not None, (a["id"], line)
+
+
+def test_second_params_type_by_new_files_through_the_real_engine(tmp_path, monkeypatch):
+    """A fixture, not a configuration: the king-relative wide format at a toy
+    width (`data/halfka_toy.py`: plain numpy eval, weights from a seed,
+    `program_params` returning the `StockfishNet` that `TpuEngine` runs on
+    its full-refresh path) added to a copy of the tree as one evaluator
+    file, one configuration and one cell, through
+    `measure.program_engine_factory` and the rehearsal's sizes."""
+    source = (ROOT / "tests/benchmark/data/halfka_toy.py").read_text()
+    root, before = fake_engine.tree_with_new_evaluator(
+        tmp_path, ROOT, "halfka_toy", source,
+        engine={"weights": {"seed": 20261004, "l1": 32}},
+        net_shapes={"features": 22528, "l1": 32})
+    bdir = root / "benchmark"
+    cell = cells.load_cell(root, "halfka_toy.trickle", bench_dir=bdir)
+    evaluator = cell["evaluator"]
+    weights = evaluator.load_weights(cell["config"]["engine"], root)
+    assert weights["ft_w"].shape == (22528, 32) and not (bdir / "weights").exists()
+    params = evaluator.program_params(weights)
+    assert type(params).__name__ == "StockfishNet" and params.l1 == 32
+    result, lines, sampled = rehearse(cell, tmp_path, monkeypatch, seed=2034000113)
+    assert result["correct"] is True, json.dumps([result["checks"], lines[-8:]])
+    assert result["failed"] == 0 and result["window"]["answers"] > 0
+    checks = {k: v["value"] for k, v in result["checks"].items()}
+    assert checks["d1_gap_cp"] == 0 and checks["d1_move_gap_cp"] == 0
+    assert checks["bad_lines"] == 0 and checks["delivery"] == 0
+    # the answers were held to the new evaluator, not to board768: the two
+    # disagree on what the roots are worth
+    old = cells.load_cell(ROOT, "standard.trickle")
+    old_w = old["evaluator"].load_weights(old["config"]["engine"], ROOT)
+    roots = [reference.replay("standard", a["moves"]) for a in sampled]
+    assert any(evaluator.evaluate(weights, p) != old["evaluator"].evaluate(old_w, p)
+               for p in roots)
+    # and the comparison can fail here: the same answers against the same
+    # format with another seed's weights
+    other = evaluator.load_weights({"weights": {"seed": 1, "l1": 32}}, root)
+    wrong, held, _detail = reference.compare(
+        reference.Reference(other, evaluator), list(sampled),
+        {"delivery": 0, "programs_inside": 0}, cell["limits"]["checks"])
+    assert wrong is False
+    assert held["d1_gap_cp"]["value"] > held["d1_gap_cp"]["limit"]
+    for p, content in before.items():
+        assert p.read_bytes() == content, f"{p} was edited"

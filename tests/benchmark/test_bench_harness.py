@@ -12,10 +12,10 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from benchmark import loadgen, measure, nnue_ref, run  # noqa: E402
+from benchmark import loadgen, measure, run  # noqa: E402
 import fake_engine  # noqa: E402
 
-WEIGHTS = nnue_ref.load_weights(ROOT / "benchmark/weights/nnue-board768-64.npz")
+WEIGHTS, EVALUATOR = fake_engine.cell_weights(ROOT)
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 V5E = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
 
@@ -47,7 +47,7 @@ def drive(tmp_path, fault=None, weights=WEIGHTS, workload="standard.trickle",
     lines = []
     result = loadgen.run_cell(
         cell, seed=seed, seconds=seconds, trace=trace,
-        make_engine=lambda: fake_engine.FakeAdapter(weights, fault),
+        make_engine=lambda: fake_engine.FakeAdapter(weights, EVALUATOR, fault),
         device=device, t_start=time.monotonic(), rehearsal=rehearsal,
         control=None, say=lines.append, trace_dir=str(tmp_path / "trace"),
         tracer_factory=NoTrace)
@@ -138,7 +138,7 @@ def test_no_answers_is_not_correct(tmp_path):
     cell = fake_engine.toy_cell(ROOT)
     result = loadgen.run_cell(
         cell, seed=1, seconds=0.3, trace=False,
-        make_engine=lambda: fake_engine.FakeAdapter(WEIGHTS, latency_s=5.0),
+        make_engine=lambda: fake_engine.FakeAdapter(WEIGHTS, EVALUATOR, latency_s=5.0),
         device=V5E, t_start=time.monotonic(), rehearsal=None, control=None,
         say=lambda s: None, trace_dir=str(tmp_path / "t"))
     assert result["correct"] is False and result["attempted"] == 0

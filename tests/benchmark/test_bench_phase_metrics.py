@@ -12,7 +12,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from benchmark import cells, nnue_ref  # noqa: E402
+from benchmark import cells  # noqa: E402
 from benchmark import trace_reduce as tr  # noqa: E402
 import fake_engine  # noqa: E402
 
@@ -49,9 +49,7 @@ def test_reader_reads_nothing_where_the_program_keeps_no_such_counter(name):
               "device_ms": 13_000.0, "transfers": 200, "refills": 400}
     assert read({"occupancy": parent, "window_s": 50.0}) is None
     assert read({"occupancy": {}, "window_s": 50.0}) is None
-    weights = nnue_ref.load_weights(
-        ROOT / "benchmark/weights/nnue-board768-64.npz")
-    fake = dict(fake_engine.FakeAdapter(weights).counters())
+    fake = dict(fake_engine.FakeAdapter(*fake_engine.cell_weights(ROOT)).counters())
     assert read({"occupancy": fake, "window_s": 50.0}) is None
 
 

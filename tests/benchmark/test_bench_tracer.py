@@ -15,10 +15,10 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from benchmark import cells, loadgen, measure, nnue_ref, trace_reduce  # noqa: E402
+from benchmark import cells, loadgen, measure, trace_reduce  # noqa: E402
 import fake_engine  # noqa: E402
 
-WEIGHTS = nnue_ref.load_weights(ROOT / "benchmark/weights/nnue-board768-64.npz")
+WEIGHTS, EVALUATOR = fake_engine.cell_weights(ROOT)
 V5E = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
 BENCH = json.load(open(ROOT / "BENCHMARK.json"))
 TRACE_SOURCED = {m["name"] for m in BENCH["per_layer"] if m["source"] == "device_trace"}
@@ -236,7 +236,7 @@ def drive(tmp_path, factory, trace_limits=None, t_start=None, seconds=1.2,
     lines = []
     result = loadgen.run_cell(
         cell, seed=2147483659, seconds=seconds, trace=True,
-        make_engine=lambda: fake_engine.FakeAdapter(WEIGHTS),
+        make_engine=lambda: fake_engine.FakeAdapter(WEIGHTS, EVALUATOR),
         device=V5E, t_start=time.monotonic() if t_start is None else t_start,
         rehearsal=None, control=None, say=lines.append,
         trace_dir=str(tmp_path / "trace"), tracer_factory=factory,
