@@ -333,7 +333,7 @@ class TpuEngine(ChunkSubmit):
             # counted on the device, in the segment's loop carry, and
             # read from the boundary summary's last row: node expansions,
             # the moves their lists hold, the drops among those
-            **{name: 0 for name in search_ops.MOVEGEN_COUNTERS},
+            **{name: 0 for name in search_ops.SEGMENT_COUNTERS},
             # the same boundary intervals by what the host was doing
             # (SyncStats.phase): sums to host_ms + device_ms, and
             # phase_wait_ms is device_ms
@@ -2351,7 +2351,7 @@ class _Session:
         per-shard steps, the (B,) node counts, now)."""
         with self.stats.phase("lanes"):
             summ, n, shard_steps = self.canon_summ(raw_summ)
-            self.count_movegen(raw_summ)
+            self.count_device(raw_summ)
             lane_done = summ[:, search_ops.SUM_DONE].astype(bool)
             nodes_row = summ[:, search_ops.SUM_NODES]
             # lanes whose park was already handled at an earlier
@@ -2640,11 +2640,15 @@ class _Session:
         ]
         return lanes, max(shard_steps), shard_steps
 
-    def count_movegen(self, raw) -> None:
-        """The summary's last rows hold what the segment's loop
-        counted (summed over shards): into the interval's snapshot."""
+    def count_device(self, raw) -> None:
+        """The summary's last two rows hold what the segment's loop
+        counted, movegen and accumulator work (summed over shards):
+        into the interval's snapshot."""
+        B, local = self.B, self.local
         self.stats.count(search_ops.movegen_counts(
-            raw[self.B] if self.mesh is None else raw[:, self.local]))
+            raw[B] if self.mesh is None else raw[:, local]))
+        self.stats.count(search_ops.acc_counts(
+            raw[B + 1] if self.mesh is None else raw[:, local + 1]))
 
     def shard_occup(self):
         """Busy (primary or helper) lane count per shard, or None

@@ -30,11 +30,12 @@ NUM_KING_BUCKETS = 32
 NUM_PIECE_KINDS = 11  # our P N B R Q, their P N B R Q, kings (shared plane)
 NUM_SQUARES = 64
 NUM_FEATURES = NUM_KING_BUCKETS * NUM_PIECE_KINDS * NUM_SQUARES  # 22528
-# board768: the TPU fast-path feature set — 12 piece kinds × 64 squares per
-# perspective, no king buckets. King-bucketed sets force a full accumulator
-# refresh whenever a king moves; under lockstep masked execution that
-# refresh branch would run every step for every lane, so the fast path uses
-# a set whose updates are *always* incremental (≤4 changed features/move).
+# board768: 12 piece kinds × 64 squares per perspective, no king buckets,
+# so its updates are *always* incremental (≤4 changed features/move). A
+# king-bucketed set has to rebuild a perspective whenever its king moves;
+# an imported net pays that across lanes, compacted (nnue_import.py's
+# header, ops/search.py::_refresh_stale), our own NnueParams of that set
+# refresh every step (acc_scheme).
 NUM_FEATURES_768 = 12 * 64
 NUM_OUTPUT_BUCKETS = 8
 OUTPUT_SCALE = 600.0  # network output [-1,1]-ish → centipawns
@@ -90,14 +91,14 @@ def init_params(
 # ------------------------------------------------------------------ features
 
 
-def feature_indices(board64: jnp.ndarray, perspective: jnp.ndarray,
-                    ksq: jnp.ndarray) -> jnp.ndarray:
-    """(64,) feature index per square for one perspective; -1 where empty.
+def feature_index(code: jnp.ndarray, sq: jnp.ndarray,
+                  perspective: jnp.ndarray, ksq: jnp.ndarray) -> jnp.ndarray:
+    """HalfKAv2_hm feature row of piece `code` on `sq` for one perspective
+    whose king stands on `ksq`; -1 where code == 0 (code and sq broadcast).
 
     Orientation: flip ranks for black's perspective, then mirror files so
     the king lands on files a-d (the _hm halving).
     """
-    sq = jnp.arange(64, dtype=jnp.int32)
     flip = jnp.where(perspective == 1, 56, 0)
     o_sq = sq ^ flip
     o_ksq = ksq ^ flip
@@ -106,12 +107,18 @@ def feature_indices(board64: jnp.ndarray, perspective: jnp.ndarray,
     o_ksq = o_ksq ^ mirror
     bucket = jnp.asarray(KING_BUCKET)[o_ksq]
 
-    code = board64
     pt = piece_type(code)  # -1 empty, 0..5
     col = piece_color(code)
     kind = jnp.where(pt == 5, 10, jnp.where(col == perspective, pt, 5 + pt))
     idx = bucket * (NUM_PIECE_KINDS * NUM_SQUARES) + kind * NUM_SQUARES + o_sq
     return jnp.where(code > 0, idx, -1)
+
+
+def feature_indices(board64: jnp.ndarray, perspective: jnp.ndarray,
+                    ksq: jnp.ndarray) -> jnp.ndarray:
+    """(64,) feature index per square for one perspective; -1 where empty."""
+    sq = jnp.arange(64, dtype=jnp.int32)
+    return feature_index(board64, sq, perspective, ksq)
 
 
 def refresh_accumulator(params: NnueParams, board64: jnp.ndarray,
@@ -199,14 +206,14 @@ def apply_acc_updates_768(params: NnueParams, acc: jnp.ndarray,
     return acc
 
 
-def cast_params(params: NnueParams, dtype=jnp.bfloat16) -> NnueParams:
+def cast_params(params, dtype=jnp.bfloat16):
     """Quantize the network weights (bf16 by default — the MXU's native
-    input type; SURVEY §7.2). Search accumulators stay f32 (init_state
-    allocates acc in f32 regardless), so incremental updates keep their
-    precision; matmuls run bf16×f32→f32 which XLA maps onto the MXU.
-    Evaluations may drift a few centipawns vs f32 — use the f32 master
-    weights for training and parity tests."""
-    return NnueParams(*[jnp.asarray(a).astype(dtype) for a in params])
+    input type; SURVEY §7.2), of either params type. Search accumulators
+    stay f32 (init_state allocates acc in f32 regardless), so incremental
+    updates keep their precision; matmuls run bf16×f32→f32 which XLA maps
+    onto the MXU. Evaluations may drift a few centipawns vs f32 — use the
+    f32 master weights for training and parity tests."""
+    return jax.tree.map(lambda a: jnp.asarray(a).astype(dtype), params)
 
 
 # int8 quantization scales (Stockfish-style fixed-point ladder):
@@ -263,6 +270,23 @@ def is_board768(params) -> bool:
         isinstance(params, NnueParams)
         and params.ft_w.shape[0] == NUM_FEATURES_768
     )
+
+
+def acc_scheme(params, variant: str = "standard"):
+    """The incremental accumulator scheme the search carries down its
+    stack for these params, or None where every step refreshes from the
+    board: "board768" (every move a ≤4-row delta, apply_acc_updates_768),
+    "halfka" (an imported StockfishNet: a ≤4-row delta a perspective, and
+    a refresh of the perspective whose king moved — nnue_import.acc_*).
+    None for our own king-relative NnueParams, and for atomic, whose
+    explosions exceed the 4 slots of board.move_piece_changes."""
+    if variant == "atomic":
+        return None
+    if is_board768(params):
+        return "board768"
+    if not isinstance(params, NnueParams):
+        return "halfka"
+    return None
 
 
 # ------------------------------------------------------------------- forward

@@ -35,15 +35,21 @@ the public nnue-pytorch trainer and read by Stockfish 15/16:
     * Quantization scales: FT 127 (QA), hidden weights 64 (QB),
       output scale 16; dequantized here to float32.
 
-SCOPE — eval-parity tooling, not the search path. Imported HalfKAv2_hm
-nets evaluate positions (engine compat path, eval A/Bs, label
-generation) but pay a full accumulator refresh per search step, because
-"incremental" HalfKAv2_hm cannot win inside a lockstep vmapped step: a
-king move forces a full per-perspective refresh, a vmapped `cond`
-compiles to a select that EXECUTES both branches, so every step would
-pay the masked 64-gather refresh anyway — exactly what the full-refresh
-path already costs. board768 (no king buckets, every move a ≤4-feature
-delta) is the search feature set by design; see README "Evaluation".
+SCOPE — the search path. `TpuEngine(params=<StockfishNet>)` (a parsed
+file through `weights_path=*.nnue`, or seeded weights: the benchmark's
+`halfka3072` configuration) searches with the accumulator and PSQT pair
+carried down the stack (ops/search.py, nnue.acc_scheme == "halfka"): a
+move updates both perspectives by its ≤ 4 changed rows (acc_update_pair),
+a king's move leaves its own perspective stale, and the stale (lane,
+perspective) pairs of a lockstep step are compacted into a few slots and
+rebuilt from the board there (search._refresh_stale, acc_refresh_row).
+An earlier version of this header argued that incremental HalfKAv2_hm
+"cannot win inside a lockstep vmapped step" because a vmapped `cond`
+runs both branches. Nobody had measured it. On the chip (PERF.md §6,
+PR 35; 64 lanes, L1 3,072, µs a step): compacted refresh 369, a fixed
+32-row gather every lane-step 573, the 64-row full refresh every step the
+cell's rate of 4.6-4.8 against 8.6-9.1 positions/s; 4.5-4.9 % of pushed
+perspectives are rebuilt. Atomic alone stays on the full refresh.
 
 Anything that doesn't match this layout (different sizes, unknown
 section lengths) raises UnsupportedNnueFormat rather than misparsing.
@@ -293,53 +299,175 @@ def load_nnue(path: str | Path, l1: int | None = None) -> StockfishNet:
 
 
 # ------------------------------------------------------------------ forward
+#
+# One accumulator row a perspective: its L1 values and, behind them, its 8
+# PSQT sums — (L1 + 8,) float32, whatever the weights' type, so the PSQT
+# sums ride down the search stack with the accumulator (ops/search.py:
+# SearchState.acc holds two such rows a ply). A row is the bias plus the
+# rows of the pieces' features; a move changes ≤ 4 of them a perspective
+# (acc_update_pair), and a move of a perspective's king changes them all
+# (acc_refresh_row). The products are float32 on every backend
+# (precision=HIGHEST: a TPU's default is one bfloat16 pass, about 2 cp off
+# over 3,072 terms).
+
+# the rows a refresh gathers: the pieces a board of that program can hold
+REFRESH_ROWS = 32
+REFRESH_ROWS_HORDE = 64  # 36 pawns + 16: no compaction buys anything
 
 
-def evaluate_sf(net: StockfishNet, board64, stm):
-    """Centipawn-ish score for one position, SFNNv5 semantics, in jax.
+def refresh_rows(variant: str = "standard") -> int:
+    return REFRESH_ROWS_HORDE if variant == "horde" else REFRESH_ROWS
 
-    Full-refresh evaluation (the engine's HalfKAv2_hm compat path; the
-    board768 fast path keeps its incremental accumulators instead)."""
+
+def _rows(net: StockfishNet, idx):
+    """[ft_w | psqt_w][idx] → (..., L1 + 8) float32: a gather of whole
+    rows."""
     import jax.numpy as jnp
 
-    l1 = net.ft_w.shape[1]
-    half = l1 // 2
+    return jnp.concatenate(
+        [jnp.asarray(net.ft_w)[idx], jnp.asarray(net.psqt_w)[idx]], axis=-1
+    ).astype(jnp.float32)
+
+
+def acc_refresh_row(net: StockfishNet, board64, perspective,
+                    n_rows: int = REFRESH_ROWS):
+    """(L1 + 8,) accumulator row of one perspective from the board: the
+    bias plus the rows of its ≤ n_rows pieces, their squares compacted
+    into n_rows slots first so that no row is gathered for an empty
+    square."""
+    import jax.numpy as jnp
 
     from ..ops.board import king_square
 
-    def persp_acc(perspective):
-        ksq = king_square(board64, perspective)
-        idx = nnue.feature_indices(board64, perspective, jnp.maximum(ksq, 0))
-        rows = jnp.asarray(net.ft_w)[jnp.clip(idx, 0)]
-        rows = jnp.where((idx >= 0)[:, None], rows, 0)
-        psqt_rows = jnp.asarray(net.psqt_w)[jnp.clip(idx, 0)]
-        psqt_rows = jnp.where((idx >= 0)[:, None], psqt_rows, 0)
-        return jnp.asarray(net.ft_b) + rows.sum(0), psqt_rows.sum(0)
+    ksq = jnp.maximum(king_square(board64, perspective), 0)
+    idx64 = nnue.feature_indices(board64, perspective, ksq)
+    if n_rows >= 64:
+        idx, used = jnp.clip(idx64, 0), idx64 >= 0
+    else:
+        occ = idx64 >= 0
+        slot = jnp.cumsum(occ) - 1
+        hit = occ[None, :] & (
+            slot[None, :] == jnp.arange(n_rows, dtype=jnp.int32)[:, None]
+        )  # (n_rows, 64), one square a used slot
+        idx = jnp.sum(jnp.where(hit, idx64[None, :], 0), axis=1)
+        used = jnp.any(hit, axis=1)
+    bias = jnp.concatenate(
+        [jnp.asarray(net.ft_b).astype(jnp.float32),
+         jnp.zeros((NUM_PSQT_BUCKETS,), jnp.float32)]
+    )
+    # a slot that is not used adds its row times 0
+    return bias + jnp.sum(_rows(net, idx) * used[:, None], axis=0)
 
-    acc_w, psqt_w_ = persp_acc(jnp.int32(0))
-    acc_b, psqt_b_ = persp_acc(jnp.int32(1))
-    acc_own = jnp.where(stm == 0, acc_w, acc_b)
-    acc_opp = jnp.where(stm == 0, acc_b, acc_w)
+
+def acc_refresh_pair(net: StockfishNet, board64,
+                     n_rows: int = REFRESH_ROWS):
+    """(2, L1 + 8): white's and black's rows from the board."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.vmap(lambda p: acc_refresh_row(net, board64, p, n_rows))(
+        jnp.arange(2, dtype=jnp.int32)
+    )
+
+
+def acc_update_pair(net: StockfishNet, pair, board64, codes, sqs, signs):
+    """The child's (2, L1 + 8) pair from the parent's by the move's piece
+    changes (board.move_piece_changes: (K,) codes, squares, signs; code 0
+    is an unused slot), and which perspectives that is not enough for:
+    (2,) bool, true where a king of that colour is among the changes, so
+    its perspective's king square — bucket and mirror, every feature —
+    may have changed and the row has to be rebuilt from the child's
+    board. board64 is the PARENT's: a perspective that is not refreshed
+    has its king where it was. 2K rows gathered."""
+    import jax.numpy as jnp
+
+    from ..ops.board import king_square, piece_color, piece_type
+
+    persp = jnp.arange(2, dtype=jnp.int32)
+    ksq = jnp.stack([jnp.maximum(king_square(board64, p), 0) for p in (0, 1)])
+    idx = nnue.feature_index(
+        codes[None, :], sqs[None, :], persp[:, None], ksq[:, None]
+    )  # (2, K)
+    weight = jnp.where(idx >= 0, signs[None, :], 0)
+    idx = jnp.clip(idx, 0)
+    # a gather and an add a slot: all K slots' rows as one (2, K, L1 + 8)
+    # tensor cost the chip a relayout and a reduction of their own
+    # (PERF.md §6, PR 35: 43 → 33 µs a 64-lane step at L1 3,072)
+    delta = sum(
+        _rows(net, idx[:, k]) * weight[:, k, None]  # ±1 and 0: exact
+        for k in range(codes.shape[0])
+    )
+    king = (codes > 0) & (piece_type(codes) == 5)
+    stale = jnp.any(
+        king[None, :] & (piece_color(codes)[None, :] == persp[:, None]), axis=1
+    )
+    return pair + delta, stale
+
+
+def _pick_stack(values, bucket):
+    """values (8·n,) of all eight stacks → the (n,) of stack `bucket`,
+    by a one-hot select and a sum of zeros: exact, and no per-lane
+    gather."""
+    import jax.numpy as jnp
+
+    v = values.reshape(NUM_STACKS, -1)
+    hot = jnp.arange(NUM_STACKS, dtype=jnp.int32) == bucket
+    return jnp.sum(jnp.where(hot[:, None], v, 0), axis=0)
+
+
+def forward_sf_from_acc(net: StockfishNet, pair, stm, bucket):
+    """Centipawns from the side to move's view, from a (2, L1 + 8) pair.
+
+    Each layer is computed for all eight stacks as ONE product — fc0 as
+    (128, L1) · (L1,) — and the stack's outputs picked afterwards: under
+    the search step's vmap that is a (B, L1) × (L1, 128) matrix product,
+    where selecting the stack's weights first would move 16 × L1 of them
+    a lane a step."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    l1 = net.ft_w.shape[1]
+    half = l1 // 2
+    own = jnp.where(stm == 0, pair[0], pair[1])
+    opp = jnp.where(stm == 0, pair[1], pair[0])
 
     def pairwise(acc):
-        c = jnp.clip(acc, 0.0, 1.0)
+        c = jnp.clip(acc[:l1], 0.0, 1.0)
         return c[:half] * c[half:]
 
-    x = jnp.concatenate([pairwise(acc_own), pairwise(acc_opp)])  # (L1,)
+    x = jnp.concatenate([pairwise(own), pairwise(opp)])  # (L1,)
 
-    bucket = nnue.output_bucket(board64)
-    h0 = jnp.asarray(net.fc0_w)[bucket] @ x + jnp.asarray(net.fc0_b)[bucket]
-    skip = h0[15]
-    h = jnp.clip(h0[:15], 0.0, 1.0)
-    h1_in = jnp.concatenate([h, jnp.square(h)])  # (30,)
+    def stack_layer(w, b, v):
+        # w (8, n, k), b (8, n), v (k,) → the (n,) of this lane's stack
+        n = w.shape[1]
+        all8 = jnp.dot(
+            jnp.asarray(w).reshape(NUM_STACKS * n, -1).astype(f32), v,
+            precision=hi,
+        ) + jnp.asarray(b).reshape(-1).astype(f32)
+        return _pick_stack(all8, bucket)
+
+    h0 = stack_layer(net.fc0_w, net.fc0_b, x)  # (16,)
+    skip = h0[FC0_OUT - 1]
+    h = jnp.clip(h0[:FC0_OUT - 1], 0.0, 1.0)
     h1 = jnp.clip(
-        jnp.asarray(net.fc1_w)[bucket] @ h1_in + jnp.asarray(net.fc1_b)[bucket],
+        stack_layer(net.fc1_w, net.fc1_b, jnp.concatenate([h, jnp.square(h)])),
         0.0, 1.0,
     )
-    out = (jnp.asarray(net.fc2_w)[bucket] @ h1)[0] + jnp.asarray(net.fc2_b)[bucket][0]
+    out = stack_layer(net.fc2_w, net.fc2_b, h1)[0]
+    ps = _pick_stack((own[l1:] - opp[l1:]).astype(f32), bucket)[0] / 2.0
+    return (out + skip + ps) * NNUE2SCORE
 
-    psqt = jnp.where(stm == 0, psqt_w_ - psqt_b_, psqt_b_ - psqt_w_)[bucket] / 2.0
-    return (out + skip + psqt) * NNUE2SCORE
+
+def evaluate_sf(net: StockfishNet, board64, stm):
+    """Centipawn-ish score for one position, SFNNv5 semantics, in jax:
+    both rows from the board, then forward_sf_from_acc — what the search
+    computes at a node whose accumulator came down its stack."""
+    return forward_sf_from_acc(
+        net, acc_refresh_pair(net, board64, 64), stm,
+        nnue.output_bucket(board64),
+    )
 
 
 def evaluate_sf_reference(net: StockfishNet, board64: np.ndarray, stm: int) -> float:

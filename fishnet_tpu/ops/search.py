@@ -39,7 +39,7 @@ lanes are cheap, so multipv lanes are just more lanes.
 from __future__ import annotations
 
 import warnings
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +55,7 @@ warnings.filterwarnings(
 )
 
 from ..aot import registry as _aot_registry
-from ..models import nnue
+from ..models import nnue, nnue_import
 from ..utils import sanitize as _sanitize
 from ..utils import settings
 from .board import (
@@ -80,27 +80,43 @@ MODE_RETURN = 1
 MODE_TRYMOVE = 2
 MODE_DONE = 3
 
-# packed boundary summary (int32, shape (B+1, 4)): everything the host
+# packed boundary summary (int32, shape (B+2, 4)): everything the host
 # needs to decide a segment boundary — done bitmap plus per-lane
 # nodes/score/best-move — in ONE small transfer instead of the full
 # extract_results set; row B holds the segment's step count and, in
-# columns SUM_MOVEGEN, its movegen counters (node expansions, the moves
-# their lists hold, the drops among those; `movegen_counts` reads them).
-# PV rows are pulled separately, and only for lanes that actually finished.
+# columns SUM_COUNTS, its movegen counters (node expansions, the moves
+# their lists hold, the drops among those), row B+1 in the same columns
+# its accumulator counters (perspective updates made, perspectives
+# rebuilt from the board, feature rows gathered); `movegen_counts` and
+# `acc_counts` read them. PV rows are pulled separately, and only for
+# lanes that actually finished.
 SUM_DONE, SUM_NODES, SUM_SCORE, SUM_MOVE = range(4)
 SUM_W = 4
-SUM_MOVEGEN = slice(1, 4)
+SUM_TAIL = 2  # the rows behind the lanes'
+SUM_COUNTS = slice(1, 4)
 MOVEGEN_COUNTERS = ("movegen_nodes", "movegen_moves", "movegen_drops")
+ACC_COUNTERS = ("acc_updates", "acc_refreshes", "acc_rows")
+SEGMENT_COUNTERS = MOVEGEN_COUNTERS + ACC_COUNTERS
+
+
+def _tail_counts(rows: np.ndarray, names) -> dict:
+    """One tail row of the summary ((SUM_W,), or (n_shard, SUM_W) under a
+    mesh: summed over shards) under `names`. The device counts in int32
+    and may wrap past 2^31 on the longest segments at full width; read as
+    uint32 a count holds to 2^32."""
+    rows = np.asarray(rows, np.int32).reshape(-1, SUM_W)
+    sums = rows[:, SUM_COUNTS].view(np.uint32).astype(np.int64).sum(axis=0)
+    return dict(zip(names, map(int, sums)))
 
 
 def movegen_counts(last_rows: np.ndarray) -> dict:
-    """The movegen counters of one segment from the summary's last row
-    ((SUM_W,), or (n_shard, SUM_W) under a mesh: summed over shards).
-    The device counts in int32 and may wrap past 2^31 on the longest
-    segments at full width; read as uint32 a count holds to 2^32."""
-    rows = np.asarray(last_rows, np.int32).reshape(-1, SUM_W)
-    sums = rows[:, SUM_MOVEGEN].view(np.uint32).astype(np.int64).sum(axis=0)
-    return dict(zip(MOVEGEN_COUNTERS, map(int, sums)))
+    """The movegen counters of one segment from the summary's row B."""
+    return _tail_counts(last_rows, MOVEGEN_COUNTERS)
+
+
+def acc_counts(last_rows: np.ndarray) -> dict:
+    """The accumulator counters of one segment from the summary's row B+1."""
+    return _tail_counts(last_rows, ACC_COUNTERS)
 
 # game-history repetition seeding: hashes of up to MAX_HIST reversible
 # game positions before each lane's root (the reference feeds Stockfish
@@ -244,7 +260,10 @@ class SearchState(NamedTuple):
     moves: jnp.ndarray  # (B, P, MAX_MOVES) int32
     hist: jnp.ndarray  # (B, 4096) from|to-indexed history counters
     pv: jnp.ndarray  # (B, P, P) int32
-    acc: jnp.ndarray  # (B, P+1, 2, L1) incremental NNUE accumulators
+    # incremental NNUE accumulators, by nnue.acc_scheme: (B, P+1, 2, L1),
+    # or for "halfka" (B, 2·(P+2), L1+8) — row 2·ply + perspective, the 8
+    # PSQT sums behind the L1 values (nnue_import.acc_*), a spare pair last
+    acc: jnp.ndarray
 
 
 def init_state(params: nnue.NnueParams, roots: Board, depth: jnp.ndarray,
@@ -272,18 +291,32 @@ def init_state(params: nnue.NnueParams, roots: Board, depth: jnp.ndarray,
     B = roots.stm.shape[0]
     P = max_ply
     l1 = params.ft_w.shape[1]
-    if nnue.is_board768(params):
-        root_acc = jax.vmap(nnue.accumulators_768, in_axes=(None, 0))(
-            params, roots.board
-        )
-    else:
-        root_acc = jnp.zeros((B, 2, l1), params.ft_w.dtype)
+    scheme = nnue.acc_scheme(params, variant)
     # acc stays f32 even under bf16-quantized weights (nnue.cast_params):
     # incremental adds accumulate rounding error down the stack otherwise.
     # int8-quantized nets use int32 accumulators — integer adds are exact.
     adt = nnue.acc_dtype(params)
-    acc = jnp.zeros((B, P + 1, 2, l1), adt)
-    acc = acc.at[:, 0].set(root_acc.astype(adt))
+    if scheme == "halfka":
+        # the root's two rows, rebuilt from its board (a refill comes
+        # through here too: _splice_lanes)
+        root_acc = jax.vmap(
+            lambda b: nnue_import.acc_refresh_pair(
+                params, b, nnue_import.refresh_rows(variant))
+        )(roots.board)
+        # ... and one pair past the deepest ply's, which a lane that
+        # pushes no child writes to (_step_lane), so that every lane's
+        # write is in bounds
+        acc = jnp.zeros((B, 2 * (P + 2), root_acc.shape[-1]), adt)
+        acc = acc.at[:, :2].set(root_acc)
+    else:
+        if scheme == "board768":
+            root_acc = jax.vmap(nnue.accumulators_768, in_axes=(None, 0))(
+                params, roots.board
+            )
+        else:
+            root_acc = jnp.zeros((B, 2, l1), params.ft_w.dtype)
+        acc = jnp.zeros((B, P + 1, 2, l1), adt)
+        acc = acc.at[:, 0].set(root_acc.astype(adt))
 
     bt = jnp.zeros((B, P + 1, BT_W), jnp.int32)
     bt = bt.at[:, :, BT_EP].set(-1)
@@ -358,7 +391,7 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
                tt_hit=None, tt_score=None, tt_move=None,
                variant: str = "standard"):
     """One state-machine step for a single lane (vmapped over B):
-    → (the lane's next SearchState, its (3,) MOVEGEN_COUNTERS of the step).
+    → (the lane's next SearchState, its StepAux of the step).
 
     The three phases keep their row state in registers: ENTER composes
     the entered node's nt/bt rows, RETURN composes the parent's, and
@@ -454,15 +487,23 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
     stack_full = ply0 >= s.moves.shape[0]  # no moves row / child slot left
 
     with jax.named_scope("step.eval"):
-        # leaf value: NNUE eval (or draw for 50-move). On the board768 fast
-        # path the accumulator came down the stack incrementally and only the
-        # small layer stack runs here; the halfkav2_hm compat path pays a full
-        # refresh per step — as does atomic, whose explosions exceed the
-        # 4-slot incremental update scheme (move_piece_changes).
-        if nnue.is_board768(params) and variant != "atomic":
+        # leaf value: NNUE eval (or draw for 50-move). Under an incremental
+        # scheme (nnue.acc_scheme: board768, or an imported king-relative
+        # net) the accumulator came down the stack and only the layer stack
+        # runs here; our own king-relative NnueParams pay a full refresh per
+        # step — as does atomic, whose explosions exceed the 4-slot
+        # incremental update scheme (move_piece_changes). The oracle
+        # (ops/oracle.py) mirrors board768's scheme and refreshes otherwise.
+        scheme = nnue.acc_scheme(params, variant)
+        if scheme == "board768":
             leaf_val = jnp.int32(
                 nnue.forward_from_acc(params, s.acc[ply0], us, nnue.output_bucket(b.board))
             )
+        elif scheme == "halfka":
+            leaf_val = jnp.int32(nnue_import.forward_sf_from_acc(
+                params, jax.lax.dynamic_slice_in_dim(s.acc, 2 * ply0, 2),
+                us, nnue.output_bucket(b.board),
+            ))
         else:
             leaf_val = jnp.int32(nnue.evaluate(params, b.board, us))
         leaf_val = jnp.clip(leaf_val, -MATE + 1000, MATE - 1000)
@@ -838,7 +879,8 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
 
         bt_new = _row_set(bt_new, nply, _row_from_board(child), advance)
     with jax.named_scope("step.acc_update"):
-        if nnue.is_board768(params) and variant != "atomic":
+        stale = None  # "halfka": the child's perspectives still to rebuild
+        if scheme is not None:
             codes, sqs, signs = move_piece_changes(
                 parent_b, jnp.maximum(move, 0), variant
             )
@@ -847,10 +889,38 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
                 # incremental update an exact no-op (code 0 → no-op)
                 codes = jnp.where(do_null, 0, codes)
                 signs = jnp.where(do_null, 0, signs)
+        if scheme == "board768":
             child_acc = nnue.apply_acc_updates_768(params, s.acc[ply1], codes, sqs, signs)
             acc_new = _row_set(s.acc, nply, child_acc, advance)
+            acc_counts = advance.astype(jnp.int32) * jnp.array([2, 0, 0], jnp.int32)
+        elif scheme == "halfka":
+            child_acc, stale = nnue_import.acc_update_pair(
+                params, jax.lax.dynamic_slice_in_dim(s.acc, 2 * ply1, 2),
+                parent_b.board, codes, sqs, signs,
+            )
+            stale &= advance
+            # the pair goes to rows 2·nply, 2·nply + 1 in place, a
+            # scatter of whole rows: a one-hot select (_row_set) would
+            # rewrite the whole stack, 2·(P+1) rows of L1 + 8 a lane a
+            # step, and a window of two rows a lane (dynamic_update_slice,
+            # or lax.scatter with a (2, L1 + 8) window) becomes a loop
+            # over the lanes on the chip (612-615 against 409 µs a step;
+            # PERF.md §6, PR 35). A lane that pushes no child writes the
+            # spare pair behind the stack.
+            acc_new = s.acc.at[
+                jnp.where(advance, 2 * nply, 2 * P1)
+                + jnp.arange(2, dtype=jnp.int32)
+            ].set(child_acc, mode="promise_in_bounds", unique_indices=True,
+                  indices_are_sorted=True)
+            n_stale = jnp.sum(stale).astype(jnp.int32)
+            acc_counts = jnp.stack([
+                2 * advance.astype(jnp.int32) - n_stale, n_stale,
+                jnp.int32(2 * codes.shape[0]),
+            ])
         else:
             acc_new = s.acc
+            # every step rebuilt both perspectives from all 64 squares
+            acc_counts = jnp.array([0, 2, 2 * 64], jnp.int32)
 
     with jax.named_scope("step.switch"):
         ret = jnp.where(try_m & finish, fin_val, ret)
@@ -873,7 +943,60 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
         bt=bt_new, nt=nt_new, lane=lane_new,
         hist_hash=s.hist_hash, hist_halfmove=s.hist_halfmove,
         moves=moves_new, hist=hist_new, pv=pv_new, acc=acc_new,
-    ), movegen
+    ), StepAux(
+        counts=jnp.stack([movegen, acc_counts]),
+        stale=None if stale is None else (stale, 2 * nply, child.board),
+    )
+
+
+class StepAux(NamedTuple):
+    """What a lane's step hands _run_segment beside its next state."""
+    counts: jnp.ndarray  # (SUM_TAIL, 3): MOVEGEN_COUNTERS, ACC_COUNTERS
+    # "halfka" only, else None: ((2,) bool perspectives of the pushed
+    # child whose row a king's move made stale, the child's first acc
+    # row, its (64,) board) — rebuilt across lanes by _refresh_stale
+    stale: Optional[tuple]
+
+
+def _refresh_stale(params, acc: jnp.ndarray, stale: jnp.ndarray,
+                   row0: jnp.ndarray, boards: jnp.ndarray, variant: str):
+    """Rebuild from the board the accumulator rows a king's move made
+    stale: → (acc, feature rows gathered).
+
+    acc (B, R, W); stale (B, 2) bool; row0 (B,) the pushed child's first
+    row; boards (B, 64) the children's. In a lockstep step some lane
+    moves a king nearly every time and nearly every lane does not, so
+    the (lane, perspective) pairs that need a rebuild are compacted into
+    a few slots and rebuilt there, in as many passes as it takes —
+    nothing is gathered for a lane that needs nothing, and a step in
+    which no king moved pays the count alone."""
+    B = acc.shape[0]
+    n_slots = max(2, B // 8)
+    n_rows = nnue_import.refresh_rows(variant)
+    flat = stale.reshape(-1)  # pair 2·lane + perspective
+    rank = jnp.cumsum(flat) - 1
+    total = jnp.sum(flat).astype(jnp.int32)
+    pairs = jnp.arange(2 * B, dtype=jnp.int32)
+    slots = jnp.arange(n_slots, dtype=jnp.int32)
+
+    def one_pass(carry):
+        acc, done = carry
+        hit = flat[None, :] & (rank[None, :] == done + slots[:, None])
+        pair = jnp.sum(jnp.where(hit, pairs[None, :], 0), axis=1)
+        lane, persp = pair >> 1, pair & 1
+        rows = jax.vmap(
+            lambda b, p: nnue_import.acc_refresh_row(params, b, p, n_rows)
+        )(boards[lane], persp)
+        # a slot past the last stale pair writes past the end: dropped
+        dest = jnp.where(jnp.any(hit, axis=1), row0[lane] + persp, acc.shape[1])
+        acc = acc.at[lane, dest].set(rows.astype(acc.dtype), mode="drop")
+        return acc, done + n_slots
+
+    with jax.named_scope("step.acc_refresh"):
+        acc, done = jax.lax.while_loop(
+            lambda c: c[1] < total, one_pass, (acc, jnp.int32(0))
+        )
+    return acc, (done // n_slots) * (n_slots * n_rows)
 
 
 def make_search_step(params: nnue.NnueParams, variant: str = "standard"):
@@ -932,18 +1055,29 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
     one HBM table worth more than B private ones."""
     gen_i = jnp.asarray(tt_gen, jnp.int32)
 
+    def account(s, aux, counts):
+        """The step's counters into the carry and, where a king's move
+        left accumulator rows stale, those rows rebuilt."""
+        counts = counts + jnp.sum(aux.counts, axis=0)
+        if aux.stale is not None:
+            acc, rows = _refresh_stale(params, s.acc, *aux.stale, variant)
+            s = s._replace(acc=acc)
+            counts = counts.at[1, 2].add(rows)
+        return s, counts
+
     if ttab is None:
         step = make_search_step(params, variant)
 
         def body(carry):
-            s, t, i, mg = carry
-            s, movegen = step(s)
-            return s, t, i + 1, mg + jnp.sum(movegen, axis=0)
+            s, t, i, counts = carry
+            s, aux = step(s)
+            s, counts = account(s, aux, counts)
+            return s, t, i + 1, counts
     else:
         step = make_search_step_tt(params, variant)
 
         def body(carry):
-            s, t, i, mg = carry
+            s, t, i, counts = carry
             lane = s.lane
             ply = lane[:, LN_PLY]
             btrow = _gather_ply(s.bt, ply)  # one row gather serves all
@@ -1010,7 +1144,8 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
                 )
             usable &= enter
             order_mv = jnp.where(enter, order_mv, -1)
-            s, movegen = step(s, usable, score, order_mv)
+            s, aux = step(s, usable, score, order_mv)
+            s, counts = account(s, aux, counts)
 
             # ---- store leaves the step just evaluated (depth-0 EXACT).
             # Their hash is the PRE-step hash: a marking lane was in ENTER
@@ -1023,14 +1158,15 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
                     jnp.full_like(sval, -1), s.lane[:, LN_SMARK] != 0,
                     prefer_deep=prefer_deep, gen=gen_i,
                 )
-            return s, t, i + 1, mg + jnp.sum(movegen, axis=0)
+            return s, t, i + 1, counts
 
     def cond(carry):
-        s, t, i, mg = carry
+        s, t, i, counts = carry
         return (i < segment_steps) & jnp.any(s.lane[:, LN_MODE] != MODE_DONE)
 
-    state, ttab, n, movegen = jax.lax.while_loop(
-        cond, body, (state, ttab, jnp.int32(0), jnp.zeros(3, jnp.int32))
+    state, ttab, n, counts = jax.lax.while_loop(
+        cond, body,
+        (state, ttab, jnp.int32(0), jnp.zeros((SUM_TAIL, 3), jnp.int32)),
     )
     lane = state.lane
     summary = jnp.concatenate([
@@ -1040,7 +1176,7 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
             lane[:, LN_RSCORE],
             lane[:, LN_RMOVE],
         ], axis=1),
-        jnp.concatenate([n[None], movegen])[None],
+        jnp.concatenate([jnp.stack([n, jnp.int32(0)])[:, None], counts], axis=1),
     ], axis=0)
     return state, ttab, n, summary
 

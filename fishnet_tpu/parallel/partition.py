@@ -18,7 +18,7 @@ Layout, in one screen:
   * per-lane search state (SearchState: bt/nt/lane/hist_hash/
     hist_halfmove/moves/hist/pv/acc) — leading dim is the lane axis,
     sharded over ``dp``; trailing dims replicated.
-  * NNUE weights (NnueParams) — replicated on every chip (`PARAM_RULES`),
+  * NNUE weights (NnueParams, StockfishNet) — replicated on every chip (`PARAM_RULES`),
     or tensor-sharded over an optional ``tp`` axis for the
     feature-transform width (`PARAM_RULES_TP`, the training layout).
   * transposition table (TTable.data, (ndev, N, 4)) — leading shard dim
@@ -73,6 +73,9 @@ TT_RULES: Tuple[Rule, ...] = (
 # eval stack is tiny and the lanes are embarrassingly parallel
 PARAM_RULES: Tuple[Rule, ...] = (
     (r"(^|/)(ft_w|ft_b|l1_w|l1_b|l2_w|l2_b|out_w|out_b)$", P()),
+    # an imported net (models/nnue_import.StockfishNet): its feature
+    # table is gathered by row, lane by lane, so every chip holds it whole
+    (r"(^|/)(psqt_w|fc0_w|fc0_b|fc1_w|fc1_b|fc2_w|fc2_b)$", P()),
 )
 
 # NNUE weights, training layout: the gather-heavy feature transform
@@ -264,11 +267,20 @@ def param_proto():
     return NnueParams(*NnueParams._fields)
 
 
+def imported_param_proto() -> Dict[str, str]:
+    """The array fields of an imported net (nnue_import.StockfishNet), as
+    a dict of field-name strings."""
+    from ..models.nnue_import import _ARRAY_FIELDS
+
+    return {name: name for name in _ARRAY_FIELDS}
+
+
 def search_proto() -> Dict[str, Any]:
     """Everything that crosses the mesh boundary, as one prototype tree —
     the default subject of validate_rules()."""
     return {
         "params": param_proto(),
+        "params_imported": imported_param_proto(),
         "state": state_proto(),
         "tt": tt_proto(),
         "tt_gen": "tt_gen",
@@ -303,6 +315,20 @@ def param_specs(tp: bool = False):
     return match_partition_rules(param_proto(), rules)
 
 
+def search_param_spec() -> P:
+    """The search layout of the weights as one spec for the whole
+    subtree, whichever params type the engine runs (a shard_map spec may
+    be a prefix of its argument's tree): every PARAM_RULES leaf says
+    replicated, which is checked here, so the callables need no
+    prototype of the type."""
+    for proto in (param_proto(), imported_param_proto()):
+        leaves = jax.tree_util.tree_leaves(
+            match_partition_rules(proto, PARAM_RULES),
+            is_leaf=lambda x: isinstance(x, P))
+        assert all(spec == P() for spec in leaves), leaves
+    return P()
+
+
 def spec_for(name: str, axis: str = "dp") -> P:
     """The registry's spec for one named boundary value (tt_gen, mask,
     segment_steps, steps, summary)."""
@@ -320,7 +346,7 @@ def segment_specs(has_tt: bool, axis: str = "dp"):
     placeholder."""
     tt = tt_specs(axis) if has_tt else P()
     in_specs = (
-        param_specs(),
+        search_param_spec(),
         state_specs(axis),
         tt,
         spec_for("segment_steps", axis),
@@ -343,7 +369,7 @@ def splice_specs(axis: str = "dp"):
     root Board as one subtree) shard their leading, lane dim."""
     st = state_specs(axis)
     lanes = spec_for("mask", axis)
-    return (param_specs(), st) + (lanes,) * 10, st
+    return (search_param_spec(), st) + (lanes,) * 10, st
 
 
 def batch_spec(ndim: int, axis: str = "dp") -> P:

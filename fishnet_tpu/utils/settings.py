@@ -213,8 +213,10 @@ SETTINGS: Tuple[Setting, ...] = (
         name="FISHNET_TPU_DTYPE",
         kind="str",
         default="",
-        doc="Quantize NNUE weights: \"bf16\" for MXU-native inputs; "
-            "\"int8\" is experimental and additionally gated.",
+        doc="Quantize NNUE weights, of either params type (board768 or "
+            "an imported StockfishNet; accumulators stay float32): "
+            "\"bf16\" for MXU-native inputs; \"int8\" is experimental, "
+            "board768 only and additionally gated.",
         engine=True,
     ),
     Setting(

@@ -269,7 +269,7 @@ def _segments(params, roots, depth, variant, seg=16):
         state, _tt, n, summ = S._run_segment_jit(
             params, state, None, seg, variant, False)
         summ = np.asarray(summ)
-        assert summ.shape == (B + 1, S.SUM_W) and summ[B, S.SUM_DONE] == int(n)
+        assert summ.shape == (B + S.SUM_TAIL, S.SUM_W) and summ[B, S.SUM_DONE] == int(n)
         for k, v in S.movegen_counts(summ[B]).items():
             total[k] += v
         segments += 1

@@ -171,6 +171,63 @@ def test_segment_sorts_only_live_slots(one_chip, params, variant, widest):
     assert dims[int(axis)] == len(movegen._live_slots(variant)) <= widest
 
 
+@pytest.fixture(scope="module")
+def wide_net():
+    """The shapes of the benchmark's `halfka3072` net (no array: a
+    3,072-wide table is 277 MB)."""
+    from fishnet_tpu.models import nnue_import as ni
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    l1 = 3072
+    return ni.StockfishNet(
+        ft_w=f32(ni.NUM_FEATURES, l1), ft_b=f32(l1),
+        psqt_w=f32(ni.NUM_FEATURES, ni.NUM_PSQT_BUCKETS),
+        fc0_w=f32(ni.NUM_STACKS, ni.FC0_OUT, l1), fc0_b=f32(ni.NUM_STACKS, ni.FC0_OUT),
+        fc1_w=f32(ni.NUM_STACKS, ni.FC1_OUT, ni.FC1_IN),
+        fc1_b=f32(ni.NUM_STACKS, ni.FC1_OUT),
+        fc2_w=f32(ni.NUM_STACKS, 1, ni.FC1_OUT), fc2_b=f32(ni.NUM_STACKS, 1),
+    )
+
+
+@pytest.mark.parametrize("lanes", [64, MAX_LANES])
+def test_wide_net_segment_compiles(one_chip, wide_net, lanes):
+    """The king-relative 3,072-wide net on the incremental path at the
+    widths `halfka3072.trickle` runs and at the lane ceiling: the
+    accumulator stack rides in the state — 2 x (33 plies + the spare pair)
+    rows of 3,080 float32 a lane — the step gathers whole rows of the
+    22,528 x 3,072 table, and the ordering sort is the one every net
+    shares."""
+    compiled = _compile_segment(wide_net, one_chip, lanes, "standard", False, True)
+    used = _fits(compiled)
+    acc = lanes * 2 * (MAX_PLY + 2) * 3080 * 4
+    assert used > acc + 22528 * 3072 * 4
+    text = compiled.as_text()
+    assert f"f32[{lanes},{2 * (MAX_PLY + 2)},3080]" in text
+    sorts = re.findall(r"= s32\[([\d,]+)\]\S* sort\(", text)
+    assert len(sorts) == 1 and str(len(movegen._live_slots("standard"))) in sorts[0]
+
+
+def test_wide_net_splice_compiles(one_chip, wide_net):
+    """The refill's program for the wide net at 64 lanes: every admitted
+    lane's root rebuilt from its board (32 rows a perspective)."""
+    lanes = 64
+    state = _state_shape(wide_net, lanes)
+    roots, depth, budget = _init_args(lanes)
+    hist = jnp.zeros((lanes, S.MAX_HIST, 2), jnp.uint32)
+    col = jnp.zeros(lanes, jnp.int32)
+    fn = jax.jit(S._splice_lanes, static_argnames=("variant",),
+                 donate_argnums=(1,))
+    compiled = fn.lower(
+        _on(wide_net, one_chip), _on(state, one_chip),
+        *_on((roots, depth, budget, hist, hist[:, :, 0].astype(jnp.int32),
+              col, col, col, col, col != 0), one_chip),
+        variant="standard",
+    ).compile()
+    _fits(compiled)
+
+
 def test_merge_lanes_compiles(one_chip, params):
     state = _on(_state_shape(params, BUCKET), one_chip)
     mask = jax.ShapeDtypeStruct((BUCKET,), jnp.bool_, sharding=one_chip)

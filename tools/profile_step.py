@@ -11,7 +11,7 @@ built it.
 
 Usage:
   python tools/profile_step.py [B] [depth] [max_ply] [--trace] [--tt]
-                               [--variant crazyhouse]
+                               [--variant crazyhouse] [--net halfka3072]
 """
 from __future__ import annotations
 
@@ -37,6 +37,10 @@ def main() -> None:
     ap.add_argument("--variant", default="standard",
                     help="the statically compiled program to profile "
                          "(engine/tpu.py DEVICE_VARIANTS' values)")
+    ap.add_argument("--net", default="board768",
+                    help="board768 (64 wide), or halfka<L1> for the "
+                         "king-relative net of that width with seeded "
+                         "weights (halfka3072 is the benchmark's)")
     opts = ap.parse_args()
     B, depth, variant = opts.B, opts.depth, opts.variant
     max_ply = opts.max_ply if opts.max_ply is not None else depth + 1
@@ -63,7 +67,10 @@ def main() -> None:
 
     roots = _roots_for(
         B, variant, "variant" if variant in FENS_VARIANT else "standard")
-    params = nnue.init_params(jax.random.PRNGKey(0), l1=64, feature_set="board768")
+    if opts.net.startswith("halfka"):
+        params = seeded_halfka(int(opts.net[len("halfka"):]))
+    else:
+        params = nnue.init_params(jax.random.PRNGKey(0), l1=64, feature_set="board768")
     depth_arr = jnp.full((B,), depth, jnp.int32)
     budget_arr = jnp.full((B,), 10_000_000, jnp.int32)
 
@@ -113,6 +120,32 @@ def main() -> None:
         jax.block_until_ready(out.lane)
     print(f"trace written to {trace_dir}", file=sys.stderr)
     report(trace_dir, scope_of_instruction(compiled.as_text()), steps)
+
+
+def seeded_halfka(l1: int):
+    """A StockfishNet of width l1 drawn on the device, at scales that keep
+    the accumulators around the clip's 0 and 1 (no file is read: times do
+    not depend on the values)."""
+    import jax
+    import jax.numpy as jnp
+
+    from fishnet_tpu.models import nnue_import as ni
+
+    shapes = {
+        "ft_w": ((ni.NUM_FEATURES, l1), 0.1), "ft_b": ((l1,), 0.5),
+        "psqt_w": ((ni.NUM_FEATURES, ni.NUM_PSQT_BUCKETS), 0.02),
+        "fc0_w": ((ni.NUM_STACKS, ni.FC0_OUT, l1), l1 ** -0.5),
+        "fc0_b": ((ni.NUM_STACKS, ni.FC0_OUT), 0.1),
+        "fc1_w": ((ni.NUM_STACKS, ni.FC1_OUT, ni.FC1_IN), ni.FC1_IN ** -0.5),
+        "fc1_b": ((ni.NUM_STACKS, ni.FC1_OUT), 0.1),
+        "fc2_w": ((ni.NUM_STACKS, 1, ni.FC1_OUT), 0.05),
+        "fc2_b": ((ni.NUM_STACKS, 1), 0.02),
+    }
+    keys = jax.random.split(jax.random.PRNGKey(0), len(shapes))
+    return ni.StockfishNet(**{
+        name: jax.random.normal(k, shape, jnp.float32) * scale
+        for k, (name, (shape, scale)) in zip(keys, shapes.items())
+    })
 
 
 SCOPE = re.compile(r"\b((?:step|refill)\.[a-z_]+)")

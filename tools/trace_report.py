@@ -111,6 +111,12 @@ def summarize(events: List[dict]) -> dict:
     args_other = sum(
         float((a.get("phases") or {}).get("other", 0.0)) for a in seg_args
     )
+    # what the device counted in the segments' loops (ops/search.py
+    # SEGMENT_COUNTERS: movegen_*, acc_*), where the trace has them
+    seg_counts: Dict[str, int] = defaultdict(int)
+    for a in seg_args:
+        for name, n in (a.get("counts") or {}).items():
+            seg_counts[name] += int(n)
     boundary: Dict[str, dict] = defaultdict(
         lambda: {"count": 0, "total_ms": 0.0}
     )
@@ -202,6 +208,7 @@ def summarize(events: List[dict]) -> dict:
             # cross-validation: fetches; phase self times + other
             "span_device_ms": round(span_device, 3),
             "span_host_ms": round(span_host + args_other, 3),
+            "counts": dict(seg_counts),
         },
         "boundary_phases": {
             name: {
@@ -494,6 +501,9 @@ def render_text(report: dict) -> str:
             f"({seg['device_share']:.1%})  host {seg['host_ms']:.3f}ms "
             f"({seg['host_share']:.1%})",
         ]
+        if seg.get("counts"):
+            lines.append("  counted on the device: " + "  ".join(
+                f"{name} {n}" for name, n in seg["counts"].items()))
     if report["boundary_phases"]:
         lines += [
             "",
