@@ -519,12 +519,15 @@ class Position:
         move = self.parse_uci(uci)
         return self.push(move)
 
-    def parse_uci(self, uci: str) -> Move:
+    def parse_uci(self, uci: str, legal_moves: Optional[List[Move]] = None) -> Move:
         """Parse a UCI move, accepting both standard (e1g1) and Chess960
-        (king-takes-rook, e1h1) castling notation; validates legality."""
+        (king-takes-rook, e1h1) castling notation; validates legality.
+
+        Pass precomputed `legal_moves` (this position's) to avoid
+        regenerating them, as for `outcome`."""
         move = Move.parse_uci(uci)
         move = self.normalize_move(move)
-        legal = self.legal_moves()
+        legal = self.legal_moves() if legal_moves is None else legal_moves
         if move not in legal:
             raise IllegalMoveError(f"illegal move {uci!r} in {self.to_fen()!r}")
         return move

@@ -108,6 +108,135 @@ def _position_keys(positions, variant: str):
         b.board, b.stm, b.ep, b.castling, b.extra, variant)
 
 
+def _fill_history_row(hh, hm, h1, h2, halfmoves, keep_last=0):
+    """One lane's repetition-history rows (`hh` (MAX_HIST, 2), `hm`
+    (MAX_HIST,), written in place) from its tail's keys and halfmove
+    clocks, oldest first, right-aligned; only positions occurring >=2x
+    in the tail stay planted, the last keep_last slots always do (see
+    `TpuEngine._history_arrays`)."""
+    n = len(halfmoves)
+    if not n:
+        return
+    lo = search_ops.MAX_HIST - n
+    hh[lo:, 0], hh[lo:, 1] = h1, h2
+    hm[lo:] = halfmoves
+    filled = hm != search_ops.HIST_HM_SENTINEL
+    pairs = [tuple(hh[k]) for k in range(search_ops.MAX_HIST)]
+    for k in range(search_ops.MAX_HIST - keep_last):
+        if filled[k] and pairs.count(pairs[k]) < 2:
+            hm[k] = search_ops.HIST_HM_SENTINEL
+            hh[k] = 0
+
+
+class _ChunkReplay:
+    """A chunk's positions replayed from their root FENs, each line of
+    play walked once.
+
+    A chunk is mostly consecutive plies of one game (the planner's
+    tiling), so each position's moves are a prefix of the deepest one's:
+    replaying every position from its root validates the shared prefix
+    again and again. Here positions with the same root whose moves are
+    a prefix of a longer position's share that position's walk; a
+    position that shares nothing is a line of its own. Every move is
+    validated by `Position.parse_uci` as a per-position replay would,
+    when the first position (in chunk order) that needs it asks, so an
+    illegal move raises the same `IllegalMoveError` at the same
+    position. `parsed` counts the moves validated."""
+
+    class _Line:
+        __slots__ = ("root_fen", "moves", "boards", "legal")
+
+        def __init__(self, root_fen: str, moves: tuple):
+            self.root_fen = root_fen
+            self.moves = moves  # the deepest member's moves
+            self.boards: List[Position] = []  # boards[k]: after k moves
+            self.legal: dict = {}  # k -> legal moves of boards[k]
+
+        def legal_at(self, k: int):
+            if k not in self.legal:
+                self.legal[k] = self.boards[k].legal_moves()
+            return self.legal[k]
+
+    def __init__(self, positions, variant: str):
+        self.variant = variant
+        self.parsed = 0  # moves validated by the walks
+        self._moves = [tuple(wp.moves) for wp in positions]
+        self._line_of: List[_ChunkReplay._Line] = [None] * len(positions)
+        by_root: dict = {}
+        # longest first: a position joins the first line it is a prefix of
+        for i in sorted(range(len(positions)),
+                        key=lambda i: -len(self._moves[i])):
+            mv = self._moves[i]
+            lines = by_root.setdefault(positions[i].root_fen, [])
+            line = next((ln for ln in lines if ln.moves[:len(mv)] == mv),
+                        None)
+            if line is None:
+                line = self._Line(positions[i].root_fen, mv)
+                lines.append(line)
+            self._line_of[i] = line
+
+    def _walk(self, i: int):
+        """Position i's line, walked (and validated) at least as deep as
+        position i's moves, and that depth."""
+        line = self._line_of[i]
+        if not line.boards:
+            line.boards.append(from_fen(line.root_fen, self.variant))
+        n = len(self._moves[i])
+        while len(line.boards) <= n:
+            k = len(line.boards) - 1
+            board = line.boards[k]
+            move = board.parse_uci(line.moves[k], line.legal_at(k))
+            line.boards.append(board.push(move))
+            self.parsed += 1
+        return line, n
+
+    def position(self, i: int):
+        """(board after position i's moves, its legal moves where the
+        walk needs them anyway for a deeper position, else None) — the
+        list `Position.outcome(legal_moves=...)` takes."""
+        line, n = self._walk(i)
+        legal = line.legal_at(n) if n < len(line.moves) else None
+        return line.boards[n], legal
+
+    def game(self, i: int) -> List[Position]:
+        """The boards before position i, oldest first."""
+        line, n = self._walk(i)
+        return line.boards[:n]
+
+    def history(self, idxs, variant: str):
+        """(hh, hm) of each position in idxs, as
+        `TpuEngine._history_arrays([game(i)], 1, variant)` gives them,
+        with the keys hashed once for each line over the boards its
+        positions' tails cover."""
+        def tail(i):
+            line, n = self._walk(i)
+            return line, range(max(0, n - search_ops.MAX_HIST), n)
+
+        covered: dict = {}  # line -> the board indices its tails cover
+        for i in idxs:
+            line, ks = tail(i)
+            covered.setdefault(line, set()).update(ks)
+        keys = {}  # line -> (board index -> row, h1, h2)
+        for line, ks in covered.items():
+            if ks:
+                ks = sorted(ks)
+                h1, h2 = _position_keys([line.boards[k] for k in ks], variant)
+                keys[line] = ({k: j for j, k in enumerate(ks)}, h1, h2)
+        out = []
+        for i in idxs:
+            line, ks = tail(i)
+            hh = np.zeros((search_ops.MAX_HIST, 2), np.uint32)
+            hm = np.full(search_ops.MAX_HIST, search_ops.HIST_HM_SENTINEL,
+                         np.int32)
+            if ks:
+                row, h1, h2 = keys[line]
+                js = [row[k] for k in ks]
+                _fill_history_row(hh, hm, h1[js], h2[js],
+                                  [line.boards[k].halfmove for k in ks])
+            out.append((hh, hm))
+        return out
+
+
 def _score_from_int(v: int, root_ply_to_mate_sign: int = 1) -> Score:
     if v >= MATE - 1000:
         return Score.mate((MATE - v + 1) // 2)
@@ -353,6 +482,10 @@ class TpuEngine(ChunkSubmit):
             "chunks_submitted": 0, "positions_submitted": 0,
             "submit_ms": 0.0, "submit_replay_ms": 0.0,
             "submit_history_ms": 0.0, "submit_ttwarm_ms": 0.0,
+            # Σ moves of the submitted positions, and the moves the
+            # chunk's line walks validated (_ChunkReplay): a chunk of
+            # consecutive plies validates its shared prefix once
+            "submit_prefix_moves": 0, "submit_moves_parsed": 0,
             # programs built or loaded while serving a chunk, by where
             **{f"compiles_{site}": 0 for site in COMPILE_SITES},
             "compile_ms": 0.0,
@@ -814,26 +947,19 @@ class TpuEngine(ChunkSubmit):
 
         hh = np.zeros((B, MAX_HIST, 2), np.uint32)
         hm = np.full((B, MAX_HIST), HIST_HM_SENTINEL, np.int32)
-        flat, lanes, ks = [], [], []
-        for lane, hist in enumerate(hist_lists):
-            tail = hist[-MAX_HIST:]
-            flat.extend(tail)
-            lanes.extend([lane] * len(tail))
-            ks.extend(range(MAX_HIST - len(tail), MAX_HIST))
+        tails = [hist[-MAX_HIST:] for hist in hist_lists]
+        flat = [p for tail in tails for p in tail]
         if flat:
             # on the host: the keys are `tt.hash_board`'s bit for bit,
             # and a device call here would wait behind a running segment
-            hh[lanes, ks, 0], hh[lanes, ks, 1] = _position_keys(flat, variant)
-            hm[lanes, ks] = [p.halfmove for p in flat]
-            # keep only positions occurring >=2x within their lane's tail
-            # (the last keep_last slots are exempt — see docstring)
-            for lane in range(B):
-                filled = hm[lane] != HIST_HM_SENTINEL
-                pairs = [tuple(hh[lane, k]) for k in range(MAX_HIST)]
-                for k in range(MAX_HIST - keep_last):
-                    if filled[k] and pairs.count(pairs[k]) < 2:
-                        hm[lane, k] = HIST_HM_SENTINEL
-                        hh[lane, k] = 0
+            h1, h2 = _position_keys(flat, variant)
+            at = 0
+            for lane, tail in enumerate(tails):
+                n = len(tail)
+                _fill_history_row(hh[lane], hm[lane], h1[at:at + n],
+                                  h2[at:at + n], [p.halfmove for p in tail],
+                                  keep_last)
+                at += n
         return hh, hm
 
     @classmethod
@@ -875,16 +1001,12 @@ class TpuEngine(ChunkSubmit):
         # out-rank each other by depth but always replace earlier chunks'
         # entries (ops/tt.py store; wraps long before int32 overflow)
         self._tt_gen = (self._tt_gen + 1) & 0x3FFFFFFF
+        replay = _ChunkReplay(chunk.positions, chunk.variant)
         positions = []
         games = []  # per position: the replayed game prefix (oldest first)
-        for wp in chunk.positions:
-            pos = from_fen(wp.root_fen, chunk.variant)
-            prefix = []
-            for uci in wp.moves:
-                prefix.append(pos)
-                pos = pos.push(pos.parse_uci(uci))
-            positions.append(pos)
-            games.append(prefix)
+        for i in range(len(chunk.positions)):
+            positions.append(replay.position(i)[0])
+            games.append(replay.game(i))
 
         work = chunk.work
         if isinstance(work, MoveWork):
@@ -1652,10 +1774,11 @@ class LaneScheduler:
                        f":{wp0.position_index}")
             rec.nest("submit", nest_id, "b", "engine",
                      positions=len(chunk.positions))
+        replay = _ChunkReplay(chunk.positions, chunk.variant)
         with self._q_lock:
             self._submits_open[id(entry)] = t_sub
         try:
-            jobs = self._plan_jobs(entry, rec, nest_id, spent)
+            jobs = self._plan_jobs(entry, replay, rec, nest_id, spent)
             entry.n_open = len(jobs)
             if not jobs:
                 entry.event.set()
@@ -1673,6 +1796,9 @@ class LaneScheduler:
                 tot = self.engine.occupancy_totals
                 tot["chunks_submitted"] += 1
                 tot["positions_submitted"] += len(chunk.positions)
+                tot["submit_prefix_moves"] += sum(
+                    len(wp.moves) for wp in chunk.positions)
+                tot["submit_moves_parsed"] += replay.parsed
                 tot["submit_ms"] += (t_end - t_sub) * 1000.0
                 for name, ms in spent.items():
                     tot[f"submit_{name}_ms"] += ms
@@ -1680,8 +1806,8 @@ class LaneScheduler:
                 rec.nest("submit", nest_id, "e", "engine")
         return entry
 
-    def _plan_jobs(self, entry: _ChunkEntry, rec, nest_id: str,
-                   spent: dict) -> List[_RefillJob]:
+    def _plan_jobs(self, entry: _ChunkEntry, replay: _ChunkReplay, rec,
+                   nest_id: str, spent: dict) -> List[_RefillJob]:
         """The chunk's positions as refill jobs; terminal positions are
         answered here and now."""
         eng = self.engine
@@ -1695,28 +1821,30 @@ class LaneScheduler:
         per_pos_budget = budget if budget is not None else 10_000_000
         variant = DEVICE_VARIANTS.get(chunk.variant, "standard")
         deadline = chunk.deadline - 0.25  # slack to package results
-        jobs = []
-        for wp in chunk.positions:
+        live = []
+        for i, wp in enumerate(chunk.positions):
             with self._submit_step(rec, nest_id, "replay", spent):
-                pos = from_fen(wp.root_fen, chunk.variant)
-                game = []
-                for uci in wp.moves:
-                    game.append(pos)
-                    pos = pos.push(pos.parse_uci(uci))
-                over = pos.outcome() is not None
+                pos, legal = replay.position(i)
+                over = pos.outcome(legal) is not None
             if over:
                 self._deliver(
                     entry, wp, eng._terminal_response(chunk, wp, pos, 0.001)
                 )
-                continue
+            else:
+                live.append((i, pos))
+        history = []
+        if live:
             with self._submit_step(rec, nest_id, "history", spent):
-                hh, hm = TpuEngine._history_arrays([game], 1, variant)
+                history = replay.history([i for i, _ in live], variant)
+        jobs = []
+        for (i, pos), (hh, hm) in zip(live, history):
+            wp = chunk.positions[i]
             if eng.tt_warm is not None:
                 with self._submit_step(rec, nest_id, "ttwarm", spent):
                     self._tt_warm_plan(entry, wp, pos, variant)
             job = _RefillJob(
                 entry, wp, pos, position_fields(pos), variant, target_depth,
-                per_pos_budget, deadline, hh[0], hm[0],
+                per_pos_budget, deadline, hh, hm,
             )
             ctx = wp.ctx
             if ctx and ctx.get("trace_id"):
