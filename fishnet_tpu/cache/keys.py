@@ -142,7 +142,8 @@ def engine_identity(engine, flavor: EngineFlavor = EngineFlavor.TPU) -> str:
     """The net/settings fingerprint folded into every key.
 
     Captures what changes answers without re-keying per request: the
-    net weights identity, the engine's depth cap (resolves default-
+    net weights identity (both nets of a two-net deployment), the
+    engine's depth cap (resolves default-
     depth requests), the engine class, the eval flavor, and the
     search-visible settings (the same registry slice that keys AOT
     bundles — aot/keys.py AOT_KEY_SETTINGS)."""
@@ -162,5 +163,10 @@ def engine_identity(engine, flavor: EngineFlavor = EngineFlavor.TPU) -> str:
             name: settings.raw(name) or "" for name in AOT_KEY_SETTINGS
         },
     }
+    # a deployment's second net (weights_small_path) chooses answers too;
+    # an engine of one net keeps the fingerprint it had
+    small = getattr(engine, "weights_small_path", None)
+    if small:
+        ident["net_small"] = small
     blob = json.dumps(ident, sort_keys=True).encode("utf-8")
     return hashlib.sha256(blob).hexdigest()[:16]

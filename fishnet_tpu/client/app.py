@@ -85,6 +85,7 @@ def make_engine_factory(cfg: Config, logger: Logger, stats=None):
                         name,
                         backend=backend,
                         weights_path=cfg.tpu_weights,
+                        weights_small_path=cfg.tpu_weights_small,
                         max_depth=cfg.tpu_depth,
                         helper_lanes=cfg.tpu_helpers,
                         refill=cfg.tpu_refill,
@@ -118,6 +119,7 @@ def make_engine_factory(cfg: Config, logger: Logger, stats=None):
                     tpu_engine = SupervisedEngine(
                         backend="tpu",
                         weights_path=cfg.tpu_weights,
+                        weights_small_path=cfg.tpu_weights_small,
                         max_depth=cfg.tpu_depth,
                         helper_lanes=cfg.tpu_helpers,
                         refill=cfg.tpu_refill,
@@ -133,6 +135,7 @@ def make_engine_factory(cfg: Config, logger: Logger, stats=None):
 
                     tpu_engine = TpuEngine(
                         weights_path=cfg.tpu_weights,
+                        weights_small_path=cfg.tpu_weights_small,
                         max_depth=cfg.tpu_depth,
                         helper_lanes=cfg.tpu_helpers,
                         refill=cfg.tpu_refill,

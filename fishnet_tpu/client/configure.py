@@ -79,6 +79,9 @@ class Config:
     engine_path: Optional[str] = None  # external Stockfish (Official flavor)
     variant_engine_path: Optional[str] = None  # external Fairy-Stockfish
     tpu_weights: Optional[str] = None
+    # the small net of a Stockfish deployment beside a .nnue tpu_weights
+    # (upstream's EvalFileSmall); every eval chooses between the two
+    tpu_weights_small: Optional[str] = None
     # analysis depth cap. Deepening is ALSO governed per position by the
     # server node budget and the chunk deadline (engine/tpu.py stops
     # iterating when either runs out), so this cap only binds when budget
@@ -192,6 +195,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--variant-engine-path", help="external Fairy-Stockfish binary")
     p.add_argument("--tpu-weights",
                    help="NNUE weights: our .npz or a Stockfish .nnue file")
+    p.add_argument("--tpu-weights-small",
+                   help="the small Stockfish .nnue net beside a .nnue "
+                        "--tpu-weights: both resident, chosen per eval by "
+                        "Stockfish's rule")
     p.add_argument("--tpu-depth", type=int, help="max search depth for the TPU engine")
     p.add_argument("--tpu-helpers", type=int,
                    help="Lazy-SMP helper lanes per position (1 disables)")
@@ -323,6 +330,7 @@ def merge(args: argparse.Namespace, ini: dict) -> Config:
     cfg.engine_path = pick(args.engine_path, "engine_path")
     cfg.variant_engine_path = pick(args.variant_engine_path, "variant_engine_path")
     cfg.tpu_weights = pick(args.tpu_weights, "tpu_weights")
+    cfg.tpu_weights_small = pick(args.tpu_weights_small, "tpu_weights_small")
     cfg.tpu_depth = int(pick(args.tpu_depth, "tpu_depth", Config.tpu_depth))
     cfg.tpu_helpers = int(pick(args.tpu_helpers, "tpu_helpers", Config.tpu_helpers))
     refill_ini = str(ini.get("tpu_refill", "")).strip().lower()

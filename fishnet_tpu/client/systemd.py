@@ -31,6 +31,8 @@ def exec_start(cfg: Config) -> str:
         parts += ["--variant-engine-path", cfg.variant_engine_path]
     if cfg.tpu_weights:
         parts += ["--tpu-weights", cfg.tpu_weights]
+    if cfg.tpu_weights_small:
+        parts += ["--tpu-weights-small", cfg.tpu_weights_small]
     if cfg.user_backlog is not None:
         parts += ["--user-backlog", f"{int(cfg.user_backlog)}s"]
     if cfg.system_backlog is not None:

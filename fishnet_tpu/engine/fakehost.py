@@ -346,6 +346,7 @@ def main(argv=None) -> int:
     # a supervisor-built host_cmd works verbatim; echoed, not interpreted
     p.add_argument("--backend", default=None)
     p.add_argument("--weights", default=None)
+    p.add_argument("--weights-small", default=None)
     p.add_argument("--depth", type=int, default=None)
     p.add_argument("--helpers", type=int, default=None)
     p.add_argument("--refill", type=int, default=None)

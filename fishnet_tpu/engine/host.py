@@ -63,6 +63,7 @@ def _build_engine(args, log):
 
     engine = TpuEngine(
         weights_path=args.weights or None,
+        weights_small_path=args.weights_small or None,
         max_depth=args.depth or 12,
         helper_lanes=args.helpers,
         refill=None if args.refill is None else bool(args.refill),
@@ -93,6 +94,9 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="fishnet-tpu-engine-host")
     p.add_argument("--backend", choices=["tpu", "py"], default="tpu")
     p.add_argument("--weights", default=None)
+    # a Stockfish deployment's small net beside a .nnue --weights
+    # (upstream's EvalFileSmall): both resident, chosen per eval
+    p.add_argument("--weights-small", default=None)
     p.add_argument("--depth", type=int, default=None)
     # Lazy-SMP lanes per analysed position (engine/tpu.py helper_lanes);
     # None defers to FISHNET_TPU_HELPERS / the engine default, 1 disables

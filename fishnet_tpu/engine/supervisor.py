@@ -83,6 +83,7 @@ def default_host_cmd(
     backend: str = "tpu",
     weights: Optional[str] = None,
     depth: Optional[int] = None,
+    weights_small: Optional[str] = None,
     hb_interval: float = 1.0,
     helpers: Optional[int] = None,
     refill: Optional[bool] = None,
@@ -95,6 +96,8 @@ def default_host_cmd(
     ]
     if weights:
         cmd += ["--weights", str(weights)]
+    if weights_small:
+        cmd += ["--weights-small", str(weights_small)]
     if depth is not None:
         cmd += ["--depth", str(depth)]
     if helpers is not None:
@@ -165,6 +168,7 @@ class SupervisedEngine(ChunkSubmit):
         *,
         backend: str = "tpu",
         weights_path: Optional[str] = None,
+        weights_small_path: Optional[str] = None,
         max_depth: Optional[int] = None,
         helper_lanes: Optional[int] = None,
         refill: Optional[bool] = None,
@@ -203,8 +207,13 @@ class SupervisedEngine(ChunkSubmit):
         # though heartbeats flow — the device-hang signature caught
         # before the deadline, leaving the ladder time to bisect
         self.progress_timeout = progress_timeout
+        # what the child's nets are read from (cache/keys.py
+        # engine_identity keys on both)
+        self.weights_path = weights_path
+        self.weights_small_path = weights_small_path
         self.host_cmd = host_cmd or default_host_cmd(
             backend=backend, weights=weights_path, depth=max_depth,
+            weights_small=weights_small_path,
             hb_interval=hb_interval, helpers=helper_lanes, refill=refill,
             mesh_refill=mesh_refill, partials=self.replay,
         )

@@ -298,6 +298,10 @@ class TpuEngine(ChunkSubmit):
         self,
         params: Optional[nnue.NnueParams] = None,
         weights_path: Optional[str] = None,
+        # a Stockfish deployment's second net (upstream's EvalFileSmall):
+        # with a .nnue weights_path, both are resident and every eval
+        # chooses between them (models/nnue_import.py DualNet)
+        weights_small_path: Optional[str] = None,
         max_depth: int = 12,  # production value flows from configure.tpu_depth
         seed: int = 1234,
         tt_size_log2: int = 21,  # 2M slots ≈ 24 MiB HBM; 0 disables
@@ -346,12 +350,25 @@ class TpuEngine(ChunkSubmit):
         else:
             self.tt = tt_mod.make_table(tt_size_log2)
         self._lock = threading.Lock()
+        # what the nets were read from: the analysis cache keys on both
+        # (cache/keys.py engine_identity)
+        self.weights_path = weights_path
+        self.weights_small_path = weights_small_path
+        if weights_small_path and not (
+                params is None and str(weights_path or "").endswith(".nnue")):
+            raise ValueError(
+                "a small net (weights_small_path) goes beside a big .nnue "
+                "net read from weights_path")
         if params is None:
             if weights_path and str(weights_path).endswith(".nnue"):
                 # real Stockfish network file (models/nnue_import.py)
                 from ..models import nnue_import
 
-                params = nnue_import.load_nnue(weights_path).as_device()
+                params = nnue_import.load_nnue(weights_path)
+                if weights_small_path:
+                    params = nnue_import.DualNet.from_nets(
+                        params, nnue_import.load_nnue(weights_small_path))
+                params = params.as_device()
             elif weights_path:
                 params = nnue.load_params(weights_path)
             else:
@@ -460,8 +477,10 @@ class TpuEngine(ChunkSubmit):
             # bookkeeping, plus the host-device transfer count
             "host_ms": 0.0, "device_ms": 0.0, "transfers": 0,
             # counted on the device, in the segment's loop carry, and
-            # read from the boundary summary's last row: node expansions,
-            # the moves their lists hold, the drops among those
+            # read from the boundary summary's last rows: node expansions,
+            # the moves their lists hold, the drops among those; the
+            # accumulator's work; which of two nets answered (search_ops
+            # SEGMENT_COUNTERS)
             **{name: 0 for name in search_ops.SEGMENT_COUNTERS},
             # the same boundary intervals by what the host was doing
             # (SyncStats.phase): sums to host_ms + device_ms, and
@@ -2769,14 +2788,15 @@ class _Session:
         return lanes, max(shard_steps), shard_steps
 
     def count_device(self, raw) -> None:
-        """The summary's last two rows hold what the segment's loop
-        counted, movegen and accumulator work (summed over shards):
-        into the interval's snapshot."""
+        """The summary's last rows hold what the segment's loop counted,
+        movegen, accumulator and eval work (summed over shards): into the
+        interval's snapshot."""
         B, local = self.B, self.local
-        self.stats.count(search_ops.movegen_counts(
-            raw[B] if self.mesh is None else raw[:, local]))
-        self.stats.count(search_ops.acc_counts(
-            raw[B + 1] if self.mesh is None else raw[:, local + 1]))
+        for row, read in enumerate((search_ops.movegen_counts,
+                                    search_ops.acc_counts,
+                                    search_ops.eval_counts)):
+            self.stats.count(read(
+                raw[B + row] if self.mesh is None else raw[:, local + row]))
 
     def shard_occup(self):
         """Busy (primary or helper) lane count per shard, or None

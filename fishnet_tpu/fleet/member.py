@@ -156,6 +156,7 @@ def make_local_member(
     host_cmd: Optional[List[str]] = None,
     backend: str = "tpu",
     weights_path: Optional[str] = None,
+    weights_small_path: Optional[str] = None,
     max_depth: Optional[int] = None,
     helper_lanes: Optional[int] = None,
     refill: Optional[bool] = None,
@@ -188,6 +189,7 @@ def make_local_member(
         host_cmd,
         backend=backend,
         weights_path=weights_path,
+        weights_small_path=weights_small_path,
         max_depth=max_depth,
         helper_lanes=helper_lanes,
         refill=refill,

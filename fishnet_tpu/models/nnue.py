@@ -277,7 +277,9 @@ def acc_scheme(params, variant: str = "standard"):
     stack for these params, or None where every step refreshes from the
     board: "board768" (every move a ≤4-row delta, apply_acc_updates_768),
     "halfka" (an imported StockfishNet: a ≤4-row delta a perspective, and
-    a refresh of the perspective whose king moved — nnue_import.acc_*).
+    a refresh of the perspective whose king moved — nnue_import.acc_*),
+    "dual" (a nnue_import.DualNet: halfka's scheme on rows that hold both
+    nets, and Stockfish's choice between them at every eval).
     None for our own king-relative NnueParams, and for atomic, whose
     explosions exceed the 4 slots of board.move_piece_changes."""
     if variant == "atomic":
@@ -285,8 +287,15 @@ def acc_scheme(params, variant: str = "standard"):
     if is_board768(params):
         return "board768"
     if not isinstance(params, NnueParams):
-        return "halfka"
+        from .nnue_import import DualNet
+
+        return "dual" if isinstance(params, DualNet) else "halfka"
     return None
+
+
+# the schemes whose stack holds, a ply, two rows of a king-relative
+# net's L1 values and PSQT sums (nnue_import.acc_*)
+ROW_SCHEMES = ("halfka", "dual")
 
 
 # ------------------------------------------------------------------- forward

@@ -51,6 +51,16 @@ PR 35; 64 lanes, L1 3,072, µs a step): compacted refresh 369, a fixed
 cell's rate of 4.6-4.8 against 8.6-9.1 positions/s; 4.5-4.9 % of pushed
 perspectives are rebuilt. Atomic alone stays on the full refresh.
 
+THE PAIR. Stockfish evaluates with both embedded nets (src/evaluate.cpp,
+Eval::use_smallnet and Eval::evaluate): where the material imbalance is
+large the small net answers, and where its value then comes out small
+the big net evaluates the position again and its value stands. `DualNet`
+holds both for the search: one feature table whose rows are the big
+net's L1 and PSQT columns followed by the small net's, so the stack row,
+the ≤ 4-row update and the refresh serve both nets with one op each
+(nnue.acc_scheme == "dual"), and `forward_dual_from_acc` applies the
+rule lane by lane. `evaluate_dual_reference` is the plain numpy rule.
+
 Anything that doesn't match this layout (different sizes, unknown
 section lengths) raises UnsupportedNnueFormat rather than misparsing.
 There are no real `.nnue` files in this build environment, so the parser
@@ -134,6 +144,94 @@ class StockfishNet:
         return dataclasses.replace(
             self, **{f: jnp.asarray(getattr(self, f)) for f in _ARRAY_FIELDS}
         )
+
+
+_STACK_FIELDS = _ARRAY_FIELDS[3:]  # fc0_w .. fc2_b
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=list(_STACK_FIELDS), meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class NetStacks:
+    """One net's eight layer stacks (the fields of StockfishNet behind its
+    feature transformer), float32."""
+
+    fc0_w: np.ndarray  # (8, 16, L1)
+    fc0_b: np.ndarray
+    fc1_w: np.ndarray
+    fc1_b: np.ndarray
+    fc2_w: np.ndarray
+    fc2_b: np.ndarray
+
+    @property
+    def l1(self) -> int:
+        return self.fc0_w.shape[-1]
+
+
+# a DualNet's row width is a multiple of this: a TPU v5e lays a table of
+# such rows out row by row, where at 3,216 its default layout for the
+# parameter is column-major and every program that takes the table copied
+# all 290 MB into rows first (0.9 ms a segment; PERF.md §6)
+ROW_ALIGN = 128
+
+# Stockfish's choice between its two nets (src/evaluate.cpp, src/types.h)
+PAWN_VALUE = 208
+PIECE_VALUES = (PAWN_VALUE, 781, 825, 1276, 2538, 0)  # P N B R Q K
+SMALLNET_MATERIAL = 962  # |simple_eval| above it: the small net answers
+SMALLNET_REEVAL = 236  # |small net's value| below it: the big net again
+# ... by piece code (0 empty, 1-6 white P..K, 7-12 black): white's side
+_MATERIAL_BY_CODE = np.array(
+    (0,) + PIECE_VALUES + tuple(-v for v in PIECE_VALUES), np.int32)
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["ft", "ft_b", "big", "small"], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class DualNet:
+    """Both nets of a Stockfish deployment, resident together.
+
+    ft (NUM_FEATURES, W) is every feature's row as the search stack holds
+    it: the big net's L1 weights and PSQT columns, then the small net's,
+    then zeros up to a multiple of ROW_ALIGN — W = 3,328 for L1 3,072 and
+    128, where the two nets take 3,216; ft_b (W,) the biases in the same
+    layout, 0 under the PSQT columns and the padding. big / small: each
+    net's stacks."""
+
+    ft: np.ndarray
+    ft_b: np.ndarray
+    big: NetStacks
+    small: NetStacks
+
+    @classmethod
+    def from_nets(cls, big: StockfishNet, small: StockfishNet) -> "DualNet":
+        """Host arrays of two nets (as load_nnue returns them) joined."""
+        big, small = (jax.tree.map(np.asarray, n) for n in (big, small))
+        zeros = np.zeros(NUM_PSQT_BUCKETS, np.float32)
+        used = big.ft_w.shape[1] + small.ft_w.shape[1] + 2 * NUM_PSQT_BUCKETS
+        pad = -used % ROW_ALIGN
+        return cls(
+            ft=np.concatenate(
+                [big.ft_w, big.psqt_w, small.ft_w, small.psqt_w,
+                 np.zeros((NUM_FEATURES, pad), np.float32)], axis=1),
+            ft_b=np.concatenate(
+                [big.ft_b, zeros, small.ft_b, zeros, np.zeros(pad, np.float32)]),
+            big=NetStacks(**{f: getattr(big, f) for f in _STACK_FIELDS}),
+            small=NetStacks(**{f: getattr(small, f) for f in _STACK_FIELDS}),
+        )
+
+    @property
+    def big_width(self) -> int:
+        """Where the small net's columns start in a row."""
+        return self.big.l1 + NUM_PSQT_BUCKETS
+
+    @property
+    def small_width(self) -> int:
+        return self.small.l1 + NUM_PSQT_BUCKETS
+
+    def as_device(self) -> "DualNet":
+        import jax.numpy as jnp
+
+        return jax.tree.map(jnp.asarray, self)
 
 
 # ------------------------------------------------------------------ LEB128
@@ -319,22 +417,36 @@ def refresh_rows(variant: str = "standard") -> int:
     return REFRESH_ROWS_HORDE if variant == "horde" else REFRESH_ROWS
 
 
-def _rows(net: StockfishNet, idx):
+def _rows(net, idx):
     """[ft_w | psqt_w][idx] → (..., L1 + 8) float32: a gather of whole
-    rows."""
+    rows (a DualNet's table holds them so, both nets' side by side)."""
     import jax.numpy as jnp
 
+    if isinstance(net, DualNet):
+        return jnp.asarray(net.ft)[idx].astype(jnp.float32)
     return jnp.concatenate(
         [jnp.asarray(net.ft_w)[idx], jnp.asarray(net.psqt_w)[idx]], axis=-1
     ).astype(jnp.float32)
 
 
-def acc_refresh_row(net: StockfishNet, board64, perspective,
-                    n_rows: int = REFRESH_ROWS):
-    """(L1 + 8,) accumulator row of one perspective from the board: the
-    bias plus the rows of its ≤ n_rows pieces, their squares compacted
-    into n_rows slots first so that no row is gathered for an empty
-    square."""
+def _bias_row(net):
+    """The row of a perspective with no piece: the biases, 0 under the
+    PSQT columns."""
+    import jax.numpy as jnp
+
+    if isinstance(net, DualNet):
+        return jnp.asarray(net.ft_b).astype(jnp.float32)
+    return jnp.concatenate(
+        [jnp.asarray(net.ft_b).astype(jnp.float32),
+         jnp.zeros((NUM_PSQT_BUCKETS,), jnp.float32)]
+    )
+
+
+def acc_refresh_row(net, board64, perspective, n_rows: int = REFRESH_ROWS):
+    """(L1 + 8,) accumulator row of one perspective from the board (a
+    DualNet's: both nets' row, DualNet.ft's width): the bias plus the
+    rows of its ≤ n_rows pieces, their squares compacted into n_rows
+    slots first so that no row is gathered for an empty square."""
     import jax.numpy as jnp
 
     from ..ops.board import king_square
@@ -351,16 +463,11 @@ def acc_refresh_row(net: StockfishNet, board64, perspective,
         )  # (n_rows, 64), one square a used slot
         idx = jnp.sum(jnp.where(hit, idx64[None, :], 0), axis=1)
         used = jnp.any(hit, axis=1)
-    bias = jnp.concatenate(
-        [jnp.asarray(net.ft_b).astype(jnp.float32),
-         jnp.zeros((NUM_PSQT_BUCKETS,), jnp.float32)]
-    )
     # a slot that is not used adds its row times 0
-    return bias + jnp.sum(_rows(net, idx) * used[:, None], axis=0)
+    return _bias_row(net) + jnp.sum(_rows(net, idx) * used[:, None], axis=0)
 
 
-def acc_refresh_pair(net: StockfishNet, board64,
-                     n_rows: int = REFRESH_ROWS):
+def acc_refresh_pair(net, board64, n_rows: int = REFRESH_ROWS):
     """(2, L1 + 8): white's and black's rows from the board."""
     import jax
     import jax.numpy as jnp
@@ -370,7 +477,7 @@ def acc_refresh_pair(net: StockfishNet, board64,
     )
 
 
-def acc_update_pair(net: StockfishNet, pair, board64, codes, sqs, signs):
+def acc_update_pair(net, pair, board64, codes, sqs, signs):
     """The child's (2, L1 + 8) pair from the parent's by the move's piece
     changes (board.move_piece_changes: (K,) codes, squares, signs; code 0
     is an unused slot), and which perspectives that is not enough for:
@@ -415,8 +522,9 @@ def _pick_stack(values, bucket):
     return jnp.sum(jnp.where(hot[:, None], v, 0), axis=0)
 
 
-def forward_sf_from_acc(net: StockfishNet, pair, stm, bucket):
-    """Centipawns from the side to move's view, from a (2, L1 + 8) pair.
+def forward_sf_from_acc(net, pair, stm, bucket):
+    """Centipawns from the side to move's view, from a (2, L1 + 8) pair;
+    net: a StockfishNet, or one net's NetStacks.
 
     Each layer is computed for all eight stacks as ONE product — fc0 as
     (128, L1) · (L1,) — and the stack's outputs picked afterwards: under
@@ -428,7 +536,7 @@ def forward_sf_from_acc(net: StockfishNet, pair, stm, bucket):
 
     f32 = jnp.float32
     hi = jax.lax.Precision.HIGHEST
-    l1 = net.ft_w.shape[1]
+    l1 = net.fc0_w.shape[-1]
     half = l1 // 2
     own = jnp.where(stm == 0, pair[0], pair[1])
     opp = jnp.where(stm == 0, pair[1], pair[0])
@@ -460,14 +568,54 @@ def forward_sf_from_acc(net: StockfishNet, pair, stm, bucket):
     return (out + skip + ps) * NNUE2SCORE
 
 
-def evaluate_sf(net: StockfishNet, board64, stm):
+def material_imbalance(board64):
+    """Stockfish's simple_eval from white's side: 208 a pawn and the
+    non-pawn material (N 781, B 825, R 1276, Q 2538), white's less
+    black's. The rule reads its magnitude, the same from either side."""
+    import jax.numpy as jnp
+
+    # a compare against the twelve codes, not a gather from the table: on
+    # a TPU v5e a vmapped 13-entry gather cost 39 µs a 64-lane step
+    codes = jnp.arange(1, _MATERIAL_BY_CODE.shape[0], dtype=jnp.int32)
+    return jnp.sum(jnp.where(board64[:, None] == codes[None, :],
+                             jnp.asarray(_MATERIAL_BY_CODE[1:]), 0))
+
+
+def forward_dual_from_acc(net: DualNet, pair, stm, bucket, board64):
+    """The pair's value by Stockfish's rule, from a (2, W) pair of both
+    nets' rows: → (centipawns, int32, from the side to move's view;
+    (3,) int32 one-hot: the small net's value kept, the small net chosen
+    and handed back to the big net, the big net chosen).
+
+    Each net's value is forward_sf_from_acc's, truncated to an integer as
+    Stockfish's Value is before it is compared. Both nets run for every
+    lane: under lockstep a lane's branch is a select, and the small net's
+    layers cost a few percent of the big one's."""
+    import jax
+    import jax.numpy as jnp
+
+    wb, ws = net.big_width, net.small_width
+    v_big = jnp.int32(forward_sf_from_acc(net.big, pair[:, :wb], stm, bucket))
+    with jax.named_scope("step.eval_small"):
+        v_small = jnp.int32(
+            forward_sf_from_acc(net.small, pair[:, wb:wb + ws], stm, bucket))
+        small = jnp.abs(material_imbalance(board64)) > SMALLNET_MATERIAL
+        reeval = small & (jnp.abs(v_small) < SMALLNET_REEVAL)
+        kept = small & ~reeval
+    return (jnp.where(kept, v_small, v_big),
+            jnp.stack([kept, reeval, ~small]).astype(jnp.int32))
+
+
+def evaluate_sf(net, board64, stm):
     """Centipawn-ish score for one position, SFNNv5 semantics, in jax:
     both rows from the board, then forward_sf_from_acc — what the search
-    computes at a node whose accumulator came down its stack."""
-    return forward_sf_from_acc(
-        net, acc_refresh_pair(net, board64, 64), stm,
-        nnue.output_bucket(board64),
-    )
+    computes at a node whose accumulator came down its stack (a DualNet:
+    both nets' rows, then the rule of forward_dual_from_acc)."""
+    pair = acc_refresh_pair(net, board64, 64)
+    bucket = nnue.output_bucket(board64)
+    if isinstance(net, DualNet):
+        return forward_dual_from_acc(net, pair, stm, bucket, board64)[0]
+    return forward_sf_from_acc(net, pair, stm, bucket)
 
 
 def evaluate_sf_reference(net: StockfishNet, board64: np.ndarray, stm: int) -> float:
@@ -513,6 +661,21 @@ def evaluate_sf_reference(net: StockfishNet, board64: np.ndarray, stm: int) -> f
     out = float((net.fc2_w[ob] @ h1 + net.fc2_b[ob])[0])
     psqt = (psqts[own][ob] - psqts[opp][ob]) / 2.0
     return (out + skip + psqt) * NNUE2SCORE
+
+
+def evaluate_dual_reference(big: StockfishNet, small: StockfishNet,
+                            board64: np.ndarray, stm: int) -> int:
+    """Pure-numpy mirror of the two-net rule (forward_dual_from_acc):
+    each net's value truncated to an integer, the small net's where the
+    material imbalance is large and its value is not small, else the big
+    net's."""
+    board64 = np.asarray(board64)
+    material = int(_MATERIAL_BY_CODE[board64].sum())
+    if abs(material) > SMALLNET_MATERIAL:
+        v_small = int(evaluate_sf_reference(small, board64, stm))
+        if abs(v_small) >= SMALLNET_REEVAL:
+            return v_small
+    return int(evaluate_sf_reference(big, board64, stm))
 
 
 # ---------------------------------------------------- synthetic writer (tests)

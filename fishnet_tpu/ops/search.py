@@ -80,23 +80,27 @@ MODE_RETURN = 1
 MODE_TRYMOVE = 2
 MODE_DONE = 3
 
-# packed boundary summary (int32, shape (B+2, 4)): everything the host
+# packed boundary summary (int32, shape (B+3, 4)): everything the host
 # needs to decide a segment boundary — done bitmap plus per-lane
 # nodes/score/best-move — in ONE small transfer instead of the full
 # extract_results set; row B holds the segment's step count and, in
 # columns SUM_COUNTS, its movegen counters (node expansions, the moves
 # their lists hold, the drops among those), row B+1 in the same columns
 # its accumulator counters (perspective updates made, perspectives
-# rebuilt from the board, feature rows gathered); `movegen_counts` and
-# `acc_counts` read them. PV rows are pulled separately, and only for
-# lanes that actually finished.
+# rebuilt from the board, feature rows gathered), row B+2 which net
+# answered the node expansions' evals (the "dual" scheme's: the small
+# net alone, the small net handed back to the big one, the big net
+# chosen; 0 under every other scheme); `movegen_counts`, `acc_counts`
+# and `eval_counts` read them. PV rows are pulled separately, and only
+# for lanes that actually finished.
 SUM_DONE, SUM_NODES, SUM_SCORE, SUM_MOVE = range(4)
 SUM_W = 4
-SUM_TAIL = 2  # the rows behind the lanes'
+SUM_TAIL = 3  # the rows behind the lanes'
 SUM_COUNTS = slice(1, 4)
 MOVEGEN_COUNTERS = ("movegen_nodes", "movegen_moves", "movegen_drops")
 ACC_COUNTERS = ("acc_updates", "acc_refreshes", "acc_rows")
-SEGMENT_COUNTERS = MOVEGEN_COUNTERS + ACC_COUNTERS
+EVAL_COUNTERS = ("eval_small", "eval_reeval", "eval_big")
+SEGMENT_COUNTERS = MOVEGEN_COUNTERS + ACC_COUNTERS + EVAL_COUNTERS
 
 
 def _tail_counts(rows: np.ndarray, names) -> dict:
@@ -117,6 +121,11 @@ def movegen_counts(last_rows: np.ndarray) -> dict:
 def acc_counts(last_rows: np.ndarray) -> dict:
     """The accumulator counters of one segment from the summary's row B+1."""
     return _tail_counts(last_rows, ACC_COUNTERS)
+
+
+def eval_counts(last_rows: np.ndarray) -> dict:
+    """The eval counters of one segment from the summary's row B+2."""
+    return _tail_counts(last_rows, EVAL_COUNTERS)
 
 # game-history repetition seeding: hashes of up to MAX_HIST reversible
 # game positions before each lane's root (the reference feeds Stockfish
@@ -262,7 +271,8 @@ class SearchState(NamedTuple):
     pv: jnp.ndarray  # (B, P, P) int32
     # incremental NNUE accumulators, by nnue.acc_scheme: (B, P+1, 2, L1),
     # or for "halfka" (B, 2·(P+2), L1+8) — row 2·ply + perspective, the 8
-    # PSQT sums behind the L1 values (nnue_import.acc_*), a spare pair last
+    # PSQT sums behind the L1 values (nnue_import.acc_*), a spare pair last;
+    # for "dual" the same with both nets' values and sums a row
     acc: jnp.ndarray
 
 
@@ -290,13 +300,12 @@ def init_state(params: nnue.NnueParams, roots: Board, depth: jnp.ndarray,
     the search itself)."""
     B = roots.stm.shape[0]
     P = max_ply
-    l1 = params.ft_w.shape[1]
     scheme = nnue.acc_scheme(params, variant)
     # acc stays f32 even under bf16-quantized weights (nnue.cast_params):
     # incremental adds accumulate rounding error down the stack otherwise.
     # int8-quantized nets use int32 accumulators — integer adds are exact.
     adt = nnue.acc_dtype(params)
-    if scheme == "halfka":
+    if scheme in nnue.ROW_SCHEMES:
         # the root's two rows, rebuilt from its board (a refill comes
         # through here too: _splice_lanes)
         root_acc = jax.vmap(
@@ -309,12 +318,16 @@ def init_state(params: nnue.NnueParams, roots: Board, depth: jnp.ndarray,
         acc = jnp.zeros((B, 2 * (P + 2), root_acc.shape[-1]), adt)
         acc = acc.at[:, :2].set(root_acc)
     else:
+        # (a full-refresh program carries a stack it never reads)
+        table = (params.ft if isinstance(params, nnue_import.DualNet)
+                 else params.ft_w)
+        l1 = table.shape[1]
         if scheme == "board768":
             root_acc = jax.vmap(nnue.accumulators_768, in_axes=(None, 0))(
                 params, roots.board
             )
         else:
-            root_acc = jnp.zeros((B, 2, l1), params.ft_w.dtype)
+            root_acc = jnp.zeros((B, 2, l1), table.dtype)
         acc = jnp.zeros((B, P + 1, 2, l1), adt)
         acc = acc.at[:, 0].set(root_acc.astype(adt))
 
@@ -495,6 +508,8 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
         # incremental update scheme (move_piece_changes). The oracle
         # (ops/oracle.py) mirrors board768's scheme and refreshes otherwise.
         scheme = nnue.acc_scheme(params, variant)
+        # "dual": which net answered, counted below for node expansions
+        eval_pick = jnp.zeros((3,), jnp.int32)
         if scheme == "board768":
             leaf_val = jnp.int32(
                 nnue.forward_from_acc(params, s.acc[ply0], us, nnue.output_bucket(b.board))
@@ -504,6 +519,11 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
                 params, jax.lax.dynamic_slice_in_dim(s.acc, 2 * ply0, 2),
                 us, nnue.output_bucket(b.board),
             ))
+        elif scheme == "dual":
+            leaf_val, eval_pick = nnue_import.forward_dual_from_acc(
+                params, jax.lax.dynamic_slice_in_dim(s.acc, 2 * ply0, 2),
+                us, nnue.output_bucket(b.board), b.board,
+            )
         else:
             leaf_val = jnp.int32(nnue.evaluate(params, b.board, us))
         leaf_val = jnp.clip(leaf_val, -MATE + 1000, MATE - 1000)
@@ -582,6 +602,8 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
             gen_drops = jnp.int32(0)
         movegen = expand.astype(jnp.int32) * jnp.stack(
             [jnp.int32(1), gen_count, gen_drops])
+        # (EVAL_COUNTERS) and which net's eval the expanded node took
+        eval_counts = expand.astype(jnp.int32) * eval_pick
         # mark fresh static-eval leaves for the runner's depth-0 TT store.
         # Quiet positions only: a quiet static eval IS the node's QS value,
         # while a noisy leaf (budget/stack cutoff) stored as depth-0 EXACT
@@ -893,7 +915,7 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
             child_acc = nnue.apply_acc_updates_768(params, s.acc[ply1], codes, sqs, signs)
             acc_new = _row_set(s.acc, nply, child_acc, advance)
             acc_counts = advance.astype(jnp.int32) * jnp.array([2, 0, 0], jnp.int32)
-        elif scheme == "halfka":
+        elif scheme in nnue.ROW_SCHEMES:
             child_acc, stale = nnue_import.acc_update_pair(
                 params, jax.lax.dynamic_slice_in_dim(s.acc, 2 * ply1, 2),
                 parent_b.board, codes, sqs, signs,
@@ -944,15 +966,16 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
         hist_hash=s.hist_hash, hist_halfmove=s.hist_halfmove,
         moves=moves_new, hist=hist_new, pv=pv_new, acc=acc_new,
     ), StepAux(
-        counts=jnp.stack([movegen, acc_counts]),
+        counts=jnp.stack([movegen, acc_counts, eval_counts]),
         stale=None if stale is None else (stale, 2 * nply, child.board),
     )
 
 
 class StepAux(NamedTuple):
     """What a lane's step hands _run_segment beside its next state."""
-    counts: jnp.ndarray  # (SUM_TAIL, 3): MOVEGEN_COUNTERS, ACC_COUNTERS
-    # "halfka" only, else None: ((2,) bool perspectives of the pushed
+    # (SUM_TAIL, 3): MOVEGEN_COUNTERS, ACC_COUNTERS, EVAL_COUNTERS
+    counts: jnp.ndarray
+    # nnue.ROW_SCHEMES only, else None: ((2,) bool perspectives of the pushed
     # child whose row a king's move made stale, the child's first acc
     # row, its (64,) board) — rebuilt across lanes by _refresh_stale
     stale: Optional[tuple]
@@ -1176,7 +1199,9 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
             lane[:, LN_RSCORE],
             lane[:, LN_RMOVE],
         ], axis=1),
-        jnp.concatenate([jnp.stack([n, jnp.int32(0)])[:, None], counts], axis=1),
+        jnp.concatenate([
+            jnp.zeros((SUM_TAIL, 1), jnp.int32).at[0, 0].set(n), counts,
+        ], axis=1),
     ], axis=0)
     return state, ttab, n, summary
 

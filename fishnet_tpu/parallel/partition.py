@@ -18,7 +18,7 @@ Layout, in one screen:
   * per-lane search state (SearchState: bt/nt/lane/hist_hash/
     hist_halfmove/moves/hist/pv/acc) — leading dim is the lane axis,
     sharded over ``dp``; trailing dims replicated.
-  * NNUE weights (NnueParams, StockfishNet) — replicated on every chip (`PARAM_RULES`),
+  * NNUE weights (NnueParams, StockfishNet, DualNet) — replicated on every chip (`PARAM_RULES`),
     or tensor-sharded over an optional ``tp`` axis for the
     feature-transform width (`PARAM_RULES_TP`, the training layout).
   * transposition table (TTable.data, (ndev, N, 4)) — leading shard dim
@@ -76,6 +76,9 @@ PARAM_RULES: Tuple[Rule, ...] = (
     # an imported net (models/nnue_import.StockfishNet): its feature
     # table is gathered by row, lane by lane, so every chip holds it whole
     (r"(^|/)(psqt_w|fc0_w|fc0_b|fc1_w|fc1_b|fc2_w|fc2_b)$", P()),
+    # both nets of a deployment (nnue_import.DualNet): one table of both
+    # nets' rows, gathered by row as the imported net's is
+    (r"(^|/)ft$", P()),
 )
 
 # NNUE weights, training layout: the gather-heavy feature transform
@@ -275,12 +278,23 @@ def imported_param_proto() -> Dict[str, str]:
     return {name: name for name in _ARRAY_FIELDS}
 
 
+def dual_param_proto() -> Dict[str, Any]:
+    """The array fields of both nets of a deployment
+    (nnue_import.DualNet), as a tree of field-name strings."""
+    from ..models.nnue_import import _STACK_FIELDS
+
+    stacks = {name: name for name in _STACK_FIELDS}
+    return {"ft": "ft", "ft_b": "ft_b", "big": dict(stacks),
+            "small": dict(stacks)}
+
+
 def search_proto() -> Dict[str, Any]:
     """Everything that crosses the mesh boundary, as one prototype tree —
     the default subject of validate_rules()."""
     return {
         "params": param_proto(),
         "params_imported": imported_param_proto(),
+        "params_dual": dual_param_proto(),
         "state": state_proto(),
         "tt": tt_proto(),
         "tt_gen": "tt_gen",
@@ -321,7 +335,7 @@ def search_param_spec() -> P:
     be a prefix of its argument's tree): every PARAM_RULES leaf says
     replicated, which is checked here, so the callables need no
     prototype of the type."""
-    for proto in (param_proto(), imported_param_proto()):
+    for proto in (param_proto(), imported_param_proto(), dual_param_proto()):
         leaves = jax.tree_util.tree_leaves(
             match_partition_rules(proto, PARAM_RULES),
             is_leaf=lambda x: isinstance(x, P))

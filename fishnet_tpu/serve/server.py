@@ -627,6 +627,9 @@ async def run_serve(cfg) -> int:
             # so the fingerprint tracks netswaps (cache/keys.py)
             if getattr(engine, "weights_path", None) is None:
                 engine.weights_path = cfg.tpu_weights
+            if getattr(engine, "weights_small_path", None) is None:
+                engine.weights_small_path = getattr(
+                    cfg, "tpu_weights_small", None)
             if getattr(engine, "max_depth", None) is None:
                 engine.max_depth = cfg.tpu_depth
         cache = cache_from_settings(

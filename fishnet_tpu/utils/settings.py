@@ -213,8 +213,9 @@ SETTINGS: Tuple[Setting, ...] = (
         name="FISHNET_TPU_DTYPE",
         kind="str",
         default="",
-        doc="Quantize NNUE weights, of either params type (board768 or "
-            "an imported StockfishNet; accumulators stay float32): "
+        doc="Quantize NNUE weights, of any params type (board768, an "
+            "imported StockfishNet or a two-net DualNet; accumulators stay "
+            "float32): "
             "\"bf16\" for MXU-native inputs; \"int8\" is experimental, "
             "board768 only and additionally gated.",
         engine=True,
@@ -773,7 +774,27 @@ def render_rows(rows: List[tuple]) -> str:
             f"| `{name}` | {kind} | {default_cell} | "
             f"{'yes' if engine else 'no'} | {doc} |"
         )
-    return "\n".join(lines) + "\n"
+    return "\n".join(lines + NET_SETTINGS_MD) + "\n"
+
+
+# the nets are chosen by client settings (fishnet.ini / the `run` flags,
+# client/configure.py), not by environment variables; listed here so that
+# one page names every knob a deployment sets
+NET_SETTINGS_MD = [
+    "",
+    "## Nets (client settings, not environment variables)",
+    "",
+    "| Setting | Flag | Description |",
+    "|---|---|---|",
+    "| `tpu_weights` | `--tpu-weights` (engine host: `--weights`) | NNUE "
+    "weights: this program's .npz, or a Stockfish .nnue file. |",
+    "| `tpu_weights_small` | `--tpu-weights-small` (engine host: "
+    "`--weights-small`) | The small Stockfish .nnue net beside a .nnue "
+    "`tpu_weights` (upstream's EvalFileSmall): both nets resident, every "
+    "eval chooses between them by Stockfish's material rule and hands an "
+    "unsure small value back to the big net (models/nnue_import.py "
+    "DualNet). |",
+]
 
 
 def render_config_md() -> str:

@@ -166,8 +166,8 @@ def test_host_refuses_an_unasked_for_cpu_backend(monkeypatch, perf_state):
     monkeypatch.setattr("fishnet_tpu.parallel.mesh.make_mesh", built)
     monkeypatch.setattr("fishnet_tpu.ops.tt.make_table", built)
     args = type("Args", (), dict(
-        backend="tpu", weights=None, depth=2, helpers=None, refill=None,
-        mesh_refill=None, skip_warmup=True))()
+        backend="tpu", weights=None, weights_small=None, depth=2,
+        helpers=None, refill=None, mesh_refill=None, skip_warmup=True))()
     with pytest.raises(NoAcceleratorError):
         engine_host._build_engine(args, lambda msg: None)
     # asked for, the same boot goes through to the (stubbed) build

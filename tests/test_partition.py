@@ -42,11 +42,14 @@ def test_validate_rules_counts_cover_the_whole_prototype():
     assert sum(counts.values()) == len(PT.iter_paths(proto))
     # the layout in one screen: 9 state fields, 1 TT shard array,
     # 8 NNUE tensors and the 9 of an imported net (its ft_w and ft_b under
-    # the first rule too), 5 boundary values
+    # the first rule too), the 14 of a two-net deployment (its joined
+    # table, its ft_b under the first rule, two nets' six stack tensors),
+    # 5 boundary values
     assert counts[PT.STATE_RULES[0][0]] == 9
     assert counts[PT.TT_RULES[0][0]] == 1
-    assert counts[PT.PARAM_RULES[0][0]] == 8 + 2
-    assert counts[PT.PARAM_RULES[1][0]] == 7
+    assert counts[PT.PARAM_RULES[0][0]] == 8 + 2 + 1
+    assert counts[PT.PARAM_RULES[1][0]] == 7 + 2 * 6
+    assert counts[PT.PARAM_RULES[2][0]] == 1
 
 
 def test_param_rules_tp_cover_params_exactly():

@@ -209,6 +209,43 @@ def test_wide_net_segment_compiles(one_chip, wide_net, lanes):
     assert len(sorts) == 1 and str(len(movegen._live_slots("standard"))) in sorts[0]
 
 
+@pytest.fixture(scope="module")
+def dual_net(wide_net):
+    """The shapes of the benchmark's `halfka_dual` pair: the 3,072-wide
+    net and the 128-wide one, their rows joined in one table (3,216
+    columns, padded to 3,328 = 26 x 128)."""
+    from fishnet_tpu.models import nnue_import as ni
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    def stacks(l1):
+        return ni.NetStacks(
+            fc0_w=f32(ni.NUM_STACKS, ni.FC0_OUT, l1), fc0_b=wide_net.fc0_b,
+            fc1_w=wide_net.fc1_w, fc1_b=wide_net.fc1_b,
+            fc2_w=wide_net.fc2_w, fc2_b=wide_net.fc2_b)
+
+    width = 3328
+    return ni.DualNet(ft=f32(ni.NUM_FEATURES, width), ft_b=f32(width),
+                      big=stacks(3072), small=stacks(128))
+
+
+@pytest.mark.parametrize("lanes", [64, MAX_LANES])
+def test_dual_net_segment_compiles(one_chip, dual_net, lanes):
+    """Both nets of the deployment on the incremental path: the stack's
+    rows hold both nets' L1 values and PSQT sums, one gather and one
+    scatter serve both, the rule picks per lane; and the table is taken
+    as it is laid out by default, row by row, with no copy of it."""
+    compiled = _compile_segment(dual_net, one_chip, lanes, "standard", False, True)
+    used = _fits(compiled)
+    assert used > lanes * 2 * (MAX_PLY + 2) * 3328 * 4 + 22528 * 3328 * 4
+    text = compiled.as_text()
+    assert f"f32[{lanes},{2 * (MAX_PLY + 2)},3328]" in text
+    assert not re.search(r"= f32\[22528,3328\]\S* copy\(", text)
+    sorts = re.findall(r"= s32\[([\d,]+)\]\S* sort\(", text)
+    assert len(sorts) == 1
+
+
 def test_wide_net_splice_compiles(one_chip, wide_net):
     """The refill's program for the wide net at 64 lanes: every admitted
     lane's root rebuilt from its board (32 rows a perspective)."""

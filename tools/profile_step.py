@@ -3,7 +3,8 @@
 Times run_segment per-step wall clock at a given shape, then captures a
 jax.profiler trace of a short segment and aggregates device time per op
 and per `jax.named_scope` of the step (ops/search.py: step.rules,
-step.eval, step.movegen, step.order, ...), so the hot spots are
+step.eval, step.movegen, step.order, ...; the small net's forward under
+--net halfka_dual is step.eval_small), so the hot spots are
 attributable (VERDICT r4 weak #6: perf claims need a committed artifact).
 With --trace the program is compiled here, past the persistent compile
 cache: an executable loaded from it carries the names of the tree that
@@ -12,6 +13,7 @@ built it.
 Usage:
   python tools/profile_step.py [B] [depth] [max_ply] [--trace] [--tt]
                                [--variant crazyhouse] [--net halfka3072]
+                               [--net halfka_dual]
 """
 from __future__ import annotations
 
@@ -40,7 +42,9 @@ def main() -> None:
     ap.add_argument("--net", default="board768",
                     help="board768 (64 wide), or halfka<L1> for the "
                          "king-relative net of that width with seeded "
-                         "weights (halfka3072 is the benchmark's)")
+                         "weights (halfka3072 is the benchmark's), or "
+                         "halfka_dual for the 3,072- and 128-wide pair "
+                         "Stockfish chooses between")
     opts = ap.parse_args()
     B, depth, variant = opts.B, opts.depth, opts.variant
     max_ply = opts.max_ply if opts.max_ply is not None else depth + 1
@@ -67,7 +71,12 @@ def main() -> None:
 
     roots = _roots_for(
         B, variant, "variant" if variant in FENS_VARIANT else "standard")
-    if opts.net.startswith("halfka"):
+    if opts.net == "halfka_dual":
+        from fishnet_tpu.models import nnue_import as ni
+
+        params = ni.DualNet.from_nets(
+            seeded_halfka(3072), seeded_halfka(128, seed=1)).as_device()
+    elif opts.net.startswith("halfka"):
         params = seeded_halfka(int(opts.net[len("halfka"):]))
     else:
         params = nnue.init_params(jax.random.PRNGKey(0), l1=64, feature_set="board768")
@@ -122,7 +131,7 @@ def main() -> None:
     report(trace_dir, scope_of_instruction(compiled.as_text()), steps)
 
 
-def seeded_halfka(l1: int):
+def seeded_halfka(l1: int, seed: int = 0):
     """A StockfishNet of width l1 drawn on the device, at scales that keep
     the accumulators around the clip's 0 and 1 (no file is read: times do
     not depend on the values)."""
@@ -141,7 +150,7 @@ def seeded_halfka(l1: int):
         "fc2_w": ((ni.NUM_STACKS, 1, ni.FC1_OUT), 0.05),
         "fc2_b": ((ni.NUM_STACKS, 1), 0.02),
     }
-    keys = jax.random.split(jax.random.PRNGKey(0), len(shapes))
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
     return ni.StockfishNet(**{
         name: jax.random.normal(k, shape, jnp.float32) * scale
         for k, (name, (shape, scale)) in zip(keys, shapes.items())

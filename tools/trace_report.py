@@ -112,7 +112,7 @@ def summarize(events: List[dict]) -> dict:
         float((a.get("phases") or {}).get("other", 0.0)) for a in seg_args
     )
     # what the device counted in the segments' loops (ops/search.py
-    # SEGMENT_COUNTERS: movegen_*, acc_*), where the trace has them
+    # SEGMENT_COUNTERS: movegen_*, acc_*, eval_*), where the trace has them
     seg_counts: Dict[str, int] = defaultdict(int)
     for a in seg_args:
         for name, n in (a.get("counts") or {}).items():
