@@ -103,6 +103,8 @@ class RacingKingsPosition(Position):
         for color in (WHITE, BLACK):
             if popcount(self.bbs[color][KING]) != 1:
                 raise InvalidFenError("each side needs exactly one king")
+        if self.bbs[WHITE][PAWN] & (RANK_1 | RANK_8) or self.bbs[BLACK][PAWN] & (RANK_1 | RANK_8):
+            raise InvalidFenError("pawn on back rank")
         if self.is_check():
             raise InvalidFenError("racingKings positions can never have a check")
 
@@ -156,8 +158,10 @@ class HordePosition(Position):
             raise InvalidFenError("black must have exactly one king")
         if self.bbs[WHITE][KING]:
             raise InvalidFenError("the horde has no king")
-        if self.bbs[WHITE][PAWN] & RANK_8 or self.bbs[BLACK][PAWN] & RANK_1:
-            raise InvalidFenError("pawn on promotion rank")
+        # white pawns may stand on rank 1 (they start there); no pawn on a
+        # promotion rank, no black pawn on its own back rank
+        if self.bbs[WHITE][PAWN] & RANK_8 or self.bbs[BLACK][PAWN] & (RANK_1 | RANK_8):
+            raise InvalidFenError("pawn on promotion rank or black back rank")
         if self.turn == WHITE:
             bksq = self.king_sq(BLACK)
             if bksq is not None and self.attackers(WHITE, bksq):

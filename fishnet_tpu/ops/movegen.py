@@ -9,11 +9,14 @@ list with one single-array sort of packed (ordering_key << 16 | move)
 values (see generate_moves for the packing invariants). The space that is
 sorted is not the space that is enumerated: about half the slots are table
 padding (a ray past the edge, a knight target off the board), False on
-every board, and a constant per-variant index table (_live_slots) takes
-only the slots that can hold a move — 2,550 of 4,962; 2,854 of 5,282 with
-crazyhouse's drops — to the ordering refinements, the pack and the sort.
-The sort's cost on the TPU steps at powers of two of its width (PERF.md
-§5), so the table is what takes it below 4,096. Legality is *not*
+every board, more are False on every board the rules admit (a pawn on a
+back rank, a second king), and a constant per-variant index table
+(_live_slots) plus the one row of the king's square take only the slots
+that can hold a move — 2,046 of 4,962 (2,076 in horde, 2,482 in
+antichess); 2,350 of 5,282 with crazyhouse's drops — to the ordering
+refinements, the pack and the sort. The sort's cost on the TPU steps at
+powers of two of its width (PERF.md §5), so the table is what takes it to
+2,048. Legality is *not*
 fully resolved here: the search uses king-capture pruning (an illegal mover
 is refuted one ply later when its king is captured), so only castling does
 attack checks. This keeps the kernel free of pin/evasion logic; the host
@@ -61,6 +64,8 @@ _CSQ = np.clip(_CAPS, 0, 63)
 _PROMO_FROM = np.stack(
     [np.arange(48, 56, dtype=np.int32), np.arange(8, 16, dtype=np.int32)]
 )  # (2, 8)
+# the king section's move values, the same for either color
+_KING_MOVES = _SQ[:, None] | (np.clip(np.asarray(T.KING_TARGETS), 0, None) << 6)
 
 MAX_MOVES = T.MAX_MOVES
 # crazyhouse adds up to 5 droppable types × ≤64 empty squares on top of
@@ -97,7 +102,7 @@ def _static_moves(variant: str):
     rsq = np.clip(np.asarray(T.RAYS), 0, None)
     sl = (_SQ[:, None, None] | (rsq << 6)).reshape(-1)
     kn = (_SQ[:, None] | (np.clip(np.asarray(T.KNIGHT_TARGETS), 0, None) << 6)).reshape(-1)
-    kg = (_SQ[:, None] | (np.clip(np.asarray(T.KING_TARGETS), 0, None) << 6)).reshape(-1)
+    kg = _KING_MOVES.reshape(-1)
     promos = np.asarray(_promo_list(variant), np.int32)
     out = []
     for c in (0, 1):
@@ -138,23 +143,43 @@ def _hist_idx_tables(variant: str):
     return mw & 4095, mb & 4095
 
 
+def _royal_king(variant: str) -> bool:
+    """A side has at most one king on every board of `variant`: every root
+    with two is refused (chess/position.py, chess/variants.py `_validate`),
+    and a search only loses kings — no promotion or drop makes one. All
+    but antichess, whose kings are not royal and may be many."""
+    return variant != "antichess"
+
+
 @functools.lru_cache(maxsize=None)
 def _live_slots(variant: str) -> np.ndarray:
-    """The flat indices, in section order, of the candidate slots whose
-    STATIC factor of `valid` is not identically False: the slots that can
-    hold a move on some board. Built from the very tables the sections of
-    `_candidate_space` AND into `valid` (`rvalid`, `tvalid`, `cvalid`, the
-    pawn-drop ranks), so a slot left out is False on every board of this
-    variant. Push columns, promos and castling stay whole (horde has white
-    pawns on rank 0; a section with no static factor has nothing to drop).
-    2,550 of 4,962 slots (2,574 of 4,986 in antichess, 2,854 of 5,282 in
-    crazyhouse): what `generate_moves` packs and sorts."""
+    """The flat indices, in section order, of the candidate slots that can
+    hold a move on some board of `variant` — the king section of a royal
+    variant (`_royal_king`) excepted: `generate_moves` takes its one live
+    row, the king's square's, by a one-hot compare. Built from the very
+    tables the sections of `_candidate_space` AND into `valid` (`rvalid`,
+    `tvalid`, `cvalid`, the pawn-drop ranks) and from the pawn ranks every
+    root is held to: no pawn stands on rank 0 or 7 (horde's white pawns on
+    rank 0 excepted; promotions always promote, pawn drops skip those
+    ranks), so a non-promoting push or capture starts on ranks 1-6 and a
+    double push on either color's start rank. A slot left out is False on
+    every board of this variant. Promos and castling stay whole (a section
+    with no static factor has nothing to drop). 2,038 of 4,962 slots +
+    the king's row = 2,046 (2,068 + 8 in horde, 2,482 of 4,986 in
+    antichess, 2,342 + 8 of 5,282 in crazyhouse): what `generate_moves`
+    packs and sorts."""
+    ranks = _SQ >> 3
+    back = (ranks == 0) if variant == "horde" else np.zeros(64, bool)
+    push1 = ((ranks >= 1) & (ranks <= 6)) | back
+    push2 = (ranks == 1) | (ranks == 6) | back
     caps_on = (_CAPS[0] >= 0) | (_CAPS[1] >= 0)  # (64, 2): either color's
+    king = np.asarray(T.KING_TARGETS) >= 0
     secs = [
         np.asarray(T.RAYS) >= 0,
         np.asarray(T.KNIGHT_TARGETS) >= 0,
-        np.asarray(T.KING_TARGETS) >= 0,
-        np.concatenate([np.ones((64, 2), bool), caps_on], axis=1),
+        king & (not _royal_king(variant)),
+        np.concatenate([push1[:, None], push2[:, None],
+                        caps_on & push1[:, None]], axis=1),
         np.ones(8 * 3 * len(_promo_list(variant)), bool),
         np.ones(2, bool),
     ]
@@ -172,6 +197,8 @@ class _LiveTables(NamedTuple):
     slots: np.ndarray  # _live_slots(variant)
     castle_lo: int  # the first castling slot in the candidate space ...
     castle_at: int  # ... and among the live slots
+    king_lo: int | None  # the king section's first slot; None: in `slots`
+    width: int  # what is packed and sorted: the slots, then the king's row
     moves: tuple  # per color: _static_moves(variant)[c][slots]
     hist: tuple  # per color: _hist_idx_tables(variant)[c][slots]
 
@@ -183,8 +210,11 @@ def _live_tables(variant: str) -> _LiveTables:
     castle_lo = moves[0].shape[0] - 2 - (5 * 64 if variant == "crazyhouse" else 0)
     castle_at = int(np.searchsorted(slots, castle_lo))
     assert slots[castle_at] == castle_lo and slots[castle_at + 1] == castle_lo + 1
+    # the king section follows the sliders (64, 8, 7) and knights (64, 8)
+    king_lo = 64 * 8 * 7 + 64 * 8 if _royal_king(variant) else None
     return _LiveTables(
-        slots, castle_lo, castle_at,
+        slots, castle_lo, castle_at, king_lo,
+        slots.shape[0] + (8 if king_lo is not None else 0),
         tuple(m[slots] for m in moves),
         tuple(h[slots] for h in _hist_idx_tables(variant)),
     )
@@ -218,21 +248,41 @@ def generate_moves(b: Board, variant: str = "standard",
     """
     white, flat_moves, flat_valid, flat_keys = _candidate_space(b, variant)
 
-    # from here on only the slots that can hold a move on some board
-    # (_live_slots: about half the space is table padding, False on every
-    # board): one constant-index gather takes valid and key through the
-    # table together (keys are >= 10, so -1 marks an invalid slot), and the
-    # move values of the live slots are constants per side to move, the
-    # two castling slots excepted. The history lookup, the killer compare,
-    # the pack and the sort below all run at the live width.
+    # from here on only the slots that can hold a move on some board the
+    # rules admit (_live_slots: about half the space is table padding,
+    # False on every board): one constant-index gather takes valid and key
+    # through the table together (keys are >= 10, so -1 marks an invalid
+    # slot), and the move values of the live slots are constants per side
+    # to move, the two castling slots excepted. Where the king is royal,
+    # its section holds one live row, the king's square's, taken by a
+    # one-hot compare over the 64 origin squares (`board[ksq]` would be a
+    # gather the TPU runs lane by lane, see _TO1 above); with no king all
+    # 8 slots are invalid. The history lookup, the killer compare, the
+    # pack and the sort below all run at the live width.
     live = _live_tables(variant)
     keys = jnp.where(flat_valid, flat_keys, -1)[live.slots]
-    valid = keys >= 0
     cands = jax.lax.dynamic_update_slice_in_dim(
         jnp.where(white, live.moves[0], live.moves[1]),
         jax.lax.slice_in_dim(flat_moves, live.castle_lo, live.castle_lo + 2),
         live.castle_at, axis=0,
     )
+    if live.king_lo is not None:
+        at_king = (
+            jnp.arange(64, dtype=jnp.int32) == king_square(b.board, b.stm)
+        )[:, None]
+
+        def king_row(a):  # (64, 8) → the king's square's (8,)
+            return jnp.sum(jnp.where(at_king, a, 0), axis=0)
+
+        def king_sec(flat):
+            return jax.lax.slice_in_dim(
+                flat, live.king_lo, live.king_lo + 512).reshape(64, 8)
+
+        king_valid = jnp.any(at_king & king_sec(flat_valid), axis=0)
+        keys = jnp.concatenate(
+            [keys, jnp.where(king_valid, king_row(king_sec(flat_keys)), -1)])
+        cands = jnp.concatenate([cands, king_row(jnp.asarray(_KING_MOVES))])
+    valid = keys >= 0
 
     # quiet-move ordering refinements: history first (quiets 1000 →
     # 911..1010, drops 1100 → 1011..1110 by counter magnitude), then
@@ -240,8 +290,11 @@ def generate_moves(b: Board, variant: str = "standard",
     if hist is not None:
         # candidate from|to indices are static per color (castling slots
         # excepted — their key is 900, never history-adjusted), so the
-        # lookup is a constant-index gather per color + a stm select
+        # lookup is a constant-index gather per color + a stm select; the
+        # king's row takes its square's row of a constant (64, 8) gather
         hval = jnp.where(white, hist[live.hist[0]], hist[live.hist[1]])
+        if live.king_lo is not None:
+            hval = jnp.concatenate([hval, king_row(hist[_KING_MOVES & 4095])])
         hbonus = jnp.clip(hval >> 5, 0, 99)
         keys = jnp.where(keys == 1000, 1010 - hbonus, keys)
         keys = jnp.where(keys == 1100, 1110 - hbonus, keys)

@@ -7,6 +7,7 @@ so the move *sets* must match square-for-square.
 """
 import functools
 import random
+import re
 
 import jax
 import numpy as np
@@ -231,19 +232,27 @@ def test_history_ordering_crazyhouse_drop_slot():
 # ------------------------------------------------------------------------
 # The ordering sort sorts only the slots that can hold a move (PR 33):
 # `_live_slots(variant)` drops the candidate slots that are False on every
-# board, and `generate_moves` packs and sorts what is left. Proofs: (a) the
-# table holds every slot whose static factor is true, (b) no board reaches
-# a slot outside it, (c) `generate_moves` is the parent's, bit for bit,
-# (d) the pruned history tables are the full ones taken through the table.
+# board the rules admit — table padding, pawn moves from a back rank, and,
+# where a side has at most one king, every row of the king section but
+# the king's square's, which `generate_moves` takes by a one-hot compare —
+# and `generate_moves` packs and sorts what is left. Proofs: (a) the table
+# holds every slot whose static factor is true, (b) no board reaches a
+# slot outside it or a king row but its king's, (c) `generate_moves` is
+# the parent's, bit for bit, (d) the pruned history tables are the full
+# ones taken through the table, (e) the FEN validators refuse every root
+# that would hold a move in a dropped slot.
 
 # every statically compiled program (engine/tpu.py DEVICE_VARIANTS' values)
 PROGRAMS = [
     "standard", "threeCheck", "kingOfTheHill", "racingKings", "atomic",
     "horde", "antichess", "crazyhouse",
 ]
-LIVE_WIDTH = dict.fromkeys(PROGRAMS, 2550) | {
-    "antichess": 2574, "crazyhouse": 2854}
+ROYAL = [v for v in PROGRAMS if v != "antichess"]
+LIVE_WIDTH = dict.fromkeys(PROGRAMS, 2046) | {
+    "horde": 2076, "antichess": 2482, "crazyhouse": 2350}
 _LANE = Board(0, 0, 0, 0, 0, 0)
+_KING_LO = 64 * 8 * 7 + 64 * 8  # the king section, after sliders, knights
+_PAWN_LO = _KING_LO + 64 * 8  # the pawn section: (64, [push1 push2 capL capR])
 # what seeded playouts from the start rarely reach
 _EXTRA_FENS = {
     "standard": [  # chess960 castling (the standard program serves it)
@@ -303,17 +312,30 @@ def _playout_boards(variant: str) -> Board:
 
 def _static_factor(variant: str) -> np.ndarray:
     """The static factor of `valid`, slot by slot, written out from
-    `_candidate_space`'s sections: what `_live_slots` may not drop."""
+    `_candidate_space`'s sections and the rules every root is held to:
+    what `_live_slots` may not drop. A pawn of either color stands on
+    ranks 1-6 (horde's white pawns on rank 0 too), pushes once unless it
+    stands on its pre-promotion rank (that push is a promotion slot),
+    pushes twice from its start rank; a royal king's section holds one
+    live row, taken apart from the slots, so none of it is here."""
     from fishnet_tpu.ops.movegen import _CAPS
 
     ranks = np.arange(64) >> 3
+    stands = [(ranks >= 1) & (ranks <= 6)] * 2  # per color: white, black
+    starts = [ranks == 1, ranks == 6]
+    if variant == "horde":
+        stands[0] = stands[0] | (ranks == 0)
+        starts[0] = starts[0] | (ranks == 0)
+    quiet = [stands[0] & (ranks != 6), stands[1] & (ranks != 1)]
     secs = [
         np.asarray(T.RAYS) >= 0,  # rvalid
         np.asarray(T.KNIGHT_TARGETS) >= 0,  # tvalid
-        np.asarray(T.KING_TARGETS) >= 0,
-        np.stack([np.ones(64, bool), np.ones(64, bool),  # pushes: none
-                  (_CAPS[0][:, 0] >= 0) | (_CAPS[1][:, 0] >= 0),  # cvalid
-                  (_CAPS[0][:, 1] >= 0) | (_CAPS[1][:, 1] >= 0)], axis=1),
+        (np.asarray(T.KING_TARGETS) >= 0) & (variant not in ROYAL),
+        np.stack([quiet[0] | quiet[1],
+                  (stands[0] & starts[0]) | (stands[1] & starts[1])]
+                 + [((_CAPS[0][:, k] >= 0) & quiet[0])  # cvalid
+                    | ((_CAPS[1][:, k] >= 0) & quiet[1]) for k in (0, 1)],
+                 axis=1),
         np.ones((8, 3, 5 if variant == "antichess" else 4), bool),
         np.ones(2, bool),
     ]
@@ -361,9 +383,58 @@ def parent_generate_moves(b, variant="standard", killers=None, hist=None):
     return moves, count, noisy
 
 
+@functools.lru_cache(maxsize=None)
+def _gen_pair(variant: str, ordering: str):
+    """(`generate_moves`, `parent_generate_moves`) vmapped over lanes and
+    jitted once a program and ordering, so that tests of one shape share
+    a compile. "killers_hist" takes (boards, killers, hist)."""
+    if ordering == "plain":
+        new = jax.vmap(lambda b: generate_moves(b, variant), in_axes=(_LANE,))
+        old = jax.vmap(
+            lambda b: parent_generate_moves(b, variant), in_axes=(_LANE,))
+    else:
+        new = jax.vmap(
+            lambda b, k, h: generate_moves(b, variant, killers=k, hist=h),
+            in_axes=(_LANE, 0, 0))
+        old = jax.vmap(
+            lambda b, k, h: parent_generate_moves(
+                b, variant, killers=k, hist=h),
+            in_axes=(_LANE, 0, 0))
+    return jax.jit(new), jax.jit(old)
+
+
+def _ordering_state(listed: np.ndarray):
+    """Killers taken from each board's own list (a capture and late quiets
+    among them, -1 where the list is short) and a dense history."""
+    import jax.numpy as jnp
+
+    n = listed.shape[0]
+    nrng = np.random.default_rng(33)
+    cols = nrng.integers(0, 40, (n, 2))
+    killers = jnp.asarray(np.take_along_axis(listed, cols, axis=1))
+    hist = jnp.asarray(
+        nrng.integers(0, 1 << 13, (n, 4096)) * (nrng.random((n, 4096)) < 0.5),
+        jnp.int32)
+    return killers, hist
+
+
+def _assert_parents(variant: str, ordering: str, boards: Board):
+    """`generate_moves` on `boards` is the parent's, bit for bit on moves
+    (the whole list, padding included), count and noisy; → the parent's."""
+    new, old = _gen_pair(variant, ordering)
+    args = ()
+    if ordering == "killers_hist":
+        args = _ordering_state(np.asarray(_gen_pair(variant, "plain")[1](boards)[0]))
+    got = new(boards, *args)
+    want = old(boards, *args)
+    for name, g, w in zip(("moves", "count", "noisy"), got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), (variant, name)
+    return want
+
+
 @pytest.mark.parametrize("variant", PROGRAMS)
 def test_live_slots_by_construction(variant):
-    from fishnet_tpu.ops.movegen import _live_slots, _static_moves
+    from fishnet_tpu.ops.movegen import _live_slots, _live_tables, _static_moves
 
     live = _live_slots(variant)
     factor = _static_factor(variant)
@@ -372,7 +443,9 @@ def test_live_slots_by_construction(variant):
     assert np.all(np.diff(live) > 0), "sorted and unique"
     assert 0 <= live[0] and live[-1] < factor.shape[0]
     assert set(np.flatnonzero(factor).tolist()) <= set(live.tolist())
-    assert live.shape == (LIVE_WIDTH[variant],)
+    # what is packed and sorted: the slots, and a royal king's one row
+    assert live.shape[0] + 8 * (variant in ROYAL) == LIVE_WIDTH[variant]
+    assert _live_tables(variant).width == LIVE_WIDTH[variant]
 
 
 @pytest.mark.parametrize("variant", PROGRAMS)
@@ -382,11 +455,21 @@ def test_no_board_reaches_a_slot_outside_live_slots(variant):
     boards = _playout_boards(variant)
     space = jax.jit(jax.vmap(
         lambda b: _candidate_space(b, variant)[2], in_axes=(_LANE,)))
-    seen = np.asarray(space(boards)).any(axis=0)
+    valid = np.asarray(space(boards))
+    seen = valid.any(axis=0)
     assert boards.board.shape[0] >= 300
     assert {0, 1} == set(np.asarray(boards.stm).tolist())
     dead = np.ones(seen.shape[0], bool)
     dead[_live_slots(variant)] = False
+    if variant in ROYAL:
+        # of the king section, only the row of the mover's king holds a move
+        rows = valid[:, _KING_LO:_PAWN_LO].reshape(-1, 64, 8).any(axis=2)
+        stm = np.asarray(boards.stm)[:, None]
+        at_king = np.asarray(boards.board) == np.where(stm == 0, T.W_KING, T.B_KING)
+        assert at_king.sum(axis=1).max() == 1
+        assert not np.any(rows & ~at_king)
+        assert rows.any()
+        dead[_KING_LO:_PAWN_LO] = False
     assert not np.any(seen & dead), np.flatnonzero(seen & dead)[:10]
     # the playouts do exercise the space: most live slots held a move
     assert seen.sum() > 0.3 * _live_slots(variant).shape[0]
@@ -397,45 +480,152 @@ def test_no_board_reaches_a_slot_outside_live_slots(variant):
 def test_generate_moves_is_the_parents(variant, ordering):
     """Bit for bit on moves (the whole list, padding included), count and
     noisy, both colors, with and without the quiet-ordering state."""
-    import jax.numpy as jnp
-
-    boards = _playout_boards(variant)
-    n = boards.board.shape[0]
-    if ordering == "plain":
-        args = ()
-        new = jax.vmap(lambda b: generate_moves(b, variant), in_axes=(_LANE,))
-        old = jax.vmap(
-            lambda b: parent_generate_moves(b, variant), in_axes=(_LANE,))
-    else:
-        # killers taken from each board's own list (a capture and late
-        # quiets among them, -1 where the list is short), dense history
-        plain = jax.jit(jax.vmap(
-            lambda b: parent_generate_moves(b, variant), in_axes=(_LANE,)))
-        listed = np.asarray(plain(boards)[0])
-        nrng = np.random.default_rng(33)
-        cols = nrng.integers(0, 40, (n, 2))
-        killers = jnp.asarray(np.take_along_axis(listed, cols, axis=1))
-        hist = jnp.asarray(
-            nrng.integers(0, 1 << 13, (n, 4096)) * (nrng.random((n, 4096)) < 0.5),
-            jnp.int32)
-        args = (killers, hist)
-        new = jax.vmap(
-            lambda b, k, h: generate_moves(b, variant, killers=k, hist=h),
-            in_axes=(_LANE, 0, 0))
-        old = jax.vmap(
-            lambda b, k, h: parent_generate_moves(
-                b, variant, killers=k, hist=h),
-            in_axes=(_LANE, 0, 0))
-    got = jax.jit(new)(boards, *args)
-    want = jax.jit(old)(boards, *args)
-    for name, g, w in zip(("moves", "count", "noisy"), got, want):
-        assert np.array_equal(np.asarray(g), np.asarray(w)), (variant, name)
+    want = _assert_parents(variant, ordering, _playout_boards(variant))
     assert int(np.asarray(want[1]).max()) > 30
+
+
+def _lane_rows(boards, stm) -> Board:
+    """Batched numpy Board of (n, 64) piece arrays, `stm` to move, no
+    castling rights, no en-passant square, empty side-state."""
+    from fishnet_tpu.ops.board import EXTRA_W
+
+    n = boards.shape[0]
+    return Board(
+        boards.astype(np.int32), np.full(n, stm, np.int32),
+        np.full(n, -1, np.int32), np.full((n, 4), -1, np.int32),
+        np.zeros(n, np.int32), np.zeros((n, EXTRA_W), np.int32))
+
+
+@pytest.mark.parametrize("color", [0, 1], ids=["white", "black"])
+@pytest.mark.parametrize("variant", ["standard", "atomic"])
+def test_king_row_is_the_whole_king_section(variant, color):
+    """The mover's king alone on each of the 64 squares, then ringed by
+    enemy queens on every other target (captures beside quiet moves): the
+    one row taken by the king's square lists what the whole section
+    listed, with and without the quiet-ordering state (the row's history
+    lookup)."""
+    king, queen = ((T.W_KING, T.B_QUEEN), (T.B_KING, T.W_QUEEN))[color]
+    targets = np.asarray(T.KING_TARGETS)
+    rows = np.zeros((128, 64), np.int32)
+    for sq in range(64):
+        rows[2 * sq, sq] = rows[2 * sq + 1, sq] = king
+        ring = targets[sq][::2]
+        rows[2 * sq + 1, ring[ring >= 0]] = queen
+    boards = _lane_rows(rows, color)
+    want = _assert_parents(variant, "plain", boards)
+    _assert_parents(variant, "killers_hist", boards)
+    # a lone king lists each of its targets
+    assert np.array_equal(np.asarray(want[1])[0::2], (targets >= 0).sum(axis=1))
+
+
+@pytest.mark.parametrize("variant", ROYAL)
+def test_generate_moves_without_the_movers_king(variant):
+    """The playouts with the mover's king lifted off the board (a search
+    that captured it under king-capture pruning): the king's row is all
+    invalid, and the list is still the parent's."""
+    boards = _playout_boards(variant)
+    stm = np.asarray(boards.stm)[:, None]
+    board = np.asarray(boards.board)
+    kingless = boards._replace(board=np.where(
+        board == np.where(stm == 0, T.W_KING, T.B_KING), 0, board))
+    from fishnet_tpu.ops.movegen import DROP_FLAG
+
+    want = _assert_parents(variant, "plain", kingless)
+    moves = np.asarray(want[0])
+    from_sq = np.where(moves >= 0, moves & 63, 0)
+    pieces = np.take_along_axis(board, from_sq, axis=1)
+    on_board = (moves >= 0) & (moves < DROP_FLAG)  # a drop's from is its to
+    assert not np.any(on_board & (pieces == np.where(stm == 0, T.W_KING, T.B_KING)))
+    assert int(np.asarray(want[1]).max()) > 20
+
+
+@pytest.mark.parametrize("fen,listed,absent", [
+    # every white pawn on rank 0: single and double pushes from there
+    ("rnbqkbnr/pppppppp/8/8/8/8/8/PPPPPPPP w kq - 0 1",
+     ["a1a2", "a1a3", "h1h2", "h1h3"], []),
+    # black knights on rank 1: captures from rank 0, blocked pushes
+    ("rnbqkbnr/pppppppp/8/8/8/8/1n1n1n1n/PPPPPPPP w kq - 0 1",
+     ["a1b2", "c1b2", "c1d2", "g1h2", "a1a2", "a1a3", "g1g3"], ["b1b2"]),
+    # a double push from rank 0 blocked on its second square
+    ("rnbqkbnr/pppppppp/8/8/8/n7/8/PPPPPPPP w kq - 0 1",
+     ["a1a2", "b1b2", "b1b3"], ["a1a3"]),
+])
+def test_horde_pawns_on_rank_0(fen, listed, absent):
+    """Horde keeps the pawn slots of rank 0: its white pawns stand there
+    and push once, twice, or capture from it, as the host lists them."""
+    from fishnet_tpu.chess.variants import from_fen as v_from_fen
+    from fishnet_tpu.ops.board import position_fields, stack_fields
+
+    pos = v_from_fen(fen, "horde")
+    want = _assert_parents(
+        "horde", "plain", stack_fields([position_fields(pos)] * 2))
+    dev = set(np.asarray(want[0])[0, : int(want[1][0])].tolist())
+    assert dev == host_pseudo_set(pos)
+    enc = {u: encode_host_move(Move.parse_uci(u)) for u in listed + absent}
+    assert {enc[u] for u in listed} <= dev
+    assert not {enc[u] for u in absent} & dev
+
+
+# a root the validators take, per program, with a1 and a8 empty; and what
+# each case adds to it: a second king, a pawn on a back rank
+_GATE_BASE = dict.fromkeys(PROGRAMS, "4k3/8/8/8/8/8/8/4K3 w - - 0 1") | {
+    "horde": "4k3/8/8/8/8/8/4P3/8 w - - 0 1"}
+_GATE_CASES = {
+    "white_king": ("K", 28), "black_king": ("k", 36),
+    "white_pawn_rank0": ("P", 0), "white_pawn_rank7": ("P", 56),
+    "black_pawn_rank0": ("p", 0), "black_pawn_rank7": ("p", 56),
+}
+
+
+def _with_piece(fen: str, piece: str, sq: int) -> str:
+    """`fen` with `piece` put on the empty square `sq`."""
+    rows = fen.split()[0].split("/")
+    rank, file = 7 - (sq >> 3), sq & 7
+    cells = "".join("." * int(c) if c.isdigit() else c for c in rows[rank])
+    assert cells[file] == "."
+    cells = cells[:file] + piece + cells[file + 1:]
+    rows[rank] = re.sub(r"\.+", lambda m: str(len(m.group())), cells)
+    return " ".join(["/".join(rows)] + fen.split()[1:])
+
+
+@pytest.mark.parametrize("case", list(_GATE_CASES))
+@pytest.mark.parametrize("variant", PROGRAMS)
+def test_the_validators_refuse_what_the_table_drops(variant, case):
+    """Every root that would put a move in a slot the table drops is
+    refused by the FEN validator of that program: a second king where the
+    king is royal (one row is taken), a pawn on rank 0 or 7 (the pawn
+    slots of those ranks are gone), but horde's white pawns on rank 0."""
+    from fishnet_tpu.chess import InvalidFenError
+    from fishnet_tpu.chess.variants import from_fen as v_from_fen
+    from fishnet_tpu.ops.movegen import _live_tables
+
+    v_from_fen(_GATE_BASE[variant], variant)  # the base is taken
+    piece, sq = _GATE_CASES[case]
+    fen = _with_piece(_GATE_BASE[variant], piece, sq)
+    live = _live_tables(variant)
+    if piece in "Kk":
+        dropped = live.king_lo is not None  # one row of the section is taken
+    else:
+        dropped = _PAWN_LO + 4 * sq not in set(live.slots.tolist())  # push1
+    try:
+        v_from_fen(fen, variant)
+        refused = False
+    except InvalidFenError:
+        refused = True
+    assert refused or not dropped, fen
+    # the rules this table rests on, and the two programs that differ
+    assert dropped == (variant != "antichess" if piece in "Kk"
+                       else not (variant == "horde" and sq == 0))
+    if variant == "antichess" and piece in "Kk":
+        assert not refused
+    if variant == "horde" and case == "white_pawn_rank0":
+        assert not refused
 
 
 @pytest.mark.parametrize("variant", PROGRAMS)
 def test_live_tables_are_the_full_tables_through_live_slots(variant):
     from fishnet_tpu.ops.movegen import (
+        _KING_MOVES,
         _hist_idx_tables,
         _live_slots,
         _live_tables,
@@ -453,3 +643,15 @@ def test_live_tables_are_the_full_tables_through_live_slots(variant):
     assert live.slots[live.castle_at] == live.castle_lo
     assert live.slots[live.castle_at + 1] == live.castle_lo + 1
     assert _static_moves(variant)[0][live.castle_lo] == 0
+    # a royal king's section: none of it among the slots, its row of
+    # move values the full tables' (either color) at the king's square
+    if variant in ROYAL:
+        assert live.king_lo == _KING_LO
+        assert not np.any((live.slots >= _KING_LO) & (live.slots < _PAWN_LO))
+        for c in (0, 1):
+            assert np.array_equal(
+                _static_moves(variant)[c][_KING_LO:_PAWN_LO],
+                _KING_MOVES.reshape(-1))
+    else:
+        assert live.king_lo is None
+    assert live.width == live.slots.shape[0] + 8 * (variant in ROYAL)

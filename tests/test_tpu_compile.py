@@ -153,13 +153,14 @@ def test_run_segment_compiles(one_chip, params, lanes, variant, deep_tt,
 
 
 @pytest.mark.parametrize("variant,widest", [
-    ("standard", 2600), ("crazyhouse", 2900),
+    ("standard", 2048), ("crazyhouse", 2350),
 ], ids=["standard", "crazyhouse"])
 def test_segment_sorts_only_live_slots(one_chip, params, variant, widest):
     """The shape as the counter (PR 33): the 64-lane segment the trickle
     cells run holds exactly ONE sort, and what it sorts is the live slots
-    of the candidate space (ops/movegen.py _live_slots: 2,550 / 2,854),
-    not the whole space (4,962 / 5,282). The op's name in a trace,
+    of the candidate space and the king's row (ops/movegen.py _live_slots
+    and _live_tables: 2,046 / 2,350), not the whole space (4,962 / 5,282):
+    2,046 is in the sort's 2,048 class (PERF.md §5). The op's name in a trace,
     `sort s32[64,<width>]`, is what the ledger's `breakdown.device_ops`
     shows."""
     text = _compile_segment(params, one_chip, 64, variant, False, True).as_text()
@@ -168,7 +169,7 @@ def test_segment_sorts_only_live_slots(one_chip, params, variant, widest):
     dtype, dims, axis = sorts[0]
     dims = [int(d) for d in dims.split(",")]
     assert dtype == "s32" and sorted(dims)[0] == 64, sorts
-    assert dims[int(axis)] == len(movegen._live_slots(variant)) <= widest
+    assert dims[int(axis)] == movegen._live_tables(variant).width <= widest
 
 
 @pytest.fixture(scope="module")
@@ -206,7 +207,7 @@ def test_wide_net_segment_compiles(one_chip, wide_net, lanes):
     text = compiled.as_text()
     assert f"f32[{lanes},{2 * (MAX_PLY + 2)},3080]" in text
     sorts = re.findall(r"= s32\[([\d,]+)\]\S* sort\(", text)
-    assert len(sorts) == 1 and str(len(movegen._live_slots("standard"))) in sorts[0]
+    assert len(sorts) == 1 and str(movegen._live_tables("standard").width) in sorts[0]
 
 
 @pytest.fixture(scope="module")
@@ -243,7 +244,7 @@ def test_dual_net_segment_compiles(one_chip, dual_net, lanes):
     assert f"f32[{lanes},{2 * (MAX_PLY + 2)},3328]" in text
     assert not re.search(r"= f32\[22528,3328\]\S* copy\(", text)
     sorts = re.findall(r"= s32\[([\d,]+)\]\S* sort\(", text)
-    assert len(sorts) == 1
+    assert len(sorts) == 1 and str(movegen._live_tables("standard").width) in sorts[0]
 
 
 def test_wide_net_splice_compiles(one_chip, wide_net):
