@@ -282,6 +282,46 @@ PHASES = ("reap", "admit", "refill", "dispatch", "wait", "lanes", "pv",
 COMPILE_SITES = ("refill", "dispatch", "pv", "session_setup",
                  "submit_history", "other")
 
+# what a lane of a drive session is at a segment's dispatch (_Session.role):
+# free — no job's, and last seen DONE, so it stays DONE until a splice;
+# primary / helper — a job's; stray — no job's, but not yet seen DONE:
+# the session's base lanes before its first boundary, a helper whose
+# position was finalized, a lane cut at its position's deadline. The
+# device does not know the host let a stray lane go: it searches on
+# until it parks, and holds the segment open meanwhile.
+ROLE_FREE, ROLE_PRIMARY, ROLE_HELPER, ROLE_STRAY = range(4)
+# a segment's lane-steps by those roles, from the summary's run column
+# (ops/search.py SUM_RUN): the lane-steps of primary and helper lanes,
+# the steps each kind of lane ran, the steps the segment ran after its
+# last primary lane parked, and dispatches that ran no step at all
+LANE_STEP_COUNTERS = (
+    "lane_steps_assigned", "lane_steps_primary_run",
+    "lane_steps_helper_run", "lane_steps_stray_run", "lane_steps_free_run",
+    "steps_after_last_primary", "segments_empty",
+)
+
+
+def _lane_step_counts(n: int, run: np.ndarray, roles: np.ndarray) -> dict:
+    """One segment's LANE_STEP_COUNTERS: n its steps, run the (B,)
+    steps each lane was not DONE, roles the (B,) ROLE_* at dispatch."""
+    primary = roles == ROLE_PRIMARY
+    helper = roles == ROLE_HELPER
+    return {
+        "lane_steps_assigned": n * int(np.count_nonzero(primary | helper)),
+        "lane_steps_primary_run": int(run[primary].sum()),
+        "lane_steps_helper_run": int(run[helper].sum()),
+        "lane_steps_stray_run": int(run[roles == ROLE_STRAY].sum()),
+        "lane_steps_free_run": int(run[roles == ROLE_FREE].sum()),
+        "steps_after_last_primary":
+            n - int(run[primary].max()) if primary.any() else 0,
+        "segments_empty": int(n == 0),
+    }
+
+
+# what the `session` span's args add up over the session
+SESSION_TOTALS = ("segments", "steps", "positions_done") + LANE_STEP_COUNTERS + (
+    "position_queue_ms", "position_engine_ms", "positions_finalized")
+
 
 def _pad_lanes(n: int) -> int:
     for b in LANE_BUCKETS:
@@ -482,6 +522,10 @@ class TpuEngine(ChunkSubmit):
             # accumulator's work; which of two nets answered (search_ops
             # SEGMENT_COUNTERS)
             **{name: 0 for name in search_ops.SEGMENT_COUNTERS},
+            # the same segments' lane-steps by the role each lane had at
+            # dispatch (LANE_STEP_COUNTERS), and the speculative
+            # dispatches that found every lane parked
+            **{name: 0 for name in LANE_STEP_COUNTERS},
             # the same boundary intervals by what the host was doing
             # (SyncStats.phase): sums to host_ms + device_ms, and
             # phase_wait_ms is device_ms
@@ -505,6 +549,10 @@ class TpuEngine(ChunkSubmit):
             # chunk's line walks validated (_ChunkReplay): a chunk of
             # consecutive plies validates its shared prefix once
             "submit_prefix_moves": 0, "submit_moves_parsed": 0,
+            # per finalized position: queued (the job made in _submit)
+            # to its first lane, and queued to _finalize
+            "position_queue_ms": 0.0, "position_engine_ms": 0.0,
+            "positions_finalized": 0,
             # programs built or loaded while serving a chunk, by where
             **{f"compiles_{site}": 0 for site in COMPILE_SITES},
             "compile_ms": 0.0,
@@ -531,15 +579,6 @@ class TpuEngine(ChunkSubmit):
         # run_chunk exports the rows the search earned back out.
         self.tt_warm = None
         self.tt_warm_prefix = 8
-        # FISHNET_TPU_TRACE=1: per-dispatch / per-depth timing lines to
-        # stderr (verdict A1: a hang or slow depth must be localizable
-        # from logs — compile-vs-run shows up as a slow FIRST dispatch
-        # of a shape, steady-state cost as the later ones)
-        self.trace = (
-            (lambda msg: print(f"T: {msg}", file=sys.stderr, flush=True))
-            if settings.get_bool("FISHNET_TPU_TRACE")
-            else None
-        )
 
     def _warn(self, msg: str) -> None:
         if self._logger is not None:
@@ -761,7 +800,6 @@ class TpuEngine(ChunkSubmit):
         # whenever helper lanes are enabled (multipv groups too, which
         # benefit from the same shallow-write protection) so warmup
         # compiles exactly one program per bucket either way.
-        t0 = time.monotonic()
         out = search_batch_resumable(
             self.params, roots, jnp.asarray(depth_arr),
             jnp.asarray(budget_arr), max_ply=MAX_PLY,
@@ -785,17 +823,7 @@ class TpuEngine(ChunkSubmit):
             self.tt = out.pop("tt")
         else:
             out.pop("tt")
-        out = {k: np.asarray(v) for k, v in out.items()}
-        if self.trace:
-            dt = time.monotonic() - t0
-            nodes = int(out["nodes"].sum())
-            self.trace(
-                f"dispatch variant={variant} B={int(roots.stm.shape[0])} "
-                f"maxdepth={int(np.max(depth_arr))} steps={int(out['steps'])} "
-                f"nodes={nodes} wall={dt:.3f}s "
-                f"nps={nodes / max(dt, 1e-9):,.0f}"
-            )
-        return out
+        return {k: np.asarray(v) for k, v in out.items()}
 
     def _search_windowed(self, roots, depth_arr, budget_arr, deadline,
                          variant, hist, prev_score, use_win,
@@ -873,16 +901,6 @@ class TpuEngine(ChunkSubmit):
                 st[1] += int(fail_lo.sum())
                 st[2] += int(fail_hi.sum())
                 st[3] += int(out["nodes"][live].sum())
-            if self.trace and delta is not None and use_win.any():
-                # aspiration economics (round-3 verdict: window deltas
-                # were guesses with no recorded fail rates or costs)
-                self.trace(
-                    f"aspiration delta={delta}: windowed="
-                    f"{int((use_win & live & primary).sum())} "
-                    f"fail_lo={int(fail_lo.sum())} "
-                    f"fail_hi={int(fail_hi.sum())} "
-                    f"nodes={int(out['nodes'][live].sum())}"
-                )
             # lanes that didn't finish (deadline) stay merged as not-done
             live = fail
             if not live.any():
@@ -1250,18 +1268,10 @@ class TpuEngine(ChunkSubmit):
                     # the pre-helper engine — bit-for-bit the same search
                     hist_args = {}
                     hist_d = (hist_hh, hist_hm)
-                t_depth = time.monotonic()
                 out = self._search_windowed(
                     roots, depth_arr, budget_arr, deadline,
                     variant, hist_d, prev_full, use_full, **hist_args,
                 )
-                if self.trace:
-                    self.trace(
-                        f"ID depth={depth} B={B} lanes={n} "
-                        f"helpers={len(helpers)} "
-                        f"nodes={int(out['nodes'].sum())} "
-                        f"wall={time.monotonic() - t_depth:.3f}s"
-                    )
                 exhausted_all = True
                 for j, i in enumerate(lanes):
                     if remaining[j] <= 0 or not bool(out["done"][j]):
@@ -1533,20 +1543,6 @@ class TpuEngine(ChunkSubmit):
                 if not progressed or time.monotonic() >= deadline:
                     break
 
-            if self.trace:
-                # budget honesty: root-move lanes make a position spend up
-                # to ~len(legal)× a single-PV search's nodes against the
-                # same server budget — keep the actual consumption visible
-                spent = {i: per_pos_budget - remaining[i] for i in live}
-                self.trace(
-                    "multipv budget: "
-                    + " ".join(
-                        f"pos{i}={spent[i]}/{per_pos_budget}"
-                        f"({len(legal[i])}lanes)"
-                        for i in live
-                    )
-                )
-
 
 # ---------------------------------------------- continuous lane refill
 
@@ -1567,7 +1563,7 @@ class _RefillJob:
         "remaining", "deadline", "hh", "hm", "depth", "delta_idx",
         "prev_score", "have_prev", "hardness", "scores", "pvs",
         "depth_reached", "best_move", "nodes_total", "nodes_depth",
-        "lane", "helpers", "traced", "t_spliced",
+        "lane", "helpers", "traced", "t_queued", "t_spliced",
     )
 
     def __init__(self, entry, wp, pos, board, variant, target_depth,
@@ -1597,9 +1593,11 @@ class _RefillJob:
         self.helpers: dict = {}  # helper lane index -> helper number h
         # request-scoped tracing (host-side bookkeeping ONLY — nothing
         # here ever reaches a device buffer): traced is the per-request
-        # sampling verdict hoisted out of the boundary loop, t_spliced
-        # the monotonic time this position won its first lane
+        # sampling verdict hoisted out of the boundary loop
         self.traced = False
+        # monotonic: the job made, and the position won its first lane
+        # (0.0 until then)
+        self.t_queued = time.monotonic()
         self.t_spliced = 0.0
 
 
@@ -1925,6 +1923,12 @@ class LaneScheduler:
     def _finalize(self, job: _RefillJob, now: float,
                   error: Optional[str] = None) -> None:
         entry = job.entry
+        tot = self.engine.occupancy_totals
+        # a position never admitted waited in the queue all along
+        t_lane = job.t_spliced or now
+        tot["position_queue_ms"] += (t_lane - job.t_queued) * 1000.0
+        tot["position_engine_ms"] += (now - job.t_queued) * 1000.0
+        tot["positions_finalized"] += 1
         if job.traced and job.t_spliced > 0.0:
             rec = obs_trace.RECORDER
             if rec is not None:
@@ -1950,7 +1954,7 @@ class LaneScheduler:
                 best_move=job.best_move, depth=job.depth_reached,
                 nodes=job.nodes_total, time_s=dt, nps=nps,
             ))
-            self.engine.occupancy_totals["positions_done"] += 1
+            tot["positions_done"] += 1
         entry.n_open -= 1
         if entry.n_open <= 0:
             entry.event.set()
@@ -2026,7 +2030,7 @@ class LaneScheduler:
             # the row that shows the race between submitters and driver
             session_args = {"n_hint": n_hint, "pending": len(self._pending),
                             "variant": variant}
-        at_start = (tot["segments"], tot["steps"], tot["positions_done"])
+        at_start = {k: tot[k] for k in SESSION_TOTALS}
         B = eng._helper_width(min(max(n_hint, 1), eng.max_lanes))
         session = _Session(self, entry, variant, filler, B, t_enter)
         session_args["setup_ms"] = round(session.setup_ms, 3)
@@ -2062,19 +2066,19 @@ class LaneScheduler:
                 self._submits_done.clear()
             rec = obs_trace.RECORDER
             if rec is not None:
+                spent = {k: tot[k] - at_start[k] for k in SESSION_TOTALS}
+                for k in ("position_queue_ms", "position_engine_ms"):
+                    spent[k] = round(spent[k], 3)
+                spent["positions"] = spent.pop("positions_done")
                 rec.complete(
                     "session", t_enter * 1e6, (t_end - t_enter) * 1e6,
                     cat="engine",
-                    args=dict(
-                        session_args, width=B, tail_ms=round(tail_ms, 3),
-                        segments=tot["segments"] - at_start[0],
-                        steps=tot["steps"] - at_start[1],
-                        positions=tot["positions_done"] - at_start[2],
-                    ),
+                    args=dict(session_args, width=B,
+                              tail_ms=round(tail_ms, 3), **spent),
                 )
 
     def _record_occupancy(self, width, steps, live, helpers, refilled,
-                          queue, wall, host_ms=0.0, device_ms=0.0,
+                          queue, host_ms=0.0, device_ms=0.0,
                           transfers=0, phases=None, shard=None,
                           counts=None):
         eng = self.engine
@@ -2092,7 +2096,8 @@ class LaneScheduler:
             # Its sync costs are real, but a no-op segment must not become
             # an occupancy row — consumers weight columns by `steps`, and
             # a refilled lane always steps at least once, so nothing else
-            # is lost by dropping it.
+            # is lost by dropping it. It is counted, as `segments_empty`
+            # in `counts` above.
             tot["host_ms"] += host_ms
             tot["device_ms"] += device_ms
             tot["transfers"] += transfers
@@ -2139,14 +2144,6 @@ class LaneScheduler:
         reg.gauge("fishnet_lanes_live").set(live)
         reg.gauge("fishnet_queue_depth").set(queue)
         reg.histogram("fishnet_boundary_host_ms").observe(host_ms)
-        if eng.trace:
-            eng.trace(
-                f"refill seg={tot['segments']} steps={steps} "
-                f"live={live}/{width} helpers={helpers} idle={idle} "
-                f"refilled={refilled} queue={queue} wall={wall:.3f}s "
-                f"host={host_ms:.1f}ms dev={device_ms:.1f}ms "
-                f"xfers={transfers}"
-            )
 
 
 class _Session:
@@ -2208,6 +2205,9 @@ class _Session:
         self.lane_alpha = np.full(B, -INF, np.int64)
         self.lane_beta = np.full(B, INF, np.int64)
         self.gen = np.zeros(B, np.int32)
+        # ROLE_* of every lane; the base state's lanes search (for a
+        # step or two) until a boundary sees them DONE
+        self.role = np.full(B, ROLE_STRAY, np.int8)
         self.active: List[_RefillJob] = []
 
         # idle base state: budget-0 lanes park in DONE within two steps.
@@ -2284,7 +2284,7 @@ class _Session:
                 nxt = self.dispatch(0, None, True)
             raw_summ = self.stats.fetch(summ, "summary")
             self.residency(meta)
-            n, shard_steps, nodes_row, now = self.lanes(raw_summ)
+            n, shard_steps, nodes_row, now = self.lanes(raw_summ, meta)
             self.reap(now, nodes_row)
             self.admit(now)
             if nxt is None:
@@ -2468,6 +2468,7 @@ class _Session:
                 "shard_live": self.shard_occup(),
                 "shard_refilled": adm_shard,
                 "traced": self.traced_snapshot(),
+                "roles": self.role.copy(),
             }
         meta["t0"] = time.monotonic()
         with self.stats.phase("dispatch", steps=self.seg,
@@ -2476,7 +2477,7 @@ class _Session:
                 from ..parallel.mesh import run_segment_sharded
 
                 # each device advances its shard locally; the summary
-                # arrives stacked (n_shard, local+1, 4)
+                # arrives stacked (n_shard, local + SUM_TAIL, SUM_W)
                 self.state, self.tt, _n, summ = run_segment_sharded(
                     self.mesh, self.eng.params, self.state, self.tt,
                     self.seg, variant=self.variant,
@@ -2491,14 +2492,18 @@ class _Session:
                 )
         return summ, meta
 
-    def lanes(self, raw_summ):
+    def lanes(self, raw_summ, meta: dict):
         """The parks of one boundary, from its packed summary: helper
         lanes that parked on their own are charged and freed, then each
-        parked primary lane gets its verdict (`on_parked`). → (steps,
-        per-shard steps, the (B,) node counts, now)."""
+        parked primary lane gets its verdict (`on_parked`); the
+        segment's lane-steps are counted by the roles `meta` took at
+        dispatch. → (steps, per-shard steps, the (B,) node counts,
+        now)."""
         with self.stats.phase("lanes"):
             summ, n, shard_steps = self.canon_summ(raw_summ)
             self.count_device(raw_summ)
+            self.stats.count(_lane_step_counts(
+                n, summ[:, search_ops.SUM_RUN], meta["roles"]))
             lane_done = summ[:, search_ops.SUM_DONE].astype(bool)
             nodes_row = summ[:, search_ops.SUM_NODES]
             # lanes whose park was already handled at an earlier
@@ -2517,6 +2522,7 @@ class _Session:
                     job.remaining -= hn
                     del job.helpers[lane]
                     lane_owner[lane] = None
+                    self.role[lane] = ROLE_FREE
             # primary lanes that parked: aspiration verdict
             for lane in range(self.B):
                 job = lane_job[lane]
@@ -2529,6 +2535,12 @@ class _Session:
                     int(summ[lane, search_ops.SUM_MOVE]),
                     int(nodes_row[lane]), nodes_row, now,
                 )
+            # a lane let go of and now seen DONE stays so: free — but
+            # not one a staged splice is about to restart
+            seen = lane_done.copy()
+            if staged:
+                seen[list(staged)] = False
+            self.role[(self.role == ROLE_STRAY) & seen] = ROLE_FREE
         return n, shard_steps, nodes_row, now
 
     def pv(self, now: float) -> None:
@@ -2567,7 +2579,6 @@ class _Session:
             self.sched._record_occupancy(
                 self.B, n, meta["live"], meta["helpers"],
                 meta["refilled"], meta["queue"],
-                (snap["host_ms"] + snap["device_ms"]) / 1000.0,
                 snap["host_ms"], snap["device_ms"],
                 snap["transfers"], snap["phases"],
                 counts=snap["counts"],
@@ -2626,6 +2637,8 @@ class _Session:
     def admit_primary(self, job: _RefillJob, lane: int) -> None:
         job.lane = lane
         self.lane_job[lane] = job
+        self.role[lane] = ROLE_PRIMARY
+        job.t_spliced = time.monotonic()
         wp = job.wp
         if wp.ctx:
             obs_inflight.REGISTRY.position(
@@ -2633,7 +2646,6 @@ class _Session:
                 "lane", lane=lane,
             )
         if job.traced:
-            job.t_spliced = time.monotonic()
             rec = obs_trace.RECORDER
             if rec is not None:
                 rec.instant(
@@ -2656,6 +2668,7 @@ class _Session:
         sched = self.sched
         job.helpers[lane] = h
         self.lane_owner[lane] = job
+        self.role[lane] = ROLE_HELPER
         sched._jitter_seq = (sched._jitter_seq & 0xFFFF) + 1
         a, b, _delta = self.window_for(job, 1 << min(h, 4))
         d = min(job.depth + (1 - (h & 1)), job.target_depth)
@@ -2667,9 +2680,11 @@ class _Session:
         work is charged at its last-boundary node count (nodes_row:
         the latest boundary's (B,) per-lane node counts — the work
         actually spent against the position's budget, same honesty
-        rule as _analyse_single's helper charging)."""
+        rule as _analyse_single's helper charging). The lanes turn
+        stray: the device searches on in those not yet DONE."""
         if job.lane >= 0:
             self.lane_job[job.lane] = None
+            self.role[job.lane] = ROLE_STRAY
             job.lane = -1
         for hl in list(job.helpers):
             if nodes_row is not None:
@@ -2677,6 +2692,7 @@ class _Session:
                 job.nodes_total += hn
                 job.remaining -= hn
             self.lane_owner[hl] = None
+            self.role[hl] = ROLE_STRAY
         job.helpers.clear()
 
     def on_parked(self, job: _RefillJob, lane: int, score: int,
@@ -2773,11 +2789,11 @@ class _Session:
                 )
 
     def canon_summ(self, raw):
-        """Boundary summary → ((B, 4) lane rows, step count,
-        per-shard step list). Single-device summaries are (B+1, 4);
-        sharded ones come back stacked (n_shard, local+1, 4) and
-        the step count is the max over shards (devices park
-        independently)."""
+        """Boundary summary → ((B, SUM_W) lane rows, step count,
+        per-shard step list). Single-device summaries are
+        (B + SUM_TAIL, SUM_W) (ops/search.py); sharded ones come back
+        stacked (n_shard, local + SUM_TAIL, SUM_W) and the step count
+        is the max over shards (devices park independently)."""
         B, local = self.B, self.local
         if self.mesh is None:
             return raw[:B], int(raw[B, search_ops.SUM_DONE]), None
@@ -2790,7 +2806,8 @@ class _Session:
     def count_device(self, raw) -> None:
         """The summary's last rows hold what the segment's loop counted,
         movegen, accumulator and eval work (summed over shards): into the
-        interval's snapshot."""
+        interval's snapshot. (The lane rows' SUM_RUN column is read in
+        `lanes`, against the roles the lanes had at dispatch.)"""
         B, local = self.B, self.local
         for row, read in enumerate((search_ops.movegen_counts,
                                     search_ops.acc_counts,

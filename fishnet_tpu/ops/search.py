@@ -80,21 +80,24 @@ MODE_RETURN = 1
 MODE_TRYMOVE = 2
 MODE_DONE = 3
 
-# packed boundary summary (int32, shape (B+3, 4)): everything the host
-# needs to decide a segment boundary — done bitmap plus per-lane
-# nodes/score/best-move — in ONE small transfer instead of the full
-# extract_results set; row B holds the segment's step count and, in
-# columns SUM_COUNTS, its movegen counters (node expansions, the moves
-# their lists hold, the drops among those), row B+1 in the same columns
-# its accumulator counters (perspective updates made, perspectives
-# rebuilt from the board, feature rows gathered), row B+2 which net
-# answered the node expansions' evals (the "dual" scheme's: the small
-# net alone, the small net handed back to the big one, the big net
-# chosen; 0 under every other scheme); `movegen_counts`, `acc_counts`
-# and `eval_counts` read them. PV rows are pulled separately, and only
-# for lanes that actually finished.
-SUM_DONE, SUM_NODES, SUM_SCORE, SUM_MOVE = range(4)
-SUM_W = 4
+# packed boundary summary (int32, shape (B + SUM_TAIL, SUM_W)):
+# everything the host needs to decide a segment boundary — per lane the
+# done flag, nodes, score, best move and SUM_RUN, the steps of the
+# segment in which the lane was not DONE (a DONE lane stays DONE until a
+# refill splice, so a lane runs from the segment's first step and parks
+# once) — in ONE small transfer instead of the full extract_results
+# set; row B holds the segment's step count and, in columns SUM_COUNTS,
+# its movegen counters (node expansions, the moves their lists hold, the
+# drops among those), row B+1 in the same columns its accumulator
+# counters (perspective updates made, perspectives rebuilt from the
+# board, feature rows gathered), row B+2 which net answered the node
+# expansions' evals (the "dual" scheme's: the small net alone, the small
+# net handed back to the big one, the big net chosen; 0 under every
+# other scheme); `movegen_counts`, `acc_counts` and `eval_counts` read
+# them. PV rows are pulled separately, and only for lanes that actually
+# finished.
+SUM_DONE, SUM_NODES, SUM_SCORE, SUM_MOVE, SUM_RUN = range(5)
+SUM_W = 5
 SUM_TAIL = 3  # the rows behind the lanes'
 SUM_COUNTS = slice(1, 4)
 MOVEGEN_COUNTERS = ("movegen_nodes", "movegen_moves", "movegen_drops")
@@ -1088,19 +1091,24 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
             counts = counts.at[1, 2].add(rows)
         return s, counts
 
+    def running(s):
+        return (s.lane[:, LN_MODE] != MODE_DONE).astype(jnp.int32)
+
     if ttab is None:
         step = make_search_step(params, variant)
 
         def body(carry):
-            s, t, i, counts = carry
+            s, t, i, counts, run = carry
+            run = run + running(s)
             s, aux = step(s)
             s, counts = account(s, aux, counts)
-            return s, t, i + 1, counts
+            return s, t, i + 1, counts, run
     else:
         step = make_search_step_tt(params, variant)
 
         def body(carry):
-            s, t, i, counts = carry
+            s, t, i, counts, run = carry
+            run = run + running(s)
             lane = s.lane
             ply = lane[:, LN_PLY]
             btrow = _gather_ply(s.bt, ply)  # one row gather serves all
@@ -1181,15 +1189,16 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
                     jnp.full_like(sval, -1), s.lane[:, LN_SMARK] != 0,
                     prefer_deep=prefer_deep, gen=gen_i,
                 )
-            return s, t, i + 1, counts
+            return s, t, i + 1, counts, run
 
     def cond(carry):
-        s, t, i, counts = carry
+        s, t, i, counts, run = carry
         return (i < segment_steps) & jnp.any(s.lane[:, LN_MODE] != MODE_DONE)
 
-    state, ttab, n, counts = jax.lax.while_loop(
+    state, ttab, n, counts, run = jax.lax.while_loop(
         cond, body,
-        (state, ttab, jnp.int32(0), jnp.zeros((SUM_TAIL, 3), jnp.int32)),
+        (state, ttab, jnp.int32(0), jnp.zeros((SUM_TAIL, 3), jnp.int32),
+         jnp.zeros(state.lane.shape[:1], jnp.int32)),
     )
     lane = state.lane
     summary = jnp.concatenate([
@@ -1198,9 +1207,11 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
             lane[:, LN_NODES],
             lane[:, LN_RSCORE],
             lane[:, LN_RMOVE],
+            run,
         ], axis=1),
         jnp.concatenate([
             jnp.zeros((SUM_TAIL, 1), jnp.int32).at[0, 0].set(n), counts,
+            jnp.zeros((SUM_TAIL, SUM_W - SUM_COUNTS.stop), jnp.int32),
         ], axis=1),
     ], axis=0)
     return state, ttab, n, summary
@@ -1444,8 +1455,9 @@ def search_stream(
     (width must divide evenly) and segment/refill/merge route through
     the shard_map'd callables in parallel.mesh: each device advances and
     resplices ITS lanes locally, the host sees one stacked
-    (ndev, width/ndev + 1, 4) boundary summary per dispatch, and the
-    sharded jits donate state+TT exactly like the single-device path.
+    (ndev, width/ndev + SUM_TAIL, SUM_W) boundary summary per dispatch,
+    and the sharded jits donate state+TT exactly like the single-device
+    path.
     With a mesh, tt must carry a leading (ndev,) shard dim
     (parallel.mesh.make_sharded_table) or be None, and each occupancy
     row gains shard_live / shard_refilled / shard_steps lists (one entry
@@ -1583,10 +1595,11 @@ def search_stream(
             )
 
     def canon_summ(raw):
-        """Boundary summary → ((width, 4) lane rows, step count,
-        per-shard step list). Single-device summaries are (width+1, 4);
-        sharded ones come back stacked (ndev, local+1, 4) and the step
-        count is the max over shards (devices park independently)."""
+        """Boundary summary → ((width, SUM_W) lane rows, step count,
+        per-shard step list). Single-device summaries are
+        (width + SUM_TAIL, SUM_W); sharded ones come back stacked
+        (ndev, local + SUM_TAIL, SUM_W) and the step count is the max
+        over shards (devices park independently)."""
         if mesh is None:
             return raw[:width], int(raw[width, SUM_DONE]), None
         lanes = raw[:, :local, :].reshape(width, SUM_W)

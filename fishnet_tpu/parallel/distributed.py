@@ -173,7 +173,8 @@ def replicate_tree(mesh, tree):
 
 
 def fetch_summary(mesh, p_summ, stats, label: str = "summary"):
-    """The stacked (ndev, local+1, 4) boundary summary, on every host.
+    """The stacked (ndev, local + SUM_TAIL, SUM_W) boundary summary
+    (ops/search.py), on every host.
 
     Single-process: the usual one SyncStats fetch. Multi-process: ONE
     fetch of this process's addressable summary rows (keeping the

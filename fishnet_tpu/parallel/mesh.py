@@ -81,9 +81,11 @@ def _segment_callable(mesh: Mesh, axis: str, has_tt: bool,
 
     segment_steps is a TRACED replicated scalar (retuning never recompiles)
     and tt_gen a per-lane (B,) sharded array. The per-shard packed boundary
-    summary comes back stacked as (ndev, local_B+1, 4) so a no-finish
-    boundary is one small host fetch, and state+TT are donated — a
-    boundary rebinds shard handles instead of copying them."""
+    summary (ops/search.py SUM_*: each lane's row, its run column
+    included, and the shard's tail rows of counters) comes back stacked
+    as (ndev, local_B + SUM_TAIL, SUM_W) so a no-finish boundary is one
+    small host fetch, and state+TT are donated — a boundary rebinds
+    shard handles instead of copying them."""
     from ..ops.search import _run_segment
 
     def seg(params, state, ttab, segment_steps, tt_gen):
@@ -132,8 +134,8 @@ def run_segment_sharded(mesh: Mesh, params, state, ttab, segment_steps: int,
     state: SearchState with lane dim divisible by mesh size. ttab: TTable
     whose arrays carry a leading (n_devices,) shard dim (see
     make_sharded_table), or None. Returns (state, ttab, steps (ndev,),
-    summary (ndev, B/ndev + 1, 4)) — the packed per-shard boundary
-    summary of ops/search._run_segment, stacked over shards.
+    summary (ndev, B/ndev + SUM_TAIL, SUM_W)) — the packed per-shard
+    boundary summary of ops/search._run_segment, stacked over shards.
 
     state and ttab are DONATED: the handles passed in are dead after the
     call and the caller must rebind to the outputs. segment_steps is

@@ -243,13 +243,6 @@ SETTINGS: Tuple[Setting, ...] = (
             "\"none\", or \"auto\" (all on accelerators, none on CPU).",
     ),
     Setting(
-        name="FISHNET_TPU_TRACE",
-        kind="bool",
-        default="0",
-        doc="Per-dispatch / per-depth timing lines to stderr "
-            "(localize compile-vs-run cost from logs).",
-    ),
-    Setting(
         name="FISHNET_TPU_TRACE_DIR",
         kind="str",
         default="",

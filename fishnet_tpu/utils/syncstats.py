@@ -180,8 +180,9 @@ class SyncStats:
         return _Phase(self, name, args)
 
     def count(self, counts: dict) -> None:
-        """Add what the device counted for this interval (the boundary
-        summary's movegen counters) to the interval's `counts`."""
+        """Add what was counted for this interval (the boundary
+        summary's device counters, the lane-steps by role) to the
+        interval's `counts`."""
         table = self._seg_counts
         for name, n in counts.items():
             table[name] = table.get(name, 0) + n

@@ -316,7 +316,7 @@ def test_movegen_counters_tie_out_with_the_oracle(params, variant, fens):
 
 
 def test_counters_wrap_as_unsigned_and_sum_over_shards():
-    row = np.array([[7, -1, 5, 0], [9, 3, -(2 ** 31), 2]], np.int32)
+    row = np.array([[7, -1, 5, 0, 0], [9, 3, -(2 ** 31), 2, 0]], np.int32)
     assert S.movegen_counts(row[0]) == {
         "movegen_nodes": 2 ** 32 - 1, "movegen_moves": 5, "movegen_drops": 0}
     assert S.movegen_counts(row) == {
@@ -352,3 +352,8 @@ def test_engine_totals_carry_the_counters_on_one_device_and_on_a_mesh():
     mesh = make_mesh_engine(refill=True)
     run(mesh, make_chunk(analysis_work(depth=3), n_positions=4))
     assert counters(mesh) == got
+    # the run column rides in every shard's lane rows: each position's
+    # lane searched the same steps, and no free lane ran
+    tot, tot1 = mesh.occupancy_totals, one.occupancy_totals
+    assert tot["lane_steps_primary_run"] == tot1["lane_steps_primary_run"] > 0
+    assert tot["lane_steps_free_run"] == tot1["lane_steps_free_run"] == 0

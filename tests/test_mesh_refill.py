@@ -148,7 +148,7 @@ def test_stream_mesh_occupancy_shard_columns(mesh_streams):
 
 def test_stream_mesh_pipelined_boundary_is_one_transfer(mesh_streams):
     """ISSUE acceptance: a no-finish boundary under a mesh is ONE host
-    transfer — the stacked (ndev, local+1, 4) summary
+    transfer — the stacked (ndev, local + SUM_TAIL, SUM_W) summary
     comes back as a single fetch, not one per shard."""
     occ = mesh_streams["mesh_piped"]["occupancy"]
     nofin = [o for o in occ[:-1] if o["refilled"] == 0]

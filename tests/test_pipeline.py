@@ -105,6 +105,57 @@ def test_no_use_after_donate():
     assert int(np.asarray(n)) > 0
 
 
+def _parking_lanes():
+    """Six lanes that park at different steps: depths 1-2, budgets from
+    a few dozen nodes up."""
+    from fishnet_tpu.ops import search as S
+
+    params, roots, _depth, _budget = _stream_inputs(n=6)
+    depth_arr = np.array([1, 2, 1, 2, 2, 1], np.int32)
+    budget = np.array([40, 400, 5, 60, 10_000, 200], np.int32)
+
+    def fresh():
+        return S._init_state_jit(params, roots, depth_arr, budget, 6,
+                                 "standard")
+    return params, fresh
+
+
+def test_run_column_counts_each_lanes_steps_not_done():
+    """The summary's run column (SUM_RUN) of one segment equals, lane by
+    lane, the steps before which the lane was not DONE, taken by
+    stepping the same lanes one step a segment; and a lane once DONE
+    stays DONE inside the segment, which the count of steps a segment
+    runs after its last primary parks rests on."""
+    from fishnet_tpu.ops import search as S
+
+    params, fresh = _parking_lanes()
+    state, _tt, n, summ = S._run_segment_jit(
+        params, fresh(), None, 10_000, "standard", False)
+    n, summ = int(np.asarray(n)), np.asarray(summ)
+    B = summ.shape[0] - S.SUM_TAIL
+    assert summ.shape == (B + S.SUM_TAIL, S.SUM_W) and summ[B, S.SUM_DONE] == n
+    run = summ[:B, S.SUM_RUN]
+    assert summ[:B, S.SUM_DONE].all() and run.max() == n
+    assert len(set(run.tolist())) > 2  # the lanes parked at different steps
+    assert (summ[B:, S.SUM_RUN] == 0).all()  # the tail rows hold none
+
+    state = fresh()
+    counted = np.zeros(B, np.int64)
+    done_before = np.zeros(B, bool)
+    for _ in range(n):
+        done = np.asarray(state.lane)[:, S.LN_MODE] == S.MODE_DONE
+        assert (done | ~done_before).all(), "a DONE lane left DONE"
+        done_before = done
+        counted += ~done
+        state, _tt, one, step_summ = S._run_segment_jit(
+            params, state, None, 1, "standard", False)
+        step_summ = np.asarray(step_summ)
+        assert int(np.asarray(one)) == 1
+        np.testing.assert_array_equal(step_summ[:B, S.SUM_RUN], ~done)
+    assert (np.asarray(state.lane)[:, S.LN_MODE] == S.MODE_DONE).all()
+    np.testing.assert_array_equal(counted, run)
+
+
 # --------------------------------------------------------- engine level
 
 

@@ -306,6 +306,101 @@ def test_occupancy_accounting():
         + totals["idle_lane_steps"])
 
 
+def test_lane_step_counts_by_role():
+    """One segment's lane-steps from its run column and the roles the
+    lanes had at dispatch (engine/tpu.py _lane_step_counts)."""
+    from fishnet_tpu.engine.tpu import (
+        ROLE_FREE, ROLE_HELPER, ROLE_PRIMARY, ROLE_STRAY, _lane_step_counts)
+
+    roles = np.array([ROLE_PRIMARY, ROLE_HELPER, ROLE_HELPER, ROLE_PRIMARY,
+                      ROLE_STRAY, ROLE_FREE], np.int8)
+    run = np.array([40, 100, 7, 60, 2, 0], np.int32)
+    assert _lane_step_counts(100, run, roles) == {
+        "lane_steps_assigned": 400, "lane_steps_primary_run": 100,
+        "lane_steps_helper_run": 107, "lane_steps_stray_run": 2,
+        "lane_steps_free_run": 0, "steps_after_last_primary": 40,
+        "segments_empty": 0,
+    }
+    # helpers only: no primary, no tail; a dispatch that ran no step
+    helpers = np.full(6, ROLE_HELPER, np.int8)
+    assert _lane_step_counts(100, run, helpers)["steps_after_last_primary"] == 0
+    empty = _lane_step_counts(0, np.zeros(6, np.int32), roles)
+    assert empty["segments_empty"] == 1 and empty["lane_steps_assigned"] == 0
+
+
+def make_helper_engine(helpers):
+    """A refill engine whose positions get up to `helpers` - 1 helper
+    lanes: single-device, with a single-device table to share."""
+    from fishnet_tpu.ops import tt as tt_mod
+
+    engine = make_refill_engine(max_depth=3, helper_lanes=helpers)
+    engine.tt = tt_mod.make_table(12)
+    engine.helper_lanes = helpers
+    return engine
+
+
+@pytest.mark.parametrize("helpers", [1, 2])
+def test_lane_steps_tie_out(helpers):
+    """A refill session's lane-steps by role: the primary and helper
+    lanes ran no more than they were assigned, a free lane never ran,
+    the tail after a segment's last primary is part of its steps, and
+    the positions' queue wait is part of their time in the engine."""
+    engine = make_helper_engine(helpers)
+    responses = run(engine, make_chunk(analysis_work(depth=3)))
+    tot = engine.occupancy_totals
+    assert tot["lane_steps_assigned"] == (
+        tot["live_lane_steps"] + tot["helper_lane_steps"])
+    assert 0 < tot["lane_steps_primary_run"]
+    assert (tot["lane_steps_primary_run"] + tot["lane_steps_helper_run"]
+            <= tot["lane_steps_assigned"])
+    assert tot["lane_steps_free_run"] == 0
+    assert 0 <= tot["steps_after_last_primary"] <= tot["steps"]
+    run_total = sum(tot[f"lane_steps_{role}_run"]
+                    for role in ("primary", "helper", "stray", "free"))
+    assert run_total <= tot["lane_steps"]
+    assert 0.0 <= tot["position_queue_ms"] <= tot["position_engine_ms"]
+    assert tot["positions_finalized"] == len(responses) == tot["positions_done"]
+    if helpers == 1:
+        assert tot["lane_steps_helper_run"] == 0
+        assert tot["steps_after_last_primary"] == 0  # no lane outlasts them
+    else:
+        assert tot["lane_steps_helper_run"] > 0
+
+
+def test_lane_steps_ride_the_segment_and_session_spans():
+    """With a recorder on, each `segment` span carries its segment's
+    lane-step counts and each `session` span its session's, and both
+    add up to the totals; tools/trace_report.py prints them."""
+    rec = obs_trace.install(obs_trace.TraceRecorder(capacity=16384))
+    try:
+        engine = make_helper_engine(2)
+        run(engine, make_chunk(analysis_work(depth=3)))
+    finally:
+        obs_trace.uninstall()
+    from fishnet_tpu.engine.tpu import LANE_STEP_COUNTERS
+    from tools import trace_report
+
+    tot, events = engine.occupancy_totals, rec.snapshot()
+    segments = [e["args"] for e in events if e["name"] == "segment"]
+    sessions = [e["args"] for e in events if e["name"] == "session"]
+    assert segments and sessions
+    for name in LANE_STEP_COUNTERS:
+        assert sum(a["counts"][name] for a in segments) == tot[name], name
+        assert sum(a[name] for a in sessions) == tot[name], name
+    for name in ("positions_finalized", "position_queue_ms",
+                 "position_engine_ms"):
+        assert sum(a[name] for a in sessions) == pytest.approx(tot[name], abs=0.01)
+    report = trace_report.summarize(events)
+    lanes = report["segments"]["lane_steps"]
+    assert lanes["parked"] == (tot["lane_steps_assigned"]
+                               - tot["lane_steps_primary_run"]
+                               - tot["lane_steps_helper_run"])
+    assert not set(LANE_STEP_COUNTERS) & set(report["segments"]["counts"])
+    text = trace_report.render_text(report)
+    assert f"parked {lanes['parked']} " in text
+    assert f"empty dispatches {tot['segments_empty']}" in text
+
+
 def test_search_stream_matches_batch():
     """Ops-level: streaming N positions through a narrower width yields
     the same per-position results as one full-width batch (no TT)."""

@@ -19,8 +19,15 @@ measurement items need without opening Perfetto:
   lanes a splice and ms a splice, from the `lanes` argument of the
   `phase.refill` spans (the engine's `refill_splices`, `refills` and
   `phase_refill_ms` are the same three over a window).
+- **lane-steps**: the segments' lane-steps by the role each lane had at
+  dispatch (the `lane_steps_*`, `steps_after_last_primary` and
+  `segments_empty` counts in the `segment` spans' args, engine/tpu.py
+  LANE_STEP_COUNTERS): parked lane-steps (a primary or helper lane DONE
+  while the segment ran on), the steps run after a segment's last
+  primary parked, and the dispatches that ran no step.
 - **sessions**: one row per `session` span — the width the drive
-  session got, what was pending when it chose, set-up, segments.
+  session got, what was pending when it chose, set-up, segments, and
+  its parked lane-steps, tail steps and empty dispatches.
 - **boundary-gap histogram**: the distribution of gaps between
   consecutive `segment` spans on the host timeline — the fixed
   per-boundary cost itself, bucketed.
@@ -63,6 +70,23 @@ from typing import Dict, List, Optional
 
 # gap buckets in milliseconds (upper bounds; the last is open-ended)
 GAP_BUCKETS_MS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 50.0, 250.0)
+# the scheduler's lane-step counts among a `segment` span's `counts`
+# (engine/tpu.py LANE_STEP_COUNTERS); the rest are the device's
+LANE_STEPS = (
+    "lane_steps_assigned", "lane_steps_primary_run", "lane_steps_helper_run",
+    "lane_steps_stray_run", "lane_steps_free_run", "steps_after_last_primary",
+    "segments_empty",
+)
+
+
+def _parked(counts: dict) -> Optional[int]:
+    """Lane-steps a primary or helper lane sat DONE, where the counts
+    have them."""
+    if "lane_steps_assigned" not in counts:
+        return None
+    return (int(counts["lane_steps_assigned"])
+            - int(counts.get("lane_steps_primary_run", 0))
+            - int(counts.get("lane_steps_helper_run", 0)))
 
 
 def load_events(path: str) -> List[dict]:
@@ -112,11 +136,15 @@ def summarize(events: List[dict]) -> dict:
         float((a.get("phases") or {}).get("other", 0.0)) for a in seg_args
     )
     # what the device counted in the segments' loops (ops/search.py
-    # SEGMENT_COUNTERS: movegen_*, acc_*, eval_*), where the trace has them
+    # SEGMENT_COUNTERS: movegen_*, acc_*, eval_*) and the lane-steps by
+    # role (LANE_STEPS), where the trace has them
     seg_counts: Dict[str, int] = defaultdict(int)
     for a in seg_args:
         for name, n in (a.get("counts") or {}).items():
             seg_counts[name] += int(n)
+    lane_steps = {k: seg_counts.pop(k) for k in LANE_STEPS if k in seg_counts}
+    if lane_steps:
+        lane_steps["parked"] = _parked(lane_steps)
     boundary: Dict[str, dict] = defaultdict(
         lambda: {"count": 0, "total_ms": 0.0}
     )
@@ -149,7 +177,8 @@ def summarize(events: List[dict]) -> dict:
     sessions = [
         dict(e.get("args") or {},
              start_ms=round((float(e.get("ts", 0.0)) - t_first) / 1000.0, 3),
-             dur_ms=round(float(e.get("dur", 0.0)) / 1000.0, 3))
+             dur_ms=round(float(e.get("dur", 0.0)) / 1000.0, 3),
+             parked=_parked(e.get("args") or {}))
         for e in sorted(_spans(events, "session"),
                         key=lambda e: float(e.get("ts", 0.0)))
     ]
@@ -209,6 +238,7 @@ def summarize(events: List[dict]) -> dict:
             "span_device_ms": round(span_device, 3),
             "span_host_ms": round(span_host + args_other, 3),
             "counts": dict(seg_counts),
+            "lane_steps": lane_steps,
         },
         "boundary_phases": {
             name: {
@@ -504,6 +534,19 @@ def render_text(report: dict) -> str:
         if seg.get("counts"):
             lines.append("  counted on the device: " + "  ".join(
                 f"{name} {n}" for name, n in seg["counts"].items()))
+        ls = seg.get("lane_steps")
+        if ls:
+            assigned = ls["lane_steps_assigned"]
+            lines.append(
+                f"  lane-steps: assigned {assigned}, run by primary "
+                f"{ls.get('lane_steps_primary_run', 0)} / helper "
+                f"{ls.get('lane_steps_helper_run', 0)} / stray "
+                f"{ls.get('lane_steps_stray_run', 0)} / free "
+                f"{ls.get('lane_steps_free_run', 0)}; parked {ls['parked']} "
+                f"({ls['parked'] / assigned if assigned else 0.0:.1%} of "
+                f"assigned); tail steps "
+                f"{ls.get('steps_after_last_primary', 0)}; empty dispatches "
+                f"{ls.get('segments_empty', 0)}")
     if report["boundary_phases"]:
         lines += [
             "",
@@ -526,15 +569,19 @@ def render_text(report: dict) -> str:
             "",
             f"{'session at ms':>14} {'dur_ms':>10} {'width':>6} "
             f"{'pending':>8} {'setup_ms':>9} {'segments':>9} "
-            f"{'positions':>10}",
+            f"{'positions':>10} {'parked':>9} {'tail':>7} {'empty':>6}",
         ]
         for row in report["sessions"]:
+            parked = row.get("parked")
             lines.append(
                 f"{row['start_ms']:>14.3f} {row['dur_ms']:>10.3f} "
                 f"{row.get('width', 0):>6} {row.get('pending', 0):>8} "
                 f"{row.get('setup_ms', 0.0):>9.3f} "
                 f"{row.get('segments', 0):>9} "
-                f"{row.get('positions', 0):>10}"
+                f"{row.get('positions', 0):>10} "
+                f"{'-' if parked is None else parked:>9} "
+                f"{row.get('steps_after_last_primary', '-'):>7} "
+                f"{row.get('segments_empty', '-'):>6}"
             )
     gaps = report["boundary_gaps"]
     if gaps["count"]:
