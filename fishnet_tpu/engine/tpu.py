@@ -293,17 +293,23 @@ ROLE_FREE, ROLE_PRIMARY, ROLE_HELPER, ROLE_STRAY = range(4)
 # a segment's lane-steps by those roles, from the summary's run column
 # (ops/search.py SUM_RUN): the lane-steps of primary and helper lanes,
 # the steps each kind of lane ran, the steps the segment ran after its
-# last primary lane parked, and dispatches that ran no step at all
+# last primary lane parked, dispatches that ran no step at all, and
+# segments that the watched-lane stop ended (ops/search.py _run_segment:
+# the session watches its primaries)
 LANE_STEP_COUNTERS = (
     "lane_steps_assigned", "lane_steps_primary_run",
     "lane_steps_helper_run", "lane_steps_stray_run", "lane_steps_free_run",
-    "steps_after_last_primary", "segments_empty",
+    "steps_after_last_primary", "segments_empty", "segments_primary_stop",
 )
 
 
-def _lane_step_counts(n: int, run: np.ndarray, roles: np.ndarray) -> dict:
+def _lane_step_counts(n: int, run: np.ndarray, roles: np.ndarray,
+                      done: np.ndarray, cap: int) -> dict:
     """One segment's LANE_STEP_COUNTERS: n its steps, run the (B,)
-    steps each lane was not DONE, roles the (B,) ROLE_* at dispatch."""
+    steps each lane was not DONE, roles the (B,) ROLE_* at dispatch,
+    done the (B,) DONE flags at its end, cap its step limit. A segment
+    stopped at its last primary when it ran, some lane still runs, every
+    primary is DONE and the cap did not bind."""
     primary = roles == ROLE_PRIMARY
     helper = roles == ROLE_HELPER
     return {
@@ -315,6 +321,9 @@ def _lane_step_counts(n: int, run: np.ndarray, roles: np.ndarray) -> dict:
         "steps_after_last_primary":
             n - int(run[primary].max()) if primary.any() else 0,
         "segments_empty": int(n == 0),
+        "segments_primary_stop": int(
+            0 < n < cap and bool(primary.any()) and bool(done[primary].all())
+            and not done.all()),
     }
 
 
@@ -523,8 +532,9 @@ class TpuEngine(ChunkSubmit):
             # SEGMENT_COUNTERS)
             **{name: 0 for name in search_ops.SEGMENT_COUNTERS},
             # the same segments' lane-steps by the role each lane had at
-            # dispatch (LANE_STEP_COUNTERS), and the speculative
-            # dispatches that found every lane parked
+            # dispatch (LANE_STEP_COUNTERS), the speculative dispatches
+            # that found every lane parked, and the segments that ended
+            # when their last primary lane parked
             **{name: 0 for name in LANE_STEP_COUNTERS},
             # the same boundary intervals by what the host was doing
             # (SyncStats.phase): sums to host_ms + device_ms, and
@@ -2212,16 +2222,18 @@ class _Session:
 
         # idle base state: budget-0 lanes park in DONE within two steps.
         # Built from host rows, like every refill after it: one call
-        # whose operands are numpy at the session's width
+        # whose operands are numpy at the session's width; `idle` splices
+        # the same row into one lane
+        self.filler = filler
+        self.idle_hh = np.zeros((MAX_HIST, 2), np.uint32)
+        self.idle_hm = np.full(MAX_HIST, HIST_HM_SENTINEL, np.int32)
         with syncstats.step("session_setup"):
             self.state = search_ops._init_state_jit(
                 eng.params, stack_fields([filler] * B),
                 np.zeros(B, np.int32), np.zeros(B, np.int32),
                 MAX_PLY, variant,
-                hist_hash=np.zeros((B, MAX_HIST, 2), np.uint32),
-                hist_halfmove=np.full(
-                    (B, MAX_HIST), HIST_HM_SENTINEL, np.int32
-                ),
+                hist_hash=np.stack([self.idle_hh] * B),
+                hist_halfmove=np.stack([self.idle_hm] * B),
                 root_alpha=np.full((B,), -INF, np.int32),
                 root_beta=np.full((B,), INF, np.int32),
                 order_jitter=np.zeros((B,), np.int32),
@@ -2248,6 +2260,10 @@ class _Session:
         # the packed summary
         self.pv_pending: List[tuple] = []
         self.last_device_s = 0.0
+        # the last boundary's (B,) per-lane node counts (`left`), and
+        # the node budget each lane was last staged with (`share`)
+        self.nodes_row = None
+        self.lane_budget = np.zeros(B, np.int64)
 
         # the session's boundary intervals open here: what came before
         # is set-up, and the first admission and refill are phases of
@@ -2342,11 +2358,13 @@ class _Session:
         """Admit pending positions, earliest deadline first, then spend
         leftover free lanes on Lazy-SMP helpers.
 
-        Free lanes are tracked per shard and every admission lands on
+        Free lanes are tracked per shard and every position lands on
         the shard with the most free lanes (ties → lowest shard),
         hardest-deadline-first within the boundary, so queued positions
         spread across devices instead of piling onto shard 0's early
-        lanes. With one shard this is exactly the historical
+        lanes; its helpers land beside it while that shard has room.
+        A lane let go of that still searches, and is not reused here,
+        gets the idle row. With one shard this is exactly the historical
         ascending-lane assignment (one list, front pops) — the
         single-device bit-identity contract holds."""
         sched = self.sched
@@ -2361,11 +2379,17 @@ class _Session:
                         free_by_shard[i // local].append(i)
             n_free = sum(len(f) for f in free_by_shard)
 
-            def take_lane() -> int:
-                s = max(
-                    range(self.n_shard),
-                    key=lambda i: len(free_by_shard[i])
-                )
+            def take_lane(near: int = -1) -> int:
+                # a helper goes to its primary's shard while it has
+                # room: each shard's segment stops at its own watched
+                # lanes, so one that holds only helpers would run on
+                # until they park, and the boundary waits for it
+                s = near // local if near >= 0 else -1
+                if s < 0 or not free_by_shard[s]:
+                    s = max(
+                        range(self.n_shard),
+                        key=lambda i: len(free_by_shard[i])
+                    )
                 return free_by_shard[s].pop(0)
 
             if not self.entry.event.is_set():
@@ -2395,7 +2419,7 @@ class _Session:
                 n_act = len(active)
                 cur = sum(len(j.helpers) for j in active)
                 hardness = [
-                    j.hardness if j.remaining > 0 else 0
+                    j.hardness if self.left(j) > 0 else 0
                     for j in active
                 ]
                 plan = TpuEngine._plan_helpers(
@@ -2407,9 +2431,27 @@ class _Session:
                 for r, job in enumerate(active):
                     while n_free and len(job.helpers) < want.get(r, 0):
                         self.admit_helper(
-                            job, take_lane(), len(job.helpers) + 1
+                            job, take_lane(job.lane), len(job.helpers) + 1
                         )
                         n_free -= 1
+            # a primary staged at this boundary runs beside the helpers
+            # placed above: its budget is settled against theirs
+            adm = self.adm
+            for job in active:
+                if job.helpers and job.lane in adm["lane"]:
+                    i = adm["lane"].index(job.lane)
+                    budget = int(np.clip(
+                        self.share(job, job.lane), 1, 2**31 - 1))
+                    adm["budget"][i] = self.lane_budget[job.lane] = budget
+            # a lane let go of while it still searched, and not reused
+            # above, is stopped at the next splice: no node is spent on
+            # a position past its last charge (`release`). Before the
+            # first boundary the stray lanes are the idle base state's
+            if self.nodes_row is not None:
+                staged = set(self.adm["lane"])
+                for lane in np.flatnonzero(self.role == ROLE_STRAY):
+                    if int(lane) not in staged:
+                        self.idle(int(lane))
 
     def refill(self):
         """Flush the staged admissions in ONE refill splice: the staged
@@ -2471,6 +2513,10 @@ class _Session:
                 "roles": self.role.copy(),
             }
         meta["t0"] = time.monotonic()
+        # the segment ends when its last primary lane parks (or at the
+        # cap): a helper still searching carries on in the next segment
+        # and holds no position's next iteration
+        watch = meta["roles"] == ROLE_PRIMARY
         with self.stats.phase("dispatch", steps=self.seg,
                               speculative=speculative):
             if self.mesh is not None:
@@ -2482,13 +2528,13 @@ class _Session:
                     self.mesh, self.eng.params, self.state, self.tt,
                     self.seg, variant=self.variant,
                     prefer_deep=self.prefer_deep,
-                    tt_gen=jnp.asarray(self.gen),
+                    tt_gen=jnp.asarray(self.gen), watch=watch,
                 )
             else:
                 self.state, self.tt, _n, summ = search_ops._run_segment_jit(
                     self.eng.params, self.state, self.tt, self.seg,
                     self.variant, False, self.prefer_deep,
-                    jnp.asarray(self.gen),
+                    jnp.asarray(self.gen), jnp.asarray(watch),
                 )
         return summ, meta
 
@@ -2502,10 +2548,11 @@ class _Session:
         with self.stats.phase("lanes"):
             summ, n, shard_steps = self.canon_summ(raw_summ)
             self.count_device(raw_summ)
-            self.stats.count(_lane_step_counts(
-                n, summ[:, search_ops.SUM_RUN], meta["roles"]))
             lane_done = summ[:, search_ops.SUM_DONE].astype(bool)
-            nodes_row = summ[:, search_ops.SUM_NODES]
+            self.stats.count(_lane_step_counts(
+                n, summ[:, search_ops.SUM_RUN], meta["roles"],
+                lane_done, self.seg))
+            nodes_row = self.nodes_row = summ[:, search_ops.SUM_NODES]
             # lanes whose park was already handled at an earlier
             # speculative boundary (admission staged, splice still
             # pending) report DONE again — skip them
@@ -2618,7 +2665,9 @@ class _Session:
         adm["lane"].append(lane)
         adm["board"].append(board)
         adm["depth"].append(depth)
-        adm["budget"].append(int(np.clip(budget, 1, 2**31 - 1)))
+        budget = int(np.clip(budget, 1 if depth else 0, 2**31 - 1))
+        adm["budget"].append(budget)
+        self.lane_budget[lane] = budget
         adm["alpha"].append(alpha)
         adm["beta"].append(beta)
         adm["jitter"].append(jit)
@@ -2633,6 +2682,12 @@ class _Session:
         eng = self.eng
         eng._tt_gen = (eng._tt_gen + 1) & 0x3FFFFFFF
         self.gen[lane] = eng._tt_gen
+
+    def idle(self, lane: int) -> None:
+        """Stage the idle row on a lane: the base state's depth 0 and
+        budget 0 (it parks in DONE within two steps)."""
+        self.stage(lane, self.filler, 0, 0, -INF, INF, 0, 0,
+                   self.idle_hh, self.idle_hm)
 
     def admit_primary(self, job: _RefillJob, lane: int) -> None:
         job.lane = lane
@@ -2657,7 +2712,7 @@ class _Session:
                 )
                 rec.flow("request", wp.ctx["trace_id"], "t")
         a, b, _delta = self.window_for(job, 1)
-        self.stage(lane, job.board, job.depth, job.remaining, a, b,
+        self.stage(lane, job.board, job.depth, self.left(job), a, b,
                    0, lane, job.hh, job.hm)
 
     def admit_helper(self, job: _RefillJob, lane: int, h: int) -> None:
@@ -2666,14 +2721,57 @@ class _Session:
         # even h one ply deeper; staggered window scale; nonzero
         # unique jitter; group = primary lane
         sched = self.sched
+        budget = self.share(job, lane)
         job.helpers[lane] = h
         self.lane_owner[lane] = job
         self.role[lane] = ROLE_HELPER
         sched._jitter_seq = (sched._jitter_seq & 0xFFFF) + 1
         a, b, _delta = self.window_for(job, 1 << min(h, 4))
         d = min(job.depth + (1 - (h & 1)), job.target_depth)
-        self.stage(lane, job.board, d, job.remaining, a, b,
+        self.stage(lane, job.board, d, budget, a, b,
                    sched._jitter_seq, job.lane, job.hh, job.hm)
+
+    def left(self, job: _RefillJob) -> int:
+        """The job's node budget left for a decision: `remaining`, less
+        the nodes its helper lanes still searching had spent by the last
+        boundary (a helper is charged when it parks or is let go of). A
+        segment that ran until every lane parked left none of them; one
+        that ends at its last primary's park can. Lanes staged for a
+        splice have not started."""
+        row = self.nodes_row
+        if row is None or not job.helpers:
+            return job.remaining
+        staged = self.adm["lane"]
+        return job.remaining - sum(
+            int(row[h]) for h in job.helpers if h not in staged)
+
+    def share(self, job: _RefillJob, lane: int) -> int:
+        """The node budget for `lane`, about to be staged for the job:
+        what is `left`, less what the job's other lanes still searching
+        will spend beside it. In lockstep a lane spends nodes at about
+        the rate of any other, up to what is left of its own budget
+        (c), so the lane gets the x with x + sum(min(x, c)) = left."""
+        budget = self.left(job)
+        row = self.nodes_row
+        if row is None:
+            return budget
+        staged = self.adm["lane"]
+        caps = []
+        for other in (job.lane, *job.helpers):
+            if other == lane or other < 0:
+                continue
+            spent = 0 if other in staged else int(row[other])
+            if other == job.lane:
+                budget -= spent  # `left` charges a primary at its park
+            caps.append(int(self.lane_budget[other]) - spent)
+        caps.sort()
+        k = len(caps)
+        for i, c in enumerate(caps):
+            x = budget // (1 + k - i)
+            if x <= c:
+                return x
+            budget -= max(c, 0)
+        return budget
 
     def release(self, job: _RefillJob, nodes_row) -> None:
         """Free the job's primary + helper lanes; mid-flight helper
@@ -2681,7 +2779,8 @@ class _Session:
         the latest boundary's (B,) per-lane node counts — the work
         actually spent against the position's budget, same honesty
         rule as _analyse_single's helper charging). The lanes turn
-        stray: the device searches on in those not yet DONE."""
+        stray: those not yet DONE that `admit` does not reuse get the
+        idle row at the next splice, so they spend nothing uncharged."""
         if job.lane >= 0:
             self.lane_job[job.lane] = None
             self.role[job.lane] = ROLE_STRAY
@@ -2724,7 +2823,7 @@ class _Session:
             # the lane stays this job's — only its window changes
             job.delta_idx += 1
             a, b, _d = self.window_for(job, 1)
-            self.stage(lane, job.board, job.depth, job.remaining, a, b,
+            self.stage(lane, job.board, job.depth, self.share(job, lane), a, b,
                        0, lane, job.hh, job.hm)
             return
         # depth complete: record, charge the depth's nodes, advance
@@ -2740,7 +2839,7 @@ class _Session:
         job.best_move = _decode_uci(move) if move >= 0 else None
         final = (
             job.depth >= job.target_depth
-            or job.remaining <= 0
+            or self.left(job) <= 0
             or now >= job.deadline
         )
         self.pv_pending.append((job, lane, job.depth, final))
@@ -2750,7 +2849,7 @@ class _Session:
             return  # _finalize waits in pv for the PV row
         job.depth += 1
         a, b, _d = self.window_for(job, 1)
-        self.stage(lane, job.board, job.depth, job.remaining, a, b,
+        self.stage(lane, job.board, job.depth, self.share(job, lane), a, b,
                    0, lane, job.hh, job.hm)
 
     # --------------------------------------- what a boundary records

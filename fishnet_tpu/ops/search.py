@@ -1065,13 +1065,23 @@ def _gather_ply(arr: jnp.ndarray, ply: jnp.ndarray) -> jnp.ndarray:
 def _run_segment(params: nnue.NnueParams, state: SearchState,
                  ttab, segment_steps: int, variant: str = "standard",
                  deep_tt: bool = False, prefer_deep: bool = False,
-                 tt_gen=0):
+                 tt_gen=0, watch=None):
     """Advance all lanes ≤ segment_steps. ttab: shared tt.TTable or None.
     deep_tt (STATIC): accept deeper LOWER/UPPER TT entries as cutoffs
     (move-job strength mode — see ops/tt.py probe).
     prefer_deep (STATIC) + tt_gen (traced): helper-lane dispatches store
     under the depth-preferred generation-aware replacement policy
     (ops/tt.py store) so helper writes don't evict primary-path entries.
+
+    The segment runs while some lane is not DONE, for at most
+    segment_steps steps. watch (traced (B,) bool, optional): the lanes
+    whose parking ends the segment — where it marks any lane, the
+    segment also stops on the step after which every watched lane is
+    DONE (the scheduler watches its primary lanes, so a helper cannot
+    hold a position's next iteration). The lanes left running are not
+    touched: the next segment carries on from their state, so two
+    segments back to back compute bit for bit what one longer segment
+    computes. With watch absent or all False the rule is the plain one.
 
     The TT lives OUTSIDE the vmap: each iteration first stores every lane
     parked in RETURN (its finished node's value), then probes every lane
@@ -1191,9 +1201,14 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
                 )
             return s, t, i + 1, counts, run
 
+    watched = (jnp.zeros(state.lane.shape[:1], jnp.bool_) if watch is None
+               else jnp.asarray(watch, jnp.bool_))
+
     def cond(carry):
         s, t, i, counts, run = carry
-        return (i < segment_steps) & jnp.any(s.lane[:, LN_MODE] != MODE_DONE)
+        live = s.lane[:, LN_MODE] != MODE_DONE
+        return ((i < segment_steps) & jnp.any(live)
+                & (~jnp.any(watched) | jnp.any(watched & live)))
 
     state, ttab, n, counts, run = jax.lax.while_loop(
         cond, body,
@@ -1219,10 +1234,12 @@ def _run_segment(params: nnue.NnueParams, state: SearchState,
 
 # segment_steps is TRACED (an int32 operand of the while cond), not
 # static: callers pass different lengths (FISHNET_TPU_SEGMENT, a test's
-# own, search_batch's max_steps) to one program. state and ttab are
-# DONATED — chained segments alias the multi-MB tables in place instead of
-# copying them, so a caller must treat the arguments it passed as
-# consumed and continue from the returned state/ttab only.
+# own, search_batch's max_steps) to one program. watch is a traced
+# operand too: the scheduler's sessions pass one every segment, and a
+# caller that passes none gets an all-False mask, the plain rule. state
+# and ttab are DONATED — chained segments alias the multi-MB tables in
+# place instead of copying them, so a caller must treat the arguments it
+# passed as consumed and continue from the returned state/ttab only.
 _run_segment_jit = _aot_registry.wrap(
     "run_segment",
     jax.jit(

@@ -80,7 +80,10 @@ def _segment_callable(mesh: Mesh, axis: str, has_tt: bool,
     (reference: src/main.rs:151-161).
 
     segment_steps is a TRACED replicated scalar (retuning never recompiles)
-    and tt_gen a per-lane (B,) sharded array. The per-shard packed boundary
+    and tt_gen and watch per-lane (B,) sharded arrays: each shard applies
+    the watched-lane stop (ops/search.py _run_segment) to its own lanes,
+    so a shard with no watched lane keeps the plain rule, and no step
+    waits on another device. The per-shard packed boundary
     summary (ops/search.py SUM_*: each lane's row, its run column
     included, and the shard's tail rows of counters) comes back stacked
     as (ndev, local_B + SUM_TAIL, SUM_W) so a no-finish boundary is one
@@ -88,12 +91,12 @@ def _segment_callable(mesh: Mesh, axis: str, has_tt: bool,
     shard handles instead of copying them."""
     from ..ops.search import _run_segment
 
-    def seg(params, state, ttab, segment_steps, tt_gen):
+    def seg(params, state, ttab, segment_steps, tt_gen, watch):
         if ttab is not None:
             ttab = jax.tree.map(lambda a: a[0], ttab)  # (1, N) block → (N,)
         state, ttab, n, summ = _run_segment(
             params, state, ttab, segment_steps, variant, deep_tt,
-            prefer_deep, tt_gen,
+            prefer_deep, tt_gen, watch,
         )
         if ttab is not None:
             ttab = jax.tree.map(lambda a: a[None], ttab)
@@ -128,7 +131,7 @@ def _segment_callable(mesh: Mesh, axis: str, has_tt: bool,
 def run_segment_sharded(mesh: Mesh, params, state, ttab, segment_steps: int,
                         axis: str = "dp", variant: str = "standard",
                         deep_tt: bool = False, prefer_deep: bool = False,
-                        tt_gen=0):
+                        tt_gen=0, watch=None):
     """Advance a sharded search ≤ segment_steps on every device.
 
     state: SearchState with lane dim divisible by mesh size. ttab: TTable
@@ -141,7 +144,9 @@ def run_segment_sharded(mesh: Mesh, params, state, ttab, segment_steps: int,
     call and the caller must rebind to the outputs. segment_steps is
     traced, so retuning the segment length reuses the compiled program.
     prefer_deep/tt_gen: helper-lane TT store policy (ops/tt.py store);
-    tt_gen may be a scalar or a per-lane (B,) array."""
+    tt_gen may be a scalar or a per-lane (B,) array. watch: the (B,)
+    lanes whose parking ends a shard's segment, or None for none (one
+    program either way: None is an all-False mask)."""
     import jax.numpy as jnp
 
     from . import distributed as _distributed
@@ -153,6 +158,8 @@ def run_segment_sharded(mesh: Mesh, params, state, ttab, segment_steps: int,
     gen = jnp.asarray(tt_gen, jnp.int32)
     if gen.ndim == 0:
         gen = jnp.full((B,), gen, jnp.int32)
+    watch = (np.zeros(B, bool) if watch is None
+             else np.asarray(watch, bool))
     steps = jnp.int32(segment_steps)
     if _distributed.spans_processes(mesh):
         # host-local scalars/arrays must be promoted to global arrays
@@ -162,7 +169,9 @@ def run_segment_sharded(mesh: Mesh, params, state, ttab, segment_steps: int,
             mesh, gen, _partition.spec_for("tt_gen", axis))
         steps = _distributed.put_global(
             mesh, steps, _partition.spec_for("segment_steps", axis))
-    return fn(params, state, ttab, steps, gen)
+        watch = _distributed.put_global(
+            mesh, watch, _partition.spec_for("watch", axis))
+    return fn(params, state, ttab, steps, gen, watch)
 
 
 @functools.lru_cache(maxsize=None)

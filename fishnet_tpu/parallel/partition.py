@@ -23,10 +23,10 @@ Layout, in one screen:
     feature-transform width (`PARAM_RULES_TP`, the training layout).
   * transposition table (TTable.data, (ndev, N, 4)) — leading shard dim
     over ``dp``: each device hashes into its private shard.
-  * boundary plumbing — per-lane ``tt_gen`` and splice ``mask`` shard
-    with the lanes; the traced ``segment_steps`` scalar is replicated;
-    per-shard ``steps`` and the packed boundary ``summary`` come back
-    sharded over ``dp``.
+  * boundary plumbing — per-lane ``tt_gen``, the segment's ``watch``
+    mask and the splice ``mask`` shard with the lanes; the traced
+    ``segment_steps`` scalar is replicated; per-shard ``steps`` and the
+    packed boundary ``summary`` come back sharded over ``dp``.
 
 fishnet-lint's `mesh-unregistered-spec` rule (lint/mesh_rules.py) pins
 spec construction to this module + mesh.py, so a new sharded callable
@@ -93,6 +93,7 @@ PARAM_RULES_TP: Tuple[Rule, ...] = (
 # boundary plumbing of the segment/merge callables
 AUX_RULES: Tuple[Rule, ...] = (
     (r"(^|/)tt_gen$", P("dp")),        # per-lane TT generation tags
+    (r"(^|/)watch$", P("dp")),         # (B,) lanes whose parking ends a segment
     (r"(^|/)segment_steps$", P()),     # traced replicated scalar
     (r"(^|/)mask$", P("dp")),          # (B,) refill splice mask
     (r"(^|/)steps$", P("dp")),         # (ndev,) per-shard step counts
@@ -298,6 +299,7 @@ def search_proto() -> Dict[str, Any]:
         "state": state_proto(),
         "tt": tt_proto(),
         "tt_gen": "tt_gen",
+        "watch": "watch",
         "segment_steps": "segment_steps",
         "mask": "mask",
         "steps": "steps",
@@ -355,7 +357,7 @@ def segment_specs(has_tt: bool, axis: str = "dp"):
     registry-derived replacement for mesh.py's old hand-built literals.
 
     Argument order mirrors parallel.mesh._segment_callable's seg():
-    (params, state, ttab, segment_steps, tt_gen) →
+    (params, state, ttab, segment_steps, tt_gen, watch) →
     (state, ttab, steps, summary). A ttab-less build replicates the None
     placeholder."""
     tt = tt_specs(axis) if has_tt else P()
@@ -365,6 +367,7 @@ def segment_specs(has_tt: bool, axis: str = "dp"):
         tt,
         spec_for("segment_steps", axis),
         spec_for("tt_gen", axis),
+        spec_for("watch", axis),
     )
     out_specs = (
         state_specs(axis),

@@ -151,8 +151,10 @@ SETTINGS: Tuple[Setting, ...] = (
         name="FISHNET_TPU_SEGMENT",
         kind="int",
         default="20000",
-        doc="Device steps per resumable segment between host checks "
-            "(deadline / narrowing / refill boundaries).",
+        doc="Cap on device steps per resumable segment between host "
+            "checks (deadline / narrowing / refill boundaries). A "
+            "scheduler session's segment also ends on the step its last "
+            "primary lane parks.",
         engine=True,
     ),
     Setting(

@@ -89,7 +89,8 @@ def test_scalar_leaves_short_circuit_to_replicated():
 # -------------------------------------------------- literal equivalence
 #
 # Pre-registry, parallel/mesh.py hand-built these exact specs:
-#   segment: in  (P(), P(axis), P(axis)|P(), P(), P(axis))
+#   segment: in  (P(), P(axis), P(axis)|P(), P(), P(axis)), and since
+#            the watched-lane stop a last P(axis) for its (B,) mask
 #            out (P(axis), P(axis)|P(), P(axis), P(axis, None, None))
 #   merge:   in  (P(axis), P(axis), P(axis)) → out P(axis)
 # The registry derives per-leaf trees; every leaf must equal the literal
@@ -105,12 +106,13 @@ def _leaves(spec_tree):
 @pytest.mark.parametrize("has_tt", [True, False])
 def test_segment_specs_equal_old_hand_built_literals(axis, has_tt):
     in_specs, out_specs = PT.segment_specs(has_tt, axis)
-    p_params, p_state, p_tt, p_steps, p_gen = in_specs
+    p_params, p_state, p_tt, p_steps, p_gen, p_watch = in_specs
     assert all(s == P() for s in _leaves(p_params))
     assert all(s == P(axis) for s in _leaves(p_state))
     assert all(s == (P(axis) if has_tt else P()) for s in _leaves(p_tt))
     assert p_steps == P()
     assert p_gen == P(axis)
+    assert p_watch == P(axis)
     o_state, o_tt, o_steps, o_summ = out_specs
     assert all(s == P(axis) for s in _leaves(o_state))
     assert all(s == (P(axis) if has_tt else P()) for s in _leaves(o_tt))

@@ -22,6 +22,10 @@ buffer donation, engine/tpu.py _Session, utils/syncstats.py).
    inside its segment and session, overlapping no other — while the
    submit path leaves async pairs only. Results are bit-identical with
    the recorder on and off.
+5. The segment's stop rule: a segment with watched lanes ends when the
+   last of them parks, and leaves the others untouched, so a watched
+   segment and the next one are one longer segment, bit for bit; with
+   no lane watched it is the plain segment.
 
 Engine tests build the single-device scheduler (mesh=None) with no
 helper coupling, exactly like tests/test_refill.py.
@@ -154,6 +158,98 @@ def test_run_column_counts_each_lanes_steps_not_done():
         np.testing.assert_array_equal(step_summ[:B, S.SUM_RUN], ~done)
     assert (np.asarray(state.lane)[:, S.LN_MODE] == S.MODE_DONE).all()
     np.testing.assert_array_equal(counted, run)
+
+
+def _segment(params, state, table, steps, watch=None):
+    """One segment of `_run_segment_jit` → (state, table, steps, the
+    summary as numpy); `watch` passed only where given."""
+    from fishnet_tpu.ops import search as S
+
+    extra = () if watch is None else (False, 0, watch)
+    state, table, n, summ = S._run_segment_jit(
+        params, state, table, steps, "standard", False, *extra)
+    return state, table, int(np.asarray(n)), np.asarray(summ)
+
+
+def _same_arrays(a, b):
+    import jax
+
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _table(with_table):
+    from fishnet_tpu.ops import tt
+
+    return tt.make_table(8) if with_table else None
+
+
+@pytest.mark.parametrize("watched", [[0, 2, 5], [3], [0, 1, 2, 3, 5]],
+                         ids=["three", "one", "all-but-one"])
+def test_watched_segment_stops_when_its_last_watched_lane_parks(watched):
+    """With watch set on some lanes the segment runs exactly as many
+    steps as its longest-running watched lane: every watched lane is
+    DONE, and a lane that is not watched is left running."""
+    from fishnet_tpu.ops import search as S
+
+    params, fresh = _parking_lanes()
+    watch = np.zeros(6, bool)
+    watch[watched] = True
+    _state, _tt, n, summ = _segment(params, fresh(), None, 10_000, watch)
+    B = summ.shape[0] - S.SUM_TAIL
+    run, done = summ[:B, S.SUM_RUN], summ[:B, S.SUM_DONE].astype(bool)
+    assert summ[B, S.SUM_DONE] == n == run[watch].max() > 0
+    assert done[watch].all()
+    assert not done[~watch].all()
+    assert (run[~watch & ~done] == n).all()
+
+
+@pytest.mark.parametrize("with_table", [False, True],
+                         ids=["no-table", "table"])
+def test_watched_then_plain_segment_is_one_longer_segment(with_table):
+    """A segment stopped by its watched lanes, followed with no splice
+    between by a plain one, computes bit for bit what one plain segment
+    of the summed length computes: state, table, the run column and
+    the counters, summed over the two."""
+    from fishnet_tpu.ops import search as S
+
+    params, fresh = _parking_lanes()
+    watch = np.array([1, 0, 1, 0, 0, 1], bool)
+    state, table, n1, s1 = _segment(
+        params, fresh(), _table(with_table), 10_000, watch)
+    state, table, n2, s2 = _segment(params, state, table, 7)
+    B = s1.shape[0] - S.SUM_TAIL
+    assert 0 < n1 and n2 == 7
+    assert not s2[:B, S.SUM_DONE].all()  # the cut falls inside the run
+    one_state, one_table, n, s = _segment(
+        params, fresh(), _table(with_table), n1 + n2)
+    assert n == n1 + n2
+    _same_arrays(state, one_state)
+    _same_arrays(table, one_table)
+    np.testing.assert_array_equal(s2[:B, :S.SUM_RUN], s[:B, :S.SUM_RUN])
+    np.testing.assert_array_equal(
+        s1[:B, S.SUM_RUN] + s2[:B, S.SUM_RUN], s[:B, S.SUM_RUN])
+    np.testing.assert_array_equal(
+        s1[B:, S.SUM_COUNTS] + s2[B:, S.SUM_COUNTS], s[B:, S.SUM_COUNTS])
+
+
+@pytest.mark.parametrize("with_table", [False, True],
+                         ids=["no-table", "table"])
+def test_unwatched_segment_is_the_plain_one(with_table):
+    """A watch mask that marks no lane gives the plain segment, bit for
+    bit: state, table and summary."""
+    params, fresh = _parking_lanes()
+    for steps in (60, 10_000):
+        state, table, n, summ = _segment(
+            params, fresh(), _table(with_table), steps, np.zeros(6, bool))
+        p_state, p_table, p_n, p_summ = _segment(
+            params, fresh(), _table(with_table), steps)
+        assert n == p_n > 0
+        np.testing.assert_array_equal(summ, p_summ)
+        _same_arrays(state, p_state)
+        _same_arrays(table, p_table)
 
 
 # --------------------------------------------------------- engine level

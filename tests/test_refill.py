@@ -315,17 +315,105 @@ def test_lane_step_counts_by_role():
     roles = np.array([ROLE_PRIMARY, ROLE_HELPER, ROLE_HELPER, ROLE_PRIMARY,
                       ROLE_STRAY, ROLE_FREE], np.int8)
     run = np.array([40, 100, 7, 60, 2, 0], np.int32)
-    assert _lane_step_counts(100, run, roles) == {
+    done = np.ones(6, bool)
+    assert _lane_step_counts(100, run, roles, done, 20_000) == {
         "lane_steps_assigned": 400, "lane_steps_primary_run": 100,
         "lane_steps_helper_run": 107, "lane_steps_stray_run": 2,
         "lane_steps_free_run": 0, "steps_after_last_primary": 40,
-        "segments_empty": 0,
+        "segments_empty": 0, "segments_primary_stop": 0,
     }
     # helpers only: no primary, no tail; a dispatch that ran no step
     helpers = np.full(6, ROLE_HELPER, np.int8)
-    assert _lane_step_counts(100, run, helpers)["steps_after_last_primary"] == 0
-    empty = _lane_step_counts(0, np.zeros(6, np.int32), roles)
+    assert _lane_step_counts(
+        100, run, helpers, done, 20_000)["steps_after_last_primary"] == 0
+    empty = _lane_step_counts(0, np.zeros(6, np.int32), roles, done, 20_000)
     assert empty["segments_empty"] == 1 and empty["lane_steps_assigned"] == 0
+    # stopped at its last primary: both primaries parked, a helper runs
+    # on, the cap did not bind; not where the cap bound, a primary runs,
+    # no lane runs, no primary was watched, or no step ran
+    run = np.array([40, 60, 7, 60, 2, 0], np.int32)
+    helper_on = np.array([True, False, True, True, True, True])
+    stop = _lane_step_counts(60, run, roles, helper_on, 20_000)
+    assert stop["segments_primary_stop"] == 1
+    assert stop["steps_after_last_primary"] == 0
+    primary_on = np.array([True, False, True, False, True, True])
+    for n, done_at_end, lane_roles, cap in (
+            (60, helper_on, roles, 60), (60, primary_on, roles, 20_000),
+            (60, done, roles, 20_000), (60, helper_on, helpers, 20_000),
+            (0, helper_on, roles, 20_000)):
+        assert _lane_step_counts(
+            n, run, lane_roles, done_at_end, cap)["segments_primary_stop"] == 0
+
+
+def test_budget_left_counts_helpers_still_searching():
+    """A decision on a position's budget (engine/tpu.py _Session.left)
+    reads its `remaining` less what its helper lanes still searching had
+    spent by the last boundary: they are charged only when they park or
+    are let go of. A lane staged for a splice has spent nothing (its
+    count is the last occupant's), and with no helper or no boundary yet
+    it is `remaining` itself."""
+    from types import SimpleNamespace
+
+    from fishnet_tpu.engine.tpu import _RefillJob, _Session
+
+    job = _RefillJob(None, None, None, None, "standard", 3, 1_000,
+                     0.0, None, None)
+    session = SimpleNamespace(nodes_row=None, adm={"lane": []})
+    assert _Session.left(session, job) == 1_000
+    session.nodes_row = np.array([50, 120, 7, 300], np.int32)
+    assert _Session.left(session, job) == 1_000  # no helper
+    job.helpers = {1: 1, 2: 2, 3: 3}
+    assert _Session.left(session, job) == 1_000 - 120 - 7 - 300
+    session.adm["lane"].append(3)
+    assert _Session.left(session, job) == 1_000 - 120 - 7
+
+
+@pytest.mark.parametrize("lane, staged, want", [
+    # the primary's next iteration beside three helpers still searching:
+    # left 573; one helper can spend only 20 more, the others share
+    (0, [], 184),
+    # a helper admitted while the primary searches: the primary's spend
+    # so far comes off too, and what is left of its budget is a cap
+    (4, [], 125),
+    # a helper staged for the splice has spent nothing: its whole
+    # budget is its cap
+    (0, [3], 218),
+])
+def test_budget_share_holds_the_position_budget(lane, staged, want):
+    """A lane staged for a position (engine/tpu.py _Session.share) gets
+    the part of `left` that its other lanes still searching leave it,
+    each of them spending beside it at its rate up to what is left of
+    its own budget: x + sum(min(x, c)) stays within `left`."""
+    from types import SimpleNamespace
+
+    from fishnet_tpu.engine.tpu import _RefillJob, _Session
+
+    job = _RefillJob(None, None, None, None, "standard", 3, 1_000,
+                     0.0, None, None)
+    job.lane, job.helpers = 0, {1: 1, 2: 2, 3: 3}
+    session = SimpleNamespace(
+        nodes_row=np.array([50, 120, 7, 300, 0], np.int32),
+        lane_budget=np.array([400, 900, 900, 320, 0], np.int64),
+        adm={"lane": list(staged)})
+    session.left = lambda j: _Session.left(session, j)
+    x = _Session.share(session, job, lane)
+    assert x == want
+    left = _Session.left(session, job)
+    spend = x
+    for other in (0, 1, 2, 3):
+        if other == lane:
+            continue
+        spent = 0 if other in staged else int(session.nodes_row[other])
+        if other == job.lane:
+            left -= spent
+        spend += min(x, int(session.lane_budget[other]) - spent)
+    assert spend <= left < spend + 1 + len(job.helpers)
+    # no boundary yet, or no other lane: `left` itself
+    session.nodes_row = None
+    assert _Session.share(session, job, lane) == 1_000
+    session.nodes_row = np.zeros(5, np.int32)
+    job.helpers = {}
+    assert _Session.share(session, job, 0) == 1_000
 
 
 def make_helper_engine(helpers):
@@ -343,8 +431,9 @@ def make_helper_engine(helpers):
 def test_lane_steps_tie_out(helpers):
     """A refill session's lane-steps by role: the primary and helper
     lanes ran no more than they were assigned, a free lane never ran,
-    the tail after a segment's last primary is part of its steps, and
-    the positions' queue wait is part of their time in the engine."""
+    no segment runs on after its last primary parks (with helper lanes,
+    some segments end there while a helper searches on), and the
+    positions' queue wait is part of their time in the engine."""
     engine = make_helper_engine(helpers)
     responses = run(engine, make_chunk(analysis_work(depth=3)))
     tot = engine.occupancy_totals
@@ -360,11 +449,50 @@ def test_lane_steps_tie_out(helpers):
     assert run_total <= tot["lane_steps"]
     assert 0.0 <= tot["position_queue_ms"] <= tot["position_engine_ms"]
     assert tot["positions_finalized"] == len(responses) == tot["positions_done"]
+    # a segment ends when its last primary parks: no step runs after it,
+    # with helper lanes or without
+    assert tot["steps_after_last_primary"] == 0
     if helpers == 1:
         assert tot["lane_steps_helper_run"] == 0
-        assert tot["steps_after_last_primary"] == 0  # no lane outlasts them
+        assert tot["segments_primary_stop"] == 0  # no lane outlasts them
     else:
         assert tot["lane_steps_helper_run"] > 0
+        assert tot["segments_primary_stop"] > 0
+
+
+def test_lanes_let_go_of_are_stopped_at_the_next_splice(monkeypatch):
+    """A finalized position's helper lanes still searching are stopped
+    at the next splice when no admission reuses them (engine/tpu.py
+    _Session.idle): every refill splice holds every stray lane, so no
+    node is spent on a position past its last charge; a stray lane runs
+    only the steps an idle row (or the base state) takes to park."""
+    from fishnet_tpu.engine.tpu import ROLE_STRAY, _Session
+
+    seen = {"idle": 0, "base": 0}
+    init, refill, idle = _Session.__init__, _Session.refill, _Session.idle
+
+    def count_init(self, *a, **kw):
+        init(self, *a, **kw)
+        seen["base"] += self.B
+
+    def check_refill(self):
+        if self.nodes_row is not None:
+            stray = set(np.flatnonzero(self.role == ROLE_STRAY).tolist())
+            assert stray <= set(self.adm["lane"])
+        return refill(self)
+
+    def count_idle(self, lane):
+        seen["idle"] += 1
+        return idle(self, lane)
+
+    monkeypatch.setattr(_Session, "__init__", count_init)
+    monkeypatch.setattr(_Session, "refill", check_refill)
+    monkeypatch.setattr(_Session, "idle", count_idle)
+    engine = make_helper_engine(2)
+    run(engine, make_chunk(analysis_work(depth=3)))
+    tot = engine.occupancy_totals
+    assert seen["idle"] > 0
+    assert tot["lane_steps_stray_run"] <= 2 * (seen["idle"] + seen["base"])
 
 
 def test_lane_steps_ride_the_segment_and_session_spans():
@@ -399,6 +527,34 @@ def test_lane_steps_ride_the_segment_and_session_spans():
     text = trace_report.render_text(report)
     assert f"parked {lanes['parked']} " in text
     assert f"empty dispatches {tot['segments_empty']}" in text
+
+
+def test_primary_stops_ride_the_segment_and_session_spans():
+    """segments_primary_stop, the segments that ended when their last
+    primary lane parked, is in each `segment` span's counts and each
+    `session` span's args, adds up to the engine's total there, and is
+    printed on tools/trace_report.py's segment line."""
+    rec = obs_trace.install(obs_trace.TraceRecorder(capacity=16384))
+    try:
+        engine = make_helper_engine(2)
+        run(engine, make_chunk(analysis_work(depth=3)))
+    finally:
+        obs_trace.uninstall()
+    from tools import trace_report
+
+    tot, events = engine.occupancy_totals, rec.snapshot()
+    stops = tot["segments_primary_stop"]
+    assert 0 < stops <= tot["segments"]
+    segments = [e["args"] for e in events if e["name"] == "segment"]
+    sessions = [e["args"] for e in events if e["name"] == "session"]
+    assert all("segments_primary_stop" in a["counts"] for a in segments)
+    assert all("segments_primary_stop" in a for a in sessions)
+    assert sum(a["counts"]["segments_primary_stop"] for a in segments) == stops
+    assert sum(a["segments_primary_stop"] for a in sessions) == stops
+    report = trace_report.summarize(events)
+    assert report["segments"]["lane_steps"]["segments_primary_stop"] == stops
+    text = trace_report.render_text(report)
+    assert f"stopped at the last primary {stops}" in text
 
 
 def test_search_stream_matches_batch():

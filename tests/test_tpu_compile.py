@@ -124,6 +124,8 @@ def _compile_segment(params, sharding, lanes: int, variant: str,
         jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding),
         variant=variant, deep_tt=deep_tt, prefer_deep=prefer_deep,
         tt_gen=jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=sharding),
+        # the scheduler's sessions watch their primary lanes
+        watch=jax.ShapeDtypeStruct((lanes,), jnp.bool_, sharding=sharding),
     ).compile()
 
 
@@ -306,7 +308,8 @@ def test_sharded_segment_compiles_on_four_chips(topo, params):
             is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
         )
 
-    p_sh, st_sh, tt_sh, steps_sh, gen_sh = (named(s) for s in in_specs)
+    p_sh, st_sh, tt_sh, steps_sh, gen_sh, watch_sh = (
+        named(s) for s in in_specs)
     lanes = BUCKET * 4  # one production bucket per chip
     state = _state_shape(params, lanes)
     ttab = jax.eval_shape(
@@ -318,6 +321,7 @@ def test_sharded_segment_compiles_on_four_chips(topo, params):
         _on(params, p_sh), _on(state, st_sh), _on(ttab, tt_sh),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=steps_sh),
         jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=gen_sh),
+        jax.ShapeDtypeStruct((lanes,), jnp.bool_, sharding=watch_sh),
     ).compile()
     # per-device bytes: each chip holds its lanes and its TT shard
     _fits(compiled)

@@ -20,11 +20,12 @@ measurement items need without opening Perfetto:
   `phase.refill` spans (the engine's `refill_splices`, `refills` and
   `phase_refill_ms` are the same three over a window).
 - **lane-steps**: the segments' lane-steps by the role each lane had at
-  dispatch (the `lane_steps_*`, `steps_after_last_primary` and
-  `segments_empty` counts in the `segment` spans' args, engine/tpu.py
-  LANE_STEP_COUNTERS): parked lane-steps (a primary or helper lane DONE
-  while the segment ran on), the steps run after a segment's last
-  primary parked, and the dispatches that ran no step.
+  dispatch (the `lane_steps_*`, `steps_after_last_primary`,
+  `segments_empty` and `segments_primary_stop` counts in the `segment`
+  spans' args, engine/tpu.py LANE_STEP_COUNTERS): parked lane-steps (a
+  primary or helper lane DONE while the segment ran on), the steps run
+  after a segment's last primary parked, the dispatches that ran no
+  step, and the segments that ended when their last primary parked.
 - **sessions**: one row per `session` span — the width the drive
   session got, what was pending when it chose, set-up, segments, and
   its parked lane-steps, tail steps and empty dispatches.
@@ -75,7 +76,7 @@ GAP_BUCKETS_MS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 50.0, 250.0)
 LANE_STEPS = (
     "lane_steps_assigned", "lane_steps_primary_run", "lane_steps_helper_run",
     "lane_steps_stray_run", "lane_steps_free_run", "steps_after_last_primary",
-    "segments_empty",
+    "segments_empty", "segments_primary_stop",
 )
 
 
@@ -546,7 +547,8 @@ def render_text(report: dict) -> str:
                 f"({ls['parked'] / assigned if assigned else 0.0:.1%} of "
                 f"assigned); tail steps "
                 f"{ls.get('steps_after_last_primary', 0)}; empty dispatches "
-                f"{ls.get('segments_empty', 0)}")
+                f"{ls.get('segments_empty', 0)}; stopped at the last "
+                f"primary {ls.get('segments_primary_stop', 0)}")
     if report["boundary_phases"]:
         lines += [
             "",
