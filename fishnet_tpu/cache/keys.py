@@ -142,8 +142,8 @@ def engine_identity(engine, flavor: EngineFlavor = EngineFlavor.TPU) -> str:
     """The net/settings fingerprint folded into every key.
 
     Captures what changes answers without re-keying per request: the
-    net weights identity (both nets of a two-net deployment), the
-    engine's depth cap (resolves default-
+    net weights identity (both nets of a two-net deployment, and the
+    integer arithmetic a .nnue file is searched in), the engine's depth cap (resolves default-
     depth requests), the engine class, the eval flavor, and the
     search-visible settings (the same registry slice that keys AOT
     bundles — aot/keys.py AOT_KEY_SETTINGS)."""
@@ -168,5 +168,10 @@ def engine_identity(engine, flavor: EngineFlavor = EngineFlavor.TPU) -> str:
     small = getattr(engine, "weights_small_path", None)
     if small:
         ident["net_small"] = small
+    # a .nnue file searched in its own integers answers otherwise than
+    # the same file divided into floats; a float engine keeps its
+    # fingerprint
+    if getattr(engine, "arithmetic", None) == "int":
+        ident["arithmetic"] = "int"
     blob = json.dumps(ident, sort_keys=True).encode("utf-8")
     return hashlib.sha256(blob).hexdigest()[:16]

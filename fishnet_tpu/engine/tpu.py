@@ -349,7 +349,7 @@ class TpuEngine(ChunkSubmit):
         weights_path: Optional[str] = None,
         # a Stockfish deployment's second net (upstream's EvalFileSmall):
         # with a .nnue weights_path, both are resident and every eval
-        # chooses between them (models/nnue_import.py DualNet)
+        # chooses between them (models/nnue_import.py DualNetInt)
         weights_small_path: Optional[str] = None,
         max_depth: int = 12,  # production value flows from configure.tpu_depth
         seed: int = 1234,
@@ -410,12 +410,13 @@ class TpuEngine(ChunkSubmit):
                 "net read from weights_path")
         if params is None:
             if weights_path and str(weights_path).endswith(".nnue"):
-                # real Stockfish network file (models/nnue_import.py)
+                # real Stockfish network file (models/nnue_import.py),
+                # searched in the integers it holds
                 from ..models import nnue_import
 
                 params = nnue_import.load_nnue(weights_path)
                 if weights_small_path:
-                    params = nnue_import.DualNet.from_nets(
+                    params = nnue_import.DualNetInt.from_nets(
                         params, nnue_import.load_nnue(weights_small_path))
                 params = params.as_device()
             elif weights_path:
@@ -446,6 +447,7 @@ class TpuEngine(ChunkSubmit):
         # so it survives only as an experiment behind an extra flag.
         dtype_env = (settings.get_str("FISHNET_TPU_DTYPE") or "").lower()
         if dtype_env in ("bf16", "bfloat16"):
+            # refuses an integer net (a .nnue file): ValueError
             params = nnue.cast_params(params, jnp.bfloat16)
         elif dtype_env == "int8":
             if settings.get_bool("FISHNET_TPU_EXPERIMENTAL_INT8"):
@@ -463,6 +465,9 @@ class TpuEngine(ChunkSubmit):
                     "FISHNET_TPU_EXPERIMENTAL_INT8=1 to run it anyway"
                 )
         self.params = params
+        # what the eval computes in ("int" for a .nnue file's integers):
+        # the session span and the analysis cache's identity name it
+        self.arithmetic = nnue.arithmetic(params)
         self.max_depth = max_depth
         # B=2048 falls off the VMEM cliff on v5e (docs/tpu-hang.md round 5:
         # ~1024 lanes is the ceiling) — never let one dispatch exceed it;
@@ -2038,8 +2043,10 @@ class LaneScheduler:
             ).board
             # what the session's width was chosen from, and against:
             # the row that shows the race between submitters and driver
+            # and which arithmetic the net answers in (nnue.arithmetic)
             session_args = {"n_hint": n_hint, "pending": len(self._pending),
-                            "variant": variant}
+                            "variant": variant,
+                            "arithmetic": eng.arithmetic}
         at_start = {k: tot[k] for k in SESSION_TOTALS}
         B = eng._helper_width(min(max(n_hint, 1), eng.max_lanes))
         session = _Session(self, entry, variant, filler, B, t_enter)

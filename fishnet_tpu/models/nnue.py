@@ -9,9 +9,9 @@ feature transform, and a bucketed layer stack selected by piece count —
 resident in HBM as arrays, evaluated by XLA, and trainable in-framework
 (fishnet_tpu.models.train).
 
-Weights are float (bf16/f32) rather than Stockfish's int8/int16: the MXU
-natively prefers bf16, and quantization is a later optimization, not a
-architectural requirement as it is on CPU.
+This module's own nets are float (bf16/f32) rather than Stockfish's
+int8/int16: the MXU natively prefers bf16. An imported Stockfish file is
+searched in its own integers (models/nnue_import.py, scheme "int").
 """
 from __future__ import annotations
 
@@ -212,7 +212,18 @@ def cast_params(params, dtype=jnp.bfloat16):
     stay f32 (init_state allocates acc in f32 regardless), so incremental
     updates keep their precision; matmuls run bf16×f32→f32 which XLA maps
     onto the MXU. Evaluations may drift a few centipawns vs f32 — use the
-    f32 master weights for training and parity tests."""
+    f32 master weights for training and parity tests.
+
+    An integer net (nnue_import.StockfishNetInt, DualNetInt) is refused:
+    bf16 of its int16 rows is not a lower precision of its arithmetic but
+    another one (dequantize it first for a float control)."""
+    from .nnue_import import is_int_net
+
+    if is_int_net(params):
+        raise ValueError(
+            "an integer net computes in Stockfish's int16/int8 arithmetic; "
+            "a float cast of it is not a control (nnue_import.dequantize "
+            "gives its float32 net)")
     return jax.tree.map(lambda a: jnp.asarray(a).astype(dtype), params)
 
 
@@ -261,8 +272,26 @@ def is_int8(params) -> bool:
 
 def acc_dtype(params) -> jnp.dtype:
     """Search accumulator dtype for a params set (int32 under int8
-    quantization — integer adds are exact; f32 otherwise)."""
+    quantization — integer adds are exact; int16 for an imported integer
+    net, whose PSQT sums ride in int32 beside the rows; f32 otherwise)."""
+    from .nnue_import import is_int_net
+
+    if is_int_net(params):
+        return jnp.int16
     return jnp.int32 if is_int8(params) else jnp.float32
+
+
+def arithmetic(params) -> str:
+    """What the eval computes in: "int" for an imported integer net
+    (Stockfish's int16 rows and int8 stacks), else the dtype of the
+    feature table ("float32", "bfloat16", or "int16" under the emulated
+    int8 ladder of board768)."""
+    from .nnue_import import is_int_net
+
+    if is_int_net(params):
+        return "int"
+    table = params.ft if hasattr(params, "ft") else params.ft_w
+    return str(jnp.asarray(table).dtype)
 
 
 def is_board768(params) -> bool:
@@ -279,7 +308,10 @@ def acc_scheme(params, variant: str = "standard"):
     "halfka" (an imported StockfishNet: a ≤4-row delta a perspective, and
     a refresh of the perspective whose king moved — nnue_import.acc_*),
     "dual" (a nnue_import.DualNet: halfka's scheme on rows that hold both
-    nets, and Stockfish's choice between them at every eval).
+    nets, and Stockfish's choice between them at every eval), "int" (an
+    integer net, nnue_import.StockfishNetInt or DualNetInt: halfka's or
+    dual's scheme on int16 rows with their int32 PSQT sums beside them,
+    and Stockfish's integer ladder).
     None for our own king-relative NnueParams, and for atomic, whose
     explosions exceed the 4 slots of board.move_piece_changes."""
     if variant == "atomic":
@@ -287,15 +319,18 @@ def acc_scheme(params, variant: str = "standard"):
     if is_board768(params):
         return "board768"
     if not isinstance(params, NnueParams):
-        from .nnue_import import DualNet
+        from .nnue_import import DualNet, is_int_net
 
+        if is_int_net(params):
+            return "int"
         return "dual" if isinstance(params, DualNet) else "halfka"
     return None
 
 
 # the schemes whose stack holds, a ply, two rows of a king-relative
-# net's L1 values and PSQT sums (nnue_import.acc_*)
-ROW_SCHEMES = ("halfka", "dual")
+# net's L1 values and PSQT sums (nnue_import.acc_*; under "int" a pair of
+# stacks, the int16 rows and the int32 sums)
+ROW_SCHEMES = ("halfka", "dual", "int")
 
 
 # ------------------------------------------------------------------- forward

@@ -33,7 +33,9 @@ the public nnue-pytorch trainer and read by Stockfish 15/16:
     * Any int16/int8/int32 array section may instead be stored LEB128-
       compressed: magic b"COMPRESSED_LEB128" + uint32 byte_count + stream.
     * Quantization scales: FT 127 (QA), hidden weights 64 (QB),
-      output scale 16; dequantized here to float32.
+      output scale 16. `load_nnue` keeps the file's integers
+      (StockfishNetInt); `dequantize` divides them into float32
+      (StockfishNet), for the float programs and controls.
 
 SCOPE — the search path. `TpuEngine(params=<StockfishNet>)` (a parsed
 file through `weights_path=*.nnue`, or seeded weights: the benchmark's
@@ -60,6 +62,17 @@ net's L1 and PSQT columns followed by the small net's, so the stack row,
 the ≤ 4-row update and the refresh serve both nets with one op each
 (nnue.acc_scheme == "dual"), and `forward_dual_from_acc` applies the
 rule lane by lane. `evaluate_dual_reference` is the plain numpy rule.
+
+THE INTEGERS. A net as its file holds it (`StockfishNetInt`, what
+`load_nnue` returns), or both of a deployment's (`DualNetInt`: the two
+nets' int16 L1 columns joined into one row, their int32 PSQT columns in a
+side table), is searched in Stockfish's own arithmetic (nnue.acc_scheme ==
+"int"): int16 rows and int32 PSQT sums ride down the stack, and
+`forward_int_from_acc` is the integer ladder of Stockfish 15.1-16
+(SFNNv5-v7: src/nnue/nnue_feature_transformer.h's 127 / 128 pairwise
+transform, layers/affine_transform.h, clipped_relu.h, sqr_clipped_relu.h,
+nnue_architecture.h's propagate). `evaluate_int_reference` and
+`evaluate_dual_int_reference` are the plain numpy int64 mirror.
 
 Anything that doesn't match this layout (different sizes, unknown
 section lengths) raises UnsupportedNnueFormat rather than misparsing.
@@ -146,6 +159,43 @@ class StockfishNet:
         )
 
 
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=list(_ARRAY_FIELDS),
+    meta_fields=["version", "net_hash", "description"],
+)
+@dataclasses.dataclass(frozen=True)
+class StockfishNetInt:
+    """A HalfKAv2_hm net as its file stores it: int16 ft_w / ft_b, int32
+    psqt_w, int8 fc*_w, int32 fc*_b, in StockfishNet's shapes. The search
+    computes it in Stockfish's integers (nnue.acc_scheme "int");
+    `dequantize` gives the float32 StockfishNet of the same file."""
+
+    ft_w: np.ndarray
+    ft_b: np.ndarray
+    psqt_w: np.ndarray
+    fc0_w: np.ndarray
+    fc0_b: np.ndarray
+    fc1_w: np.ndarray
+    fc1_b: np.ndarray
+    fc2_w: np.ndarray
+    fc2_b: np.ndarray
+    version: int = 0
+    net_hash: int = 0
+    description: bytes = b""
+
+    @property
+    def l1(self) -> int:
+        return self.ft_w.shape[1]
+
+    def as_device(self) -> "StockfishNetInt":
+        import jax.numpy as jnp
+
+        return dataclasses.replace(
+            self, **{f: jnp.asarray(getattr(self, f)) for f in _ARRAY_FIELDS}
+        )
+
+
 _STACK_FIELDS = _ARRAY_FIELDS[3:]  # fc0_w .. fc2_b
 
 
@@ -154,7 +204,8 @@ _STACK_FIELDS = _ARRAY_FIELDS[3:]  # fc0_w .. fc2_b
 @dataclasses.dataclass(frozen=True)
 class NetStacks:
     """One net's eight layer stacks (the fields of StockfishNet behind its
-    feature transformer), float32."""
+    feature transformer): float32 in a DualNet, the file's int8 weights
+    and int32 biases in a DualNetInt."""
 
     fc0_w: np.ndarray  # (8, 16, L1)
     fc0_b: np.ndarray
@@ -232,6 +283,55 @@ class DualNet:
         import jax.numpy as jnp
 
         return jax.tree.map(jnp.asarray, self)
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["ft", "ft_b", "psqt", "big", "small"], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class DualNetInt:
+    """Both nets of a Stockfish deployment in the integers of their files.
+
+    ft (NUM_FEATURES, W) int16: every feature's row of the big net's L1
+    weights, then the small net's, then zeros up to a multiple of
+    ROW_ALIGN (W = 3,200 = 25 x 128 for L1 3,072 and 128: no padding);
+    ft_b (W,) int16 the biases in the same layout; psqt
+    (NUM_FEATURES, 16) int32 the big net's 8 PSQT columns, then the
+    small net's. big / small: each net's int8 / int32 stacks. The search
+    stack holds a perspective's int16 row and its int32 PSQT sums beside
+    it, and one gather, scatter and refresh of each serves both nets."""
+
+    ft: np.ndarray
+    ft_b: np.ndarray
+    psqt: np.ndarray
+    big: NetStacks
+    small: NetStacks
+
+    @classmethod
+    def from_nets(cls, big: StockfishNetInt,
+                  small: StockfishNetInt) -> "DualNetInt":
+        """Host arrays of two integer nets (as load_nnue returns them)
+        joined."""
+        big, small = (jax.tree.map(np.asarray, n) for n in (big, small))
+        pad = -(big.l1 + small.l1) % ROW_ALIGN
+        return cls(
+            ft=np.concatenate([big.ft_w, small.ft_w,
+                               np.zeros((NUM_FEATURES, pad), np.int16)], axis=1),
+            ft_b=np.concatenate([big.ft_b, small.ft_b, np.zeros(pad, np.int16)]),
+            psqt=np.concatenate([big.psqt_w, small.psqt_w], axis=1),
+            big=NetStacks(**{f: getattr(big, f) for f in _STACK_FIELDS}),
+            small=NetStacks(**{f: getattr(small, f) for f in _STACK_FIELDS}),
+        )
+
+    def as_device(self) -> "DualNetInt":
+        import jax.numpy as jnp
+
+        return jax.tree.map(jnp.asarray, self)
+
+
+def is_int_net(net) -> bool:
+    """An integer net (StockfishNetInt, DualNetInt): searched in
+    Stockfish's arithmetic (nnue.acc_scheme "int")."""
+    return isinstance(net, (StockfishNetInt, DualNetInt))
 
 
 # ------------------------------------------------------------------ LEB128
@@ -335,8 +435,9 @@ def _infer_l1(total: int, header_end: int) -> int:
     )
 
 
-def load_nnue(path: str | Path, l1: int | None = None) -> StockfishNet:
-    """Parse a `.nnue` file into dequantized float32 arrays."""
+def load_nnue(path: str | Path, l1: int | None = None) -> StockfishNetInt:
+    """Parse a `.nnue` file into its own integers (`dequantize` gives the
+    float32 net)."""
     data = Path(path).read_bytes()
     r = _Reader(data)
     version = r.u32()
@@ -366,33 +467,49 @@ def load_nnue(path: str | Path, l1: int | None = None) -> StockfishNet:
     )
 
     _net_hash2 = r.u32()
-    fc0_w = np.empty((NUM_STACKS, FC0_OUT, l1), np.float32)
-    fc0_b = np.empty((NUM_STACKS, FC0_OUT), np.float32)
-    fc1_w = np.empty((NUM_STACKS, FC1_OUT, FC1_IN), np.float32)
-    fc1_b = np.empty((NUM_STACKS, FC1_OUT), np.float32)
-    fc2_w = np.empty((NUM_STACKS, 1, FC1_OUT), np.float32)
-    fc2_b = np.empty((NUM_STACKS, 1), np.float32)
+    fc0_w = np.empty((NUM_STACKS, FC0_OUT, l1), np.int8)
+    fc0_b = np.empty((NUM_STACKS, FC0_OUT), np.int32)
+    fc1_w = np.empty((NUM_STACKS, FC1_OUT, FC1_IN), np.int8)
+    fc1_b = np.empty((NUM_STACKS, FC1_OUT), np.int32)
+    fc2_w = np.empty((NUM_STACKS, 1, FC1_OUT), np.int8)
+    fc2_b = np.empty((NUM_STACKS, 1), np.int32)
     for b in range(NUM_STACKS):
-        fc0_b[b] = r.array(np.int32, FC0_OUT) / (QA * QB)
-        fc0_w[b] = r.array(np.int8, FC0_OUT * l1).reshape(FC0_OUT, l1) / QB
-        fc1_b[b] = r.array(np.int32, FC1_OUT) / (QA * QB)
-        fc1_w[b] = r.array(np.int8, FC1_OUT * FC1_IN).reshape(FC1_OUT, FC1_IN) / QB
-        fc2_b[b] = r.array(np.int32, 1) / (NNUE2SCORE * OUTPUT_SCALE)
-        fc2_w[b] = r.array(np.int8, FC1_OUT).reshape(1, FC1_OUT) / (
-            NNUE2SCORE * OUTPUT_SCALE / QA
-        )
+        fc0_b[b] = r.array(np.int32, FC0_OUT)
+        fc0_w[b] = r.array(np.int8, FC0_OUT * l1).reshape(FC0_OUT, l1)
+        fc1_b[b] = r.array(np.int32, FC1_OUT)
+        fc1_w[b] = r.array(np.int8, FC1_OUT * FC1_IN).reshape(FC1_OUT, FC1_IN)
+        fc2_b[b] = r.array(np.int32, 1)
+        fc2_w[b] = r.array(np.int8, FC1_OUT).reshape(1, FC1_OUT)
     if not r.eof():
         raise UnsupportedNnueFormat(
             f"{len(data) - r.pos} trailing bytes after last layer stack"
         )
 
-    return StockfishNet(
-        ft_w=(ft_w / QA).astype(np.float32),
-        ft_b=(ft_b / QA).astype(np.float32),
-        psqt_w=(psqt / (NNUE2SCORE * OUTPUT_SCALE)).astype(np.float32),
+    return StockfishNetInt(
+        ft_w=ft_w, ft_b=ft_b, psqt_w=psqt,
         fc0_w=fc0_w, fc0_b=fc0_b, fc1_w=fc1_w, fc1_b=fc1_b,
         fc2_w=fc2_w, fc2_b=fc2_b,
         version=version, net_hash=net_hash, description=description,
+    )
+
+
+def dequantize(net: StockfishNetInt) -> StockfishNet:
+    """The float32 net of a file's integers, by the storage scales above
+    (the float programs, the bf16 control and the float tests take this).
+    fc1's columns stay in the file's order."""
+    n = jax.tree.map(np.asarray, net)
+    return StockfishNet(
+        ft_w=(n.ft_w / QA).astype(np.float32),
+        ft_b=(n.ft_b / QA).astype(np.float32),
+        psqt_w=(n.psqt_w / (NNUE2SCORE * OUTPUT_SCALE)).astype(np.float32),
+        fc0_w=(n.fc0_w / QB).astype(np.float32),
+        fc0_b=(n.fc0_b / (QA * QB)).astype(np.float32),
+        fc1_w=(n.fc1_w / QB).astype(np.float32),
+        fc1_b=(n.fc1_b / (QA * QB)).astype(np.float32),
+        fc2_w=(n.fc2_w / (NNUE2SCORE * OUTPUT_SCALE / QA)).astype(np.float32),
+        fc2_b=(n.fc2_b / (NNUE2SCORE * OUTPUT_SCALE)).astype(np.float32),
+        version=net.version, net_hash=net.net_hash,
+        description=net.description,
     )
 
 
@@ -406,7 +523,9 @@ def load_nnue(path: str | Path, l1: int | None = None) -> StockfishNet:
 # (acc_update_pair), and a move of a perspective's king changes them all
 # (acc_refresh_row). The products are float32 on every backend
 # (precision=HIGHEST: a TPU's default is one bfloat16 pass, about 2 cp off
-# over 3,072 terms).
+# over 3,072 terms). An integer net's row is a pair of arrays instead: its
+# int16 L1 values and its int32 PSQT sums, (L1,) and (8,) (a DualNetInt's
+# (W,) and (16,)), added in their own integers as Stockfish adds them.
 
 # the rows a refresh gathers: the pieces a board of that program can hold
 REFRESH_ROWS = 32
@@ -417,11 +536,22 @@ def refresh_rows(variant: str = "standard") -> int:
     return REFRESH_ROWS_HORDE if variant == "horde" else REFRESH_ROWS
 
 
+def _int_tables(net):
+    """An integer net's (int16 L1 table, int32 PSQT table, int16 biases)."""
+    if isinstance(net, DualNetInt):
+        return net.ft, net.psqt, net.ft_b
+    return net.ft_w, net.psqt_w, net.ft_b
+
+
 def _rows(net, idx):
     """[ft_w | psqt_w][idx] → (..., L1 + 8) float32: a gather of whole
-    rows (a DualNet's table holds them so, both nets' side by side)."""
+    rows (a DualNet's table holds them so, both nets' side by side); an
+    integer net's (int16 rows, int32 PSQT rows)."""
     import jax.numpy as jnp
 
+    if is_int_net(net):
+        ft, psqt, _ = _int_tables(net)
+        return jnp.asarray(ft)[idx], jnp.asarray(psqt)[idx]
     if isinstance(net, DualNet):
         return jnp.asarray(net.ft)[idx].astype(jnp.float32)
     return jnp.concatenate(
@@ -431,9 +561,12 @@ def _rows(net, idx):
 
 def _bias_row(net):
     """The row of a perspective with no piece: the biases, 0 under the
-    PSQT columns."""
+    PSQT columns (an integer net's: its int16 biases, int32 zeros)."""
     import jax.numpy as jnp
 
+    if is_int_net(net):
+        _, psqt, ft_b = _int_tables(net)
+        return jnp.asarray(ft_b), jnp.zeros((psqt.shape[1],), jnp.int32)
     if isinstance(net, DualNet):
         return jnp.asarray(net.ft_b).astype(jnp.float32)
     return jnp.concatenate(
@@ -463,12 +596,20 @@ def acc_refresh_row(net, board64, perspective, n_rows: int = REFRESH_ROWS):
         )  # (n_rows, 64), one square a used slot
         idx = jnp.sum(jnp.where(hit, idx64[None, :], 0), axis=1)
         used = jnp.any(hit, axis=1)
+    if is_int_net(net):
+        # a slot that is not used adds 0; an int16 sum wraps as
+        # Stockfish's adds do
+        return jax.tree.map(
+            lambda bias, rows: bias + jnp.sum(
+                jnp.where(used[:, None], rows, 0), axis=0, dtype=bias.dtype),
+            _bias_row(net), _rows(net, idx))
     # a slot that is not used adds its row times 0
     return _bias_row(net) + jnp.sum(_rows(net, idx) * used[:, None], axis=0)
 
 
 def acc_refresh_pair(net, board64, n_rows: int = REFRESH_ROWS):
-    """(2, L1 + 8): white's and black's rows from the board."""
+    """(2, L1 + 8): white's and black's rows from the board (an integer
+    net's: the pair of (2, L1) int16 rows and (2, 8) int32 sums)."""
     import jax
     import jax.numpy as jnp
 
@@ -478,7 +619,8 @@ def acc_refresh_pair(net, board64, n_rows: int = REFRESH_ROWS):
 
 
 def acc_update_pair(net, pair, board64, codes, sqs, signs):
-    """The child's (2, L1 + 8) pair from the parent's by the move's piece
+    """The child's (2, L1 + 8) pair (an integer net's: its (2, L1) int16
+    rows and (2, 8) int32 sums) from the parent's by the move's piece
     changes (board.move_piece_changes: (K,) codes, squares, signs; code 0
     is an unused slot), and which perspectives that is not enough for:
     (2,) bool, true where a king of that colour is among the changes, so
@@ -500,14 +642,25 @@ def acc_update_pair(net, pair, board64, codes, sqs, signs):
     # a gather and an add a slot: all K slots' rows as one (2, K, L1 + 8)
     # tensor cost the chip a relayout and a reduction of their own
     # (PERF.md §6, PR 35: 43 → 33 µs a 64-lane step at L1 3,072)
-    delta = sum(
-        _rows(net, idx[:, k]) * weight[:, k, None]  # ±1 and 0: exact
-        for k in range(codes.shape[0])
-    )
+    if is_int_net(net):
+        # each table in its own integers (±1 and 0 products; int16 adds
+        # wrap as Stockfish's do)
+        parts = [_rows(net, idx[:, k]) for k in range(codes.shape[0])]
+        delta = tuple(
+            sum(p[t] * weight[:, k, None].astype(p[t].dtype)
+                for k, p in enumerate(parts))
+            for t in (0, 1))
+    else:
+        delta = sum(
+            _rows(net, idx[:, k]) * weight[:, k, None]  # ±1 and 0: exact
+            for k in range(codes.shape[0])
+        )
     king = (codes > 0) & (piece_type(codes) == 5)
     stale = jnp.any(
         king[None, :] & (piece_color(codes)[None, :] == persp[:, None]), axis=1
     )
+    if is_int_net(net):
+        return jax.tree.map(jnp.add, tuple(pair), delta), stale
     return pair + delta, stale
 
 
@@ -599,9 +752,102 @@ def forward_dual_from_acc(net: DualNet, pair, stm, bucket, board64):
     with jax.named_scope("step.eval_small"):
         v_small = jnp.int32(
             forward_sf_from_acc(net.small, pair[:, wb:wb + ws], stm, bucket))
-        small = jnp.abs(material_imbalance(board64)) > SMALLNET_MATERIAL
-        reeval = small & (jnp.abs(v_small) < SMALLNET_REEVAL)
-        kept = small & ~reeval
+        small, reeval, kept = _smallnet_rule(v_small, board64)
+    return (jnp.where(kept, v_small, v_big),
+            jnp.stack([kept, reeval, ~small]).astype(jnp.int32))
+
+
+def _smallnet_rule(v_small, board64):
+    """Stockfish's use_smallnet and its re-evaluation, lane by lane: →
+    (the small net chosen, handed back to the big net, its value kept)."""
+    import jax.numpy as jnp
+
+    small = jnp.abs(material_imbalance(board64)) > SMALLNET_MATERIAL
+    reeval = small & (jnp.abs(v_small) < SMALLNET_REEVAL)
+    return small, reeval, small & ~reeval
+
+
+# Stockfish's integer ladder (SF 15.1-16; nnue_architecture.h propagate):
+# the skip output y15 counts 127 · 64 a unit, the value 600 · 16
+SKIP_NUM = int(NNUE2SCORE * OUTPUT_SCALE)  # 9,600
+SKIP_DEN = int(QA * QB)  # 8,128
+# SqrClippedReLU: min(127, y² >> 19) reaches 127 at |y| = 8,160, so y is
+# clamped there first and y² stays inside int32
+SQR_SHIFT = 19  # 2 · WeightScaleBits + 7
+SQR_CLAMP = 8160
+SHIFT = 6  # WeightScaleBits: ClippedReLU's y >> 6
+
+
+def forward_int_from_acc(net, pair, stm, bucket):
+    """A net's Value from the side to move's view, in Stockfish's integer
+    arithmetic, from the pair (int16 rows (2, >= L1), int32 PSQT sums
+    (2, 8)); net: a StockfishNetInt, or one net's int NetStacks.
+
+    x_j = clamp(a_j, 0, 127) · clamp(a_(j + L1/2), 0, 127) / 128 for the
+    side to move, then the other (int8, 0..126); y = fc0 x, int8 x int8
+    products summed in int32, all eight stacks as one product as
+    forward_sf_from_acc computes them; fc1 takes [min(127, y² >> 19)
+    of the unclipped y, clamp(y >> 6, 0, 127)] of y's first 15 (the
+    file's order); then clamp(· >> 6, 0, 127) and fc2; the skip
+    y15 · 9,600 / 8,128; Value = ((psqt_stm - psqt_other) / 2 + fc2 +
+    skip) / 16. Every / truncates toward zero (lax.div), as C++'s."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    i32, i8 = jnp.int32, jnp.int8
+    rows, psqt = pair
+    l1 = net.fc0_w.shape[-1]
+    half = l1 // 2
+
+    def side(a, first):
+        return jnp.where(stm == 0, a[first], a[1 - first])
+
+    def pairwise(acc):
+        c = jnp.clip(acc[:l1].astype(i32), 0, 127)
+        return (c[:half] * c[half:]) >> 7
+
+    x = jnp.concatenate([pairwise(side(rows, 0)), pairwise(side(rows, 1))])
+
+    def stack_layer(w, b, v):
+        # w (8, n, k) int8, b (8, n) int32, v (k,) → this stack's (n,)
+        n = w.shape[1]
+        all8 = jnp.dot(jnp.asarray(w).reshape(NUM_STACKS * n, -1),
+                       v.astype(i8), preferred_element_type=i32)
+        return _pick_stack(all8 + jnp.asarray(b).reshape(-1), bucket)
+
+    y = stack_layer(net.fc0_w, net.fc0_b, x)  # (16,)
+    hidden = jnp.clip(y[:FC0_OUT - 1], -SQR_CLAMP, SQR_CLAMP)
+    sqr = jnp.minimum((hidden * hidden) >> SQR_SHIFT, 127)
+    act = jnp.clip(y[:FC0_OUT - 1] >> SHIFT, 0, 127)
+    z = stack_layer(net.fc1_w, net.fc1_b, jnp.concatenate([sqr, act]))
+    out = stack_layer(net.fc2_w, net.fc2_b, jnp.clip(z >> SHIFT, 0, 127))[0]
+    skip = lax.div(y[FC0_OUT - 1] * SKIP_NUM, i32(SKIP_DEN))
+    ps = lax.div(_pick_stack(side(psqt, 0) - side(psqt, 1), bucket)[0], i32(2))
+    return lax.div(ps + out + skip, i32(OUTPUT_SCALE))
+
+
+def forward_int(net, pair, stm, bucket, board64):
+    """An integer net's eval from its pair (int16 rows, int32 PSQT sums):
+    → (Value, int32; (3,) which net answered, forward_dual_from_acc's
+    one-hot for a DualNetInt, zeros for one net). The ladder's ops are
+    `step.eval_int`; a DualNetInt's small net and the rule
+    `step.eval_small`."""
+    import jax
+    import jax.numpy as jnp
+
+    rows, psqt = pair
+    if not isinstance(net, DualNetInt):
+        with jax.named_scope("step.eval_int"):
+            return (forward_int_from_acc(net, pair, stm, bucket),
+                    jnp.zeros((3,), jnp.int32))
+    wb, ws, nb = net.big.l1, net.small.l1, NUM_PSQT_BUCKETS
+    with jax.named_scope("step.eval_int"):
+        v_big = forward_int_from_acc(
+            net.big, (rows[:, :wb], psqt[:, :nb]), stm, bucket)
+    with jax.named_scope("step.eval_small"):
+        v_small = forward_int_from_acc(
+            net.small, (rows[:, wb:wb + ws], psqt[:, nb:]), stm, bucket)
+        small, reeval, kept = _smallnet_rule(v_small, board64)
     return (jnp.where(kept, v_small, v_big),
             jnp.stack([kept, reeval, ~small]).astype(jnp.int32))
 
@@ -613,6 +859,8 @@ def evaluate_sf(net, board64, stm):
     both nets' rows, then the rule of forward_dual_from_acc)."""
     pair = acc_refresh_pair(net, board64, 64)
     bucket = nnue.output_bucket(board64)
+    if is_int_net(net):
+        return forward_int(net, pair, stm, bucket, board64)[0]
     if isinstance(net, DualNet):
         return forward_dual_from_acc(net, pair, stm, bucket, board64)[0]
     return forward_sf_from_acc(net, pair, stm, bucket)
@@ -676,6 +924,88 @@ def evaluate_dual_reference(big: StockfishNet, small: StockfishNet,
         if abs(v_small) >= SMALLNET_REEVAL:
             return v_small
     return int(evaluate_sf_reference(big, board64, stm))
+
+
+def _cdiv(a: int, b: int) -> int:
+    """a / b truncated toward zero, as C++ divides integers."""
+    q = abs(a) // abs(b)
+    return q if (a >= 0) == (b > 0) else -q
+
+
+def _fits(value, bits: int, what: str) -> None:
+    lo, hi = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
+    if not np.all((np.asarray(value) >= lo) & (np.asarray(value) <= hi)):
+        raise OverflowError(f"{what} leaves int{bits}: the draw would wrap")
+
+
+def evaluate_int_reference(net: StockfishNetInt, board64: np.ndarray,
+                           stm: int) -> int:
+    """Plain numpy int64 mirror of forward_int_from_acc for one position:
+    both perspectives' rows summed from the board, no batching, no
+    incremental rows, nothing of jax. An accumulator value outside int16,
+    or a PSQT sum or a product outside int32, raises: Stockfish's adds
+    would wrap there, which this mirror does not model."""
+    n = jax.tree.map(np.asarray, net)
+    board64 = np.asarray(board64)
+    l1 = n.ft_w.shape[1]
+    half = l1 // 2
+    accs, psqts = [], []
+    for persp in (0, 1):
+        king_code = 6 if persp == 0 else 12
+        ksq = int(np.argmax(board64 == king_code))
+        flip = 56 if persp == 1 else 0
+        mirror = 7 if ((ksq ^ flip) & 7) > 3 else 0
+        bucket = nnue.KING_BUCKET[ksq ^ flip ^ mirror]
+        acc = n.ft_b.astype(np.int64)
+        ps = np.zeros(NUM_PSQT_BUCKETS, np.int64)
+        for sq in range(64):
+            code = int(board64[sq])
+            if code == 0:
+                continue
+            pt = (code - 1) % 6
+            col = 0 if code <= 6 else 1
+            kind = 10 if pt == 5 else (pt if col == persp else 5 + pt)
+            idx = bucket * (11 * 64) + kind * 64 + ((sq ^ flip) ^ mirror)
+            acc = acc + n.ft_w[idx].astype(np.int64)
+            ps = ps + n.psqt_w[idx].astype(np.int64)
+        _fits(acc, 16, "an accumulator value")
+        _fits(ps, 32, "a PSQT sum")
+        accs.append(acc)
+        psqts.append(ps)
+    own, opp = (0, 1) if stm == 0 else (1, 0)
+
+    def pairwise(a):
+        c = np.clip(a, 0, 127)
+        return (c[:half] * c[half:]) // 128
+
+    x = np.concatenate([pairwise(accs[own]), pairwise(accs[opp])])
+    ob = min((int(np.sum(board64 > 0)) - 1) // 4, NUM_STACKS - 1)
+    y = n.fc0_w[ob].astype(np.int64) @ x + n.fc0_b[ob]
+    _fits(y, 32, "an fc0 output")
+    sqr = np.minimum((y[:15] * y[:15]) >> 19, 127)
+    act = np.clip(y[:15] >> 6, 0, 127)
+    z = n.fc1_w[ob].astype(np.int64) @ np.concatenate([sqr, act]) + n.fc1_b[ob]
+    h = np.clip(z >> 6, 0, 127)
+    out = int((n.fc2_w[ob].astype(np.int64) @ h + n.fc2_b[ob])[0])
+    _fits(int(y[15]) * SKIP_NUM, 32, "the skip's product")
+    skip = _cdiv(int(y[15]) * SKIP_NUM, SKIP_DEN)
+    psqt = _cdiv(int(psqts[own][ob] - psqts[opp][ob]), 2)
+    _fits(psqt + out + skip, 32, "the value's sum")
+    return _cdiv(psqt + out + skip, int(OUTPUT_SCALE))
+
+
+def evaluate_dual_int_reference(big: StockfishNetInt, small: StockfishNetInt,
+                                board64: np.ndarray, stm: int) -> int:
+    """Plain numpy mirror of forward_int on a DualNetInt: the small net's
+    Value where the material imbalance is large and that value is not
+    small, else the big net's (evaluate_dual_reference's rule)."""
+    board64 = np.asarray(board64)
+    material = int(_MATERIAL_BY_CODE[board64].sum())
+    if abs(material) > SMALLNET_MATERIAL:
+        v_small = evaluate_int_reference(small, board64, stm)
+        if abs(v_small) >= SMALLNET_REEVAL:
+            return v_small
+    return evaluate_int_reference(big, board64, stm)
 
 
 # ---------------------------------------------------- synthetic writer (tests)

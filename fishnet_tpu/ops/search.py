@@ -39,7 +39,7 @@ lanes are cheap, so multipv lanes are just more lanes.
 from __future__ import annotations
 
 import warnings
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -275,8 +275,10 @@ class SearchState(NamedTuple):
     # incremental NNUE accumulators, by nnue.acc_scheme: (B, P+1, 2, L1),
     # or for "halfka" (B, 2·(P+2), L1+8) — row 2·ply + perspective, the 8
     # PSQT sums behind the L1 values (nnue_import.acc_*), a spare pair last;
-    # for "dual" the same with both nets' values and sums a row
-    acc: jnp.ndarray
+    # for "dual" the same with both nets' values and sums a row; for "int"
+    # a pair of such stacks: (B, 2·(P+2), W) int16 rows and (B, 2·(P+2),
+    # 8 or 16) int32 PSQT sums
+    acc: Any
 
 
 def init_state(params: nnue.NnueParams, roots: Board, depth: jnp.ndarray,
@@ -317,13 +319,15 @@ def init_state(params: nnue.NnueParams, roots: Board, depth: jnp.ndarray,
         )(roots.board)
         # ... and one pair past the deepest ply's, which a lane that
         # pushes no child writes to (_step_lane), so that every lane's
-        # write is in bounds
-        acc = jnp.zeros((B, 2 * (P + 2), root_acc.shape[-1]), adt)
-        acc = acc.at[:, :2].set(root_acc)
+        # write is in bounds ("int": the rows' stack and the sums')
+        acc = jax.tree.map(
+            lambda r: jnp.zeros((B, 2 * (P + 2), r.shape[-1]), r.dtype)
+            .at[:, :2].set(r), root_acc)
     else:
         # (a full-refresh program carries a stack it never reads)
-        table = (params.ft if isinstance(params, nnue_import.DualNet)
-                 else params.ft_w)
+        table = (params.ft if isinstance(
+            params, (nnue_import.DualNet, nnue_import.DualNetInt))
+            else params.ft_w)
         l1 = table.shape[1]
         if scheme == "board768":
             root_acc = jax.vmap(nnue.accumulators_768, in_axes=(None, 0))(
@@ -511,7 +515,8 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
         # incremental update scheme (move_piece_changes). The oracle
         # (ops/oracle.py) mirrors board768's scheme and refreshes otherwise.
         scheme = nnue.acc_scheme(params, variant)
-        # "dual": which net answered, counted below for node expansions
+        # "dual", "int": which net answered, counted below for node
+        # expansions
         eval_pick = jnp.zeros((3,), jnp.int32)
         if scheme == "board768":
             leaf_val = jnp.int32(
@@ -525,6 +530,11 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
         elif scheme == "dual":
             leaf_val, eval_pick = nnue_import.forward_dual_from_acc(
                 params, jax.lax.dynamic_slice_in_dim(s.acc, 2 * ply0, 2),
+                us, nnue.output_bucket(b.board), b.board,
+            )
+        elif scheme == "int":
+            leaf_val, eval_pick = nnue_import.forward_int(
+                params, _acc_pair(s.acc, 2 * ply0),
                 us, nnue.output_bucket(b.board), b.board,
             )
         else:
@@ -920,7 +930,7 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
             acc_counts = advance.astype(jnp.int32) * jnp.array([2, 0, 0], jnp.int32)
         elif scheme in nnue.ROW_SCHEMES:
             child_acc, stale = nnue_import.acc_update_pair(
-                params, jax.lax.dynamic_slice_in_dim(s.acc, 2 * ply1, 2),
+                params, _acc_pair(s.acc, 2 * ply1),
                 parent_b.board, codes, sqs, signs,
             )
             stale &= advance
@@ -931,12 +941,14 @@ def _step_lane(params: nnue.NnueParams, s: SearchState,
             # or lax.scatter with a (2, L1 + 8) window) becomes a loop
             # over the lanes on the chip (612-615 against 409 µs a step;
             # PERF.md §6, PR 35). A lane that pushes no child writes the
-            # spare pair behind the stack.
-            acc_new = s.acc.at[
-                jnp.where(advance, 2 * nply, 2 * P1)
-                + jnp.arange(2, dtype=jnp.int32)
-            ].set(child_acc, mode="promise_in_bounds", unique_indices=True,
-                  indices_are_sorted=True)
+            # spare pair behind the stack ("int": each stack of the pair).
+            dest = (jnp.where(advance, 2 * nply, 2 * P1)
+                    + jnp.arange(2, dtype=jnp.int32))
+            acc_new = jax.tree.map(
+                lambda stack, child: stack.at[dest].set(
+                    child, mode="promise_in_bounds", unique_indices=True,
+                    indices_are_sorted=True),
+                s.acc, child_acc)
             n_stale = jnp.sum(stale).astype(jnp.int32)
             acc_counts = jnp.stack([
                 2 * advance.astype(jnp.int32) - n_stale, n_stale,
@@ -984,19 +996,27 @@ class StepAux(NamedTuple):
     stale: Optional[tuple]
 
 
-def _refresh_stale(params, acc: jnp.ndarray, stale: jnp.ndarray,
+def _acc_pair(acc, row):
+    """The two rows from `row` of a lane's stack (of each stack under
+    "int")."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_slice_in_dim(a, row, 2), acc)
+
+
+def _refresh_stale(params, acc, stale: jnp.ndarray,
                    row0: jnp.ndarray, boards: jnp.ndarray, variant: str):
     """Rebuild from the board the accumulator rows a king's move made
     stale: → (acc, feature rows gathered).
 
-    acc (B, R, W); stale (B, 2) bool; row0 (B,) the pushed child's first
-    row; boards (B, 64) the children's. In a lockstep step some lane
+    acc (B, R, W) ("int": the pair of such stacks, rows and PSQT sums);
+    stale (B, 2) bool; row0 (B,) the pushed child's first row; boards
+    (B, 64) the children's. In a lockstep step some lane
     moves a king nearly every time and nearly every lane does not, so
     the (lane, perspective) pairs that need a rebuild are compacted into
     a few slots and rebuilt there, in as many passes as it takes —
     nothing is gathered for a lane that needs nothing, and a step in
     which no king moved pays the count alone."""
-    B = acc.shape[0]
+    B, R = jax.tree.leaves(acc)[0].shape[:2]
     n_slots = max(2, B // 8)
     n_rows = nnue_import.refresh_rows(variant)
     flat = stale.reshape(-1)  # pair 2·lane + perspective
@@ -1014,8 +1034,10 @@ def _refresh_stale(params, acc: jnp.ndarray, stale: jnp.ndarray,
             lambda b, p: nnue_import.acc_refresh_row(params, b, p, n_rows)
         )(boards[lane], persp)
         # a slot past the last stale pair writes past the end: dropped
-        dest = jnp.where(jnp.any(hit, axis=1), row0[lane] + persp, acc.shape[1])
-        acc = acc.at[lane, dest].set(rows.astype(acc.dtype), mode="drop")
+        dest = jnp.where(jnp.any(hit, axis=1), row0[lane] + persp, R)
+        acc = jax.tree.map(
+            lambda a, r: a.at[lane, dest].set(r.astype(a.dtype), mode="drop"),
+            acc, rows)
         return acc, done + n_slots
 
     with jax.named_scope("step.acc_refresh"):

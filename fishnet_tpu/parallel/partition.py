@@ -17,8 +17,10 @@ Layout, in one screen:
 
   * per-lane search state (SearchState: bt/nt/lane/hist_hash/
     hist_halfmove/moves/hist/pv/acc) — leading dim is the lane axis,
-    sharded over ``dp``; trailing dims replicated.
-  * NNUE weights (NnueParams, StockfishNet, DualNet) — replicated on every chip (`PARAM_RULES`),
+    sharded over ``dp``; trailing dims replicated (an integer net's acc
+    is a pair of stacks, rows and PSQT sums, both under acc's spec).
+  * NNUE weights (NnueParams, StockfishNet, DualNet and the integer
+    StockfishNetInt, DualNetInt) — replicated on every chip (`PARAM_RULES`),
     or tensor-sharded over an optional ``tp`` axis for the
     feature-transform width (`PARAM_RULES_TP`, the training layout).
   * transposition table (TTable.data, (ndev, N, 4)) — leading shard dim
@@ -77,8 +79,10 @@ PARAM_RULES: Tuple[Rule, ...] = (
     # table is gathered by row, lane by lane, so every chip holds it whole
     (r"(^|/)(psqt_w|fc0_w|fc0_b|fc1_w|fc1_b|fc2_w|fc2_b)$", P()),
     # both nets of a deployment (nnue_import.DualNet): one table of both
-    # nets' rows, gathered by row as the imported net's is
-    (r"(^|/)ft$", P()),
+    # nets' rows, gathered by row as the imported net's is; in integers
+    # (DualNetInt) the int16 table and its int32 PSQT side table, and
+    # int8 stacks under the names above
+    (r"(^|/)(ft|psqt)$", P()),
 )
 
 # NNUE weights, training layout: the gather-heavy feature transform
@@ -289,6 +293,12 @@ def dual_param_proto() -> Dict[str, Any]:
             "small": dict(stacks)}
 
 
+def dual_int_param_proto() -> Dict[str, Any]:
+    """The array fields of both integer nets of a deployment
+    (nnue_import.DualNetInt), as a tree of field-name strings."""
+    return dict(dual_param_proto(), psqt="psqt")
+
+
 def search_proto() -> Dict[str, Any]:
     """Everything that crosses the mesh boundary, as one prototype tree —
     the default subject of validate_rules()."""
@@ -296,6 +306,7 @@ def search_proto() -> Dict[str, Any]:
         "params": param_proto(),
         "params_imported": imported_param_proto(),
         "params_dual": dual_param_proto(),
+        "params_dual_int": dual_int_param_proto(),
         "state": state_proto(),
         "tt": tt_proto(),
         "tt_gen": "tt_gen",
@@ -337,7 +348,8 @@ def search_param_spec() -> P:
     be a prefix of its argument's tree): every PARAM_RULES leaf says
     replicated, which is checked here, so the callables need no
     prototype of the type."""
-    for proto in (param_proto(), imported_param_proto(), dual_param_proto()):
+    for proto in (param_proto(), imported_param_proto(), dual_param_proto(),
+                  dual_int_param_proto()):
         leaves = jax.tree_util.tree_leaves(
             match_partition_rules(proto, PARAM_RULES),
             is_leaf=lambda x: isinstance(x, P))

@@ -215,11 +215,12 @@ SETTINGS: Tuple[Setting, ...] = (
         name="FISHNET_TPU_DTYPE",
         kind="str",
         default="",
-        doc="Quantize NNUE weights, of any params type (board768, an "
-            "imported StockfishNet or a two-net DualNet; accumulators stay "
+        doc="Quantize NNUE weights, of a float params type (board768, a "
+            "float StockfishNet or a two-net DualNet; accumulators stay "
             "float32): "
             "\"bf16\" for MXU-native inputs; \"int8\" is experimental, "
-            "board768 only and additionally gated.",
+            "board768 only and additionally gated. A .nnue file's integer "
+            "nets refuse \"bf16\" (the engine does not start).",
         engine=True,
     ),
     Setting(
@@ -782,13 +783,16 @@ NET_SETTINGS_MD = [
     "| Setting | Flag | Description |",
     "|---|---|---|",
     "| `tpu_weights` | `--tpu-weights` (engine host: `--weights`) | NNUE "
-    "weights: this program's .npz, or a Stockfish .nnue file. |",
+    "weights: this program's .npz, or a Stockfish .nnue file, searched in "
+    "the integers the file holds (int16 accumulator rows, int8 layer "
+    "weights, Stockfish's integer ladder; models/nnue_import.py "
+    "StockfishNetInt). |",
     "| `tpu_weights_small` | `--tpu-weights-small` (engine host: "
     "`--weights-small`) | The small Stockfish .nnue net beside a .nnue "
     "`tpu_weights` (upstream's EvalFileSmall): both nets resident, every "
     "eval chooses between them by Stockfish's material rule and hands an "
-    "unsure small value back to the big net (models/nnue_import.py "
-    "DualNet). |",
+    "unsure small value back to the big net, both in their files' "
+    "integers (models/nnue_import.py DualNetInt). |",
 ]
 
 

@@ -339,11 +339,13 @@ def test_engine_loads_both_files_and_the_cache_keys_on_both(tmp_path):
     ni.write_nnue(other, quantized(64, 3))
     engine = TpuEngine(weights_path=str(big), weights_small_path=str(small),
                        tt_size_log2=0)
-    assert isinstance(engine.params, ni.DualNet)
-    # 128 + 8 + 64 + 8 columns of the two nets, padded to a multiple of 128
+    # the files' own integers (tests/test_nnue_int.py holds them to the
+    # integer reference)
+    assert isinstance(engine.params, ni.DualNetInt)
+    # 128 + 64 int16 columns of the two nets, padded to a multiple of 128
     assert engine.params.ft.shape == (ni.NUM_FEATURES, 256)
     assert engine.params.big.l1 == 128 and engine.params.small.l1 == 64
-    want = ni.DualNet.from_nets(ni.load_nnue(big), ni.load_nnue(small))
+    want = ni.DualNetInt.from_nets(ni.load_nnue(big), ni.load_nnue(small))
     assert np.array_equal(np.asarray(engine.params.ft), want.ft)
     ident = engine_identity(engine)
     engine.weights_small_path = str(other)
@@ -351,7 +353,8 @@ def test_engine_loads_both_files_and_the_cache_keys_on_both(tmp_path):
     # an engine of one net keeps the fingerprint it had before the pair
     engine.weights_small_path = None
     one_net = type("TpuEngine", (), {"weights_path": str(big),
-                                     "max_depth": engine.max_depth})()
+                                     "max_depth": engine.max_depth,
+                                     "arithmetic": engine.arithmetic})()
     assert engine_identity(engine) == engine_identity(one_net) != ident
     with pytest.raises(ValueError, match="small net"):
         TpuEngine(weights_small_path=str(small), tt_size_log2=0)

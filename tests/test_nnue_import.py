@@ -40,7 +40,7 @@ def quantized():
 def test_roundtrip_raw(tmp_path, quantized):
     path = tmp_path / "test.nnue"
     ni.write_nnue(path, quantized)
-    net = ni.load_nnue(path)  # L1 inferred from file size
+    net = ni.dequantize(ni.load_nnue(path))  # L1 inferred from file size
     assert net.l1 == L1
     assert net.description == b"test net"
     np.testing.assert_allclose(net.ft_w, quantized["ft_w"] / ni.QA, atol=1e-6)
@@ -98,7 +98,7 @@ def test_forward_parity_jax_numpy(tmp_path, quantized):
 
     path = tmp_path / "test.nnue"
     ni.write_nnue(path, quantized)
-    net = ni.load_nnue(path)
+    net = ni.dequantize(ni.load_nnue(path))
     fens = [
         "rnbqkbnr/pppppppp/8/8/8/8/PPPPPPPP/RNBQKBNR w KQkq - 0 1",
         "r3k2r/p1ppqpb1/bn2pnp1/3PN3/1p2P3/2N2Q1p/PPPBBPPP/R3K2R w KQkq - 0 1",

@@ -44,12 +44,13 @@ def test_validate_rules_counts_cover_the_whole_prototype():
     # 8 NNUE tensors and the 9 of an imported net (its ft_w and ft_b under
     # the first rule too), the 14 of a two-net deployment (its joined
     # table, its ft_b under the first rule, two nets' six stack tensors),
-    # 5 boundary values
+    # the 15 of that deployment in integers (its int16 table and int32
+    # PSQT table under the third rule), 5 boundary values
     assert counts[PT.STATE_RULES[0][0]] == 9
     assert counts[PT.TT_RULES[0][0]] == 1
-    assert counts[PT.PARAM_RULES[0][0]] == 8 + 2 + 1
-    assert counts[PT.PARAM_RULES[1][0]] == 7 + 2 * 6
-    assert counts[PT.PARAM_RULES[2][0]] == 1
+    assert counts[PT.PARAM_RULES[0][0]] == 8 + 2 + 1 + 1
+    assert counts[PT.PARAM_RULES[1][0]] == 7 + 2 * 6 + 2 * 6
+    assert counts[PT.PARAM_RULES[2][0]] == 1 + 2
 
 
 def test_param_rules_tp_cover_params_exactly():

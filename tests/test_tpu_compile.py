@@ -249,6 +249,52 @@ def test_dual_net_segment_compiles(one_chip, dual_net, lanes):
     assert len(sorts) == 1 and str(movegen._live_tables("standard").width) in sorts[0]
 
 
+@pytest.fixture(scope="module")
+def dual_int_net():
+    """The shapes of the benchmark's `halfka_dual_int` pair: both nets'
+    int16 L1 columns joined (3,200 = 25 x 128, no padding), their int32
+    PSQT columns in a side table, int8 stacks with int32 biases."""
+    from fishnet_tpu.models import nnue_import as ni
+
+    def sds(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def stacks(l1):
+        return ni.NetStacks(
+            fc0_w=sds(jnp.int8, ni.NUM_STACKS, ni.FC0_OUT, l1),
+            fc0_b=sds(jnp.int32, ni.NUM_STACKS, ni.FC0_OUT),
+            fc1_w=sds(jnp.int8, ni.NUM_STACKS, ni.FC1_OUT, ni.FC1_IN),
+            fc1_b=sds(jnp.int32, ni.NUM_STACKS, ni.FC1_OUT),
+            fc2_w=sds(jnp.int8, ni.NUM_STACKS, 1, ni.FC1_OUT),
+            fc2_b=sds(jnp.int32, ni.NUM_STACKS, 1))
+
+    return ni.DualNetInt(
+        ft=sds(jnp.int16, ni.NUM_FEATURES, 3200), ft_b=sds(jnp.int16, 3200),
+        psqt=sds(jnp.int32, ni.NUM_FEATURES, 16), big=stacks(3072),
+        small=stacks(128))
+
+
+@pytest.mark.parametrize("lanes", [64, MAX_LANES])
+def test_dual_int_net_segment_compiles(one_chip, dual_int_net, lanes):
+    """Both nets in their files' integers on the incremental path: the
+    int16 rows' stack and the int32 sums' stack ride in the state, the
+    int16 table is taken as it is laid out, with no copy of it, and the
+    ladder's products take int8 operands."""
+    compiled = _compile_segment(dual_int_net, one_chip, lanes, "standard", False, True)
+    used = _fits(compiled)
+    rows = 2 * (MAX_PLY + 2)
+    assert used > lanes * rows * (3200 * 2 + 16 * 4) + 22528 * (3200 * 2 + 16 * 4)
+    text = compiled.as_text()
+    assert f"s16[{lanes},{rows},3200]" in text and f"s32[{lanes},{rows},16]" in text
+    assert not re.search(r"= s16\[22528,3200\]\S* copy\(", text)
+    # the big net's fc0 for every stack, int8 x int8 into int32
+    conv = re.search(rf"= s32\[{lanes},128\]\S* convolution\((%[\w.-]+), "
+                     r"(%[\w.-]+)\).*step\.eval_int", text)
+    assert conv and all(re.search(rf"{re.escape(x)} = s8\[", text) for x in conv.groups())
+    sorts = re.findall(r"= s32\[([\d,]+)\]\S* sort\(", text)
+    assert len(sorts) == 1 and str(movegen._live_tables("standard").width) in sorts[0]
+
+
 def test_wide_net_splice_compiles(one_chip, wide_net):
     """The refill's program for the wide net at 64 lanes: every admitted
     lane's root rebuilt from its board (32 rows a perspective)."""

@@ -4,7 +4,9 @@ Times run_segment per-step wall clock at a given shape, then captures a
 jax.profiler trace of a short segment and aggregates device time per op
 and per `jax.named_scope` of the step (ops/search.py: step.rules,
 step.eval, step.movegen, step.order, ...; the small net's forward under
---net halfka_dual is step.eval_small), so the hot spots are
+--net halfka_dual is step.eval_small; under --net halfka_dual_int, the
+same pair in the integers of their files, the big net's integer ladder
+is step.eval_int and the small net's step.eval_small), so the hot spots are
 attributable (VERDICT r4 weak #6: perf claims need a committed artifact).
 With --trace the program is compiled here, past the persistent compile
 cache: an executable loaded from it carries the names of the tree that
@@ -13,7 +15,7 @@ built it.
 Usage:
   python tools/profile_step.py [B] [depth] [max_ply] [--trace] [--tt]
                                [--variant crazyhouse] [--net halfka3072]
-                               [--net halfka_dual]
+                               [--net halfka_dual] [--net halfka_dual_int]
 """
 from __future__ import annotations
 
@@ -44,7 +46,8 @@ def main() -> None:
                          "king-relative net of that width with seeded "
                          "weights (halfka3072 is the benchmark's), or "
                          "halfka_dual for the 3,072- and 128-wide pair "
-                         "Stockfish chooses between")
+                         "Stockfish chooses between, halfka_dual_int for "
+                         "that pair in its files' integers")
     opts = ap.parse_args()
     B, depth, variant = opts.B, opts.depth, opts.variant
     max_ply = opts.max_ply if opts.max_ply is not None else depth + 1
@@ -71,11 +74,12 @@ def main() -> None:
 
     roots = _roots_for(
         B, variant, "variant" if variant in FENS_VARIANT else "standard")
-    if opts.net == "halfka_dual":
+    if opts.net in ("halfka_dual", "halfka_dual_int"):
         from fishnet_tpu.models import nnue_import as ni
 
-        params = ni.DualNet.from_nets(
-            seeded_halfka(3072), seeded_halfka(128, seed=1)).as_device()
+        pair, draw = ((ni.DualNet, seeded_halfka) if opts.net == "halfka_dual"
+                      else (ni.DualNetInt, seeded_halfka_int))
+        params = pair.from_nets(draw(3072), draw(128, seed=1)).as_device()
     elif opts.net.startswith("halfka"):
         params = seeded_halfka(int(opts.net[len("halfka"):]))
     else:
@@ -154,6 +158,33 @@ def seeded_halfka(l1: int, seed: int = 0):
     return ni.StockfishNet(**{
         name: jax.random.normal(k, shape, jnp.float32) * scale
         for k, (name, (shape, scale)) in zip(keys, shapes.items())
+    })
+
+
+def seeded_halfka_int(l1: int, seed: int = 0):
+    """A StockfishNetInt of width l1 drawn on the device in a file's types,
+    each array uniform in +-its bound (times do not depend on the
+    values)."""
+    import jax
+    import jax.numpy as jnp
+
+    from fishnet_tpu.models import nnue_import as ni
+
+    i8, i16, i32 = jnp.int8, jnp.int16, jnp.int32
+    shapes = {
+        "ft_w": ((ni.NUM_FEATURES, l1), 16, i16), "ft_b": ((l1,), 64, i16),
+        "psqt_w": ((ni.NUM_FEATURES, ni.NUM_PSQT_BUCKETS), 2000, i32),
+        "fc0_w": ((ni.NUM_STACKS, ni.FC0_OUT, l1), 4, i8),
+        "fc0_b": ((ni.NUM_STACKS, ni.FC0_OUT), 2000, i32),
+        "fc1_w": ((ni.NUM_STACKS, ni.FC1_OUT, ni.FC1_IN), 40, i8),
+        "fc1_b": ((ni.NUM_STACKS, ni.FC1_OUT), 2000, i32),
+        "fc2_w": ((ni.NUM_STACKS, 1, ni.FC1_OUT), 10, i8),
+        "fc2_b": ((ni.NUM_STACKS, 1), 300, i32),
+    }
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+    return ni.StockfishNetInt(**{
+        name: jax.random.randint(k, shape, -bound, bound + 1, i32).astype(dtype)
+        for k, (name, (shape, bound, dtype)) in zip(keys, shapes.items())
     })
 
 
